@@ -86,12 +86,6 @@ GATE_TABLE: tuple[Gate, ...] = (
         reason="MLA/sparse/hybrid/window/sink attention has no SP path",
     ),
     Gate(
-        feature="flag:--compilation-cache-dir",
-        marker="persistent compilation cache disabled",
-        doc="docs/decode_loop.md",
-        reason="cache dir not writable or backend rejected it",
-    ),
-    Gate(
         feature="flag:--role",
         marker="kv-image handoff disabled: no host KV tier",
         doc="docs/disaggregation.md",
@@ -174,6 +168,24 @@ GATE_TABLE: tuple[Gate, ...] = (
         reason="MLA latent-page and MSA sparse-index prefill have their "
                "own dispatch chains; the fused ragged-prefill kernel "
                "covers the GQA page layout only",
+    ),
+    Gate(
+        feature="decode_fused",
+        marker="fused kernels disabled: no TPU lowering for this model",
+        doc="docs/kernels.md",
+        reason="TPU-auto only selects kernels the v5e compiler accepts: "
+               "MLA/DSA/MSA fused decode and GQA at head_dim not a "
+               "multiple of 128 are refused by Mosaic "
+               "(ops/kernel_select.fused_lowering_gap), so those models "
+               "keep the split dispatch chain",
+    ),
+    Gate(
+        feature="prefill_fused",
+        marker="fused kernels disabled: no TPU lowering for this model",
+        doc="docs/kernels.md",
+        reason="same lowering gap as decode_fused: the fused ragged "
+               "prefill kernel is refused at head_dim not a multiple "
+               "of 128",
     ),
     Gate(
         feature="prefill_seq_parallel",

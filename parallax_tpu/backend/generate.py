@@ -17,18 +17,11 @@ logger = get_logger(__name__)
 
 
 def generate_main(args) -> int:
-    import os
-
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from parallax_tpu.utils.compile_cache import enable_compilation_cache
 
     enable_compilation_cache(getattr(args, "compilation_cache_dir", None))
 
-    import jax.numpy as jnp
+    import jax
 
     from parallax_tpu.backend.http_server import IncrementalDecoder
     from parallax_tpu.config import load_config

@@ -163,6 +163,12 @@ def _stop_holdback(text: str, stops) -> int:
     return hold
 
 
+class BackendUnavailable(RuntimeError):
+    """Raised by a ``submit_fn`` whose backend can no longer serve (its
+    step loop died): the frontend answers 503, not the 429 of a full
+    queue."""
+
+
 class _GenFailed(Exception):
     """A request aborted or timed out before completing."""
 
@@ -331,6 +337,9 @@ class OpenAIFrontend:
         ])
         self._profiling = False
         self._profile_deadline_handle = None
+        # The loop ``run`` serves on, for the thread-safe ``shutdown``.
+        self._loop = None
+        self.app.on_startup.append(self._remember_loop)
 
         # Built-in web UI (setup/join/cluster/chat — reference src/frontend).
         from parallax_tpu.backend.webui import register_ui
@@ -344,6 +353,21 @@ class OpenAIFrontend:
         except Exception:  # pragma: no cover
             ui_models = [model_name]
         register_ui(self.app, ui_models)
+
+    async def _remember_loop(self, _app) -> None:
+        self._loop = asyncio.get_running_loop()
+
+    def shutdown(self, delay_s: float = 0.0) -> None:
+        """Make ``run`` return, from any thread: the backend is gone
+        and the process should exit. ``delay_s`` leaves handlers that
+        are already answering time to send their error responses."""
+        def _exit():
+            raise web.GracefulExit()
+
+        if self._loop is not None:
+            self._loop.call_soon_threadsafe(
+                self._loop.call_later, delay_s, _exit
+            )
 
     # -- endpoints ---------------------------------------------------------
 
@@ -369,8 +393,8 @@ class OpenAIFrontend:
             return web.json_response(
                 {"status": "unknown", "error": str(e)}, status=500
             )
-        status = 503 if summary.get("status") == "stalled" else 200
-        return web.json_response(summary, status=status)
+        sick = summary.get("status") in ("stalled", "failed")
+        return web.json_response(summary, status=503 if sick else 200)
 
     async def debug_timeline(self, request):
         """The merged cluster event timeline (obs/timeline.py): one
@@ -731,10 +755,20 @@ class OpenAIFrontend:
 
     # -- core generation ---------------------------------------------------
 
-    async def _generate(self, http_request, body: dict, prompt_text: str,
-                        chat: bool):
+    async def _generate(self, http_request, body: dict,
+                        prompt: str | list[int], chat: bool):
         rid = f"chatcmpl-{uuid.uuid4().hex[:16]}"
-        prompt_ids = self.tokenizer.encode(prompt_text)
+        if isinstance(prompt, list):
+            # OpenAI's token-array prompt, the input twin of the
+            # ``token_ids`` a choice carries: the only way to continue
+            # a stream whose ids the tokenizer has no text for.
+            if not all(type(t) is int and t >= 0 for t in prompt):
+                return self._error(
+                    400, "a token-array prompt holds non-negative integers"
+                )
+            prompt_ids = prompt
+        else:
+            prompt_ids = self.tokenizer.encode(prompt)
         if not prompt_ids:
             return self._error(400, "empty prompt")
         try:
@@ -821,6 +855,8 @@ class OpenAIFrontend:
             done = await asyncio.to_thread(self.submit_fn, req)
         except ValueError as e:
             return self._error(400, str(e))
+        except BackendUnavailable as e:
+            return self._error(503, str(e))
         except RuntimeError as e:
             return self._error(429, str(e))
         except asyncio.CancelledError:
@@ -894,6 +930,9 @@ class OpenAIFrontend:
             except ValueError as e:
                 await abandon(reqs)
                 return self._error(400, str(e))
+            except BackendUnavailable as e:
+                await abandon(reqs)
+                return self._error(503, str(e))
             except RuntimeError as e:
                 await abandon(reqs)
                 return self._error(429, str(e))
@@ -1180,6 +1219,9 @@ class OpenAIFrontend:
                 "finish_reason": reason,
             }
             obj = "text_completion"
+        # The committed ids themselves: text cannot show them where the
+        # tokenizer has no text for an id (byte fallback past 255).
+        choice["token_ids"] = list(req.output_ids)
         if lp is not None:
             choice["logprobs"] = lp
         return {
@@ -1198,6 +1240,10 @@ class OpenAIFrontend:
             "completion_tokens": req.num_output_tokens,
             "total_tokens": req.total_len,
             "tokens_per_second": round(req.num_output_tokens / elapsed, 2),
+            # Prompt tokens served from the prefix cache (OpenAI's field).
+            "prompt_tokens_details": {
+                "cached_tokens": req.num_cached_tokens,
+            },
         }
         if ttft_ms is not None:
             usage["ttft_ms"] = round(ttft_ms, 1)

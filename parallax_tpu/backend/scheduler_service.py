@@ -241,7 +241,7 @@ class SchedulerService:
                 bool(payload["busy"]) if "busy" in payload else None
             ),
             # Goodput ledger payload (token usefulness buckets + time
-            # taxonomy) — cluster-merged in /cluster/status.
+            # split) — cluster-merged in /cluster/status.
             goodput=(
                 payload["goodput"]
                 if isinstance(payload.get("goodput"), dict)

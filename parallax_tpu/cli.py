@@ -150,10 +150,10 @@ def build_parser() -> argparse.ArgumentParser:
     )
     serve.add_argument(
         "--compilation-cache-dir", default=None,
-        help="persistent XLA compilation cache directory (default: "
-             "$PARALLAX_TPU_COMPILE_CACHE or "
-             "~/.cache/parallax_tpu/xla_cache; 'off' disables) — "
-             "restarts reload compiled programs instead of paying a "
+        help="persistent XLA compilation cache directory; 'off' "
+             "disables. $JAX_COMPILATION_CACHE_DIR, where set, wins over "
+             "this flag; with neither the cache is <checkout>/.jax_cache "
+             "— restarts reload compiled programs instead of paying a "
              "recompilation storm",
     )
     serve.add_argument(
@@ -414,9 +414,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     join.add_argument(
         "--compilation-cache-dir", default=None,
-        help="persistent XLA compilation cache directory (default: "
-             "$PARALLAX_TPU_COMPILE_CACHE or "
-             "~/.cache/parallax_tpu/xla_cache; 'off' disables)",
+        help="persistent XLA compilation cache directory; 'off' "
+             "disables. $JAX_COMPILATION_CACHE_DIR, where set, wins over "
+             "this flag; with neither the cache is <checkout>/.jax_cache",
     )
     join.add_argument(
         "--watchdog", action="store_true",
@@ -478,9 +478,9 @@ def build_parser() -> argparse.ArgumentParser:
                           "(default auto-on-TPU — see docs/kernels.md)")
     gen.add_argument(
         "--compilation-cache-dir", default=None,
-        help="persistent XLA compilation cache directory (default: "
-             "$PARALLAX_TPU_COMPILE_CACHE or "
-             "~/.cache/parallax_tpu/xla_cache; 'off' disables)",
+        help="persistent XLA compilation cache directory; 'off' "
+             "disables. $JAX_COMPILATION_CACHE_DIR, where set, wins over "
+             "this flag; with neither the cache is <checkout>/.jax_cache",
     )
     gen.add_argument("--quantization", choices=["int8", "int4"],
                      default=None)

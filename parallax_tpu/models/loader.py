@@ -179,8 +179,14 @@ def load_stage_params(
     model: StageModel, model_path: str, dtype=jnp.bfloat16,
     quantize: str | None = None,
     lora_path: str | None = None,
+    mesh=None,
 ) -> dict:
     """Load this stage's weights from a local HF checkpoint directory.
+
+    With a TP ``mesh`` each full-precision tensor goes from host memory
+    straight to its shards (``parallel.tp.param_sharding``); without
+    one, to the default device. Staging the whole stage on one device
+    first would need that device to hold all of it.
 
     Quantized checkpoints (MLX affine format: packed-uint32 ``weight`` +
     ``scales``/``biases`` siblings, config ``quantization`` dict with
@@ -230,6 +236,20 @@ def load_stage_params(
     # compressed representation) are buffered until all parts arrive, so
     # host peak memory stays far below the stage's fp footprint.
     pending: dict[str, np.ndarray] = {}
+
+    def _to_device(local: str, arr: np.ndarray):
+        if mesh is None:
+            return jnp.asarray(arr).astype(dtype)
+        import jax
+
+        from parallax_tpu.parallel.tp import param_sharding
+
+        sharding = param_sharding(
+            mesh, tuple(local.split(".")), arr,
+            col_vecs=getattr(model, "tp_column_vector_params", frozenset()),
+        )
+        return jax.device_put(arr, sharding).astype(dtype)
+
     def _resolve(key: str) -> str | None:
         """THE stage-ownership filter (shared by file selection and the
         tensor loop): global checkpoint key -> local param path, or None
@@ -321,7 +341,7 @@ def load_stage_params(
             ):
                 pending[local] = arr
                 continue
-            _assign(tree, local, jnp.asarray(arr).astype(dtype))
+            _assign(tree, local, _to_device(local, arr))
             n_loaded += 1
 
     if fp8_weights:

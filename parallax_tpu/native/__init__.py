@@ -26,20 +26,20 @@ import threading
 
 import numpy as np
 
-from parallax_tpu.utils import get_logger
 from parallax_tpu.analysis.sanitizer import make_lock
-
-logger = get_logger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "radix_cache.cpp")
 _LIB_PATH = os.path.join(_HERE, "libradix.so")
 _lock = make_lock("native.build")
 _lib = None
-_build_failed = False
 
 
-def _build() -> bool:
+def _build() -> None:
+    """Compile ``radix_cache.cpp`` (the one committed source) next to
+    it. A failed build raises: the library is the default cache manager,
+    and a process that silently ran the other one would not be the
+    system its operator started."""
     # Compile to a process-unique temp path, then atomically rename: two
     # processes may build concurrently but never load a half-written .so.
     tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
@@ -47,30 +47,29 @@ def _build() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _LIB_PATH)
-        return True
-    except Exception as e:
-        logger.warning("native build failed (%s); using Python fallback", e)
-        try:
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed: {' '.join(cmd)}\n"
+            f"{e.stderr.decode(errors='replace')[-2000:]}"
+        ) from e
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
 
 
 def load_library():
-    """Load (building if needed) the native library, or None."""
-    global _lib, _build_failed
+    """Load (building if needed) the native library; None only when
+    ``PARALLAX_TPU_NO_NATIVE`` asks for the Python manager."""
+    global _lib
     if os.environ.get("PARALLAX_TPU_NO_NATIVE"):
         return None
     with _lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None:
             return _lib
         if not os.path.exists(_LIB_PATH) or (
             os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
         ):
-            if not _build():
-                _build_failed = True
-                return None
+            _build()
         lib = ctypes.CDLL(_LIB_PATH)
         i32p = ctypes.POINTER(ctypes.c_int32)
         sigs = {
@@ -514,6 +513,3 @@ class NativeCacheManager:
     def reset_prefix_cache(self) -> None:
         self.allocator.free(self.prefix_cache.reset())
 
-
-def native_available() -> bool:
-    return load_library() is not None

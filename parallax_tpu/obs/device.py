@@ -220,14 +220,11 @@ class HbmLedger:
         """Pull ``bytes_in_use`` / ``bytes_limit`` from the accelerator
         (TPU/GPU). Returns False when the backend exposes no stats
         (CPU) — the tracked sum then stands in for occupancy."""
-        try:
-            if device is None:
-                import jax
+        if device is None:
+            import jax
 
-                device = jax.local_devices()[0]
-            stats = device.memory_stats() or {}
-        except Exception:  # pragma: no cover - backend specific
-            return False
+            device = jax.local_devices()[0]
+        stats = device.memory_stats() or {}
         limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
         in_use = stats.get("bytes_in_use")
         if not limit and not in_use:
@@ -332,6 +329,9 @@ class CompileObservatory:
         self._pending = collections.deque(maxlen=64)
         self.compiles: dict[tuple, int] = {}
         self.compile_ms: dict[str, float] = {}
+        # Programs served from the persistent compilation cache: not
+        # compiles, so they stay out of every count above.
+        self.cache_hits = 0
         self._live_execs: dict[str, int] = {}
         self._window: dict[str, collections.deque] = {}
         self._window_s = float(storm_window_s)
@@ -415,6 +415,12 @@ class CompileObservatory:
             g.labels(program=family).set(count)
 
     # -- compile events ---------------------------------------------------
+
+    def on_cache_hit(self) -> None:
+        """One program loaded from the persistent compilation cache
+        instead of being compiled."""
+        with self._lock:
+            self.cache_hits += 1
 
     def on_compile(self, duration_s: float) -> None:
         """Attribute one ``backend_compile`` event (called from the JAX
@@ -527,6 +533,7 @@ class CompileObservatory:
             return {
                 "programs": by_program,
                 "compiles_total": total,
+                "cache_hits_total": self.cache_hits,
                 "unexplained_compiles": unexplained,
                 "compile_ms_total": round(
                     sum(self.compile_ms.values()), 3

@@ -19,7 +19,6 @@ sinks.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,26 +28,29 @@ from parallax_tpu.ops.ragged import page_chunks, ragged_token_positions
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _rpa_block_overrides() -> dict:
-    """Optional Pallas grid tuning for the bundled kernel, e.g.
-    ``PARALLAX_RPA_BLOCKS=4,32`` -> num_kv_pages_per_block=4,
-    num_queries_per_block=32. Default: kernel heuristics."""
-    spec = os.environ.get("PARALLAX_RPA_BLOCKS", "")
-    if not spec:
-        return {}
-    try:
-        nkv, nq = (int(x) for x in spec.split(","))
-        return {"num_kv_pages_per_block": nkv, "num_queries_per_block": nq}
-    except ValueError:
-        import warnings
+# The bundled kernel double-buffers ``num_kv_pages_per_block`` whole KV
+# pages in VMEM. Its own default (128 pages, taken when its tuning table
+# has no entry for the geometry) wants 33 MB at Qwen2.5-7B's 64-token
+# pages against the 16 MB a v5e kernel may scope, so the block sizes are
+# derived here from the shapes: as many pages as fit this budget.
+_RPA_KV_BUFFER_BYTES = 4 << 20
+_RPA_QUERIES_PER_BLOCK = 32
 
-        warnings.warn(
-            f"PARALLAX_RPA_BLOCKS={spec!r} is malformed (want 'NKV,NQ'); "
-            "using kernel default heuristics",
-            stacklevel=2,
-        )
-        return {}
 
+def _rpa_block_sizes(q: jax.Array, kv_pages: jax.Array,
+                     pages_per_seq: int) -> dict:
+    _, page_size, combined, head_dim = kv_pages.shape
+    itemsize = kv_pages.dtype.itemsize
+    # VMEM pads the combined-head dim to a whole sublane tile.
+    sublanes = 32 // itemsize
+    padded = -(-combined // sublanes) * sublanes
+    page_bytes = page_size * padded * head_dim * itemsize
+    return {
+        "num_kv_pages_per_block": max(
+            1, min(pages_per_seq, _RPA_KV_BUFFER_BYTES // (2 * page_bytes))
+        ),
+        "num_queries_per_block": min(q.shape[0], _RPA_QUERIES_PER_BLOCK),
+    }
 
 
 # Kernel-choice policy (TPU detection, use_pallas resolution, fused-mode
@@ -223,7 +225,7 @@ def ragged_paged_attention(
             sm_scale=sm_scale,
             sliding_window=sliding_window,
             soft_cap=soft_cap,
-            **_rpa_block_overrides(),
+            **_rpa_block_sizes(q, kv_pages, page_indices.shape[1]),
         )
     return _ragged_paged_attention_xla(
         q,

@@ -97,7 +97,7 @@ def _gqa_decode_kernel(
         # Per-KV-head dots (static unroll: Hkv is small).
         score_rows = []
         for h in range(num_kv_heads):
-            qh = jax.lax.dynamic_slice_in_dim(q, h * group, group, 0)
+            qh = q[h * group:(h + 1) * group]
             kh = kv[:, 2 * h, :]                   # [page, D]
             score_rows.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
@@ -108,7 +108,7 @@ def _gqa_decode_kernel(
         def weighted(p):
             out_rows = []
             for h in range(num_kv_heads):
-                ph = jax.lax.dynamic_slice_in_dim(p, h * group, group, 0)
+                ph = p[h * group:(h + 1) * group]
                 vh = kv[:, 2 * h + 1, :]           # [page, D]
                 out_rows.append(jax.lax.dot_general(
                     ph.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),

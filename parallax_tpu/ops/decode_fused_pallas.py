@@ -113,28 +113,30 @@ def online_softmax_update(
 ) -> None:
     """One online-softmax accumulation step over a page of scores.
 
-    ``scores``: f32[H, page] masked-input logits; ``valid``: bool
-    broadcastable to scores; ``weighted_values(p)`` maps the f32[H,
-    page] softmax numerators to the [H, D] value contribution (callers
-    own the GQA/MLA head grouping). Accumulators are VMEM scratch
-    ``m/l: f32[H, 1]``, ``o: f32[H, D]``.
+    ``scores``: f32[..., R, page] masked-input logits; ``valid``: bool
+    broadcastable to scores; ``weighted_values(p)`` maps the f32[..., R,
+    page] softmax numerators to the [..., R, D] value contribution
+    (callers own the GQA/MLA head grouping). Accumulators are VMEM
+    scratch ``m/l: f32[..., R, 1]``, ``o: f32[..., R, D]``. Every
+    intermediate keeps its trailing unit dim: Mosaic has no cheap
+    layout for rank-reduced row vectors.
     """
     scores = jnp.where(valid, scores, _NEG)
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1))
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new[:, None])
+    p = jnp.exp(scores - m_new)
     p = jnp.where(valid, p, 0.0)
-    l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-    o_ref[:, :] = o_ref[:, :] * alpha[:, None] + weighted_values(p)
-    m_ref[:, 0] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    o_ref[...] = o_ref[...] * alpha + weighted_values(p)
+    m_ref[...] = m_new
 
 
 def online_softmax_finish(l_ref, o_ref, out_ref) -> None:
     """Divide the accumulated numerator by the running denominator and
     write the row output (zeros for padding rows, whose l is 0)."""
-    out_ref[0, :, :] = (
-        o_ref[:, :] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+    out_ref[0] = (
+        o_ref[...] / jnp.maximum(l_ref[...], 1e-30)
     ).astype(out_ref.dtype)
 
 
@@ -249,7 +251,7 @@ def paged_decode_stream(
             (1, c, w), lambda i, pages, lens, slots: (i, 0, 0)
         ))
         inputs.append(append)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(cache)
 
     out_specs = []
@@ -268,7 +270,7 @@ def paged_decode_stream(
         )
     aliases = {}
     if with_append:
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         out_shape_structs.append(
             jax.ShapeDtypeStruct(cache.shape, cache.dtype)
         )
@@ -363,7 +365,7 @@ def gqa_fused_decode_pallas(
             valid = jnp.logical_and(valid, pos >= n - sliding_window)
         score_rows = []
         for h in range(num_kv_heads):
-            qh = jax.lax.dynamic_slice_in_dim(qrow, h * group, group, 0)
+            qh = qrow[h * group:(h + 1) * group]
             kh = rows[:, 2 * h, :]                    # [page, D]
             score_rows.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
@@ -376,7 +378,7 @@ def gqa_fused_decode_pallas(
         def weighted(p):
             out_rows = []
             for h in range(num_kv_heads):
-                ph = jax.lax.dynamic_slice_in_dim(p, h * group, group, 0)
+                ph = p[h * group:(h + 1) * group]
                 vh = rows[:, 2 * h + 1, :]            # [page, D]
                 out_rows.append(jax.lax.dot_general(
                     ph.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
@@ -569,35 +571,61 @@ def indexer_scores_fused_pallas(
 # --------------------------------------------------------------------------
 
 
-def _sample_kernel(logits_ref, gumbel_ref, temp_ref, topk_ref, out_ref):
-    lg = logits_ref[...]                              # [1, V] f32
-    v = lg.shape[1]
-    greedy = jnp.argmax(lg, axis=1).astype(jnp.int32)  # [1]
-    t = temp_ref[0, 0]
-    k = topk_ref[0, 0]
+_LANES = 128
+
+
+def _sample_kernel(temp_ref, topk_ref, logits_ref, gumbel_ref, out_ref,
+                   *, vocab: int):
+    """One row per program. The row's vocabulary is folded to
+    ``[V/128, 128]`` so it fills whole vector registers; every reduction
+    keeps its unit dims, and argmax is spelled max + lowest matching
+    token id (``jnp.argmax``'s first-occurrence rule)."""
+    i = pl.program_id(0)
+    lg = logits_ref[0]                                # [R, 128] f32
+    r = lg.shape[0]
+    ids = (
+        jax.lax.broadcasted_iota(jnp.int32, (r, _LANES), 0) * _LANES
+        + jax.lax.broadcasted_iota(jnp.int32, (r, _LANES), 1)
+    )                                                 # token id per cell
+    real = ids < vocab                                # lane padding off
+
+    def full_max(x):                                  # -> [1, 1]
+        return jnp.max(
+            jnp.max(x, axis=0, keepdims=True), axis=1, keepdims=True
+        )
+
+    def argmax(x):                                    # -> i32[1, 1]
+        hit = jnp.where(x == full_max(x), ids, jnp.int32(r * _LANES))
+        return jnp.min(
+            jnp.min(hit, axis=0, keepdims=True), axis=1, keepdims=True
+        )
+
+    lg = jnp.where(real, lg, _NEG_INF)
+    greedy = argmax(lg)
+    t = temp_ref[i]
+    k = topk_ref[i]
     scaled = lg / jnp.maximum(t, 1e-6)
     # k-th largest by iterative max extraction (k-1 removals): identical
     # to descending-sort[k-1] including duplicate handling, no sort.
-    need = jnp.logical_and(k > 0, k < v)
+    need = jnp.logical_and(k > 0, k < vocab)
     iters = jnp.where(need, jnp.maximum(k - 1, 0), 0)
 
     def drop_max(_, cur):
-        idx = jnp.argmax(cur, axis=1)
-        iota = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 1)
-        return jnp.where(iota == idx[:, None], _NEG, cur)
+        return jnp.where(ids == argmax(cur), _NEG, cur)
 
     red = jax.lax.fori_loop(0, iters, drop_max, scaled)
-    kth = jnp.max(red, axis=1)                        # [1]
-    thresh = jnp.where(need, kth, jnp.float32(_NEG))
+    thresh = jnp.where(need, full_max(red), jnp.float32(_NEG))
     # Value-threshold top-k (ties at the k-th value included) — the
     # exact filter ops/sampling.sample_tokens applies, so fused and
     # split draws agree bit-for-bit on the same logits.
-    keep = scaled >= thresh[:, None]
+    keep = scaled >= thresh
     filtered = jnp.where(keep, scaled, _SAMPLE_NEG_INF)
-    choice = jnp.argmax(filtered + gumbel_ref[...], axis=1).astype(
-        jnp.int32
+    choice = argmax(
+        jnp.where(real, filtered + gumbel_ref[0], _NEG_INF)
     )
-    out_ref[0, 0] = jnp.where(t <= 0.0, greedy, choice)[0]
+    out_ref[0] = jnp.broadcast_to(
+        jnp.where(t <= 0.0, greedy, choice), (1, _LANES)
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -618,20 +646,28 @@ def fused_sample_topk_pallas(
     (the engine gates them; see analysis/gates.py).
     """
     s, v = logits.shape
-    logits = logits.astype(jnp.float32)
-    temp = temperature.reshape(s, 1).astype(jnp.float32)
-    tk = top_k.reshape(s, 1).astype(jnp.int32)
+    rows = pl.cdiv(v, _LANES)
+    pad = rows * _LANES - v
+
+    def fold(x):
+        x = x.astype(jnp.float32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)))
+        return x.reshape(s, rows, _LANES)
+
+    row_block = pl.BlockSpec((1, rows, _LANES), lambda i, *_: (i, 0, 0))
     out = pl.pallas_call(
-        _sample_kernel,
-        grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
+        functools.partial(_sample_kernel, vocab=v),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(s,),
+            in_specs=[row_block, row_block],
+            out_specs=pl.BlockSpec((1, 1, _LANES), lambda i, *_: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((s, 1, _LANES), jnp.int32),
         interpret=interpret,
-    )(logits, gumbel.astype(jnp.float32), temp, tk)
-    return out[:, 0]
+    )(
+        temperature.astype(jnp.float32), top_k.astype(jnp.int32),
+        fold(logits), fold(gumbel),
+    )
+    return out[:, 0, 0]

@@ -40,11 +40,10 @@ _warned_prefill_auto_off = False
 
 
 def tpu_available() -> bool:
-    """True when the default JAX backend is a TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """True when the default JAX backend is a TPU. A backend that fails
+    to initialize raises here — "no TPU" is an answer only a working
+    backend may give."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve_use_pallas(use_pallas: bool | None) -> bool:
@@ -60,17 +59,58 @@ def fused_interpret() -> bool:
     return not tpu_available()
 
 
-def resolve_decode_fused(decode_fused: bool | None) -> bool:
-    """Engine-level fused-decode choice: None = auto-on-TPU; True forces
-    the fused kernels anywhere (interpret mode off-TPU); False = split.
+def fused_lowering_gap(config) -> str | None:
+    """Why TPU-auto must not select the fused Pallas family for this
+    model: the reason Mosaic refuses one of its kernels (v5e, JAX 0.9.0,
+    docs/kernels.md "Compiles for v5e"), or None when every fused kernel
+    the model would dispatch compiles. Decided from the model config —
+    the one thing the engine can observe before the first step."""
+    if config.is_mla:
+        return (
+            "fused MLA decode / DSA indexer kernels do not lower (their "
+            "one-row latent append is below the cache's HBM tiling)"
+        )
+    if config.msa is not None:
+        return (
+            "fused MSA indexer kernel does not lower ((1, page) score "
+            "blocks are below the (8, 128) block minimum)"
+        )
+    if config.head_dim % 128:
+        return (
+            f"fused GQA kernels do not lower at head_dim "
+            f"{config.head_dim} (page slices must fill 128 lanes)"
+        )
+    return None
+
+
+def _auto_fused(config, family: str) -> bool:
+    """TPU-auto for one fused family (``decode``/``prefill``): on when
+    the backend is a TPU and the model's kernels lower there."""
+    if not tpu_available():
+        return False
+    gap = fused_lowering_gap(config) if config is not None else None
+    if gap is not None:
+        logger.warning(
+            "%s-fused kernels disabled: no TPU lowering for this model "
+            "(%s); the split dispatch chain serves it", family, gap,
+        )
+        return False
+    return True
+
+
+def resolve_decode_fused(decode_fused: bool | None, config=None) -> bool:
+    """Engine-level fused-decode choice: None = auto (on on a TPU for a
+    model whose fused kernels lower, :func:`fused_lowering_gap`); True
+    forces the fused kernels anywhere (interpret mode off-TPU); False =
+    split.
 
     The single warning site for the non-TPU downgrade: auto mode on a
     CPU/GPU backend keeps the XLA reference path and says so once.
     """
     global _warned_non_tpu_fused, _warned_auto_off
     if decode_fused is None:
-        on = tpu_available()
-        if not on and not _warned_auto_off:
+        on = _auto_fused(config, "decode")
+        if not on and not tpu_available() and not _warned_auto_off:
             _warned_auto_off = True
             logger.info(
                 "decode-fused kernels disabled: non-TPU backend keeps "
@@ -88,7 +128,7 @@ def resolve_decode_fused(decode_fused: bool | None) -> bool:
     return bool(decode_fused)
 
 
-def resolve_prefill_fused(prefill_fused: bool | None) -> bool:
+def resolve_prefill_fused(prefill_fused: bool | None, config=None) -> bool:
     """Engine-level fused-prefill choice, mirroring
     :func:`resolve_decode_fused`: None = auto-on-TPU; True forces the
     fused ragged-prefill kernel anywhere (interpret mode off-TPU — the
@@ -100,8 +140,8 @@ def resolve_prefill_fused(prefill_fused: bool | None) -> bool:
     """
     global _warned_non_tpu_prefill, _warned_prefill_auto_off
     if prefill_fused is None:
-        on = tpu_available()
-        if not on and not _warned_prefill_auto_off:
+        on = _auto_fused(config, "prefill")
+        if not on and not tpu_available() and not _warned_prefill_auto_off:
             _warned_prefill_auto_off = True
             logger.info(
                 "prefill-fused kernels disabled: non-TPU backend keeps "

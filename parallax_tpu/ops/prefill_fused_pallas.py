@@ -45,16 +45,31 @@ from jax.experimental.pallas import tpu as pltpu
 from parallax_tpu.ops.decode_fused_pallas import _NEG, online_softmax_update
 from parallax_tpu.ops.ragged import ragged_token_positions
 
-# Default query-block edge: big enough to keep the MXU busy per page
-# DMA, small enough that the f32 [Bq*Hq, D] accumulator stays a few
-# hundred KB for typical head counts.
-_DEFAULT_Q_BLOCK = 128
+# Query-block sizing: the per-program working set (online-softmax
+# accumulators, scores, probabilities — all f32 with ``Hq * Bq`` rows)
+# has to stay well inside the 16 MB of scoped VMEM a v5e kernel gets,
+# and Mosaic unrolls every row into vector code, so compile time grows
+# with it too. The edge is therefore derived from the head count: the
+# largest power of two (at most _MAX_Q_BLOCK) that keeps ``Hq * Bq``
+# within _MAX_BLOCK_ROWS.
+_MAX_Q_BLOCK = 128
+_MAX_BLOCK_ROWS = 1024
+# Mosaic merges ``[G, Bq, D] -> [G * Bq, D]`` without a relayout only
+# when Bq fills whole packed sublane tiles (16 rows for bf16); smaller
+# token buckets are padded up to this many rows (padding rows carry
+# slot -1 and belong to no sequence).
+_MIN_Q_BLOCK = 16
 
 
-def _pick_q_block(num_tokens: int, q_block: int | None) -> int:
-    """Largest block <= the requested edge that divides the (bucketed,
-    normally power-of-two) token count; degrades to 1 for odd counts."""
-    bq = min(q_block or _DEFAULT_Q_BLOCK, num_tokens)
+def _pick_q_block(num_tokens: int, num_q_heads: int,
+                  q_block: int | None) -> int:
+    """Largest block <= the requested edge and the VMEM row budget that
+    divides the (bucketed, normally power-of-two) token count; degrades
+    to 1 for odd counts."""
+    cap = _MAX_Q_BLOCK
+    while cap > _MIN_Q_BLOCK and cap * num_q_heads > _MAX_BLOCK_ROWS:
+        cap //= 2
+    bq = min(q_block or cap, cap, num_tokens)
     while num_tokens % bq:
         bq -= 1
     return bq
@@ -90,17 +105,25 @@ def gqa_fused_prefill_pallas(
     prefill attention. Returns ``(out [T, Hq, D], kv_pages)``; when
     ``k_new`` is None the cache is returned untouched (attend-only
     mode, e.g. the sink-prefill path whose scatter already ran)."""
-    t, hq, d = q.shape
+    t_in, hq, d = q.shape
     _, page_size, combined, _ = kv_pages.shape
     num_kv_heads = combined // 2
     group = hq // num_kv_heads
     s, pages_per_seq = page_indices.shape
     with_append = k_new is not None
-    bq = _pick_q_block(t, q_block)
+    slot_mapping = slot_mapping.astype(jnp.int32)
+
+    # Token buckets below one packed sublane tile are padded up to it.
+    t = max(t_in, _MIN_Q_BLOCK)
+    if t != t_in:
+        pad = t - t_in
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        slot_mapping = jnp.pad(slot_mapping, (0, pad), constant_values=-1)
+        if with_append:
+            k_new = jnp.pad(k_new, ((0, pad), (0, 0), (0, 0)))
+            v_new = jnp.pad(v_new, ((0, pad), (0, 0), (0, 0)))
+    bq = _pick_q_block(t, hq, q_block)
     num_blocks = t // bq
-    if sinks is None:
-        sinks = jnp.zeros((hq,), jnp.float32)
-    sinks = sinks.reshape(1, hq).astype(jnp.float32)
 
     # Host-side ragged prep: which sequences does each block straddle?
     # (The kernel recovers per-token membership and causal positions
@@ -112,6 +135,15 @@ def gqa_fused_prefill_pallas(
         jnp.int32
     )
 
+    # Heads lead inside the kernel: a KV head's query group is then a
+    # slice of the untiled leading dim, and merging it with the token
+    # dim for the MXU is layout-free. XLA does the two transposes.
+    q_t = jnp.swapaxes(q, 0, 1)                           # [Hq, T, D]
+    if use_sinks:
+        sink_col = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(hq, 1, 1), (hq, bq, 1)
+        )
+
     if with_append:
         from parallax_tpu.ops.kv_cache_ops import interleave_kv
 
@@ -121,7 +153,8 @@ def gqa_fused_prefill_pallas(
                bounds_ref, *refs):
         pos = 0
         q_ref = refs[pos]; pos += 1
-        sinks_ref = refs[pos]; pos += 1
+        if use_sinks:
+            sinks_ref = refs[pos]; pos += 1
         if with_append:
             append_ref = refs[pos]; pos += 1
         cache_in_ref = refs[pos]; pos += 1
@@ -160,19 +193,15 @@ def gqa_fused_prefill_pallas(
             # Seed the sink as a virtual key (same trick as the decode
             # kernel): numerically identical to the XLA oracle's
             # finalize-time `l += exp(sink - m)`.
-            m_ref[:] = jnp.broadcast_to(
-                sinks_ref[...], (bq, hq)
-            ).reshape(bq * hq, 1)
-            l_ref[:] = jnp.ones_like(l_ref)
+            m_ref[...] = sinks_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
         else:
-            m_ref[:] = jnp.full_like(m_ref, _NEG)
-            l_ref[:] = jnp.zeros_like(l_ref)
-        o_ref[:] = jnp.zeros_like(o_ref)
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-        q_blk = q_ref[...]                                # [bq, hq, d]
-        tok_iota = tok0 + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, 1), 0
-        )[:, 0]                                           # i32[bq]
+        q_blk = q_ref[...]                                # [hq, bq, d]
+        tok = tok0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         s_lo = bounds_ref[i, 0]
         s_hi = jnp.minimum(bounds_ref[i, 1], nseq_ref[0] - 1)
 
@@ -180,18 +209,20 @@ def gqa_fused_prefill_pallas(
             n = lens_ref[seq]
             lo = cu_ref[seq]
             hi = cu_ref[seq + 1]
-            in_seq = jnp.logical_and(tok_iota >= lo, tok_iota < hi)
+            in_seq = jnp.logical_and(tok >= lo, tok < hi)  # [bq, 1]
             # Query position of each block token within seq's context:
             # the chunk's last token sits at n - 1, so position is
             # n - hi + token_index (garbage outside in_seq; masked).
-            qpos = n - hi + tok_iota
-            qmax = n - hi + jnp.minimum(hi - 1, tok0 + bq - 1)
-            qmin = n - hi + jnp.maximum(lo, tok0)
-            any_tok = jnp.any(in_seq)
-            hi_page = jnp.where(any_tok, (qmax + page_size) // page_size, 0)
+            qpos = n - hi + tok                           # [bq, 1]
+            first = jnp.maximum(lo, tok0)
+            last = jnp.minimum(hi, tok0 + bq) - 1
+            hi_page = jnp.where(
+                first <= last, (n - hi + last) // page_size + 1, 0
+            )
             if sliding_window is not None:
                 lo_page = (
-                    jnp.maximum(qmin - sliding_window + 1, 0) // page_size
+                    jnp.maximum(n - hi + first - sliding_window + 1, 0)
+                    // page_size
                 )
             else:
                 lo_page = 0
@@ -204,57 +235,48 @@ def gqa_fused_prefill_pallas(
                 cp.wait()
                 rows = page_scratch[...]                  # [page, 2Hkv, D]
                 base = j * page_size
-                score_rows = []
+                score_heads = []
                 for h in range(num_kv_heads):
-                    qh = jax.lax.dynamic_slice_in_dim(
-                        q_blk, h * group, group, 1
-                    ).reshape(bq * group, d)
+                    qh = q_blk[h * group:(h + 1) * group].reshape(
+                        group * bq, d
+                    )
                     kh = rows[:, 2 * h, :]                # [page, D]
-                    score_rows.append(jax.lax.dot_general(
+                    score_heads.append(jax.lax.dot_general(
                         qh, kh, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
-                    ).reshape(bq, group, page_size))
-                scores = jnp.concatenate(score_rows, axis=1) * sm_scale
+                    ).reshape(group, bq, page_size))
+                scores = jnp.concatenate(score_heads, axis=0) * sm_scale
                 if soft_cap is not None:
                     scores = soft_cap * jnp.tanh(scores / soft_cap)
-                scores = scores.reshape(bq * hq, page_size)
 
                 kv_pos = base + jax.lax.broadcasted_iota(
                     jnp.int32, (1, page_size), 1
                 )                                         # [1, page]
                 valid = jnp.logical_and(
-                    in_seq[:, None],
-                    jnp.logical_and(
-                        kv_pos <= qpos[:, None], kv_pos < n
-                    ),
-                )
+                    in_seq,
+                    jnp.logical_and(kv_pos <= qpos, kv_pos < n),
+                )                                         # [bq, page]
                 if sliding_window is not None:
                     valid = jnp.logical_and(
-                        valid, kv_pos > qpos[:, None] - sliding_window
+                        valid, kv_pos > qpos - sliding_window
                     )
-                valid = jnp.broadcast_to(
-                    valid[:, None, :], (bq, hq, page_size)
-                ).reshape(bq * hq, page_size)
 
-                def weighted(p):
-                    pg = p.reshape(bq, hq, page_size)
-                    out_rows = []
+                def weighted(p):                          # [hq, bq, page]
+                    out_heads = []
                     for h in range(num_kv_heads):
-                        ph = jax.lax.dynamic_slice_in_dim(
-                            pg, h * group, group, 1
-                        ).reshape(bq * group, page_size)
+                        ph = p[h * group:(h + 1) * group].reshape(
+                            group * bq, page_size
+                        )
                         vh = rows[:, 2 * h + 1, :]        # [page, D]
-                        out_rows.append(jax.lax.dot_general(
+                        out_heads.append(jax.lax.dot_general(
                             ph.astype(vh.dtype), vh,
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32,
-                        ).reshape(bq, group, d))
-                    return jnp.concatenate(out_rows, axis=1).reshape(
-                        bq * hq, d
-                    )
+                        ).reshape(group, bq, d))
+                    return jnp.concatenate(out_heads, axis=0)
 
                 online_softmax_update(
-                    m_ref, l_ref, o_ref, scores, valid, weighted
+                    m_ref, l_ref, o_ref, scores, valid[None], weighted
                 )
                 return inner
 
@@ -264,37 +286,37 @@ def gqa_fused_prefill_pallas(
         jax.lax.fori_loop(s_lo, s_hi + 1, seq_body, 0)
 
         out_ref[...] = (
-            o_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        ).reshape(bq, hq, d).astype(out_ref.dtype)
+            o_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(out_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec((bq, hq, d), lambda i, *_: (i, 0, 0)),
-        pl.BlockSpec((1, hq), lambda i, *_: (0, 0)),
-    ]
-    inputs: list = [q, sinks]
+    in_specs = [pl.BlockSpec((hq, bq, d), lambda i, *_: (0, i, 0))]
+    inputs: list = [q_t]
+    if use_sinks:
+        in_specs.append(pl.BlockSpec((hq, bq, 1), lambda i, *_: (0, 0, 0)))
+        inputs.append(sink_col)
     if with_append:
         in_specs.append(
             pl.BlockSpec((bq, combined, d), lambda i, *_: (i, 0, 0))
         )
         inputs.append(append)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(kv_pages)
 
-    out_specs = [pl.BlockSpec((bq, hq, d), lambda i, *_: (i, 0, 0))]
-    out_shapes = [jax.ShapeDtypeStruct((t, hq, d), q.dtype)]
+    out_specs = [pl.BlockSpec((hq, bq, d), lambda i, *_: (0, i, 0))]
+    out_shapes = [jax.ShapeDtypeStruct((hq, t, d), q.dtype)]
     aliases = {}
     if with_append:
-        out_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         out_shapes.append(
             jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype)
         )
-        # cache operand position: 6 scalar-prefetch + q + sinks + append.
-        aliases = {6 + 3: 1}
+        # cache operand position: 6 scalar-prefetch + the VMEM operands.
+        aliases = {6 + len(inputs) - 1: 1}
 
     scratch = [
-        pltpu.VMEM((bq * hq, 1), jnp.float32),
-        pltpu.VMEM((bq * hq, 1), jnp.float32),
-        pltpu.VMEM((bq * hq, d), jnp.float32),
+        pltpu.VMEM((hq, bq, 1), jnp.float32),
+        pltpu.VMEM((hq, bq, 1), jnp.float32),
+        pltpu.VMEM((hq, bq, d), jnp.float32),
         pltpu.VMEM((page_size, combined, d), kv_pages.dtype),
         pltpu.SemaphoreType.DMA,
     ]
@@ -319,10 +341,11 @@ def gqa_fused_prefill_pallas(
         kv_lens.astype(jnp.int32),
         cu_q_lens.astype(jnp.int32),
         num_seqs.astype(jnp.int32),
-        slot_mapping.astype(jnp.int32),
+        slot_mapping,
         block_bounds,
         *inputs,
     )
+    attn = jnp.swapaxes(out[0], 0, 1)[:t_in]              # [T, Hq, D]
     if with_append:
-        return out[0], out[1]
-    return out[0], kv_pages
+        return attn, out[1]
+    return attn, kv_pages

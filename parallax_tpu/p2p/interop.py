@@ -53,27 +53,15 @@ def _load_pb2():
         tmp_dir = f"{out}.{os.getpid()}.d"
         os.makedirs(tmp_dir, exist_ok=True)
         try:
-            try:
-                subprocess.run(
-                    ["protoc", f"-I{here}", f"--python_out={tmp_dir}",
-                     src],
-                    check=True, capture_output=True, timeout=60,
-                )
-            except (OSError, subprocess.SubprocessError):
-                # No protoc binary: the pip-installable compiler.
-                from grpc_tools import protoc as _gt
-
-                rc = _gt.main([
-                    "protoc", f"-I{here}", f"--python_out={tmp_dir}", src,
-                ])
-                if rc != 0:
-                    raise RuntimeError(f"grpc_tools.protoc rc={rc}")
+            subprocess.run(
+                ["protoc", f"-I{here}", f"--python_out={tmp_dir}", src],
+                check=True, capture_output=True, timeout=60,
+            )
             os.replace(os.path.join(tmp_dir, "interop_pb2.py"), out)
-        except Exception as e:
+        except (OSError, subprocess.SubprocessError) as e:
             raise ImportError(
                 "interop needs the generated protobuf module; protoc "
-                f"failed or is unavailable: {e}. Install protoc (or pip "
-                f"install grpcio-tools), or run: "
+                f"failed or is unavailable: {e}. Run: "
                 f"protoc -I {here} --python_out={here} {src}"
             ) from e
         finally:

@@ -3296,7 +3296,7 @@ class WorkerNode:
                     mnames.MIGRATION_MS,
                     "Park -> resume latency of migrated requests, ms",
                 ).observe(park_s * 1e3)
-                # Goodput time taxonomy: park->resume is churn overhead,
+                # Goodput time split: park->resume is churn overhead,
                 # not serving time.
                 from parallax_tpu.obs.goodput import get_goodput
 

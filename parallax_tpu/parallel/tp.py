@@ -157,6 +157,18 @@ def shard_params(
     )
 
 
+def param_sharding(
+    mesh: Mesh, path: tuple[str, ...], leaf,
+    col_vecs: frozenset = _NO_COL_VECS,
+) -> NamedSharding:
+    """Where ONE stage param lives on the mesh — the per-leaf form of
+    :func:`shard_params`, for the loader, which places each tensor as it
+    streams in so that no device ever stages the unsharded stage."""
+    return NamedSharding(
+        mesh, _spec_for(path, leaf, mesh.shape["tp"], col_vecs)
+    )
+
+
 def shard_kv_caches(kv: list, mesh: Mesh) -> list:
     return [jax.device_put(k, NamedSharding(mesh, KV_SPEC)) for k in kv]
 

@@ -65,8 +65,8 @@ def make_cache_manager(
     prefill_chunk_skip: bool = True,
 ):
     """CacheManager factory: the C++ manager (ONE ABI crossing per
-    admit/grow/release — ``native.NativeCacheManager``) by default when
-    the library builds; pure Python otherwise or with
+    admit/grow/release — ``native.NativeCacheManager``) by default — a
+    library that fails to build is an error — and pure Python with
     ``PARALLAX_TPU_NO_NATIVE=1``. Native measures ~3-16x faster in the
     production regime (full prefix cache under eviction pressure, growing
     with prompt length); the Python manager remains the behavioral oracle
@@ -112,19 +112,15 @@ def make_cache_manager(
             "(the native manager does not model tier residency)"
         )
     if use_native and host_tier is None:
-        try:
-            from parallax_tpu import native
+        from parallax_tpu import native
 
-            if native.native_available():
-                return native.NativeCacheManager(
-                    page_size, num_pages,
-                    enable_prefix_cache=enable_prefix_cache,
-                    max_model_len=max_model_len,
-                    linear_state=linear_state,
-                    on_slot_free=on_slot_free,
-                )
-        except Exception as e:  # pragma: no cover - env specific
-            logger.warning("native cache unavailable: %s", e)
+        return native.NativeCacheManager(
+            page_size, num_pages,
+            enable_prefix_cache=enable_prefix_cache,
+            max_model_len=max_model_len,
+            linear_state=linear_state,
+            on_slot_free=on_slot_free,
+        )
     return CacheManager(
         page_size, num_pages, enable_prefix_cache=enable_prefix_cache,
         max_model_len=max_model_len, linear_state=linear_state,
@@ -603,7 +599,7 @@ class CacheManager:
     @staticmethod
     def _goodput_swap(seconds: float, program: str = "swap_scatter") -> None:
         """Accrue host<->device KV transfer time into the goodput time
-        taxonomy and the per-program device-time split — ``swap_gather``
+        split and the per-program device-time split — ``swap_gather``
         is device->host (preemption park), ``swap_scatter`` is
         host->device (resume / admission swap-in). Never raises —
         metrics must not break serving."""

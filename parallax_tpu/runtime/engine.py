@@ -764,7 +764,9 @@ class StageEngine:
             IMPL_XLA as _IMPL_XLA,
         )
 
-        self._decode_fused = resolve_decode_fused(self.cfg.decode_fused)
+        self._decode_fused = resolve_decode_fused(
+            self.cfg.decode_fused, model.config
+        )
         self._attn_impl = decode_attn_impl(
             self._decode_fused, model.use_pallas
         )
@@ -773,7 +775,9 @@ class StageEngine:
         # prefill program. The GQA paged-attention block is the consumer;
         # MLA/MSA families keep their split prefill chain (registered
         # gate, analysis/gates.py).
-        self._prefill_fused = resolve_prefill_fused(self.cfg.prefill_fused)
+        self._prefill_fused = resolve_prefill_fused(
+            self.cfg.prefill_fused, model.config
+        )
         if self._prefill_fused and (
             model.config.is_mla or model.config.msa is not None
         ):
@@ -2019,6 +2023,8 @@ class StageEngine:
         /cluster/status: the active decode impl + per-(impl, path)
         dispatch counts, so a silent fallback to the split or XLA path
         is operator-visible."""
+        from parallax_tpu.ops.kernel_select import fused_interpret
+
         with self._kernel_lock:
             counts = dict(self._kernel_counts)
         return {
@@ -2026,6 +2032,12 @@ class StageEngine:
             "decode_fused": self._decode_fused,
             "prefill_impl": self._prefill_impl,
             "prefill_fused": self._prefill_fused,
+            # Fused kernels running in the Pallas interpreter (a forced
+            # off-TPU configuration), not compiled by Mosaic.
+            "interpret": (
+                (self._decode_fused or self._prefill_fused)
+                and fused_interpret()
+            ),
             "dispatch_total": {
                 f"{impl}/{path}": n
                 for (impl, path), n in sorted(counts.items())

@@ -6,7 +6,8 @@ engine's shape lattice; without a persistent cache each new process pays
 the full recompilation storm before serving its first token. The serving
 entrypoints (``serve``/``join``/``generate``/bench) therefore enable
 JAX's persistent compilation cache by default — executables land under a
-configurable directory and later processes load them from disk.
+directory placed from outside (``JAX_COMPILATION_CACHE_DIR``) or, failing
+that, at ``<checkout>/.jax_cache``, and later processes load them from disk.
 
 Compile OBSERVABILITY lives in :class:`parallax_tpu.obs.device
 .CompileObservatory`: this module's JAX monitoring listener feeds every
@@ -32,13 +33,26 @@ from parallax_tpu.analysis.sanitizer import make_lock
 
 logger = get_logger(__name__)
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# Where the cache lives when the environment does not place it: one
+# fixed path under the checkout. The directory is part of the cache
+# key, so a path built from $HOME, a temp name, a pid or a time would
+# never hit again.
 _DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "parallax_tpu", "xla_cache"
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
 )
+_OFF = ("off", "0", "none", "disabled")
 # JAX duration events fired once per backend compilation (jaxpr tracing
 # and MLIR lowering fire their own events; only the backend compile is
 # the expensive storm signal).
 _COMPILE_EVENT = "backend_compile"
+# JAX wraps the whole compile-or-load-from-cache call in that duration
+# event, so it fires for persistent-cache hits too; a hit announces
+# itself first, on the same thread, with this plain event.
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _lock = make_lock("utils.compile_cache")
 _active_path: str | None = None
@@ -47,32 +61,31 @@ _counter_registered = False
 
 def enable_compilation_cache(path: str | None = None) -> str | None:
     """Enable the persistent XLA compilation cache; returns the active
-    directory or None when disabled/unavailable. Never raises — cache
-    trouble must not take serving down.
+    directory, or None when ``path`` says ``"off"`` (or ``"0"`` /
+    ``"none"`` / an empty string).
 
-    ``path`` resolution: an explicit argument wins; else the
-    ``PARALLAX_TPU_COMPILE_CACHE`` env var; else
-    ``~/.cache/parallax_tpu/xla_cache``. Pass ``"off"`` (or ``"0"`` /
-    ``"none"`` / an empty string) to disable explicitly.
+    Placement is decided from outside first: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    this function sets no directory in code — ``path`` (the
+    ``--compilation-cache-dir`` flag) loses to it. Where it is unset,
+    the directory is ``path`` if given, else ``<checkout>/.jax_cache``.
     """
     global _active_path
-    if path is None:
-        path = os.environ.get("PARALLAX_TPU_COMPILE_CACHE", _DEFAULT_DIR)
-    if not path or str(path).lower() in ("off", "0", "none", "disabled"):
-        return None
-    try:
-        import jax
+    import jax
 
-        path = os.path.abspath(os.path.expanduser(str(path)))
+    if path is not None and (not path or str(path).lower() in _OFF):
+        return None
+    env_path = os.environ.get(ENV_VAR)
+    if env_path:
+        path = env_path
+    else:
+        path = os.path.abspath(os.path.expanduser(str(path or _DEFAULT_DIR)))
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        # Cache small entries too: the engine's lattice is many small
-        # programs, and the storm being avoided is exactly their sum.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - backend/version specific
-        logger.warning("persistent compilation cache disabled: %s", e)
-        return None
+    # Cache small entries too: the engine's lattice is many small
+    # programs, and the storm being avoided is exactly their sum.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     with _lock:
         _active_path = path
     register_compile_counter()
@@ -87,9 +100,9 @@ def active_cache_dir() -> str | None:
 
 def register_compile_counter() -> None:
     """Wire JAX's per-backend-compilation monitoring events into the
-    compile observatory (idempotent; never raises). Persistent-cache
-    HITS fire no event and so do not count — the series measures real
-    compile work only. Each event is attributed to the program family /
+    compile observatory (idempotent). Persistent-cache
+    HITS are counted apart (``cache_hits_total``) — the compile series
+    measures real compile work only. Each event is attributed to the program family /
     cause most recently declared via ``note_program`` and its duration
     lands in the goodput ledger's ``compile`` bucket."""
     global _counter_registered
@@ -97,24 +110,32 @@ def register_compile_counter() -> None:
         if _counter_registered:
             return
         _counter_registered = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        from parallax_tpu.obs.device import get_device_plane
-        from parallax_tpu.obs.goodput import get_goodput
+    from parallax_tpu.obs.device import get_device_plane
+    from parallax_tpu.obs.goodput import get_goodput
 
-        plane = get_device_plane()
-        plane.bind_registry()
-        goodput = get_goodput()
+    plane = get_device_plane()
+    plane.bind_registry()
+    goodput = get_goodput()
 
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            if _COMPILE_EVENT in event:
-                plane.compile.on_compile(duration)
-                # Goodput time taxonomy: compile seconds are not serve
-                # seconds — a recompile storm shows up as a goodput dip
-                # instead of hiding inside step latency.
-                goodput.add_time("compile", duration)
+    hit = threading.local()
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception as e:  # pragma: no cover - defensive; obs only
-        logger.debug("compile counter unavailable: %s", e)
+    def _on_event(event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            hit.pending = True
+
+    def _on_duration(event: str, duration: float, **kw) -> None:
+        if _COMPILE_EVENT in event:
+            if getattr(hit, "pending", False):
+                hit.pending = False
+                plane.compile.on_cache_hit()
+                return
+            plane.compile.on_compile(duration)
+            # Goodput time split: compile seconds are not serve
+            # seconds — a recompile storm shows up as a goodput dip
+            # instead of hiding inside step latency.
+            goodput.add_time("compile", duration)
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)

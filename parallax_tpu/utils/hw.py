@@ -16,7 +16,7 @@ TPU_CHIP_DB: dict[str, tuple[float, float, float, float]] = {
     "v5e": (197.0, 16.0, 819.0, 186.0),
     "v5p": (459.0, 95.0, 2765.0, 200.0),
     "v6e": (918.0, 32.0, 1640.0, 227.0),
-    "cpu": (1.0, 8.0, 50.0, 10.0),       # host fallback for tests
+    "cpu": (1.0, 8.0, 50.0, 10.0),       # the CPU platform (tests)
 }
 
 
@@ -47,22 +47,28 @@ class HardwareInfo:
         return cls(**d)
 
 
-def _device_kind_key(kind: str) -> str:
-    """Map a PJRT device_kind string to our spec-DB key.
+def _device_kind_key(platform: str, kind: str) -> str:
+    """Map a PJRT device (platform, device_kind) to our spec-DB key.
 
     JAX reports e.g. "TPU v4", "TPU v5 lite"/"TPU v5e", "TPU v5p"/"TPU v5",
-    "TPU v6 lite"/"TPU v6e".
+    "TPU v6 lite"/"TPU v6e". The ``cpu`` row is for the CPU platform
+    only; an accelerator the table does not know is an error, never a
+    guess — invented peaks would size real placements.
     """
-    kind = kind.lower()
-    if "v6" in kind:
-        return "v6e"
-    if "v5" in kind:
-        return "v5e" if ("lite" in kind or "v5e" in kind) else "v5p"
-    if "v4" in kind:
-        return "v4"
-    if "tpu" in kind:
-        return "v5e"
-    return "cpu"
+    if platform == "cpu":
+        return "cpu"
+    kind_l = kind.lower()
+    if platform == "tpu":
+        if "v6" in kind_l:
+            return "v6e"
+        if "v5" in kind_l:
+            return "v5e" if ("lite" in kind_l or "v5e" in kind_l) else "v5p"
+        if "v4" in kind_l:
+            return "v4"
+    raise ValueError(
+        f"unknown accelerator: platform {platform!r}, device_kind "
+        f"{kind!r} (add its peaks to utils/hw.py TPU_CHIP_DB)"
+    )
 
 
 def detect_hardware() -> HardwareInfo:
@@ -70,15 +76,13 @@ def detect_hardware() -> HardwareInfo:
     import jax
 
     devices = jax.local_devices()
-    kind = _device_kind_key(devices[0].device_kind if devices else "cpu")
+    kind = _device_kind_key(devices[0].platform, devices[0].device_kind)
     tflops, hbm, bw, ici = TPU_CHIP_DB[kind]
-    # Prefer live memory stats when the runtime exposes them.
-    try:
-        stats = devices[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            hbm = stats["bytes_limit"] / (1 << 30)
-    except Exception:
-        pass
+    # Live capacity where the runtime reports it (the CPU backend
+    # reports no memory stats).
+    stats = devices[0].memory_stats()
+    if stats and "bytes_limit" in stats:
+        hbm = stats["bytes_limit"] / (1 << 30)
     return HardwareInfo(
         device_kind=kind,
         num_chips=len(devices),
@@ -87,6 +91,30 @@ def detect_hardware() -> HardwareInfo:
         hbm_gbps=bw,
         ici_gbps=ici,
     )
+
+
+def device_report() -> dict:
+    """What JAX says this process runs on, for status payloads: the
+    first device's platform and kind, the device count, and each local
+    device's live memory counters (None where the backend has none)."""
+    import jax
+
+    devices = jax.devices()
+    per_device = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        per_device.append({
+            "id": d.id,
+            "bytes_in_use": stats.get("bytes_in_use"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "bytes_limit": stats.get("bytes_limit"),
+        })
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "devices": per_device,
+    }
 
 
 def host_available_memory_bytes() -> int:
@@ -128,17 +156,20 @@ def device_free_memory_bytes(fraction: float = 0.9) -> int:
 
     Reference counterpart: ``cache_manager._calculate_cache_allocation``
     reading device free memory (src/parallax/server/cache_manager.py:354-420).
+    An accelerator must report its own memory: only the CPU platform,
+    which has no memory stats, is budgeted from the table.
     """
     import jax
 
     dev = jax.local_devices()[0]
-    try:
-        stats = dev.memory_stats()
-        limit = stats.get("bytes_limit")
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
         used = stats.get("bytes_in_use", 0)
-        if limit:
-            return int((limit - used) * fraction)
-    except Exception:
-        pass
-    kind = _device_kind_key(dev.device_kind)
-    return int(TPU_CHIP_DB[kind][1] * (1 << 30) * fraction)
+        return int((stats["bytes_limit"] - used) * fraction)
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev.platform} device reports no memory limit "
+            f"(memory_stats() = {stats!r}); refusing to size the KV "
+            "pool from a table"
+        )
+    return int(TPU_CHIP_DB["cpu"][1] * (1 << 30) * fraction)

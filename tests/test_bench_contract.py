@@ -1,6 +1,7 @@
-"""Driver-contract smoke tests: bench.py prints one or more JSON lines
-(each an upgrade of the previous; the driver takes the LAST) with the
-required keys and exits 0; __graft_entry__.entry() must be
+"""Entry-contract smoke tests: ``bench.py`` runs its measurement once in
+a child process, prints the child's one JSON line with the required keys
+and exits with the child's code (``BENCH_CPU=1`` is the CPU fixture and
+says so in ``detail.platform``); ``__graft_entry__.entry()`` must be
 jit-lowerable."""
 
 import json
@@ -24,13 +25,10 @@ def test_bench_cpu_smoke_prints_one_json_line():
     for key in ("metric", "value", "unit", "vs_baseline"):
         assert key in rec, rec
     assert rec["value"] > 0
-    # The final (driver-visible) line records why there is no TPU number:
-    # the probe record carries attempts run, attempts skipped when the
-    # wall-clock budget (BENCH_TPU_PROBE_BUDGET_S) ran out, and the
-    # budget itself.
-    probe = rec["detail"]["tpu_probe"]
-    for key in ("attempts", "skipped", "budget_s"):
-        assert key in probe, probe
+    # One line, from the one child; the fixture labels itself as a CPU
+    # run (its number is a smoke value, never a device metric).
+    assert len(json_lines) == 1, json_lines
+    assert rec["detail"]["platform"] == "cpu", rec["detail"]["platform"]
     # Two-phase decode-loop telemetry is part of the bench contract.
     for key in ("host_ms_median", "device_ms_median", "overlapped_steps",
                 "sync_decode_dispatch_ms_median"):

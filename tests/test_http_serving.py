@@ -171,6 +171,35 @@ def test_completions_endpoint(frontend):
     with_client(frontend.app, fn)
 
 
+def test_completions_token_array_prompt_continues_a_stream(frontend):
+    """OpenAI's token-array ``prompt``: the ``token_ids`` a choice
+    carries go back in as they came out, so a greedy stream can be
+    continued (or replayed) where the tokenizer has no text for an id."""
+    text = "hello world"
+    base = {"max_tokens": 6, "temperature": 0, "ignore_eos": True}
+
+    async def fn(client):
+        status, whole = await _json(client, "POST", "/v1/completions",
+                                    dict(base, prompt=text))
+        assert status == 200, whole
+        ids = whole["choices"][0]["token_ids"]
+        assert len(ids) == 6
+        prompt_ids = SimpleTokenizer().encode(text) + ids[:3]
+        status, rest = await _json(
+            client, "POST", "/v1/completions",
+            dict(base, prompt=prompt_ids, max_tokens=3),
+        )
+        assert status == 200, rest
+        assert rest["usage"]["prompt_tokens"] == len(prompt_ids)
+        assert rest["choices"][0]["token_ids"] == ids[3:]
+        for bad in ([], [1, -2], [1, "2"], [1, 2.0], [True]):
+            status, body = await _json(client, "POST", "/v1/completions",
+                                       dict(base, prompt=bad))
+            assert status == 400, (bad, body)
+
+    with_client(frontend.app, fn)
+
+
 def test_n_choices(frontend):
     async def fn(client):
         status, body = await _json(client, "POST", "/v1/chat/completions",

@@ -315,6 +315,51 @@ def traced_frontend():
     runner.stop()
 
 
+def test_step_loop_failure_fails_requests_and_flips_healthz():
+    """A ``step_round()`` that raises (on a chip: a kernel the compiler
+    refuses at the first prefill) must not leave a live HTTP server
+    answering nothing: the waiting request gets a 5xx, ``/healthz``
+    reads 503, later submissions are refused, and ``on_failure`` — how
+    ``serve`` ends the process non-zero — is called."""
+    fe, runner = build_local_frontend(
+        build_engines([(0, 2)]), SimpleTokenizer(), model_name="tiny-fail",
+    )
+
+    def refused():
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    runner.pipeline.step_round = refused
+    failures = []
+    runner.on_failure = failures.append
+    chat = {"messages": [{"role": "user", "content": "hello"}],
+            "max_tokens": 4, "temperature": 0}
+
+    async def fn(client):
+        before = await client.get("/healthz")
+        resp = await asyncio.wait_for(
+            client.post("/v1/chat/completions", json=chat), timeout=60,
+        )
+        after = await client.get("/healthz")
+        again = await client.post("/v1/chat/completions", json=chat)
+        return (before.status, resp.status, await resp.json(),
+                after.status, await after.json(), again.status)
+
+    try:
+        before, status, body, after, health, again = with_client(fe.app, fn)
+    finally:
+        runner.stop()
+    assert before == 200
+    assert status == 502, body
+    assert "step loop failed" in body["error"]["message"], body
+    assert "Mosaic failed" in body["error"]["message"], body
+    assert after == 503, health
+    assert health["status"] == "failed", health
+    assert health["components"]["step_loop"]["status"] == "failed", health
+    assert again == 503
+    assert len(failures) == 1 and isinstance(failures[0], RuntimeError)
+    assert not runner._thread.is_alive()
+
+
 def test_metrics_endpoint_exposition(traced_frontend):
     async def fn(client):
         resp = await client.post(

@@ -19,6 +19,7 @@ from parallax_tpu.models.base import StageModel
 from parallax_tpu.ops.attention import _ragged_paged_attention_xla
 from parallax_tpu.ops.kv_cache_ops import reshape_and_cache
 from parallax_tpu.ops.prefill_fused_pallas import gqa_fused_prefill_pallas
+from parallax_tpu.parallel import make_mesh
 from parallax_tpu.runtime.checkpoint import (
     CheckpointError,
     build_resumed_request,
@@ -269,12 +270,6 @@ def test_chunk_skip_recomputes_zero_covered_chunks(gqa_model, monkeypatch,
                                                    manager, temp, seed):
     if manager == "python":
         monkeypatch.setenv("PARALLAX_TPU_NO_NATIVE", "1")
-    else:
-        pytest.importorskip("parallax_tpu.native")
-        from parallax_tpu.native import native_available
-
-        if not native_available():
-            pytest.skip("native cache manager not built")
     model, params = gqa_model
     a_on, b_on, eng_on = _run_chunk_skip_pair(
         model, params, chunk_skip=True, temp=temp, seed=seed)
@@ -454,16 +449,6 @@ def test_mid_prefill_wire_validation_rejects_bad_marks(gqa_model):
 SP_PROMPT = [int(x) for x in np.random.default_rng(3).integers(1, 198, 300)]
 
 
-def _make_mesh_or_skip(**kw):
-    """The SP/TP stack needs jax.shard_map; some pinned-jax environments
-    lack it (the same environments skip test_ring_attention.py)."""
-    try:
-        from parallax_tpu.parallel import make_mesh
-    except Exception as exc:
-        pytest.skip(f"SP/TP stack unavailable in this environment: {exc}")
-    return make_mesh(**kw)
-
-
 def _gen_one(engine, prompt):
     pipe = InProcessPipeline([engine])
     req = Request("r", prompt_ids=list(prompt),
@@ -489,7 +474,7 @@ def test_prefill_seq_parallel_matches_single_chip(gqa_model):
     sp_eng = StageEngine(
         model_b, params,
         EngineConfig(**base, prefill_seq_parallel=True, sp_threshold=256),
-        sp_mesh=_make_mesh_or_skip(sp_size=2, tp_size=1),
+        sp_mesh=make_mesh(sp_size=2, tp_size=1),
     )
     sp_out, sp_req = _gen_one(sp_eng, SP_PROMPT)
     assert sp_req.num_computed_tokens >= len(SP_PROMPT)   # one-step prefill
@@ -508,7 +493,7 @@ def test_prefill_seq_parallel_defaults_threshold(gqa_model):
         model, params,
         EngineConfig(page_size=8, num_pages=64, max_model_len=256,
                      kv_dtype="float32", prefill_seq_parallel=True),
-        sp_mesh=_make_mesh_or_skip(sp_size=2, tp_size=1),
+        sp_mesh=make_mesh(sp_size=2, tp_size=1),
     )
     assert eng.cfg.sp_threshold == 2048
     assert eng._sp_enabled

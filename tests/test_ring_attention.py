@@ -6,12 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# parallax_tpu.parallel binds jax.shard_map at import time; older jax
-# builds only ship it under jax.experimental — skip collection there.
-if not hasattr(jax, "shard_map"):
-    pytest.skip("jax.shard_map unavailable in this jax build",
-                allow_module_level=True)
-
 from parallax_tpu.parallel import make_mesh
 from parallax_tpu.parallel.sp import dense_causal_reference, ring_attention
 

@@ -10,12 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# parallax_tpu.parallel binds jax.shard_map at import time; older jax
-# builds only ship it under jax.experimental — skip collection there.
-if not hasattr(jax, "shard_map"):
-    pytest.skip("jax.shard_map unavailable in this jax build",
-                allow_module_level=True)
-
 from parallax_tpu.config import normalize_config
 from parallax_tpu.models.base import StageModel
 from parallax_tpu.parallel import make_mesh

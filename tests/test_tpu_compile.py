@@ -1,0 +1,308 @@
+"""The kernels TPU-auto selects for a dense GQA model, compiled by the
+installed TPU compiler for a *described* v5e (no chip attached) at
+Qwen2.5-7B widths and ``serve``'s defaults: 28/4 heads unsharded and the
+7/1 heads of one TP=4 shard, head_dim 128, bf16, page 64,
+``--max-model-len 8192`` (129 pages per sequence), vocab 152064.
+
+Interpret-mode parity (tests/test_decode_fused.py,
+tests/test_prefill_fused.py) says a kernel computes the right thing; it
+says nothing about whether Mosaic accepts it — block shapes below the
+(8, 128) tile, unaligned slices and scoped-VMEM limits are refused only
+here. Nothing runs, so this is not a chip run and measures nothing.
+The whole-step compiles (an unrolled N-layer program per shape bucket)
+live in ``chip_smoke.py``'s real run, not here. The kernels TPU-auto
+does NOT select because they do not lower are compiled too, expecting
+the compiler's refusal, so the gate cannot outlive its reason.
+
+The ops modules pick interpret mode from ``kernel_select.tpu_available``,
+which sees the CPU in this process; the tests pass ``interpret=False``
+to the kernels themselves.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from parallax_tpu.config import normalize_config
+from parallax_tpu.ops import kernel_select
+from parallax_tpu.ops.attention import _rpa_block_sizes
+from parallax_tpu.ops.attention_pallas import gqa_decode_attention_pallas
+from parallax_tpu.ops.decode_fused_pallas import (
+    fused_sample_topk_pallas,
+    gqa_fused_decode_pallas,
+)
+from parallax_tpu.ops.prefill_fused_pallas import gqa_fused_prefill_pallas
+
+HEAD_DIM, PAGE, PAGES_PER_SEQ, NUM_PAGES, VOCAB = 128, 64, 129, 1024, 152064
+HEADS = [(28, 4), (7, 1)]          # unsharded; one TP=4 shard
+HEAD_IDS = ["28q4kv", "tp4-7q1kv"]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device; the persistent compile cache is off
+    while this module runs (an entry compiled for a described device is
+    written but can never be read back without the chip)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"v5e:2x2 topology cannot be described: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Lower + compile for the described device; returns the HLO text."""
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _batch(dev, hq, hkv, t, s):
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    return dict(
+        q=a((t, hq, HEAD_DIM), jnp.bfloat16),
+        k=a((t, hkv, HEAD_DIM), jnp.bfloat16),
+        v=a((t, hkv, HEAD_DIM), jnp.bfloat16),
+        cache=a((NUM_PAGES, PAGE, 2 * hkv, HEAD_DIM), jnp.bfloat16),
+        kv_lens=a((s,), jnp.int32),
+        pages=a((s, PAGES_PER_SEQ), jnp.int32),
+        cu=a((s + 1,), jnp.int32),
+        nseq=a((1,), jnp.int32),
+        slots=a((t,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("s", [8, 64])
+@pytest.mark.parametrize("hq,hkv", HEADS, ids=HEAD_IDS)
+def test_fused_decode_compiles_for_v5e(v5e, hq, hkv, s):
+    b = _batch(v5e, hq, hkv, s, s)
+    _compile(
+        lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
+            q, k, v, cache, lens, pages, slots, None,
+            sm_scale=HEAD_DIM ** -0.5, interpret=False,
+        ),
+        b["q"], b["k"], b["v"], b["cache"], b["kv_lens"], b["pages"],
+        b["slots"],
+    )
+
+
+@pytest.mark.parametrize("t,s", [(256, 8), (2048, 64)])
+@pytest.mark.parametrize("hq,hkv", HEADS, ids=HEAD_IDS)
+def test_fused_prefill_compiles_for_v5e(v5e, hq, hkv, t, s):
+    b = _batch(v5e, hq, hkv, t, s)
+    _compile(
+        lambda q, k, v, cache, lens, pages, cu, nseq, slots:
+        gqa_fused_prefill_pallas(
+            q, k, v, cache, lens, pages, cu, nseq, slots, None,
+            sm_scale=HEAD_DIM ** -0.5, interpret=False,
+        ),
+        b["q"], b["k"], b["v"], b["cache"], b["kv_lens"], b["pages"],
+        b["cu"], b["nseq"], b["slots"],
+    )
+
+
+@pytest.mark.parametrize("s", [8, 64])
+def test_fused_sampler_compiles_for_v5e(v5e, s):
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    _compile(
+        functools.partial(fused_sample_topk_pallas, interpret=False),
+        a((s, VOCAB), jnp.float32), a((s, VOCAB), jnp.float32),
+        a((s,), jnp.float32), a((s,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("hq,hkv", HEADS, ids=HEAD_IDS)
+def test_bundled_ragged_attention_compiles_for_v5e(v5e, hq, hkv):
+    """What a speculative window's multi-token forward calls (and the
+    split path): the bundled kernel, with the block sizes
+    ``ops/attention`` derives from the shapes — its own default wants
+    33 MB of scoped VMEM at this geometry."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
+        ragged_paged_attention,
+    )
+
+    b = _batch(v5e, hq, hkv, 320, 64)      # 64 rows x (1 + 4 proposals)
+    blocks = _rpa_block_sizes(b["q"], b["cache"], PAGES_PER_SEQ)
+    _compile(
+        functools.partial(
+            ragged_paged_attention, sm_scale=HEAD_DIM ** -0.5, **blocks
+        ),
+        b["q"], b["cache"], b["kv_lens"], b["pages"], b["cu"], b["nseq"],
+    )
+
+
+def test_split_sink_decode_compiles_for_v5e(v5e):
+    """The split GQA decode kernel (sinks + sliding window) at head_dim
+    128; gpt-oss's own head_dim 64 is a recorded gap (docs/kernels.md)."""
+    b = _batch(v5e, 64, 8, 64, 64)
+    sinks = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=v5e)
+    _compile(
+        functools.partial(
+            gqa_decode_attention_pallas, sm_scale=HEAD_DIM ** -0.5,
+            sliding_window=128, use_sinks=True,
+        ),
+        b["q"], b["cache"], b["kv_lens"], b["pages"], sinks,
+    )
+
+
+def _refused_fused_gqa_head_dim_64(dev):
+    """gpt-oss geometry: 64 Q / 8 KV heads of 64 lanes."""
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    s, hq, hkv, d = 64, 64, 8, 64
+    return functools.partial(
+        gqa_fused_decode_pallas, sm_scale=d ** -0.5, interpret=False,
+    ), (
+        a((s, hq, d)), a((s, hkv, d)), a((s, hkv, d)),
+        a((NUM_PAGES, PAGE, 2 * hkv, d)), a((s,), jnp.int32),
+        a((s, PAGES_PER_SEQ), jnp.int32), a((s,), jnp.int32), None,
+    )
+
+
+def _refused_fused_mla(dev):
+    """DeepSeek-V2-Lite geometry: 16 heads, latent rank 512 + 64 rope."""
+    from parallax_tpu.ops.decode_fused_pallas import mla_fused_decode_pallas
+
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    s, hq, rank, rope = 64, 16, 512, 64
+    return functools.partial(
+        mla_fused_decode_pallas, sm_scale=0.1, kv_lora_rank=rank,
+        interpret=False,
+    ), (
+        a((s, hq, rank)), a((s, hq, rope)), a((s, rank)), a((s, rope)),
+        a((NUM_PAGES, PAGE, 1, rank + rope)), a((s,), jnp.int32),
+        a((s, PAGES_PER_SEQ), jnp.int32), a((s,), jnp.int32),
+    )
+
+
+def _refused_indexer(dev, kind, fused):
+    """The DSA / MSA sparse-attention indexers: 64 index heads of 128."""
+    from parallax_tpu.ops.decode_fused_pallas import (
+        indexer_scores_fused_pallas,
+    )
+    from parallax_tpu.ops.dsa_pallas import dsa_indexer_scores_decode_pallas
+    from parallax_tpu.ops.msa_pallas import msa_token_scores_decode_pallas
+
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    s, hi = 64, 64
+    q = a((s, hi, HEAD_DIM))
+    weights = a((s, hi), jnp.float32) if kind == "dsa" else None
+    cache = a((NUM_PAGES, PAGE, 1, HEAD_DIM))
+    lens, pages = a((s,), jnp.int32), a((s, PAGES_PER_SEQ), jnp.int32)
+    if fused:
+        fn = functools.partial(
+            indexer_scores_fused_pallas, reduce_kind=kind, interpret=False,
+            **({"sm_scale": 0.1} if kind == "msa" else {}),
+        )
+        return fn, (q, weights, a((s, HEAD_DIM)), cache, lens, pages,
+                    a((s,), jnp.int32))
+    if kind == "dsa":
+        return dsa_indexer_scores_decode_pallas, (
+            q, weights, cache, lens, pages)
+    return functools.partial(msa_token_scores_decode_pallas, sm_scale=0.1), (
+        q, cache, lens, pages)
+
+
+_TILE_8_128 = "last two dimensions of your block shape are divisible by 8"
+REFUSED = {
+    # id: (kernel and shapes, what the compiler says)
+    "fused-gqa-head-dim-64": (
+        _refused_fused_gqa_head_dim_64,
+        r"Slice shape along dimension 2 must be aligned to tiling \(128\), "
+        "but is 64",
+    ),
+    "fused-mla": (
+        _refused_fused_mla,
+        r"Slice shape along dimension 1 must be aligned to tiling \(2\), "
+        "but is 1",
+    ),
+    "split-dsa-indexer": (
+        functools.partial(_refused_indexer, kind="dsa", fused=False),
+        _TILE_8_128,
+    ),
+    "split-msa-indexer": (
+        functools.partial(_refused_indexer, kind="msa", fused=False),
+        _TILE_8_128,
+    ),
+    "fused-dsa-indexer": (
+        functools.partial(_refused_indexer, kind="dsa", fused=True),
+        _TILE_8_128,
+    ),
+    "fused-msa-indexer": (
+        functools.partial(_refused_indexer, kind="msa", fused=True),
+        _TILE_8_128,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(REFUSED))
+def test_recorded_lowering_gap_is_still_refused(v5e, case):
+    """The refusals ``kernel_select.fused_lowering_gap`` is written
+    around and docs/kernels.md "Compiles for v5e" records as NO, asked
+    of the installed compiler again. When one of these starts to
+    compile (a kernel was repaired, or the compiler moved), this test
+    fails: then lift the gate, the docs row and the ROADMAP entry."""
+    build, message = REFUSED[case]
+    fn, shapes = build(v5e)
+    with pytest.raises(Exception, match=message):
+        jax.jit(fn).lower(*shapes).compile()
+
+
+def _cfg(**kw):
+    return normalize_config(dict(dict(
+        architectures=["Qwen2ForCausalLM"], hidden_size=3584,
+        num_hidden_layers=2, num_attention_heads=28, num_key_value_heads=4,
+        intermediate_size=18944, vocab_size=152064,
+    ), **kw))
+
+
+def test_tpu_auto_selects_only_kernels_that_lower(monkeypatch):
+    """TPU-auto decides from the model config: fused for the dense GQA
+    geometry the tests above compile; split for the families whose fused
+    kernels Mosaic refuses. Explicit flags are not second-guessed."""
+    monkeypatch.setattr(kernel_select, "tpu_available", lambda: True)
+    dense = _cfg()
+    assert kernel_select.fused_lowering_gap(dense) is None
+    assert kernel_select.resolve_decode_fused(None, dense) is True
+    assert kernel_select.resolve_prefill_fused(None, dense) is True
+    narrow = _cfg(architectures=["GptOssForCausalLM"], head_dim=64,
+                  num_attention_heads=64, num_key_value_heads=8,
+                  hidden_size=2880)
+    assert "head_dim 64" in kernel_select.fused_lowering_gap(narrow)
+    assert kernel_select.resolve_decode_fused(None, narrow) is False
+    assert kernel_select.resolve_prefill_fused(None, narrow) is False
+    assert kernel_select.resolve_decode_fused(True, narrow) is True
+    assert kernel_select.resolve_decode_fused(False, dense) is False
+
+
+def test_tpu_available_does_not_hide_a_broken_backend(monkeypatch):
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="initialize backend"):
+        kernel_select.tpu_available()

@@ -8,6 +8,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from parallax_tpu.utils.request_metrics import parse_usage_chunk, request_metrics
 
@@ -390,3 +391,131 @@ def test_loader_fails_fast_on_missing_needed_shard(tmp_path):
     s0 = StageModel(cfg, 0, 1, use_pallas=False)
     loaded = load_stage_params(s0, str(ckpt), dtype=jnp.float32)
     assert len(loaded["layers"]) == 1
+
+
+# -- compile cache placement (utils/compile_cache.py) -----------------------
+
+
+@pytest.mark.parametrize("env_dir,flag,want_dir,sets_dir", [
+    # The environment places the cache: the program sets no directory
+    # in code, and the --compilation-cache-dir flag loses to it.
+    ("/x", None, "/x", False),
+    ("/x", "FLAG", "/x", False),
+    # Unplaced: the flag's directory, else <checkout>/.jax_cache — one
+    # fixed path (the path is part of the cache key).
+    (None, "FLAG", "FLAG", True),
+    (None, None, "CHECKOUT", True),
+    # "off" disables whatever the environment says.
+    ("/x", "off", None, False),
+    (None, "off", None, False),
+], ids=["env", "env-beats-flag", "flag", "checkout-default", "off-beats-env",
+        "off"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir, flag,
+                                 want_dir, sets_dir):
+    from parallax_tpu.utils import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    names = {"FLAG": str(tmp_path / "flag"),
+             "CHECKOUT": os.path.join(repo, ".jax_cache")}
+    flag, want_dir = names.get(flag, flag), names.get(want_dir, want_dir)
+    updates = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda key, value: updates.update({key: value})
+    )
+    monkeypatch.setattr(compile_cache, "_active_path", None)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+
+    assert compile_cache.enable_compilation_cache(flag) == want_dir
+    assert compile_cache.active_cache_dir() == want_dir
+    if sets_dir:
+        assert updates["jax_compilation_cache_dir"] == want_dir
+        assert os.path.isdir(want_dir)
+    else:
+        assert "jax_compilation_cache_dir" not in updates
+        assert not os.path.exists(names["FLAG"])
+    if want_dir is not None:
+        # Small programs are cached too, wherever the cache lives.
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+
+def test_persistent_cache_hits_are_not_counted_as_compiles():
+    """JAX fires its backend-compile duration event around the whole
+    compile-or-load-from-cache call, so it fires for persistent-cache
+    hits too; the hit announces itself first on the same thread. The
+    compile series must count real compiles only."""
+    from jax import monitoring
+
+    from parallax_tpu.obs.device import get_device_plane
+    from parallax_tpu.utils import compile_cache
+
+    compile_cache.register_compile_counter()
+    observatory = get_device_plane().compile
+    before = observatory.snapshot()
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25
+    )
+    after_hit = observatory.snapshot()
+    assert after_hit["cache_hits_total"] == before["cache_hits_total"] + 1
+    assert after_hit["compiles_total"] == before["compiles_total"]
+    assert after_hit["compile_ms_total"] == before["compile_ms_total"]
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.25
+    )
+    after_miss = observatory.snapshot()
+    assert after_miss["cache_hits_total"] == after_hit["cache_hits_total"]
+    assert after_miss["compiles_total"] == after_hit["compiles_total"] + 1
+
+
+# -- hardware table (utils/hw.py): no guessed accelerator -------------------
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", "cpu"),
+    ("tpu", "TPU v5 lite", "v5e"),
+    ("tpu", "TPU v5e", "v5e"),
+    ("tpu", "TPU v5p", "v5p"),
+    ("tpu", "TPU v6 lite", "v6e"),
+    ("tpu", "TPU v4", "v4"),
+    # An accelerator the table does not know is an error: not a v5e
+    # because its name holds "tpu", not the CPU row by default.
+    ("tpu", "TPU v9 mega", None),
+    ("gpu", "NVIDIA H100", None),
+])
+def test_device_kind_is_looked_up_never_guessed(platform, kind, want):
+    from parallax_tpu.utils.hw import _device_kind_key
+
+    if want is None:
+        with pytest.raises(ValueError, match="unknown accelerator"):
+            _device_kind_key(platform, kind)
+    else:
+        assert _device_kind_key(platform, kind) == want
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 1000, "bytes_in_use": 200}, 400),
+    # The CPU platform reports no memory: budgeted from its table row.
+    ("cpu", None, 4 << 30),
+    # A TPU that reports none must not have its KV pool sized from one.
+    ("tpu", None, None),
+    ("tpu", {}, None),
+])
+def test_kv_budget_comes_from_the_device_not_the_table(
+        monkeypatch, platform, stats, want):
+    from parallax_tpu.utils import hw
+
+    class Dev:
+        def memory_stats(self):
+            return stats
+
+    Dev.platform = platform
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev()])
+    if want is None:
+        with pytest.raises(RuntimeError, match="reports no memory limit"):
+            hw.device_free_memory_bytes(0.5)
+    else:
+        assert hw.device_free_memory_bytes(0.5) == want
