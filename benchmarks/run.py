@@ -1,0 +1,280 @@
+#!/usr/bin/env python3
+"""Run one cell of the benchmark once.
+
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+The parent (this process) never touches the chip: it reads the cell's
+files, starts the child that holds the chip
+(``benchmarks/harness/server_child.py``: ``serve`` with weights made from
+the seed), checks it against the plain reference, warms it up, offers the
+cell's traffic for ``--seconds`` over HTTP, and does all the arithmetic.
+The LAST line of standard output is one JSON object (``correct``,
+``attempted``, ``failed``, ``metrics``, ``device`` and, with
+``--trace 1``, ``breakdown``). Without a TPU holding the chips the cell
+asks for, the exit code is not 0 and no result is printed.
+
+``--rehearse`` is the CPU dress rehearsal (toy widths, traffic cut to
+size): it says ``"platform": "cpu"`` and prints no device metric.
+
+Phases: spawn -> ready -> reference check -> warm-up (lattice walk, then
+the traffic's own warm phase) -> window -> drain -> stop. ``setup_s`` runs
+from process start to the opening of the window.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import json
+import os
+import shutil
+import sys
+import time
+
+T_START = time.monotonic()
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks.harness import loadgen, metrics, spec, warmup, work  # noqa: E402
+from benchmarks.harness.server import (  # noqa: E402
+    BenchFailed,
+    Server,
+    check,
+    repeat_agrees,
+    replay_reference,
+)
+
+# The traced span: a few seconds inside the window (a trace of the whole
+# window would be hundreds of MB and slow the host throughout).
+TRACE_OFFSET_S = 2.0
+TRACE_SECONDS = 4.0
+
+
+def log(**record) -> None:
+    print(json.dumps(record), file=sys.stderr, flush=True)
+
+
+async def http_json(http, method: str, url: str, body=None, timeout=120.0):
+    import aiohttp
+
+    async with http.request(
+        method, url, json=body, timeout=aiohttp.ClientTimeout(total=timeout),
+    ) as r:
+        text = await r.text()
+        check(r.status == 200, f"{method} {url} -> {r.status}", body=text[:300])
+        return text
+
+
+async def measure(srv: Server, traffic: loadgen.Traffic, trace: bool,
+                  trace_dir: str) -> dict:
+    """The window. Returns the run's raw material."""
+    got: dict = {}
+
+    def scrape(tag: str):
+        async def fn():
+            text = await http_json(run.http, "GET", srv.base + "/metrics")
+            got["scrape_" + tag] = metrics.parse_prometheus(text)
+            got["t_" + tag] = time.monotonic()
+            if tag in ("w0", "w1"):
+                got["status_" + tag] = json.loads(await http_json(
+                    run.http, "GET", srv.base + "/cluster/status_json"))
+        return fn
+
+    async def trace_start():
+        await scrape("t0")()
+        await http_json(run.http, "POST", srv.base + "/profile/start",
+                        {"dir": trace_dir, "max_seconds": 60})
+        got["t_trace0"] = time.monotonic()
+
+    async def trace_stop():
+        got["t_trace1"] = time.monotonic()
+        await http_json(run.http, "POST", srv.base + "/profile/stop", {},
+                        timeout=300.0)
+        await scrape("t1")()
+
+    hooks = {"w0": scrape("w0"), "w1": scrape("w1"), "at": []}
+    if trace:
+        span = min(TRACE_SECONDS, max(0.5, traffic.seconds / 3))
+        off = traffic.warm + min(TRACE_OFFSET_S, traffic.seconds / 4)
+        hooks["at"] = [(off, trace_start), (off + span, trace_stop)]
+    run = loadgen.Run(traffic, srv.base, hooks)
+    await run.go()
+    got["run"] = run
+    return got
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU dress rehearsal at toy widths (no chip run)")
+    ap.add_argument("--benchmark-json", default=None, help=argparse.SUPPRESS)
+    ap.add_argument("--keep-work", action="store_true", help=argparse.SUPPRESS)
+    args = ap.parse_args()
+
+    bench = spec.load(args.benchmark_json)
+    check(args.workload in bench["cells"], f"unknown workload {args.workload}")
+    cell = bench["cells"][args.workload]
+    config = bench["configs"][cell["config"]]
+    traffic_spec = cell["traffic_spec"]
+    hf = dict(config["hf"])
+    flags = list(config["bench"]["serve_flags"])
+    if args.rehearse:
+        from benchmarks.harness.server_child import REHEARSE_FLAGS
+
+        hf.update(config["bench"]["rehearse"])
+        flags += REHEARSE_FLAGS
+    sizes = warmup.serve_sizes(flags)
+
+    work_dir = os.path.join(ROOT, ".bench_work", args.workload)
+    shutil.rmtree(work_dir, ignore_errors=True)
+    os.makedirs(work_dir)
+    trace_dir = os.path.join(work_dir, "trace")
+
+    traffic = loadgen.Traffic(traffic_spec, hf["vocab_size"], args.seed,
+                              args.seconds, rehearse=args.rehearse)
+    srv = Server(work_dir, spec.config_path(cell["config"]), args.seed,
+                 cell["chips"], args.rehearse)
+    phases = {}
+    try:
+        phases["ready_s"] = srv.wait_ready(1100)
+        device = srv.device()
+        if not args.rehearse:
+            check(device["platform"] == "tpu"
+                  and device["count"] == cell["chips"],
+                  "not the chips the cell asks for", device=device)
+            peaks = spec.peaks_for(device["kind"])
+        else:
+            peaks = None
+
+        t = time.monotonic()
+        with open(os.path.join(work_dir, "reference.json")) as f:
+            ref = json.load(f)
+        ref_check = replay_reference(srv, ref["rows"])
+        repeat_ok = repeat_agrees(
+            srv, traffic._tokens(3 * 64 + 5), 2 * warmup.DECODE_K)
+        phases["reference_s"] = time.monotonic() - t
+        log(phase="reference", **ref_check, repeat_identical=repeat_ok,
+            reference_seconds=ref["seconds"])
+
+        t = time.monotonic()
+        walked = asyncio.run(warmup.walk(srv.base, traffic, sizes))
+        phases["walk_s"] = time.monotonic() - t
+        log(phase="warmup", **walked)
+        check(walked["failed"] == 0, "warm-up requests failed", **walked)
+
+        got = asyncio.run(measure(srv, traffic, bool(args.trace), trace_dir))
+        run = got["run"]
+        setup_s = run.w0 - T_START
+        status = srv.status()
+    except BenchFailed as e:
+        print(f"benchmark FAILED: {e}", file=sys.stderr, flush=True)
+        srv.close()
+        return 1
+    except BaseException:
+        srv.close()
+        raise
+    code = srv.close()
+    log(phase="stopped", server_exit=code)
+
+    client = metrics.end_to_end(run.results, run.w0, run.w1, cell["chips"])
+    # How much of the preallocated KV pool the traffic's live context
+    # fills (the allocator's peak counts the whole pool, used or not).
+    live = {k: metrics.live_context_tokens(run.results, t)
+            for k, t in (("w0", run.w0), ("w1", run.w1))}
+    pool_tokens = status["stages"][0]["num_pages"] * sizes["page_size"]
+    client["kv_live_share"] = 100.0 * live["w1"] / pool_tokens
+    hw = status["hardware"]
+    peak = max((d.get("peak_bytes_in_use") or 0) for d in hw["devices"])
+    device_out = {"platform": device["platform"], "kind": device["kind"],
+                  "count": device["count"], "memory_peak_bytes": peak}
+    not_ok = [r for r in run.results if not r.ok]
+    correct = bool(
+        ref_check["positions_agreed"] + ref_check["ties"] > 0
+        and repeat_ok and client["failed"] == 0 and client["attempted"] > 0
+    )
+
+    values: dict[str, float] = {}
+    breakdown = None
+    # A rehearsal reports counts only: a time or a rate from the CPU is
+    # never written under a device metric's name.
+    def reported(m):
+        return args.workload in m["cells"] and (
+            not args.rehearse or m["source"] == "program_counter")
+
+    if not args.trace:
+        client["setup_s"] = setup_s
+        for name, m in bench["end_to_end"].items():
+            if reported(m) and client.get(name) is not None:
+                values[name] = client[name]
+    else:
+        red = None
+        if not args.rehearse:
+            from benchmarks.harness import trace_reduce
+
+            red = trace_reduce.reduce_trace(trace_dir)
+            check(red is not None, "the trace holds no device operation")
+            device_out["busy_s"] = red["busy_s"]
+            device_out["window_s"] = got["t_trace1"] - got["t_trace0"]
+            breakdown = trace_reduce.breakdown(red)
+        ctx = {
+            "scrape_w0": got.get("scrape_w0"), "scrape_w1": got.get("scrape_w1"),
+            "scrape_t0": got.get("scrape_t0"), "scrape_t1": got.get("scrape_t1"),
+            "status_w0": got.get("status_w0"), "status_w1": got.get("status_w1"),
+            "client": client, "trace": red, "model": hf, "peaks": peaks,
+            "span_work": work.span_work(
+                run.results, got.get("t_trace0", 0), got.get("t_trace1", 0),
+                hf) if red is not None else None,
+        }
+        for name, m in bench["per_layer"].items():
+            if not reported(m):
+                continue
+            v = metrics.read_layer_metric(m["reader"], ctx)
+            if v is not None:
+                values[name] = v
+    units = {**{k: v["unit"] for k, v in bench["end_to_end"].items()},
+             **{k: v["unit"] for k, v in bench["per_layer"].items()}}
+    if args.keep_work:
+        with open(os.path.join(work_dir, "requests.jsonl"), "w") as f:
+            for r in run.results:
+                f.write(json.dumps({
+                    "due": r.due_t - run.w0, "sent": r.sent_t - run.w0,
+                    "first": r.first_t and r.first_t - run.w0,
+                    "last": r.last_t and r.last_t - run.w0,
+                    "n": r.n_tokens, "want": r.req.max_tokens,
+                    "prompt": len(r.req.prompt), "judged": r.req.judged,
+                    "error": r.error}) + "\n")
+    else:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        shutil.rmtree(os.path.join(work_dir, "model"), ignore_errors=True)
+
+    log(phase="summary", phases=phases, setup_s=setup_s, client=client,
+        live_context_tokens=live,
+        in_flight=run.in_flight_at, not_ok=len(not_ok),
+        first_errors=[r.error for r in not_ok if r.error][:3],
+        kv_pages=status["stages"][0]["num_pages"],
+        kv_occupancy={k: got["scrape_" + k].get("parallax_kv_page_occupancy")
+                      for k in ("w0", "w1")},
+        compile=status["device"]["compile"]["compiles_total"],
+        cache_hits=status["device"]["compile"]["cache_hits_total"])
+    result = {
+        "correct": correct,
+        "attempted": client["attempted"],
+        "failed": client["failed"],
+        "metrics": {k: {"value": v, "unit": units[k]}
+                    for k, v in values.items()},
+        "device": device_out,
+    }
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
