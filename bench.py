@@ -2394,7 +2394,7 @@ def _bench():
             submitted.append(req)
             pipe.submit(req)
         dispatch_times: list[float] = []
-        device_times: list[float] = []
+        readback_wait_times: list[float] = []
         wall_times: list[float] = []
         overlapped_steps = 0
         ttft_ms: dict[str, float] = {}
@@ -2413,7 +2413,7 @@ def _bench():
                         ttft_ms[req.request_id] = (now - t_start) * 1000.0
                 if decode_t0 is not None and out.num_tokens:
                     dispatch_times.append(out.host_ms)
-                    device_times.append(out.device_ms)
+                    readback_wait_times.append(out.readback_wait_ms)
                     wall_times.append(out.step_time_ms)
                     overlapped_steps += int(out.overlapped)
                 elif decode_t0 is None:
@@ -2430,7 +2430,7 @@ def _bench():
             decode_tokens=total_tokens - tokens_at_decode_start,
             decode_wall_s=decode_wall_s,
             dispatch_times=dispatch_times,
-            device_times=device_times,
+            readback_wait_times=readback_wait_times,
             wall_times=wall_times,
             overlapped_steps=overlapped_steps,
             phase_ok=decode_t0 is not None,
@@ -2868,9 +2868,9 @@ def _bench():
                 statistics.median(r["wall_times"])
                 if r["wall_times"] else 0.0, 2,
             ),
-            "device_ms_median": round(
-                statistics.median(r["device_times"])
-                if r["device_times"] else 0.0, 3,
+            "readback_wait_ms_median": round(
+                statistics.median(r["readback_wait_times"])
+                if r["readback_wait_times"] else 0.0, 3,
             ),
             "overlapped_steps": r["overlapped_steps"],
             # Prefix-cache / memory-tier counters from the measured

@@ -138,14 +138,19 @@ def _scaled(traffic: dict, rehearse: bool) -> dict:
 
 
 class Traffic:
-    """The requests of one run: a warm phase, then the window."""
+    """The requests of one run: a warm phase, then the window, then
+    (``--trace 2``) a tail of ``tail`` seconds of the same traffic, in
+    which nothing is measured from the client's side. The tail changes
+    no request of warm phase or window: its own requests are drawn after
+    theirs (closed loop) or from a generator of its own (open loop)."""
 
     def __init__(self, traffic: dict, vocab: int, seed: int, seconds: float,
-                 rehearse: bool = False):
+                 rehearse: bool = False, tail: float = 0.0):
         self.spec = t = _scaled(traffic, rehearse)
         self.vocab = vocab
         self.seconds = float(seconds)
         self.warm = float(t.get("warm_seconds", 0.0))
+        self.tail = float(tail)
         self.rng = np.random.default_rng([int(seed), 0x10AD])
         self.sampling = {k: v for k, v in t["sampling"].items()
                          if k != "seed"}
@@ -162,6 +167,13 @@ class Traffic:
             self._closed_sizes()
         else:
             self.schedule = self._open_schedule()
+            if self.tail > 0:
+                window_rng = self.rng
+                self.rng = np.random.default_rng([int(seed), 0x7A11])
+                self.schedule += self._open_phase(
+                    "tail", self.warm + self.seconds, self.tail,
+                    first_session=len(self.schedule))
+                self.rng = window_rng
 
     # -- pieces -----------------------------------------------------------
 
@@ -190,25 +202,32 @@ class Traffic:
     # -- open loop ----------------------------------------------------------
 
     def _open_schedule(self) -> list[Req]:
-        arr = self.spec["arrival"]
         out: list[Req] = []
         for what, start, length in (("warm", 0.0, self.warm),
                                     ("window", self.warm, self.seconds)):
-            n = int(round(float(arr["rate_rps"]) * length))
-            if n <= 0:
-                continue
-            gaps = self.rng.permutation(
-                arrival_gaps(arr, n, _fixed_rng(self.spec, what + "gaps")))
-            due = start + np.cumsum(gaps) - gaps
-            p, o, shared = self._sizes(n, what)
-            for i in range(n):
-                req = Req(due=float(due[i]),
-                          prompt=self._prompt(p[i], bool(shared[i])),
-                          max_tokens=int(o[i]), seed=self._seed(),
-                          judged=what == "window", session=len(out))
-                if self.sessions:
-                    req.turns_left = self._session_turns()
-                out.append(req)
+            out += self._open_phase(what, start, length, len(out))
+        return out
+
+    def _open_phase(self, what: str, start: float, length: float,
+                    first_session: int) -> list[Req]:
+        arr = self.spec["arrival"]
+        n = int(round(float(arr["rate_rps"]) * length))
+        if n <= 0:
+            return []
+        gaps = self.rng.permutation(
+            arrival_gaps(arr, n, _fixed_rng(self.spec, what + "gaps")))
+        due = start + np.cumsum(gaps) - gaps
+        p, o, shared = self._sizes(n, what)
+        out = []
+        for i in range(n):
+            req = Req(due=float(due[i]),
+                      prompt=self._prompt(p[i], bool(shared[i])),
+                      max_tokens=int(o[i]), seed=self._seed(),
+                      judged=what == "window",
+                      session=first_session + len(out))
+            if self.sessions:
+                req.turns_left = self._session_turns()
+            out.append(req)
         return out
 
     def _session_turns(self) -> list[tuple[int, int, float]]:
@@ -326,14 +345,15 @@ async def send_one(http, base: str, body: dict, res: Result,
 
 class Run:
     """One run's clock, results and hooks. ``origin`` is the monotonic
-    time of schedule second 0; the window is ``[w0, w1)``."""
+    time of schedule second 0; the window is ``[w0, w1)``; the traffic
+    goes on until ``end`` (``w1`` plus the traffic's tail)."""
 
     def __init__(self, traffic: Traffic, base: str, hooks: dict | None = None):
         self.traffic = traffic
         self.base = base
         self.results: list[Result] = []
         self.hooks = hooks or {}
-        self.origin = self.w0 = self.w1 = 0.0
+        self.origin = self.w0 = self.w1 = self.end = 0.0
         self.in_flight_at = {}
 
     async def _at(self, t: float, coro_fn):
@@ -343,6 +363,13 @@ class Run:
     def _in_flight(self) -> int:
         return sum(1 for r in self.results
                    if r.sent_t and r.usage is None and r.error is None)
+
+    def note_in_flight(self, name: str) -> None:
+        self.in_flight_at[name] = self._in_flight()
+
+    def end_tail(self) -> None:
+        """The tail has served its purpose: send nothing more."""
+        self.end = min(self.end, time.monotonic())
 
     async def _one(self, http, req: Req) -> Result:
         res = Result(req=req, due_t=self.origin + req.due)
@@ -355,11 +382,14 @@ class Run:
         while req is not None:
             await asyncio.sleep(max(0.0, self.origin + req.due
                                     - time.monotonic()))
+            if (self.traffic.tail and time.monotonic() >= self.end
+                    and self.origin + req.due >= self.w1):
+                return             # the tail's request, the tail over
             res = await self._one(http, req)
-            now_s = time.monotonic() - self.origin
-            if now_s >= self.traffic.warm + self.traffic.seconds:
+            now = time.monotonic()
+            if now >= self.end:
                 return
-            req = self.traffic.next_turn(res, now_s)
+            req = self.traffic.next_turn(res, now - self.origin)
 
     async def _blocker(self, http) -> None:
         tr = self.traffic
@@ -373,7 +403,7 @@ class Run:
         await asyncio.sleep(0.02)      # behind the blocker
         while True:
             now = time.monotonic()
-            if now >= self.w1:
+            if now >= self.end:
                 return
             req = self.traffic.closed_next(now - self.origin)
             res = Result(req=req, due_t=now)
@@ -392,6 +422,7 @@ class Run:
             self.origin = time.monotonic() + 0.05
             self.w0 = self.origin + tr.warm
             self.w1 = self.w0 + tr.seconds
+            self.end = self.w1 + tr.tail
             side = [asyncio.ensure_future(self._at(self.origin + off, fn))
                     for off, fn in self.hooks.get("at", [])]
             marks = [asyncio.ensure_future(self._at(t, self._mark(name)))
@@ -405,7 +436,7 @@ class Run:
                         for r in tr.schedule]
             done, pending = await asyncio.wait(
                 work + side + marks,
-                timeout=tr.warm + tr.seconds + drain_s,
+                timeout=tr.warm + tr.seconds + tr.tail + drain_s,
             )
             for task in pending:
                 task.cancel()
@@ -418,7 +449,7 @@ class Run:
 
     def _mark(self, name: str):
         async def mark():
-            self.in_flight_at[name] = self._in_flight()
+            self.note_in_flight(name)
             fn = self.hooks.get(name)
             if fn is not None:
                 await fn()

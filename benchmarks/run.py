@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Run one cell of the benchmark once.
 
-    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 The parent (this process) never touches the chip: it reads the cell's
 files, starts the child that holds the chip
@@ -10,15 +10,25 @@ the seed), checks it against the plain reference, warms it up, offers the
 cell's traffic for ``--seconds`` over HTTP, and does all the arithmetic.
 The LAST line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, with
-``--trace 1``, ``breakdown``). Without a TPU holding the chips the cell
-asks for, the exit code is not 0 and no result is printed.
+``--trace 1`` or ``2``, ``breakdown``). Without a TPU holding the chips
+the cell asks for, the exit code is not 0 and no result is printed.
+
+``--trace 0`` measures the end-to-end metrics with the profiler off.
+``--trace 1`` traces a span inside the window and prints the per-layer
+metrics only (its rates are not used). ``--trace 2`` is ``--trace 0`` to
+the letter until the window closes - same spawn, checks, walk, warm
+phase and requests, end-to-end numbers from what was recorded up to
+then - followed by a tail in which the same traffic goes on: the
+profiler is started and stopped once for nothing (the cost of its first
+start falls into no number), then a few seconds are traced, and the one
+last line holds end-to-end and per-layer metrics side by side.
 
 ``--rehearse`` is the CPU dress rehearsal (toy widths, traffic cut to
 size): it says ``"platform": "cpu"`` and prints no device metric.
 
 Phases: spawn -> ready -> reference check -> warm-up (lattice walk, then
-the traffic's own warm phase) -> window -> drain -> stop. ``setup_s`` runs
-from process start to the opening of the window.
+the traffic's own warm phase) -> window -> (``--trace 2``: tail) -> drain
+-> stop. ``setup_s`` runs from process start to the opening of the window.
 """
 
 from __future__ import annotations
@@ -46,10 +56,17 @@ from benchmarks.harness.server import (  # noqa: E402
     replay_reference,
 )
 
-# The traced span: a few seconds inside the window (a trace of the whole
-# window would be hundreds of MB and slow the host throughout).
+# The traced span: a few seconds (a trace of the whole window would be
+# hundreds of MB and slow the host throughout), ``--trace 1``: inside
+# the window; ``--trace 2``: in the tail after it, once the load has
+# settled from the scrapes at the window's end and the profiler has been
+# started and stopped once. The tail's traffic ends with the span, and
+# after ``TRACE2_TAIL_S`` at the latest (an open loop's schedule is
+# drawn that far: room for that first start and the span).
 TRACE_OFFSET_S = 2.0
 TRACE_SECONDS = 4.0
+TRACE2_SETTLE_S = 0.5
+TRACE2_TAIL_S = 12.0
 
 
 def log(**record) -> None:
@@ -67,9 +84,10 @@ async def http_json(http, method: str, url: str, body=None, timeout=120.0):
         return text
 
 
-async def measure(srv: Server, traffic: loadgen.Traffic, trace: bool,
+async def measure(srv: Server, traffic: loadgen.Traffic, trace: int,
                   trace_dir: str) -> dict:
-    """The window. Returns the run's raw material."""
+    """The window (and ``--trace 2``'s tail). Returns the run's raw
+    material."""
     got: dict = {}
 
     def scrape(tag: str):
@@ -94,11 +112,47 @@ async def measure(srv: Server, traffic: loadgen.Traffic, trace: bool,
                         timeout=300.0)
         await scrape("t1")()
 
+    async def profile(what: str, body: dict) -> dict:
+        return json.loads(await http_json(
+            run.http, "POST", srv.base + "/profile/" + what, body,
+            timeout=300.0))
+
+    async def trace_tail():
+        """After the window: the profiler's first start and stop, thrown
+        away; then the traced span, while the traffic goes on. The
+        Python-call tracer and the HLO protos stay out: the program's
+        own spans say what the host does, the reducers find operations
+        by name, and with the first in the host packed a visit a third
+        slower and the trace was half as large again (PERF.md, PR 25)."""
+        await asyncio.sleep(TRACE2_SETTLE_S)
+        t = time.monotonic()
+        quiet = {"max_seconds": 60, "profiler_options": {
+            "python_tracer_level": 0, "enable_hlo_proto": False}}
+        await profile("start", dict(quiet, dir=trace_dir + ".first"))
+        await profile("stop", {})
+        shutil.rmtree(trace_dir + ".first", ignore_errors=True)
+        got["first_profile_s"] = time.monotonic() - t
+        run.note_in_flight("trace0")
+        await scrape("t0")()
+        got["profile_start"] = await profile(
+            "start", dict(quiet, dir=trace_dir))
+        got["t_trace0"] = time.monotonic()
+        await asyncio.sleep(span)
+        run.note_in_flight("trace1")
+        await scrape("t1")()
+        got["t_trace1"] = time.monotonic()
+        # The span is over: nothing more need be sent while it is written.
+        run.end_tail()
+        got["profile_stop"] = await profile("stop", {})
+        got["tail_s"] = time.monotonic() - run.w1
+
+    span = min(TRACE_SECONDS, max(0.5, traffic.seconds / 3))
     hooks = {"w0": scrape("w0"), "w1": scrape("w1"), "at": []}
-    if trace:
-        span = min(TRACE_SECONDS, max(0.5, traffic.seconds / 3))
+    if trace == 1:
         off = traffic.warm + min(TRACE_OFFSET_S, traffic.seconds / 4)
         hooks["at"] = [(off, trace_start), (off + span, trace_stop)]
+    elif trace == 2:
+        hooks["at"] = [(traffic.warm + traffic.seconds, trace_tail)]
     run = loadgen.Run(traffic, srv.base, hooks)
     await run.go()
     got["run"] = run
@@ -110,7 +164,7 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU dress rehearsal at toy widths (no chip run)")
     ap.add_argument("--benchmark-json", default=None, help=argparse.SUPPRESS)
@@ -136,8 +190,10 @@ def main() -> int:
     os.makedirs(work_dir)
     trace_dir = os.path.join(work_dir, "trace")
 
-    traffic = loadgen.Traffic(traffic_spec, hf["vocab_size"], args.seed,
-                              args.seconds, rehearse=args.rehearse)
+    traffic = loadgen.Traffic(
+        traffic_spec, hf["vocab_size"], args.seed, args.seconds,
+        rehearse=args.rehearse,
+        tail=TRACE2_TAIL_S if args.trace == 2 else 0.0)
     srv = Server(work_dir, spec.config_path(cell["config"]), args.seed,
                  cell["chips"], args.rehearse)
     phases = {}
@@ -168,7 +224,7 @@ def main() -> int:
         log(phase="warmup", **walked)
         check(walked["failed"] == 0, "warm-up requests failed", **walked)
 
-        got = asyncio.run(measure(srv, traffic, bool(args.trace), trace_dir))
+        got = asyncio.run(measure(srv, traffic, args.trace, trace_dir))
         run = got["run"]
         setup_s = run.w0 - T_START
         status = srv.status()
@@ -207,21 +263,32 @@ def main() -> int:
         return args.workload in m["cells"] and (
             not args.rehearse or m["source"] == "program_counter")
 
-    if not args.trace:
+    if args.trace != 1:
         client["setup_s"] = setup_s
         for name, m in bench["end_to_end"].items():
             if reported(m) and client.get(name) is not None:
                 values[name] = client[name]
-    else:
+    if args.trace:
         red = None
         if not args.rehearse:
-            from benchmarks.harness import trace_reduce
+            from benchmarks.harness import host_spans, trace_reduce
 
             red = trace_reduce.reduce_trace(trace_dir)
             check(red is not None, "the trace holds no device operation")
             device_out["busy_s"] = red["busy_s"]
             device_out["window_s"] = got["t_trace1"] - got["t_trace0"]
             breakdown = trace_reduce.breakdown(red)
+            if args.trace == 2:
+                # The span as the program's own clock saw it, from
+                # ``start_trace``'s return to the call of ``stop_trace``;
+                # and the idle gaps by the host span that covers them.
+                t0, t1 = (got[k].get("perf_counter_ns")
+                          for k in ("profile_start", "profile_stop"))
+                if t0 and t1:
+                    device_out["window_s"] = (t1 - t0) * 1e-9
+                by_span = host_spans.idle_gaps(red["file"])
+                if by_span is not None:
+                    breakdown["idle_gaps"] = by_span
         ctx = {
             "scrape_w0": got.get("scrape_w0"), "scrape_w1": got.get("scrape_w1"),
             "scrape_t0": got.get("scrape_t0"), "scrape_t1": got.get("scrape_t1"),
@@ -253,9 +320,21 @@ def main() -> int:
         shutil.rmtree(trace_dir, ignore_errors=True)
         shutil.rmtree(os.path.join(work_dir, "model"), ignore_errors=True)
 
+    tail = None
+    if args.trace == 2:
+        # What the tail cost, and the tokens a second the clients saw
+        # inside the traced span (the on-cost of tracing; stderr only).
+        t0, t1 = got["t_trace0"], got["t_trace1"]
+        tail = {"first_profile_s": got["first_profile_s"],
+                "tail_s": got["tail_s"],
+                "stop_seconds": got["profile_stop"].get("stop_seconds"),
+                "span_out_tok_s": sum(
+                    metrics.tokens_in_window(r.chunks, t0, t1)
+                    for r in run.results) / (t1 - t0) / cell["chips"]}
     log(phase="summary", phases=phases, setup_s=setup_s, client=client,
         live_context_tokens=live,
         in_flight=run.in_flight_at, not_ok=len(not_ok),
+        trace2=tail,
         first_errors=[r.error for r in not_ok if r.error][:3],
         kv_pages=status["stages"][0]["num_pages"],
         kv_occupancy={k: got["scrape_" + k].get("parallax_kv_page_occupancy")

@@ -163,6 +163,18 @@ def _stop_holdback(text: str, stops) -> int:
     return hold
 
 
+def _newest_xplane(trace_dir: str | None) -> str | None:
+    """The ``.xplane.pb`` the profiler wrote last under ``trace_dir``
+    (``<dir>/plugins/profile/<time>/<host>.xplane.pb``)."""
+    import glob
+    import os
+
+    found = glob.glob(
+        os.path.join(trace_dir or "", "**", "*.xplane.pb"), recursive=True
+    )
+    return max(found, key=os.path.getmtime) if found else None
+
+
 class BackendUnavailable(RuntimeError):
     """Raised by a ``submit_fn`` whose backend can no longer serve (its
     step loop died): the frontend answers 503, not the 429 of a full
@@ -223,6 +235,7 @@ class OpenAIFrontend:
         qos_config=None,
         device_fn=None,
         profile_cluster_fn=None,
+        request_spans_fn=None,
     ):
         self.tokenizer = tokenizer
         self.submit_fn = submit_fn
@@ -266,6 +279,11 @@ class OpenAIFrontend:
         # stage of a pipeline over RPC. None = single-process profiling
         # only (a {"pipeline": ...} body 501s).
         self.profile_cluster_fn = profile_cluster_fn
+        # ``request_spans_fn(rate)`` sets the engines' request-span
+        # sampling rate while a profile runs (``/profile/start``'s
+        # ``"request_spans"`` key) and, with None, restores the
+        # configured one. None = the key is refused (501).
+        self.request_spans_fn = request_spans_fn
         # Multi-tenant QoS (parallax_tpu/qos, docs/qos.md): when a
         # QoSConfig is wired, requests carry a class (header
         # ``x-parallax-qos-class`` / body ``qos_class``), a deadline
@@ -335,8 +353,15 @@ class OpenAIFrontend:
             web.post("/profile/start", self.profile_start),
             web.post("/profile/stop", self.profile_stop),
         ])
+        # A profile runs from ``start_trace``'s return until
+        # ``stop_trace`` has returned; the stop itself runs on a thread
+        # (``_profile_stop_task`` holds the auto-stop's).
         self._profiling = False
+        self._profile_stopping = False
+        self._profile_dir = None
+        self._profile_request_spans = False
         self._profile_deadline_handle = None
+        self._profile_stop_task = None
         # The loop ``run`` serves on, for the thread-safe ``shutdown``.
         self._loop = None
         self.app.on_startup.append(self._remember_loop)
@@ -627,7 +652,20 @@ class OpenAIFrontend:
         pipeline) so the whole serving path traces one wall-clock
         window; the response is a per-node trace-dir manifest instead
         of the single-process ack. Each worker arms its own
-        ``max_seconds`` auto-stop."""
+        ``max_seconds`` auto-stop.
+
+        The reply comes once ``start_trace`` has returned and carries
+        this process's ``perf_counter_ns`` at that instant (also emitted
+        as the ``parallax.clock_sync`` span, obs/trace.py).
+        ``"request_spans": <0..1>`` samples per-request ``TraceStore``
+        spans at that rate for requests submitted while the profile
+        runs; the stop restores the configured rate.
+        ``"profiler_options": {name: value}`` sets fields of
+        ``jax.profiler.ProfileOptions`` for this profile, e.g.
+        ``{"python_tracer_level": 0}``: without the Python-call tracer
+        the ``parallax.*`` host spans and the device planes stay and
+        the traced host runs nearer its untraced pace (measured:
+        PERF.md, PR 25)."""
         import jax
 
         try:
@@ -645,19 +683,54 @@ class OpenAIFrontend:
             return await self._profile_cluster(
                 "start", body["pipeline"], out_dir, max_seconds
             )
+        spans_rate = body.get("request_spans")
+        if spans_rate is not None:
+            try:
+                spans_rate = float(spans_rate)
+            except (TypeError, ValueError):
+                return self._error(400, "request_spans must be a number")
+            if not 0.0 <= spans_rate <= 1.0:
+                return self._error(400, "request_spans must be in 0..1")
+            if self.request_spans_fn is None:
+                return self._error(
+                    501, "request_spans is unavailable in this mode"
+                )
+        options = None
+        wanted = body.get("profiler_options")
+        if wanted:
+            options = jax.profiler.ProfileOptions()
+            for name, value in dict(wanted).items():
+                if name.startswith("_") or not hasattr(options, name):
+                    return self._error(
+                        400, f"profiler_options: no field {name!r}"
+                    )
+                try:
+                    setattr(options, name, value)
+                except (TypeError, ValueError) as e:
+                    return self._error(400, f"profiler_options: {e}")
         # Check AFTER the awaits: no suspension between test and set.
         if self._profiling:
             return self._error(409, "profiler already running")
+        from parallax_tpu.obs.trace import clock_sync
+
         try:
-            jax.profiler.start_trace(out_dir)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
         except Exception as e:
             return self._error(500, f"profiler start failed: {e}")
+        now_ns = clock_sync()
         self._profiling = True
+        self._profile_dir = out_dir
+        if spans_rate is not None:
+            self.request_spans_fn(spans_rate)
+            self._profile_request_spans = True
         self._profile_deadline_handle = asyncio.get_running_loop().call_later(
             max_seconds, self._profile_deadline
         )
         return web.json_response({
             "profiling": True, "dir": out_dir, "max_seconds": max_seconds,
+            # This process's clock when ``start_trace`` had returned
+            # (the ``parallax.clock_sync`` marker's reading).
+            "perf_counter_ns": now_ns,
         })
 
     def _profile_deadline(self) -> None:
@@ -665,17 +738,47 @@ class OpenAIFrontend:
         thread every profile handler runs on — no race with an explicit
         stop)."""
         self._profile_deadline_handle = None
-        if not self._profiling:
+        if not self._profiling or self._profile_stopping:
             return
-        import jax
-
         logger.warning("profiler auto-stop: max_seconds deadline reached")
-        try:
+        self._profile_stop_task = asyncio.ensure_future(
+            self._stop_profile()
+        )
+
+    async def _stop_profile(self) -> dict | None:
+        """``stop_trace`` on a thread: it writes the whole trace (14-35 s
+        for 4 s of a 7B decode; PERF.md, PR 25), and streams must keep
+        flowing meanwhile. ``_profiling`` clears only when it has returned. The
+        reply's fields, or None where ``stop_trace`` raised (logged)."""
+        from parallax_tpu.obs.trace import clock_sync
+
+        def stop():
+            import jax
+
+            now_ns = clock_sync()
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            return now_ns, time.perf_counter() - t0
+
+        self._profile_stopping = True
+        try:
+            now_ns, seconds = await asyncio.to_thread(stop)
         except Exception:
-            logger.exception("profiler auto-stop failed")
+            logger.exception("profiler stop failed")
+            return None
         finally:
-            self._profiling = False
+            self._profiling = self._profile_stopping = False
+            if self._profile_request_spans:
+                self._profile_request_spans = False
+                self.request_spans_fn(None)
+        return {
+            "profiling": False,
+            # This process's clock at the call of ``stop_trace``, the
+            # seconds the write took, and the trace it wrote.
+            "perf_counter_ns": now_ns,
+            "stop_seconds": seconds,
+            "xplane": _newest_xplane(self._profile_dir),
+        }
 
     async def _profile_cluster(self, action, pipeline, out_dir,
                                max_seconds):
@@ -704,8 +807,6 @@ class OpenAIFrontend:
         })
 
     async def profile_stop(self, request):
-        import jax
-
         try:
             body = await request.json()
         except Exception:
@@ -716,14 +817,15 @@ class OpenAIFrontend:
             )
         if not self._profiling:
             return self._error(409, "profiler not running")
+        if self._profile_stopping:
+            return self._error(409, "profiler is stopping")
         if self._profile_deadline_handle is not None:
             self._profile_deadline_handle.cancel()
             self._profile_deadline_handle = None
-        try:
-            jax.profiler.stop_trace()
-        finally:
-            self._profiling = False
-        return web.json_response({"profiling": False})
+        reply = await self._stop_profile()
+        if reply is None:
+            return self._error(500, "profiler stop failed")
+        return web.json_response(reply)
 
     async def weight_refit(self, request):
         if self.refit_fn is None:

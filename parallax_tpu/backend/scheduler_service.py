@@ -176,8 +176,8 @@ class SchedulerService:
                 if isinstance(payload.get("lora_adapters"), (list, tuple))
                 else None
             ),
-            # Two-phase decode telemetry (host_ms/device_ms/overlap
-            # EWMAs) — surfaced per node in /cluster/status.
+            # Two-phase decode telemetry (host_ms, readback_wait_ms
+            # and overlap EWMAs) — surfaced per node in /cluster/status.
             step_timing=(
                 payload["step_timing"]
                 if isinstance(payload.get("step_timing"), dict)

@@ -14,6 +14,9 @@ from parallax_tpu.backend.http_server import (
     OpenAIFrontend,
     load_tokenizer,
 )
+from parallax_tpu.obs import names as mnames
+from parallax_tpu.obs.registry import get_registry
+from parallax_tpu.obs.trace import host_span
 from parallax_tpu.runtime.engine import EngineConfig, StageEngine
 from parallax_tpu.runtime.pipeline import InProcessPipeline
 from parallax_tpu.runtime.request import Request
@@ -43,6 +46,9 @@ class LocalRunner:
         self._pending: dict[str, tuple[Request, threading.Event]] = {}
         self._lock = make_lock("backend.serve")
         self._stop = threading.Event()
+        self._h_loop_gap = get_registry().histogram(
+            mnames.LOOP_GAP_MS, mnames.help_text(mnames.LOOP_GAP_MS)
+        )
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="pipeline-runner"
         )
@@ -56,7 +62,8 @@ class LocalRunner:
 
     def submit(self, request: Request) -> threading.Event:
         ev = threading.Event()
-        with self._lock:
+        # The frontend's thread, the wait for the runner's lock included.
+        with host_span("http.submit"), self._lock:
             if self.failure is not None:
                 raise BackendUnavailable(
                     f"step loop failed: {self.failure!r}"
@@ -74,24 +81,45 @@ class LocalRunner:
             self.pipeline.head.stop_request(request_id)
 
     def _loop(self) -> None:
+        # ``runner.loop_gap``: from the end of one step round to the
+        # start of the next (waking finished requests, releasing the
+        # lock, the watchdog's beat, ``has_work``, the wait for the
+        # lock). It ends where the loop finds no work instead.
+        gap = None
+
+        def end_gap():
+            nonlocal gap
+            if gap is not None:
+                gap.__exit__(None, None, None)
+                gap = None
+
         while not self._stop.is_set():
             if self.watchdog is not None:
                 self.watchdog.beat("step_loop")
             if not self.pipeline.has_work():
-                self._stop.wait(0.002)
+                end_gap()
+                with host_span("runner.idle"):
+                    self._stop.wait(0.002)
                 continue
             with self._lock:
+                end_gap()
                 try:
-                    finished = self.pipeline.step_round()
+                    with host_span("runner.step_round"):
+                        finished = self.pipeline.step_round()
                 except Exception as e:
                     self._fail(e)
                     break
+                gap = host_span(
+                    "runner.loop_gap", self._h_loop_gap,
+                    visit=self.pipeline.visits,
+                ).__enter__()
                 for req in finished:
                     _, ev = self._pending.pop(
                         req.request_id, (None, None)
                     )
                     if ev is not None:
                         ev.set()
+        end_gap()
         if self.failure is not None and self.on_failure is not None:
             self.on_failure(self.failure)
 
@@ -234,8 +262,8 @@ def build_local_frontend(
                     "num_pages": e.cfg.num_pages,
                     "free_pages": e.cache.num_free_pages,
                     "cached_pages": e.cache.prefix_cache.num_cached_pages,
-                    # Two-phase decode telemetry (host_ms/device_ms
-                    # EWMAs + overlap fraction).
+                    # Two-phase decode telemetry (host_ms and
+                    # readback_wait_ms EWMAs + overlap fraction).
                     "step_timing": e.step_timing.summary(),
                     # Prefix-cache / memory-tier counters (hit rates
                     # split device/host, occupancy, demotions,
@@ -308,6 +336,10 @@ def build_local_frontend(
             summary["causes"].append(loop_health["error"])
         return summary
 
+    def request_spans(rate):
+        for e in engines:
+            e.sample_request_spans(rate)
+
     frontend = OpenAIFrontend(
         tokenizer,
         submit_fn=runner.submit,
@@ -318,6 +350,7 @@ def build_local_frontend(
         healthz_fn=healthz,
         timeline_fn=timeline,
         qos_config=qos_config,
+        request_spans_fn=request_spans,
     )
     runner.start()
     return frontend, runner

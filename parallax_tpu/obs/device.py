@@ -24,10 +24,12 @@ always-on, always-cheap pillars:
   new_shape_bucket / k_change / sampling_feature / spec_toggle). A
   recompile-storm detector (N same-family compiles inside a sliding
   window) emits flight events and feeds the ``compile`` watchdog probe.
-- :class:`DeviceTimeAttributor` — tags each dispatched program (prefill
-  chunk, fused decode window, spec verify, swap gather/scatter) with
-  its family so ``parallax_device_time_seconds_total{program=…}``
-  splits the goodput ledger's one ``serve`` bucket.
+- :class:`ProgramVisitAttributor` — tags each dispatched program
+  (prefill chunk, fused decode window, spec verify, swap gather/scatter)
+  with its family so ``parallax_program_visit_seconds_total{program=…}``
+  splits the goodput ledger's one ``serve`` bucket: host-visit seconds
+  on the host's clock, not device busy time (the device's own time
+  comes from a profiler trace; docs/observability.md "Host spans").
 
 Cost model (the zero-cost-on gate, same bar as trace sampling): the
 steady-state decode path pays one dict add per HOST VISIT for time
@@ -67,7 +69,7 @@ HBM_CLASSES = (
     "compile_headroom",   # XLA compile workspace reservation
 )
 
-# Canonical program families for device-time attribution. Open set,
+# Canonical program families for host-visit attribution. Open set,
 # same convention as HBM_CLASSES.
 PROGRAM_FAMILIES = (
     "prefill",       # chunked prefill step
@@ -546,8 +548,8 @@ class CompileObservatory:
         return self.snapshot()
 
 
-class DeviceTimeAttributor:
-    """Per-program device/host-visit time: one dict add per host visit.
+class ProgramVisitAttributor:
+    """Per-program host-visit seconds: one dict add per host visit.
 
     Splits the goodput ledger's single ``serve`` bucket by program
     family — the engine calls :meth:`add` at resolve with the family it
@@ -571,8 +573,8 @@ class DeviceTimeAttributor:
             registry = get_registry()
         self._registry = registry
         self._c_seconds = registry.counter(
-            mnames.DEVICE_TIME_SECONDS_TOTAL,
-            mnames.help_text(mnames.DEVICE_TIME_SECONDS_TOTAL),
+            mnames.PROGRAM_VISIT_SECONDS_TOTAL,
+            mnames.help_text(mnames.PROGRAM_VISIT_SECONDS_TOTAL),
             labelnames=("program",),
         )
         self._children = {}
@@ -617,7 +619,7 @@ class DevicePlane:
     def __init__(self, registry=None, clock=time.monotonic):
         self.hbm = HbmLedger(registry=registry, clock=clock)
         self.compile = CompileObservatory(registry=registry, clock=clock)
-        self.time = DeviceTimeAttributor(registry=registry)
+        self.time = ProgramVisitAttributor(registry=registry)
         self._bound = False
 
     def bind_registry(self, registry=None) -> None:

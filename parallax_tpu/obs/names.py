@@ -30,12 +30,20 @@ TTFT_MS = "parallax_ttft_ms"
 TPOT_MS = "parallax_tpot_ms"
 E2E_MS = "parallax_e2e_ms"
 STEP_HOST_MS = "parallax_step_host_ms"
-STEP_DEVICE_MS = "parallax_step_device_ms"
 STEP_PER_TOKEN_HOST_MS = "parallax_step_per_token_host_ms"
 STEP_BATCH_TOKENS = "parallax_step_batch_tokens"
 QUEUE_DEPTH = "parallax_queue_depth"
 RUNNING_REQUESTS = "parallax_running_requests"
 ATTN_KERNEL_DISPATCH_TOTAL = "parallax_attn_kernel_dispatch_total"
+
+# -- the host's phases of a visit (obs/trace.py host_span; engine.py,
+# backend/serve.py). Each is the duration of the span of the same name.
+VISIT_PLAN_MS = "parallax_visit_plan_ms"
+VISIT_PACK_MS = "parallax_visit_pack_ms"
+VISIT_READBACK_WAIT_MS = "parallax_visit_readback_wait_ms"
+VISIT_COMMIT_MS = "parallax_visit_commit_ms"
+LOOP_GAP_MS = "parallax_loop_gap_ms"
+ADMIT_WAIT_MS = "parallax_admit_wait_ms"
 
 # -- KV memory tier (runtime/engine.py) -------------------------------------
 KV_PAGE_OCCUPANCY = "parallax_kv_page_occupancy"
@@ -142,7 +150,7 @@ HA_REPLAY_MS = "parallax_ha_replay_ms"
 HBM_BYTES = "parallax_hbm_bytes"
 HBM_HEADROOM_BYTES = "parallax_hbm_headroom_bytes"
 HBM_HIGH_WATERMARK_BYTES = "parallax_hbm_high_watermark_bytes"
-DEVICE_TIME_SECONDS_TOTAL = "parallax_device_time_seconds_total"
+PROGRAM_VISIT_SECONDS_TOTAL = "parallax_program_visit_seconds_total"
 XLA_COMPILE_MS_TOTAL = "parallax_xla_compile_ms_total"
 XLA_LIVE_EXECUTABLES = "parallax_xla_live_executables"
 XLA_COMPILE_STORMS_TOTAL = "parallax_xla_compile_storms_total"
@@ -169,7 +177,6 @@ HELP: dict[str, str] = {
     TPOT_MS: "Time per output token after the first, milliseconds",
     E2E_MS: "End-to-end request latency, milliseconds",
     STEP_HOST_MS: "Host-blocking milliseconds per engine step",
-    STEP_DEVICE_MS: "Device-readback milliseconds per engine step",
     STEP_PER_TOKEN_HOST_MS: (
         "Host-blocking milliseconds per committed token (host-visit "
         "cost amortized over the tokens that visit committed)"
@@ -177,6 +184,34 @@ HELP: dict[str, str] = {
     STEP_BATCH_TOKENS: "New tokens per dispatched engine step",
     QUEUE_DEPTH: "Requests parked in the stage wait queue",
     RUNNING_REQUESTS: "Requests admitted into the running set",
+    VISIT_PLAN_MS: (
+        "Milliseconds a visit spent forming its plan (scheduler and "
+        "cache-manager page work); span parallax.sched.form_plan"
+    ),
+    VISIT_PACK_MS: (
+        "Milliseconds a visit spent from its plan to the return of the "
+        "jit call: host arrays, page tables, H2D, enqueue; span "
+        "parallax.engine.pack"
+    ),
+    VISIT_READBACK_WAIT_MS: (
+        "Milliseconds the host waited in the blocking read-back of a "
+        "visit's device results (host clock; not device busy time); "
+        "span parallax.engine.readback_wait"
+    ),
+    VISIT_COMMIT_MS: (
+        "Milliseconds from a visit's read-back to the return of "
+        "resolve: commit loop, ledgers, finish collection; span "
+        "parallax.engine.commit"
+    ),
+    LOOP_GAP_MS: (
+        "Milliseconds between the end of one locked step round of the "
+        "single-host step loop and the start of the next; span "
+        "parallax.runner.loop_gap"
+    ),
+    ADMIT_WAIT_MS: (
+        "Milliseconds from a request's arrival at the frontend to its "
+        "first appearance in a plan of the head stage"
+    ),
     ATTN_KERNEL_DISPATCH_TOTAL: (
         "Engine dispatches by attention kernel implementation"
     ),
@@ -394,8 +429,8 @@ HELP: dict[str, str] = {
         "Highest total device HBM occupancy observed since process "
         "start (tracked + untracked)"
     ),
-    DEVICE_TIME_SECONDS_TOTAL: (
-        "Device/host-visit seconds by dispatched program family "
+    PROGRAM_VISIT_SECONDS_TOTAL: (
+        "Host-visit seconds (host clock) by dispatched program family "
         "(prefill / decode / decode_window / spec_window / "
         "spec_verify / sp_prefill / swap_gather / swap_scatter) — "
         "splits the goodput ledger's serve bucket"

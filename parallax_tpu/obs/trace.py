@@ -19,11 +19,21 @@ events.
 
 Span timestamps use ``time.perf_counter()`` seconds; export rebases them
 to the trace's first span so the JSON is viewer-friendly.
+
+Host spans (:func:`host_span`, :func:`visit_span`) are the second kind of
+span here: what the HOST does at each layer boundary of the serve hot
+path, written into the JAX profiler's own trace (``.xplane.pb`` host
+plane, the same clock as the device's ``XLA Ops`` line) while a profile
+runs, and nowhere while none does. A span may also feed a registry
+histogram, from the same two instants. :func:`clock_sync` marks a
+``perf_counter`` reading on the profiler's clock, so the per-request
+spans above can be laid on a device trace by whoever reads both.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from parallax_tpu.analysis.sanitizer import make_lock
 
@@ -243,6 +253,101 @@ class TraceStore:
                 out.get(s["name"], 0.0) + s["dur"] * 1e3, 3
             )
         return out
+
+
+# -- host spans on the profiler's clock ---------------------------------------
+
+SPAN_PREFIX = "parallax."
+
+# jax.profiler's annotation classes, bound on first use: this module is
+# imported by processes that never load JAX (scheduler, lint).
+_annotations = None
+# The visit the calling thread is inside (``visit_span``).
+_current = threading.local()
+
+
+def _annotation_types():
+    global _annotations
+    if _annotations is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _annotations = (TraceAnnotation, StepTraceAnnotation)
+    return _annotations
+
+
+class host_span:
+    """``with host_span("engine.pack", series, rows=8):`` — one span of
+    host work.
+
+    Enters a ``jax.profiler.TraceAnnotation("parallax.<name>", **args)``:
+    while the profiler runs the span lands in the trace's host plane; while
+    it does not, the annotation is a flag check. Inside a
+    :func:`visit_span` the span carries ``visit=<n>`` unless ``args``
+    gives one. Where ``series`` (a registry histogram child) is given,
+    the span's duration in ms is observed into it on exit, taken with
+    ``time.perf_counter`` at the annotation's own two ends; setting
+    ``span.series = None`` inside the block withdraws that. ``span.ms``
+    holds the duration after exit.
+    """
+
+    __slots__ = ("series", "ms", "_annotation", "_t0")
+
+    def __init__(self, name: str, series=None, **args):
+        visit = getattr(_current, "visit", None)
+        if visit is not None:
+            args.setdefault("visit", visit)
+        self._annotation = _annotation_types()[0](SPAN_PREFIX + name, **args)
+        self.series = series
+        self.ms = 0.0
+
+    def __enter__(self) -> "host_span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        self._annotation.__exit__(*exc)
+        if self.series is not None:
+            self.series.observe(self.ms)
+
+
+class visit_span:
+    """One visit of the step loop: a
+    ``jax.profiler.StepTraceAnnotation("parallax.visit", step_num=n)``
+    whose number every :class:`host_span` entered inside it (on this
+    thread) carries as ``visit``."""
+
+    __slots__ = ("_n", "_annotation")
+
+    def __init__(self, n: int):
+        self._n = n
+        self._annotation = _annotation_types()[1](
+            SPAN_PREFIX + "visit", step_num=n
+        )
+
+    def __enter__(self) -> "visit_span":
+        _current.visit = self._n
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+        _current.visit = None
+
+
+def clock_sync() -> int:
+    """Mark this instant on the profiler's clock: a zero-length
+    ``parallax.clock_sync`` span whose ``perf_counter_ns`` argument is
+    this process's ``time.perf_counter_ns()`` reading, returned too.
+    Emitted when a profile starts and stops; the offset between the two
+    clocks is the marker's trace timestamp minus its argument."""
+    now = time.perf_counter_ns()
+    with _annotation_types()[0](
+        SPAN_PREFIX + "clock_sync", perf_counter_ns=now
+    ):
+        pass
+    return now
 
 
 _STORE = TraceStore()

@@ -11,6 +11,7 @@ O(tokens) numpy; sampling is a second fused jit call.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import random
@@ -35,8 +36,12 @@ from parallax_tpu.runtime.request import (
 from parallax_tpu.runtime.scheduler import BatchPlan, ScheduledSeq, Scheduler
 from parallax_tpu.utils import get_logger
 from parallax_tpu.obs import names as mnames
+from parallax_tpu.obs.trace import host_span
 
 logger = get_logger(__name__)
+
+# What ``_note_program`` hands back for a jit key it has seen before.
+_NO_SPAN = contextlib.nullcontext()
 
 # Adaptive multi-step decode: K used per host visit when
 # ``EngineConfig.decode_lookahead`` is None and the batch qualifies.
@@ -268,10 +273,11 @@ class StepOutputs:
     step_time_ms: float = 0.0
     # Two-phase step telemetry: ms the host spent blocked on this step
     # (plan forming + assembly + sample/emit bookkeeping + any residual
-    # device wait), the device-readback portion of that wait, and whether
-    # the step's resolve overlapped a later dispatch.
+    # device wait), the part of it spent waiting in the blocking
+    # read-back (host clock: not device busy time), and whether the
+    # step's resolve overlapped a later dispatch.
     host_ms: float = 0.0
-    device_ms: float = 0.0
+    readback_wait_ms: float = 0.0
     overlapped: bool = False
 
 
@@ -872,15 +878,15 @@ class StageEngine:
         )
         self._token_slots: dict[str, int] = {}
         self._free_token_slots = list(range(self.cfg.max_batch_size))
-        # host_ms/device_ms/overlap EWMA published via heartbeats and
-        # /cluster/status (utils/request_metrics.py), with the same
+        # host_ms/readback_wait_ms/overlap EWMA published via heartbeats
+        # and /cluster/status (utils/request_metrics.py), with the host
         # samples feeding registry histograms for /metrics and
         # cluster-wide percentile merges.
         from parallax_tpu.utils.request_metrics import StepTimingAggregator
 
         self._init_obs()
         self.step_timing = StepTimingAggregator(
-            host_hist=self._h_step_host, device_hist=self._h_step_device,
+            host_hist=self._h_step_host,
             per_token_hist=self._h_step_per_token,
         )
         # Non-head stages: hidden rows waiting per request id.
@@ -1373,6 +1379,8 @@ class StageEngine:
             self._trace_begin(request)
         accepted = self.scheduler.enqueue(request)
         if accepted:
+            if not request.request_id.startswith("__"):
+                self._unplanned.add(request.request_id)
             # Conformance: this head now serves the request — at most
             # one head per rid at a time (migration/handoff transfer
             # ownership via extract -> restore, never duplicate it).
@@ -1480,6 +1488,7 @@ class StageEngine:
         self._bias_cache.pop(request_id, None)
         self._free_token_slot(request_id)
         self._traced.discard(request_id)
+        self._unplanned.discard(request_id)
         if req is not None:
             req.device_feed_ready = False
             if not req.status.is_finished:
@@ -1521,6 +1530,7 @@ class StageEngine:
         self._bias_cache.pop(request_id, None)
         self._free_token_slot(request_id)
         self._traced.discard(request_id)
+        self._unplanned.discard(request_id)
         self._free_state_slot(req)
         req.device_feed_ready = False
         # Conformance: extraction ends this head's ownership; the
@@ -1671,10 +1681,8 @@ class StageEngine:
             get_registry,
         )
 
-        self._trace_rate = min(
-            1.0, max(0.0, float(self.cfg.trace_sample_rate or 0.0))
-        )
         self._traced: set[str] = set()
+        self.sample_request_spans(None)
         # Goodput ledger (obs/goodput.py): every device-step token this
         # engine resolves lands in exactly one usefulness bucket, and
         # serve/compile/swap/migrate time accrues alongside. Always on —
@@ -1684,7 +1692,7 @@ class StageEngine:
         self._goodput = get_goodput()
         self._goodput.bind_registry()
         # Device attribution plane (obs/device.py): HBM ledger, compile
-        # observatory and per-program device-time split. Always on, same
+        # observatory and host-visit seconds by program. Always on, same
         # cost contract as the goodput ledger — one dict add per host
         # visit for time, a set-membership check per dispatch for the
         # compile observatory, ledger refreshes at collect cadence only.
@@ -1692,7 +1700,7 @@ class StageEngine:
 
         self._device_plane = get_device_plane()
         self._device_plane.bind_registry()
-        self._dev_time = self._device_plane.time
+        self._visit_time = self._device_plane.time
         self._compile_obs = self._device_plane.compile
         # (family, frozen key) pairs already declared to the observatory:
         # the dispatch hot path pays one set lookup, note_program runs
@@ -1707,11 +1715,23 @@ class StageEngine:
             "Host-blocking milliseconds per engine step",
             labelnames=st,
         ).labels(**lbl)
-        self._h_step_device = reg.histogram(
-            mnames.STEP_DEVICE_MS,
-            "Device-readback milliseconds per engine step",
-            labelnames=st,
-        ).labels(**lbl)
+        # The host's phases of a visit, each observed by the host span
+        # of the same boundary (obs/trace.py ``host_span``): per visit
+        # plan + pack + readback_wait + commit = parallax_step_host_ms.
+        def phase(name):
+            return reg.histogram(
+                name, mnames.help_text(name), labelnames=st
+            ).labels(**lbl)
+
+        self._h_visit_plan = phase(mnames.VISIT_PLAN_MS)
+        self._h_visit_pack = phase(mnames.VISIT_PACK_MS)
+        self._h_visit_readback = phase(mnames.VISIT_READBACK_WAIT_MS)
+        self._h_visit_commit = phase(mnames.VISIT_COMMIT_MS)
+        self._h_admit_wait = phase(mnames.ADMIT_WAIT_MS)
+        # Head stage: ids of submitted requests no plan has held yet;
+        # their first plan observes parallax_admit_wait_ms. Empty in
+        # steady decode, so dispatch pays one falsy check.
+        self._unplanned: set[str] = set()
         # Per-TOKEN twin of the per-visit host histogram: with multi-step
         # decode a host visit commits K tokens, so the visit series alone
         # would overstate TPOT-relevant host cost by K.
@@ -1990,18 +2010,22 @@ class StageEngine:
         except Exception:  # pragma: no cover - obs must never take
             pass           # down the path it observes
 
-    def _note_program(self, family: str, **key) -> None:
+    def _note_program(self, family: str, **key):
         """Declare a jit key to the compile observatory the first time
-        this engine dispatches it; steady state pays one set lookup."""
+        this engine dispatches it; steady state pays one set lookup.
+        Returns the context to make the jit call in: for a new key the
+        ``engine.compile`` host span (a compile or a cache load
+        follows), else nothing."""
         kt = (family, tuple(sorted(key.items())))
         if kt in self._noted_program_keys:
-            return
+            return _NO_SPAN
         self._noted_program_keys.add(kt)
         self._compile_obs.note_program(family, key)
         self._compile_obs.set_live_executables(
             family,
             sum(1 for f, _ in self._noted_program_keys if f == family),
         )
+        return host_span("engine.compile", program=family)
 
     def _count_kernel_dispatch(
         self, path: str, impl: str | None = None
@@ -2182,6 +2206,15 @@ class StageEngine:
             "sampler (fused attention kernels stay active)", reason,
         )
 
+    def sample_request_spans(self, rate: float | None) -> None:
+        """Sample the lifecycle spans of requests submitted from now on
+        at ``rate`` (0..1) in place of ``cfg.trace_sample_rate``; None
+        goes back to the configured rate. The profiler control uses it
+        (``POST /profile/start`` ``"request_spans"``)."""
+        if rate is None:
+            rate = self.cfg.trace_sample_rate or 0.0
+        self._trace_rate = min(1.0, max(0.0, float(rate)))
+
     def _trace_begin(self, req: Request) -> None:
         from parallax_tpu.obs.trace import get_trace_store
 
@@ -2208,6 +2241,19 @@ class StageEngine:
                     args={"prompt_tokens": req.num_prompt_tokens},
                 )
 
+    def _observe_admit_wait(self, plan: BatchPlan) -> None:
+        """First plan that holds a submitted request: observe its wait
+        since it arrived at the frontend (``_trace_queue_wait``'s
+        instant, for every request)."""
+        now = time.monotonic()
+        for seg in plan.seqs:
+            req = seg.request
+            if req.request_id in self._unplanned:
+                self._unplanned.discard(req.request_id)
+                self._h_admit_wait.observe(
+                    max(0.0, now - req.arrival_time) * 1e3
+                )
+
     def _trace_plan(self, plan: BatchPlan, t0: float, t1: float) -> None:
         """Per-step spans for traced rows; decode steps coalesce into
         epochs (obs/trace.py merge) so long generations stay bounded."""
@@ -2215,15 +2261,16 @@ class StageEngine:
 
         store = get_trace_store()
         # Device attribution counter tracks (ph:"C" in the Chrome
-        # export): HBM headroom and per-program device-time share,
-        # sampled once per traced host visit alongside the span lanes.
+        # export): HBM headroom and each program family's share of the
+        # host-visit seconds, sampled once per traced host visit
+        # alongside the span lanes.
         hbm = self._device_plane.hbm.snapshot()
-        share = self._dev_time.snapshot()["share"]
+        share = self._visit_time.snapshot()["share"]
         counter_values = {
             "hbm_headroom_mb": round(hbm["headroom_bytes"] / 2**20, 3),
             "hbm_tracked_mb": round(hbm["tracked_bytes"] / 2**20, 3),
             **{
-                f"device_share_{prog}": frac
+                f"program_visit_share_{prog}": frac
                 for prog, frac in share.items()
             },
         }
@@ -2256,6 +2303,7 @@ class StageEngine:
         recorder's timeline ring (head stage), finish span + traced-set
         cleanup (every stage). Internal requests (draft proposer) skip."""
         rid = req.request_id
+        self._unplanned.discard(rid)
         traced = rid in self._traced
         store = None
         if traced:
@@ -3093,7 +3141,7 @@ class StageEngine:
                 self._build_spec_multistep(k, sampled, spec, prop_len,
                                            feats)
             )
-        self._note_program(
+        compiling = self._note_program(
             "spec_window", k=k, sampled=sampled, spec=spec,
             feats="+".join(feats), prop_len=prop_len, seq=s,
         )
@@ -3109,9 +3157,11 @@ class StageEngine:
                         produced=produced, **fextra)
             if sampled:
                 ms_w["key"] = jax.random.fold_in(window_key, wdx)
-            ys, self.kv, carry = fn(
-                self.params, self.kv, inputs, ms_w
-            )
+            with compiling:
+                ys, self.kv, carry = fn(
+                    self.params, self.kv, inputs, ms_w
+                )
+            compiling = _NO_SPAN
             windows.append(ys["toks"])
             counts.append(ys["counts"])
             if lps is not None:
@@ -3264,11 +3314,11 @@ class StageEngine:
                     seg.request.state_slot = self._slot_alloc.alloc() + 1
                     src = getattr(seg.request, "restore_state_from", None)
                     if src is not None:
-                        self._note_program("copy_state")
-                        self.kv = self._jit_copy_state(
-                            self.kv, jnp.int32(src),
-                            jnp.int32(seg.request.state_slot),
-                        )
+                        with self._note_program("copy_state"):
+                            self.kv = self._jit_copy_state(
+                                self.kv, jnp.int32(src),
+                                jnp.int32(seg.request.state_slot),
+                            )
                         del seg.request.restore_state_from
         inputs = assemble(
             plan, self.spec, self.cfg.page_size, decode_only=True,
@@ -3327,7 +3377,7 @@ class StageEngine:
             )
         # Compile observatory: the jit key that is about to (maybe)
         # compile — fn variant plus the shape bucket jax keys on.
-        self._note_program(
+        compiling = self._note_program(
             "decode_window", k=k, sampled=sampled,
             fused_sample=fused_sample, feats="+".join(feats), seq=s,
         )
@@ -3350,9 +3400,11 @@ class StageEngine:
                     key=jax.random.fold_in(window_key, w),
                     steps=jnp.asarray(steps0 + w * k),
                 )
-            ys, self.kv, carry = fn(
-                self.params, self.kv, step_inputs, ms_w
-            )
+            with compiling:
+                ys, self.kv, carry = fn(
+                    self.params, self.kv, step_inputs, ms_w
+                )
+            compiling = _NO_SPAN
             windows.append(ys["toks"])
             if lps is not None:
                 lps.append(ys["lp"])
@@ -3388,7 +3440,23 @@ class StageEngine:
         self._inflight.append(ticket)
         return ticket
 
-    def _resolve_multistep(self, ticket: StepTicket) -> StepOutputs:
+    def _readback_span(self, plan: BatchPlan) -> host_span:
+        """The blocking read-back of a visit's device results."""
+        return host_span(
+            "engine.readback_wait", self._h_visit_readback,
+            rows=len(plan.seqs),
+        )
+
+    def _commit_span(self, plan: BatchPlan) -> host_span:
+        """From the read-back to the return of ``resolve``, which closes
+        it (the resolvers enter it on ``resolve``'s ``spans`` stack)."""
+        return host_span(
+            "engine.commit", self._h_visit_commit, rows=len(plan.seqs)
+        )
+
+    def _resolve_multistep(
+        self, ticket: StepTicket, spans: contextlib.ExitStack
+    ) -> StepOutputs:
         """Complete a multi-step decode window chain: ONE device->host
         readback for all window tokens plus the final stop state
         (copies started at dispatch), then per-token ``commit_token`` so
@@ -3403,18 +3471,18 @@ class StageEngine:
         plan = ticket.plan
         t_r0 = time.perf_counter()
         try:
-            tb = time.perf_counter()
-            toks = np.concatenate(
-                [np.asarray(w) for w in ticket.ms_windows], axis=0
-            )                                           # [m*k, S]
-            lp = (
-                np.concatenate(
-                    [np.asarray(x) for x in ticket.ms_lp], axis=0
-                )                                       # f32[m*k, S]
-                if ticket.ms_lp else None
-            )
-            produced = np.asarray(ticket.ms_state[1])   # i32[S]
-            device_ms = (time.perf_counter() - tb) * 1000.0
+            with self._readback_span(plan) as waited:
+                toks = np.concatenate(
+                    [np.asarray(w) for w in ticket.ms_windows], axis=0
+                )                                       # [m*k, S]
+                lp = (
+                    np.concatenate(
+                        [np.asarray(x) for x in ticket.ms_lp], axis=0
+                    )                                   # f32[m*k, S]
+                    if ticket.ms_lp else None
+                )
+                produced = np.asarray(ticket.ms_state[1])   # i32[S]
+            spans.enter_context(self._commit_span(plan))
             total = 0
             gp_committed = gp_window = 0
             for i, seg in enumerate(plan.seqs):
@@ -3466,11 +3534,11 @@ class StageEngine:
         self._goodput.count("committed", gp_committed)
         self._goodput.count("frozen_tail", gp_window - gp_committed)
         return self._multistep_outputs(ticket, plan, total, t_r0,
-                                       device_ms)
+                                       waited.ms)
 
     def _multistep_outputs(
         self, ticket: StepTicket, plan: BatchPlan, total: int,
-        t_r0: float, device_ms: float,
+        t_r0: float, readback_wait_ms: float,
     ) -> StepOutputs:
         """The shared telemetry tail of the window resolvers (plain and
         speculative): latency EWMA amortized over steps actually
@@ -3486,11 +3554,11 @@ class StageEngine:
         # per-step latency the global scheduler uses for placement.
         steps_done = max(1, -(-total // max(1, len(plan.seqs))))
         self._record_latency(plan, host_ms / steps_done)
-        self.step_timing.update(host_ms, device_ms, overlapped,
+        self.step_timing.update(host_ms, readback_wait_ms, overlapped,
                                 tokens=total)
-        self._goodput.add_time("serve", (host_ms + device_ms) / 1e3)
-        self._dev_time.add(
-            ticket.program or "decode_window", (host_ms + device_ms) / 1e3
+        self._goodput.add_time("serve", host_ms / 1e3)
+        self._visit_time.add(
+            ticket.program or "decode_window", host_ms / 1e3
         )
         if total:
             self._h_batch_tokens.observe(total)
@@ -3502,11 +3570,13 @@ class StageEngine:
             num_tokens=total,
             step_time_ms=dt,
             host_ms=host_ms,
-            device_ms=device_ms,
+            readback_wait_ms=readback_wait_ms,
             overlapped=overlapped,
         )
 
-    def _resolve_spec_multistep(self, ticket: StepTicket) -> StepOutputs:
+    def _resolve_spec_multistep(
+        self, ticket: StepTicket, spans: contextlib.ExitStack
+    ) -> StepOutputs:
         """Complete a speculative decode window chain: ONE D2H pass for
         every iteration's target tokens ``[k, S, 1+spec]`` and commit
         counts ``[k, S]`` (copies started at dispatch), then per-token
@@ -3523,29 +3593,29 @@ class StageEngine:
         meta = ticket.spec_meta or {}
         sources = meta.get("sources") or []
         try:
-            tb = time.perf_counter()
-            toks = np.concatenate(
-                [np.asarray(x) for x in ticket.ms_windows], axis=0
-            )                                           # [m*k, S, w]
-            cnts = np.concatenate(
-                [np.asarray(x) for x in ticket.ms_counts], axis=0
-            )                                           # [m*k, S]
-            lp = (
-                np.concatenate(
-                    [np.asarray(x) for x in ticket.ms_lp], axis=0
-                )                                       # f32[m*k, S, w]
-                if ticket.ms_lp else None
-            )
-            rejs = meta.get("rejs")
-            if rejs:
-                rej_total = int(
-                    sum(int(np.asarray(r).sum()) for r in rejs)
+            with self._readback_span(plan) as waited:
+                toks = np.concatenate(
+                    [np.asarray(x) for x in ticket.ms_windows], axis=0
+                )                                       # [m*k, S, w]
+                cnts = np.concatenate(
+                    [np.asarray(x) for x in ticket.ms_counts], axis=0
+                )                                       # [m*k, S]
+                lp = (
+                    np.concatenate(
+                        [np.asarray(x) for x in ticket.ms_lp], axis=0
+                    )                                   # f32[m*k, S, w]
+                    if ticket.ms_lp else None
                 )
-                if rej_total:
-                    self._count_constrained(
-                        spec_mask_rejections=rej_total
+                rejs = meta.get("rejs")
+                if rejs:
+                    rej_total = int(
+                        sum(int(np.asarray(r).sum()) for r in rejs)
                     )
-            device_ms = (time.perf_counter() - tb) * 1000.0
+                    if rej_total:
+                        self._count_constrained(
+                            spec_mask_rejections=rej_total
+                        )
+            spans.enter_context(self._commit_span(plan))
             w = int(toks.shape[2])
             iters = int(toks.shape[0])
             total = 0
@@ -3627,7 +3697,7 @@ class StageEngine:
             + (gp_dev_committed - gp_committed),
         )
         return self._multistep_outputs(ticket, plan, total, t_r0,
-                                       device_ms)
+                                       waited.ms)
 
     # -- speculative decoding (prompt-lookup) -----------------------------
 
@@ -3834,11 +3904,11 @@ class StageEngine:
         lora = self._lora_field(spec_plan, inputs)
         if lora is not None:
             inputs = dataclasses.replace(inputs, lora=lora)
-        self._note_program(
+        with self._note_program(
             "spec_verify", tokens=int(inputs.token_ids.shape[0]),
             seq=int(inputs.kv_lens.shape[0]),
-        )
-        out, self.kv = self._jit_step(self.params, self.kv, inputs)
+        ):
+            out, self.kv = self._jit_step(self.params, self.kv, inputs)
         try:
             out.copy_to_host_async()
         except AttributeError:  # stubbed jit call in tests
@@ -3856,7 +3926,9 @@ class StageEngine:
         self._inflight.append(ticket)
         return ticket
 
-    def _resolve_speculative(self, ticket: StepTicket) -> StepOutputs:
+    def _resolve_speculative(
+        self, ticket: StepTicket, spans: contextlib.ExitStack
+    ) -> StepOutputs:
         """Complete a sync-fallback speculative verify: read the logits
         back (the designated sync point), derive per-position targets —
         greedy argmax, or the lockstep seeded draw — and commit each
@@ -3876,34 +3948,39 @@ class StageEngine:
                 and seg.request.sampling_params.seed is None
                 for seg in spec_segs
             )
-            tb = time.perf_counter()
-            if all_greedy:
-                verified = np.asarray(greedy_tokens(ticket.out))
-            else:
-                # Lockstep sampled verification: every fed position
-                # draws from the TARGET distribution with the row's
-                # params and the SAME per-output-index key a sequential
-                # decode would use. Padded positions keep temp=0
-                # (argmax, discarded).
-                entries = []
-                row = 0
-                for seg in spec_segs:
-                    n_fed = seg.num_new_tokens
-                    origin = self._row_sampling_fields(seg.request)[-1]
-                    entries.append((seg.request, row, row + n_fed, origin))
-                    row += n_fed
-                temp, top_k, top_p, min_p, seeds, steps = (
-                    self._pack_lockstep_vectors(
-                        int(ticket.out.shape[0]), entries
+            with self._readback_span(plan) as waited:
+                if all_greedy:
+                    verified = np.asarray(greedy_tokens(ticket.out))
+                else:
+                    # Lockstep sampled verification: every fed position
+                    # draws from the TARGET distribution with the row's
+                    # params and the SAME per-output-index key a
+                    # sequential decode would use. Padded positions keep
+                    # temp=0 (argmax, discarded).
+                    entries = []
+                    row = 0
+                    for seg in spec_segs:
+                        n_fed = seg.num_new_tokens
+                        origin = self._row_sampling_fields(
+                            seg.request
+                        )[-1]
+                        entries.append(
+                            (seg.request, row, row + n_fed, origin)
+                        )
+                        row += n_fed
+                    temp, top_k, top_p, min_p, seeds, steps = (
+                        self._pack_lockstep_vectors(
+                            int(ticket.out.shape[0]), entries
+                        )
                     )
-                )
-                key = jax.random.fold_in(self._base_key, ticket.step_idx)
-                verified = np.asarray(sample_tokens(
-                    ticket.out, key, temp, top_k, top_p, min_p,
-                    seeds=seeds, out_steps=steps,
-                ))
-            device_ms = (time.perf_counter() - tb) * 1000.0
-
+                    key = jax.random.fold_in(
+                        self._base_key, ticket.step_idx
+                    )
+                    verified = np.asarray(sample_tokens(
+                        ticket.out, key, temp, top_k, top_p, min_p,
+                        seeds=seeds, out_steps=steps,
+                    ))
+            spans.enter_context(self._commit_span(plan))
             total = 0
             fed_total = accepted_total = 0
             row = 0
@@ -3949,7 +4026,7 @@ class StageEngine:
             self._abandon(plan)
             raise
         return self._multistep_outputs(ticket, plan, total, t_r0,
-                                       device_ms)
+                                       waited.ms)
 
     def _extend_plan_pp_spec(self, plan: BatchPlan) -> None:
         """Multi-stage head: extend eligible decode rows with speculative
@@ -4085,18 +4162,38 @@ class StageEngine:
             )
         t0 = time.perf_counter()
         self._dispatch_seq += 1
+        with host_span("sched.form_plan", self._h_visit_plan) as planning:
+            sp_plan = self._take_sp_plan()
+            plan = sp_plan if sp_plan is not None else self._form_plan()
+            if plan.is_empty:
+                # No visit follows: its phases are observed for visits
+                # that parallax_step_host_ms counts, and no others.
+                planning.series = None
+        if plan.is_empty:
+            return StepTicket(
+                plan=plan, step_idx=self._step_count, t0=t0,
+                outputs=StepOutputs(
+                    forward=[], finished=self._collect_finished()
+                ),
+            )
+        with host_span(
+            "engine.pack", self._h_visit_pack, rows=len(plan.seqs),
+            tokens=plan.total_new_tokens,
+        ):
+            return self._dispatch_plan(plan, sp_plan, t0)
+
+    def _dispatch_plan(
+        self, plan: BatchPlan, sp_plan: BatchPlan | None, t0: float
+    ) -> StepTicket:
+        """The rest of ``dispatch`` once the plan is formed (the
+        ``engine.pack`` span): host arrays, page tables, H2D and the
+        enqueue, up to and including the jit call's return."""
 
         def _done(outputs: StepOutputs) -> StepTicket:
             return StepTicket(
                 plan=plan, step_idx=self._step_count, t0=t0, outputs=outputs
             )
 
-        sp_plan = self._take_sp_plan()
-        plan = sp_plan if sp_plan is not None else self._form_plan()
-        if plan.is_empty:
-            return _done(
-                StepOutputs(forward=[], finished=self._collect_finished())
-            )
         if plan.mixed_lora:
             # Mixed-adapter batch: abort only the rows whose adapter this
             # stage does not serve; the rest proceed.
@@ -4131,6 +4228,8 @@ class StageEngine:
             # Tracing-off fast path: the set is empty unless sampling is
             # on, so the default config pays one falsy check here.
             self._trace_queue_wait(plan)
+        if self._unplanned:
+            self._observe_admit_wait(plan)
         # The fused window path runs FIRST: with speculation configured
         # it stages proposals and verifies them INSIDE the K-step scan
         # (spec rows no longer downshift the window), and with
@@ -4185,11 +4284,11 @@ class StageEngine:
                     # reset flag stays 0 and the copied state stands).
                     src = getattr(seg.request, "restore_state_from", None)
                     if src is not None:
-                        self._note_program("copy_state")
-                        self.kv = self._jit_copy_state(
-                            self.kv, jnp.int32(src),
-                            jnp.int32(seg.request.state_slot),
-                        )
+                        with self._note_program("copy_state"):
+                            self.kv = self._jit_copy_state(
+                                self.kv, jnp.int32(src),
+                                jnp.int32(seg.request.state_slot),
+                            )
                         del seg.request.restore_state_from
         # Last stage of a multi-stage pipeline: rows carrying unverified
         # speculative tokens are greedy-verified against logits at EVERY
@@ -4208,11 +4307,13 @@ class StageEngine:
             )
             self._count_kernel_dispatch("prefill", self._sp_prefill_impl)
             program = "sp_prefill"
-            self._note_program(
+            with self._note_program(
                 program, tokens=int(inputs.token_ids.shape[0]),
                 seq=int(inputs.kv_lens.shape[0]),
-            )
-            out, self.kv = self._jit_sp_step(self.params, self.kv, inputs)
+            ):
+                out, self.kv = self._jit_sp_step(
+                    self.params, self.kv, inputs
+                )
         else:
             # Decode-only batches compile their own variant (static flag)
             # so decode-specialized Pallas kernels can dispatch. Set for
@@ -4238,12 +4339,12 @@ class StageEngine:
             if fed_rows:
                 inputs = self._substitute_feed(plan, inputs)
             program = "decode" if one_token else "prefill"
-            self._note_program(
+            with self._note_program(
                 program, tokens=int(inputs.token_ids.shape[0]),
                 seq=int(inputs.kv_lens.shape[0]),
                 decode_only=decode_only,
-            )
-            out, self.kv = self._jit_step(self.params, self.kv, inputs)
+            ):
+                out, self.kv = self._jit_step(self.params, self.kv, inputs)
 
         # Advance scheduler state first: a locally-committed sampled token
         # (single-stage ring closure) must not be clobbered by the
@@ -4312,14 +4413,13 @@ class StageEngine:
         if ticket.outputs is not None:
             o = ticket.outputs
             if o.num_tokens:
-                self.step_timing.update(o.host_ms, o.device_ms, o.overlapped,
-                                        tokens=o.num_tokens)
-                self._goodput.add_time(
-                    "serve", (o.host_ms + o.device_ms) / 1e3
+                self.step_timing.update(
+                    o.host_ms, o.readback_wait_ms, o.overlapped,
+                    tokens=o.num_tokens,
                 )
-                self._dev_time.add(
-                    ticket.program or "decode",
-                    (o.host_ms + o.device_ms) / 1e3,
+                self._goodput.add_time("serve", o.host_ms / 1e3)
+                self._visit_time.add(
+                    ticket.program or "decode", o.host_ms / 1e3
                 )
                 self._h_batch_tokens.observe(o.num_tokens)
                 if self._traced:
@@ -4327,31 +4427,45 @@ class StageEngine:
                         ticket.plan, ticket.t0, time.perf_counter()
                     )
             return o
-        if ticket.ms_counts is not None:
-            return self._resolve_spec_multistep(ticket)
-        if ticket.ms_windows is not None:
-            return self._resolve_multistep(ticket)
-        if ticket.spec_verify is not None:
-            return self._resolve_speculative(ticket)
+        # The resolver enters its ``engine.commit`` span on this stack
+        # once its read-back is done; it closes when resolve returns.
+        with contextlib.ExitStack() as spans:
+            if ticket.ms_counts is not None:
+                return self._resolve_spec_multistep(ticket, spans)
+            if ticket.ms_windows is not None:
+                return self._resolve_multistep(ticket, spans)
+            if ticket.spec_verify is not None:
+                return self._resolve_speculative(ticket, spans)
+            return self._resolve_step(ticket, spans)
+
+    def _resolve_step(
+        self, ticket: StepTicket, spans: contextlib.ExitStack
+    ) -> StepOutputs:
+        """Complete a plain (single-step) ticket."""
         plan = ticket.plan
         t_r0 = time.perf_counter()
-        device_ms = 0.0
+        readback_wait_ms = 0.0
         try:
+            # What the blocking read-back fetches, where dispatch left
+            # one to fetch: this stage's hidden rows, or the tokens of
+            # the sampler enqueued at dispatch.
+            fetch = ticket.out if not self.model.is_last else (
+                None if ticket.spec_rows else ticket.tokens_dev
+            )
+            if fetch is not None:
+                with self._readback_span(plan) as waited:
+                    fetched = np.asarray(fetch)
+                readback_wait_ms = waited.ms
+            spans.enter_context(self._commit_span(plan))
             if not self.model.is_last:
-                tb = time.perf_counter()
-                hidden_out = np.asarray(ticket.out)
-                device_ms = (time.perf_counter() - tb) * 1000.0
-                forwards = self._emit_hidden(plan, hidden_out)
+                forwards = self._emit_hidden(plan, fetched)
             elif ticket.spec_rows:
                 forwards = self._verify_and_emit(
                     plan, ticket.inputs, ticket.out, ticket.spec_rows,
                     ticket.step_idx,
                 )
             elif ticket.tokens_dev is not None:
-                tb = time.perf_counter()
-                tokens = np.asarray(ticket.tokens_dev)
-                device_ms = (time.perf_counter() - tb) * 1000.0
-                forwards = self._emit_tokens(plan, tokens, None)
+                forwards = self._emit_tokens(plan, fetched, None)
             else:
                 tokens, logprobs = self._sample(
                     ticket.out, ticket.inputs, plan, ticket.step_idx
@@ -4367,7 +4481,7 @@ class StageEngine:
         # Latency EWMA: an overlapped ticket's t0->resolve span covers
         # the interleaved next dispatch too; the per-iteration cost the
         # scheduler should see is the host-blocking time (which already
-        # includes any residual device wait as its device_ms portion).
+        # includes any residual device wait, its readback_wait_ms part).
         # Sync tickets' host_ms equals their full wall, so the EWMA is
         # unchanged there.
         self._record_latency(plan, host_ms)
@@ -4376,12 +4490,10 @@ class StageEngine:
         # a 2048-token prompt chunk would otherwise record near-zero
         # "per-token" host cost into the TPOT-facing histogram.
         emitted = sum(1 for seg in plan.seqs if self._needs_token(seg))
-        self.step_timing.update(host_ms, device_ms, overlapped,
+        self.step_timing.update(host_ms, readback_wait_ms, overlapped,
                                 tokens=emitted)
-        self._goodput.add_time("serve", (host_ms + device_ms) / 1e3)
-        self._dev_time.add(
-            ticket.program or "decode", (host_ms + device_ms) / 1e3
-        )
+        self._goodput.add_time("serve", host_ms / 1e3)
+        self._visit_time.add(ticket.program or "decode", host_ms / 1e3)
         # Goodput: a replay-restored request's prompt re-prefill
         # recomputes positions the dead pipeline already computed — the
         # price of a churn event, counted as rework (head stage only;
@@ -4406,7 +4518,7 @@ class StageEngine:
             num_tokens=plan.total_new_tokens,
             step_time_ms=dt,
             host_ms=host_ms,
-            device_ms=device_ms,
+            readback_wait_ms=readback_wait_ms,
             overlapped=overlapped,
         )
 
@@ -5158,10 +5270,10 @@ class StageEngine:
                         continue
             else:
                 slot = snap[1]
-            self._note_program("copy_state")
-            self.kv = self._jit_copy_state(
-                self.kv, jnp.int32(req.state_slot), jnp.int32(slot)
-            )
+            with self._note_program("copy_state"):
+                self.kv = self._jit_copy_state(
+                    self.kv, jnp.int32(req.state_slot), jnp.int32(slot)
+                )
             snaps[kind] = (c, slot)
 
     def _record_latency(self, plan: BatchPlan, ms: float) -> None:

@@ -14,6 +14,7 @@ pin multi-stage streams bit-identical to the direct-call path.
 
 from __future__ import annotations
 
+from parallax_tpu.obs.trace import visit_span
 from parallax_tpu.runtime.engine import StageEngine
 from parallax_tpu.runtime.request import Request
 
@@ -32,6 +33,8 @@ class InProcessPipeline:
         self.wire = wire or wire_dtype is not None
         self.wire_dtype = wire_dtype
         self.finished: list[Request] = []
+        # Step rounds so far: the number of the ``parallax.visit`` span.
+        self.visits = 0
 
     @property
     def head(self) -> StageEngine:
@@ -71,7 +74,16 @@ class InProcessPipeline:
         return out
 
     def step_round(self) -> list[Request]:
-        """One step of every stage, routing packets around the ring."""
+        """One step of every stage, routing packets around the ring:
+        one visit (``parallax.visit``; the engines' spans carry its
+        number)."""
+        self.visits += 1
+        with visit_span(self.visits):
+            newly_finished = self._step_stages()
+        self.finished.extend(newly_finished)
+        return newly_finished
+
+    def _step_stages(self) -> list[Request]:
         newly_finished: list[Request] = []
         for i, engine in enumerate(self.engines):
             out = engine.step()
@@ -95,7 +107,6 @@ class InProcessPipeline:
                 for other in self.engines:
                     if other is not engine:
                         other.release(req.request_id, abort=aborted)
-        self.finished.extend(newly_finished)
         return newly_finished
 
     def run_until_complete(self, max_rounds: int = 10000) -> list[Request]:

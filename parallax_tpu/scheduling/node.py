@@ -240,8 +240,8 @@ class Node:
     refit_version: int = 0
     # True once the node reports its executor is serving.
     is_ready: bool = False
-    # Two-phase decode telemetry from heartbeats (host_ms/device_ms
-    # EWMAs, overlap fraction); surfaced in /cluster/status.
+    # Two-phase decode telemetry from heartbeats (host_ms and
+    # readback_wait_ms EWMAs, overlap fraction); surfaced in /cluster/status.
     step_timing: dict | None = None
     # Prefix-cache / memory-tier counters from heartbeats (hit rates
     # split device/host tier, occupancy, demotion/swap-in/preemption

@@ -1251,7 +1251,7 @@ class GlobalScheduler:
                         # section (obs/device.py).
                         "device": n.device,
                         # Overlapped decode loop telemetry (host_ms /
-                        # device_ms EWMAs + overlap fraction).
+                        # readback_wait_ms EWMAs + overlap fraction).
                         "step_timing": n.step_timing,
                         # Prefix-cache / memory-tier counters (hit
                         # rates, occupancy, demotions, swap-ins,

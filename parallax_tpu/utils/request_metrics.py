@@ -7,7 +7,8 @@ chat CLI and the benchmark client to report per-request numbers without
 trusting server-side aggregation.
 
 Server side — :class:`StepTimingAggregator` folds the two-phase engine
-step's ``host_ms``/``device_ms``/``overlapped`` telemetry (StepOutputs)
+step's ``host_ms``/``readback_wait_ms``/``overlapped`` telemetry
+(StepOutputs)
 into EWMAs published via worker heartbeats and ``/cluster/status``, so
 operators can see how much host scheduling time the overlapped decode
 loop actually hides behind device compute.
@@ -22,10 +23,12 @@ from typing import Any
 class StepTimingAggregator:
     """EWMA over per-step timing from the two-phase decode loop.
 
-    Optionally feeds the same samples into metrics-registry histograms
+    Optionally feeds the host samples into metrics-registry histograms
     (``obs/registry.py``) so ``/metrics`` and cluster-wide heartbeat
     merges see full distributions, not just EWMAs — one choke point for
-    every resolve path (sync, deferred-sampler, fused multistep).
+    every resolve path (sync, deferred-sampler, fused multistep). The
+    read-back wait's histogram is fed by its host span
+    (``parallax_visit_readback_wait_ms``), not from here.
 
     Multi-step decode commits K tokens per host visit, so the aggregator
     keeps TWO series: per-HOST-VISIT cost (``host_ms_ewma`` — what a
@@ -36,29 +39,28 @@ class StepTimingAggregator:
     K=1 one it beats.
     """
 
-    def __init__(self, alpha: float = 0.2, host_hist=None, device_hist=None,
+    def __init__(self, alpha: float = 0.2, host_hist=None,
                  per_token_hist=None):
         self.alpha = alpha
         self.host_ms_ewma: float | None = None
-        self.device_ms_ewma: float | None = None
+        self.readback_wait_ms_ewma: float | None = None
         self.per_token_host_ms_ewma: float | None = None
         self.steps = 0
         self.tokens = 0
         self.overlapped_steps = 0
         self.host_hist = host_hist
-        self.device_hist = device_hist
         self.per_token_hist = per_token_hist
 
-    def update(self, host_ms: float, device_ms: float,
+    def update(self, host_ms: float, readback_wait_ms: float,
                overlapped: bool, tokens: int = 1) -> None:
         a = self.alpha
         self.host_ms_ewma = (
             host_ms if self.host_ms_ewma is None
             else (1 - a) * self.host_ms_ewma + a * host_ms
         )
-        self.device_ms_ewma = (
-            device_ms if self.device_ms_ewma is None
-            else (1 - a) * self.device_ms_ewma + a * device_ms
+        self.readback_wait_ms_ewma = (
+            readback_wait_ms if self.readback_wait_ms_ewma is None
+            else (1 - a) * self.readback_wait_ms_ewma + a * readback_wait_ms
         )
         self.steps += 1
         self.tokens += max(0, tokens)
@@ -66,8 +68,6 @@ class StepTimingAggregator:
             self.overlapped_steps += 1
         if self.host_hist is not None:
             self.host_hist.observe(host_ms)
-        if self.device_hist is not None:
-            self.device_hist.observe(device_ms)
         if tokens > 0:
             per_tok = host_ms / tokens
             self.per_token_host_ms_ewma = (
@@ -83,7 +83,7 @@ class StepTimingAggregator:
             return None
         d = {
             "host_ms_ewma": round(self.host_ms_ewma, 3),
-            "device_ms_ewma": round(self.device_ms_ewma, 3),
+            "readback_wait_ms_ewma": round(self.readback_wait_ms_ewma, 3),
             "steps": self.steps,
             "host_visits": self.steps,
             "tokens": self.tokens,

@@ -30,8 +30,8 @@ def test_bench_cpu_smoke_prints_one_json_line():
     assert len(json_lines) == 1, json_lines
     assert rec["detail"]["platform"] == "cpu", rec["detail"]["platform"]
     # Two-phase decode-loop telemetry is part of the bench contract.
-    for key in ("host_ms_median", "device_ms_median", "overlapped_steps",
-                "sync_decode_dispatch_ms_median"):
+    for key in ("host_ms_median", "readback_wait_ms_median",
+                "overlapped_steps", "sync_decode_dispatch_ms_median"):
         assert key in rec["detail"], rec["detail"]
     # Cache observability + the host-KV-tier pressure probe: the tier-on
     # run must finish everything without kv_oom while the tier-off run

@@ -18,7 +18,7 @@ from parallax_tpu.backend.http_server import OpenAIFrontend, SimpleTokenizer
 from parallax_tpu.obs.device import (
     CompileObservatory,
     DevicePlane,
-    DeviceTimeAttributor,
+    ProgramVisitAttributor,
     HbmLedger,
     get_device_plane,
     merge_device,
@@ -291,12 +291,12 @@ class TestCompileObservatory:
         assert 'parallax_xla_compile_ms_total{program="decode"} 250' in text
 
 
-# -- device time -------------------------------------------------------------
+# -- host-visit seconds by program --------------------------------------------
 
 
 class TestDeviceTime:
     def test_shares_sum_to_one(self):
-        dt = DeviceTimeAttributor(registry=MetricsRegistry())
+        dt = ProgramVisitAttributor(registry=MetricsRegistry())
         dt.add("decode_window", 3.0)
         dt.add("prefill", 1.0)
         dt.add("decode_window", 1.0)
@@ -309,7 +309,7 @@ class TestDeviceTime:
         assert abs(sum(snap["share"].values()) - 1.0) < 1e-6
 
     def test_empty_share_when_idle(self):
-        dt = DeviceTimeAttributor(registry=MetricsRegistry())
+        dt = ProgramVisitAttributor(registry=MetricsRegistry())
         snap = dt.snapshot()
         assert snap["seconds_total"] == 0
         assert snap["share"] == {}
