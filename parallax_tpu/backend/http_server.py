@@ -655,8 +655,10 @@ class OpenAIFrontend:
         ``max_seconds`` auto-stop.
 
         The reply comes once ``start_trace`` has returned and carries
-        this process's ``perf_counter_ns`` at that instant (also emitted
-        as the ``parallax.clock_sync`` span, obs/trace.py).
+        this process's ``perf_counter_ns`` from just before it was
+        called: with the stop's reading it brackets every event of the
+        trace (the ``parallax.clock_sync`` span, obs/trace.py, marks the
+        return).
         ``"request_spans": <0..1>`` samples per-request ``TraceStore``
         spans at that rate for requests submitted while the profile
         runs; the stop restores the configured rate.
@@ -713,11 +715,12 @@ class OpenAIFrontend:
             return self._error(409, "profiler already running")
         from parallax_tpu.obs.trace import clock_sync
 
+        before_ns = time.perf_counter_ns()
         try:
             jax.profiler.start_trace(out_dir, profiler_options=options)
         except Exception as e:
             return self._error(500, f"profiler start failed: {e}")
-        now_ns = clock_sync()
+        clock_sync()
         self._profiling = True
         self._profile_dir = out_dir
         if spans_rate is not None:
@@ -728,9 +731,9 @@ class OpenAIFrontend:
         )
         return web.json_response({
             "profiling": True, "dir": out_dir, "max_seconds": max_seconds,
-            # This process's clock when ``start_trace`` had returned
-            # (the ``parallax.clock_sync`` marker's reading).
-            "perf_counter_ns": now_ns,
+            # This process's clock just before ``start_trace`` was
+            # called: no event of the trace is earlier.
+            "perf_counter_ns": before_ns,
         })
 
     def _profile_deadline(self) -> None:
@@ -749,8 +752,14 @@ class OpenAIFrontend:
         """``stop_trace`` on a thread: it writes the whole trace (14-35 s
         for 4 s of a 7B decode; PERF.md, PR 25), and streams must keep
         flowing meanwhile. ``_profiling`` clears only when it has returned. The
-        reply's fields, or None where ``stop_trace`` raised (logged)."""
-        from parallax_tpu.obs.trace import clock_sync
+        reply's fields, or None where ``stop_trace`` raised (logged).
+
+        The device tracer stops some ms after the call, so the thread
+        then reads the trace once for the end of its last device event
+        (``traced_device_end_ns``): with a device that never idles, the
+        clock at the call would leave device events outside the span the
+        two replies give."""
+        from parallax_tpu.obs.trace import clock_sync, traced_device_end_ns
 
         def stop():
             import jax
@@ -758,11 +767,14 @@ class OpenAIFrontend:
             now_ns = clock_sync()
             t0 = time.perf_counter()
             jax.profiler.stop_trace()
-            return now_ns, time.perf_counter() - t0
+            seconds = time.perf_counter() - t0
+            xplane = _newest_xplane(self._profile_dir)
+            end_ns = traced_device_end_ns(xplane) if xplane else None
+            return max(now_ns, end_ns or 0), seconds, xplane
 
         self._profile_stopping = True
         try:
-            now_ns, seconds = await asyncio.to_thread(stop)
+            end_ns, seconds, xplane = await asyncio.to_thread(stop)
         except Exception:
             logger.exception("profiler stop failed")
             return None
@@ -773,11 +785,12 @@ class OpenAIFrontend:
                 self.request_spans_fn(None)
         return {
             "profiling": False,
-            # This process's clock at the call of ``stop_trace``, the
-            # seconds the write took, and the trace it wrote.
-            "perf_counter_ns": now_ns,
+            # This process's clock at the end of the trace's last device
+            # event (at the call of ``stop_trace`` where it holds none),
+            # the seconds the write took, and the trace it wrote.
+            "perf_counter_ns": end_ns,
             "stop_seconds": seconds,
-            "xplane": _newest_xplane(self._profile_dir),
+            "xplane": xplane,
         }
 
     async def _profile_cluster(self, action, pipeline, out_dir,

@@ -59,6 +59,14 @@ class LocalRunner:
     def stop(self) -> None:
         self._stop.set()
         self._thread.join(timeout=3.0)
+        # The loop keeps one step in flight; a loop that has ended
+        # leaves none (a failed loop discarded its own in ``_fail``).
+        if not self._thread.is_alive() and self.failure is None:
+            with self._lock:
+                try:
+                    self._wake(self.pipeline.settle())
+                except Exception as e:
+                    self._fail(e)
 
     def submit(self, request: Request) -> threading.Event:
         ev = threading.Event()
@@ -113,23 +121,30 @@ class LocalRunner:
                     "runner.loop_gap", self._h_loop_gap,
                     visit=self.pipeline.visits,
                 ).__enter__()
-                for req in finished:
-                    _, ev = self._pending.pop(
-                        req.request_id, (None, None)
-                    )
-                    if ev is not None:
-                        ev.set()
+                self._wake(finished)
         end_gap()
         if self.failure is not None and self.on_failure is not None:
             self.on_failure(self.failure)
 
+    def _wake(self, finished: list[Request]) -> None:
+        """Complete the events of requests a step round finished."""
+        for req in finished:
+            _, ev = self._pending.pop(req.request_id, (None, None))
+            if ev is not None:
+                ev.set()
+
     def _fail(self, exc: BaseException) -> None:
         """The step loop died (lock held): record why and release every
         waiter with an aborted request, so HTTP handlers answer 5xx
-        instead of waiting out their timeout against a dead thread."""
+        instead of waiting out their timeout against a dead thread. The
+        step the loop kept in flight is discarded with it."""
         logger.error("step loop failed; failing %d pending request(s)",
                      len(self._pending), exc_info=exc)
         self.failure = exc
+        try:
+            self.pipeline.settle(discard=True)
+        except Exception:
+            logger.exception("discarding the in-flight step failed")
         reason = f"step loop failed: {type(exc).__name__}: {exc}"
         for req, ev in self._pending.values():
             if not req.status.is_finished:

@@ -42,6 +42,7 @@ VISIT_PLAN_MS = "parallax_visit_plan_ms"
 VISIT_PACK_MS = "parallax_visit_pack_ms"
 VISIT_READBACK_WAIT_MS = "parallax_visit_readback_wait_ms"
 VISIT_COMMIT_MS = "parallax_visit_commit_ms"
+VISIT_WINDOW_AHEAD = "parallax_visit_window_ahead"
 LOOP_GAP_MS = "parallax_loop_gap_ms"
 ADMIT_WAIT_MS = "parallax_admit_wait_ms"
 
@@ -202,6 +203,12 @@ HELP: dict[str, str] = {
         "Milliseconds from a visit's read-back to the return of "
         "resolve: commit loop, ledgers, finish collection; span "
         "parallax.engine.commit"
+    ),
+    VISIT_WINDOW_AHEAD: (
+        "Per decode window enqueued: 1 where it started from the "
+        "device-resident carry of the window still in flight (the host "
+        "stayed one window ahead), 0 where it waited for a resolve; "
+        "sum/count is the share of windows ahead"
     ),
     LOOP_GAP_MS: (
         "Milliseconds between the end of one locked step round of the "

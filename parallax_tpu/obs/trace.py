@@ -350,6 +350,35 @@ def clock_sync() -> int:
     return now
 
 
+def traced_device_end_ns(xplane: str) -> int | None:
+    """This process's ``perf_counter_ns`` at the end of the last device
+    event in one ``.xplane.pb``. The profiler stops its device tracer
+    last, some ms after ``stop_trace`` is called (12-18 ms on the v5e;
+    PERF.md, PR 26), so a device that never idles leaves events past the
+    stop's :func:`clock_sync`. The trace's clock is laid on this
+    process's by its ``parallax.clock_sync`` marks (the smallest offset:
+    a mark's timestamp can only lag its reading). None where the trace
+    holds no device plane or no mark (the CPU backend writes none)."""
+    from jax.profiler import ProfileData
+
+    end = offset = None
+    for plane in ProfileData.from_file(xplane).planes:
+        device = plane.name.startswith("/device:")
+        for line in plane.lines:
+            for ev in line.events:
+                if device:
+                    t = ev.start_ns + ev.duration_ns
+                    if end is None or t > end:
+                        end = t
+                elif ev.name == SPAN_PREFIX + "clock_sync":
+                    off = ev.start_ns - int(dict(ev.stats)["perf_counter_ns"])
+                    if offset is None or off < offset:
+                        offset = off
+    if end is None or offset is None:
+        return None
+    return int(end - offset)
+
+
 _STORE = TraceStore()
 
 

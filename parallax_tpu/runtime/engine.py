@@ -113,10 +113,15 @@ class EngineConfig:
     # host round. Window j+1 is dispatched from window j's device-resident
     # carry (last token + context length) BEFORE window j's tokens are
     # read back, so the host<->device roundtrip is paid once per
-    # ``decode_pipeline * decode_lookahead`` tokens and the chip never
-    # idles between windows (async dispatch; same exactness invariants as
-    # a single window — surplus tokens past a mid-chain finish are
-    # discarded). 1 = off.
+    # ``decode_pipeline * decode_lookahead`` tokens (async dispatch; same
+    # exactness invariants as a single window — surplus tokens past a
+    # mid-chain finish are discarded). 1 = off. Since the step loops
+    # hand a steady batch's window N+1 over from window N's carry across
+    # two dispatches (``_dispatch_multistep``), the chip no longer idles
+    # between windows at 1 either; what m > 1 still buys is ONE host
+    # visit per m*k tokens, at the price of streaming m*k tokens a chunk
+    # — worth it only where the host's work per visit is longer than a
+    # window of device time (host-bound batches).
     decode_pipeline: int = 1
     # Speculative decoding: verify up to this many proposed continuation
     # tokens per decode step. 0 = off. Proposals come from prompt-lookup
@@ -316,6 +321,15 @@ class StepTicket:
     # produced counts).
     ms_windows: list | None = None
     ms_state: tuple | None = None
+    # Plain window, hand-over (``_dispatch_multistep``): the chain's final
+    # device-resident carry, kept while the window's rows are offered to
+    # the next plan; ``chained`` marks a window that itself started from
+    # the previous ticket's carry (its rows' computed count was not
+    # advanced at dispatch), ``handed_over`` one whose rows the next
+    # ticket already took (its resolve leaves them un-schedulable).
+    ms_carry: dict | None = None
+    chained: bool = False
+    handed_over: bool = False
     # Speculative decode window: per-window [k, S] commit-count arrays
     # (each scan iteration's tokens are [S, 1+spec]; counts bound the
     # commits) plus staging metadata (width, per-row proposal source,
@@ -873,6 +887,14 @@ class StageEngine:
         # rows whose sampled token has not reached the host yet.
         self._inflight: list[StepTicket] = []
         self._dispatch_seq = 0
+        # Where a window program returns its carry on a TP-sharded stage
+        # (``_tp_wrap_multistep``: replicated over the mesh); None on an
+        # unsharded engine. See ``_carry_in``.
+        self._carry_sharding = None
+        if mesh is not None and model.tp_size > 1:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._carry_sharding = NamedSharding(mesh, PartitionSpec())
         self._last_token_dev = jnp.zeros(
             (self.cfg.max_batch_size,), jnp.int32
         )
@@ -1128,12 +1150,14 @@ class StageEngine:
         return rows_of, cached
 
     def _pack_window_features(self, plan: BatchPlan, s: int,
-                              feats: tuple):
+                              feats: tuple, initial: bool = True):
         """Device-side state for a feature window: the ms-dict arrays
         the compiled scan reads (penalty strengths, bias vectors,
         combined grammar tables, per-row constrained flags) plus the
         INITIAL scan-carry feature state (per-row output-token counts
-        seeded from the committed stream; per-row DFA rows). Every
+        seeded from the committed stream; per-row DFA rows) — left out
+        (``initial=False``) for a window that takes that state from the
+        carry of the window before it. Every
         array replicates the host sampler's packing exactly — neutral
         rows carry neutral params (0/0/1.0 penalties, bias row -1,
         constrained False), which the feature math leaves bit-identical
@@ -1159,23 +1183,24 @@ class StageEngine:
                     freq[i] = sp.frequency_penalty
                     rep[i] = sp.repetition_penalty
                     gen_lists[i] = self._generated_ids(seg.request)
-            max_len = max(
-                (len(g) for g in gen_lists.values()), default=0
-            )
-            bucket = 8
-            while bucket < max_len:
-                bucket *= 2
-            out_ids = np.full((s, bucket), -1, np.int32)
-            for i, gen in gen_lists.items():
-                if gen:
-                    out_ids[i, : len(gen)] = gen
             ms_extra.update(
                 pen_pres=jnp.asarray(pres), pen_freq=jnp.asarray(freq),
                 pen_rep=jnp.asarray(rep),
             )
-            fcarry["pen_counts"] = output_token_counts(
-                jnp.asarray(out_ids), v
-            )
+            if initial:
+                max_len = max(
+                    (len(g) for g in gen_lists.values()), default=0
+                )
+                bucket = 8
+                while bucket < max_len:
+                    bucket *= 2
+                out_ids = np.full((s, bucket), -1, np.int32)
+                for i, gen in gen_lists.items():
+                    if gen:
+                        out_ids[i, : len(gen)] = gen
+                fcarry["pen_counts"] = output_token_counts(
+                    jnp.asarray(out_ids), v
+                )
         if "bias" in feats:
             b_rows, b_vecs = [], []
             for i, seg in enumerate(plan.seqs):
@@ -1231,7 +1256,8 @@ class StageEngine:
                 g_constrained=jnp.asarray(constrained),
                 g_dead=jnp.asarray(dead),
             )
-            fcarry["dfa"] = jnp.asarray(dfa0)
+            if initial:
+                fcarry["dfa"] = jnp.asarray(dfa0)
         return ms_extra, fcarry
 
     def _stage_fn(self, params, kv, inputs: BatchInputs):
@@ -1728,6 +1754,14 @@ class StageEngine:
         self._h_visit_readback = phase(mnames.VISIT_READBACK_WAIT_MS)
         self._h_visit_commit = phase(mnames.VISIT_COMMIT_MS)
         self._h_admit_wait = phase(mnames.ADMIT_WAIT_MS)
+        # 1 per decode window enqueued off the carry of the window still
+        # in flight, 0 per window that waited for a resolve: the mean is
+        # the share of windows the host stayed ahead of.
+        self._h_window_ahead = reg.histogram(
+            mnames.VISIT_WINDOW_AHEAD,
+            mnames.help_text(mnames.VISIT_WINDOW_AHEAD),
+            buckets=(0.0, 1.0), labelnames=st,
+        ).labels(**lbl)
         # Head stage: ids of submitted requests no plan has held yet;
         # their first plan observes parallax_admit_wait_ms. Empty in
         # steady decode, so dispatch pays one falsy check.
@@ -2613,10 +2647,10 @@ class StageEngine:
         combined EOS + stop-token set (-1 padded; empty under
         ``ignore_eos``, matching commit_token which ignores both then),
         the remaining generation budget before a length freeze, and the
-        min_new_tokens gate. Budgets count the pending device-fed token
-        of overlap-fed rows (sampled by the in-flight step, not yet
-        committed). Padded bucket rows keep limit 0 and freeze at step
-        one."""
+        min_new_tokens gate. Budgets count the pending tokens of
+        device-fed rows (sampled by the in-flight step or window, not
+        yet committed). Padded bucket rows keep limit 0 and freeze at
+        step one."""
         limits = np.zeros((s,), np.int32)
         min_req = np.zeros((s,), np.int32)
         sets: list[tuple[int, ...]] = []
@@ -2624,10 +2658,7 @@ class StageEngine:
         for i, seg in enumerate(plan.seqs):
             req = seg.request
             sp = req.sampling_params
-            pending = int(
-                seg.device_token and req.total_len < seg.context_len
-            )
-            n_out = req.num_generated + pending
+            n_out = req.num_generated + seg.pending_fed
             limits[i] = max(0, sp.max_new_tokens - n_out)
             min_req[i] = max(0, sp.min_new_tokens - n_out)
             stop: tuple[int, ...] = ()
@@ -3181,6 +3212,7 @@ class StageEngine:
             except AttributeError:  # stubbed jit call in tests
                 pass
         self.scheduler.on_batch_computed(plan)
+        self._h_window_ahead.observe(0.0)
         step_idx = self._step_count
         self._step_count += 1
         ticket = StepTicket(
@@ -3201,13 +3233,30 @@ class StageEngine:
         return ticket
 
     def _dispatch_multistep(
-        self, plan: BatchPlan, t0: float
+        self, plan: BatchPlan, t0: float,
+        chain: StepTicket | None = None,
     ) -> StepTicket | None:
         """ENQUEUE a chained k-step decode window over ``plan`` and
         return its in-flight ticket, or None to use the normal path.
         Nothing blocks on device results here: the window tokens and the
         final stop state come back in resolve()'s single D2H pass, so a
         driver's next dispatch overlaps the whole window's compute.
+
+        Hand-over (``chain``, the unresolved plain window over the same
+        rows in the same order; ``_window_ahead``): the window starts
+        from that ticket's device-resident carry — fed token, context,
+        stop mask, feature state — exactly as window j+1 of a
+        ``decode_pipeline`` chain starts from window j's inside one
+        dispatch, so the host packs and enqueues window N+1 while the
+        device computes window N and the device never waits for the
+        host between the two. The host packs budgets, the min_new_tokens
+        gate and the seeded step origin counting the tokens window N
+        still holds (``ScheduledSeq.pending_fed``): a row that window N
+        did not stop produced all of them, and a row it stopped rides
+        this window frozen by the carried mask (no KV written, nothing
+        committed; the frozen tail resolve discards and counts). Same
+        jit function, same shapes, same placement of every argument:
+        no second program.
 
         Qualification: single-stage engine (the ring is local), decode
         rows with no per-step host state (penalties, logprobs, grammar,
@@ -3241,6 +3290,8 @@ class StageEngine:
         s_bucket = next_bucket(max(len(plan.seqs), 1),
                                self.spec.seq_buckets)
         spec_w = self._spec_window_width(plan, k, s_bucket)
+        # Only a batch speculation cannot touch hands its rows over.
+        spec_off = spec_w == 0
         m = 0
         if spec_w > 0:
             # Worst-case reservation: K * (1 + spec) tokens per row per
@@ -3329,7 +3380,7 @@ class StageEngine:
         lora = self._lora_field(plan, inputs)
         if lora is not None:
             inputs = dataclasses.replace(inputs, lora=lora)
-        if any(seg.device_token for seg in plan.seqs):
+        if chain is None and any(seg.device_token for seg in plan.seqs):
             # Overlap-fed rows: their first window token is a gather
             # from the device-resident last-token array, enqueued after
             # the in-flight step's sampler — no host round trip.
@@ -3340,8 +3391,6 @@ class StageEngine:
             stop_tokens=jnp.asarray(stop_tokens),
             limit=jnp.asarray(limits),
             min_req=jnp.asarray(min_req),
-            stopped=jnp.asarray(limits <= 0),
-            produced=jnp.zeros((s,), jnp.int32),
         )
         steps0 = None
         if sampled:
@@ -3356,7 +3405,9 @@ class StageEngine:
             window_key = jax.random.fold_in(self._base_key, self._step_count)
         fextra = {}
         if feats:
-            ms_extra, fextra = self._pack_window_features(plan, s, feats)
+            ms_extra, fextra = self._pack_window_features(
+                plan, s, feats, initial=chain is None
+            )
             ms.update(ms_extra)
             self._count_constrained(
                 rows=sum(
@@ -3388,8 +3439,26 @@ class StageEngine:
         # reads it back.
         windows = []
         lps = [] if "lp" in feats else None
-        feed, ctx = inputs.token_ids, inputs.kv_lens
-        stopped, produced = ms["stopped"], ms["produced"]
+        # The carry the chain starts from. A fresh window builds it on
+        # the host and places it as the window program returns it
+        # (``_carry_in``), a handed-over one takes the in-flight
+        # window's; ``produced`` counts from zero per ticket either way
+        # (the host packed ``limit`` and ``min_req`` to match).
+        produced = self._carry_in(np.zeros((s,), np.int32))
+        if chain is None:
+            feed = self._carry_in(inputs.token_ids)
+            ctx = self._carry_in(inputs.kv_lens)
+            stopped = self._carry_in(limits <= 0)
+            fextra = {
+                key: self._carry_in(val) for key, val in fextra.items()
+            }
+        else:
+            prev = chain.ms_carry
+            feed, ctx, stopped = prev["feed"], prev["ctx"], prev["stopped"]
+            fextra = {
+                key: prev[key] for key in ("pen_counts", "dfa")
+                if key in prev
+            }
         for w in range(m):
             step_inputs = dataclasses.replace(
                 inputs, token_ids=feed, kv_lens=ctx
@@ -3422,11 +3491,21 @@ class StageEngine:
                 arr.copy_to_host_async()
             except AttributeError:  # stubbed jit call in tests
                 pass
-        # Advance scheduler bookkeeping exactly like a normal decode
-        # dispatch (+1 computed per row, rows un-ready until their
-        # tokens resolve); resolve() adds the remaining commits and
-        # rolls this back for rows that committed nothing.
-        self.scheduler.on_batch_computed(plan)
+        if chain is None:
+            # Advance scheduler bookkeeping exactly like a normal decode
+            # dispatch (+1 computed per row, rows un-ready until their
+            # tokens resolve); resolve() adds the remaining commits and
+            # rolls this back for rows that committed nothing.
+            self.scheduler.on_batch_computed(plan)
+        else:
+            # The rows are un-ready already and stay so; their computed
+            # count stands at what window N will have committed and
+            # advances by this window's commits at its own resolve (a
+            # row window N stops was never fed here: nothing to roll
+            # back, no phantom KV to donate when resolve(N) releases it).
+            chain.handed_over = True
+            chain.ms_carry = None
+        self._h_window_ahead.observe(0.0 if chain is None else 1.0)
         step_idx = self._step_count
         self._step_count += 1
         ticket = StepTicket(
@@ -3435,10 +3514,76 @@ class StageEngine:
             ms_lp=lps,
             dispatch_seq=self._dispatch_seq,
             program="decode_window",
+            chained=chain is not None,
         )
+        if spec_off and self._offer_window_rows(plan, m * k):
+            ticket.ms_carry = dict(
+                feed=feed, ctx=ctx, stopped=stopped, **fextra
+            )
         ticket.host_ms = (time.perf_counter() - t0) * 1000.0
         self._inflight.append(ticket)
         return ticket
+
+    def _carry_in(self, x) -> jax.Array:
+        """A host-built piece of a window's starting carry, placed as
+        the window program returns its carry: replicated over the TP
+        mesh (committed), or plain on an unsharded engine (nothing
+        there is committed). A handed-over window's carry then enters
+        the jit call under the signature a fresh window's does, so one
+        program per jit key serves both."""
+        if self._carry_sharding is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self._carry_sharding)
+
+    def _offer_window_rows(self, plan: BatchPlan, steps: int) -> bool:
+        """Make the rows of the plain window just enqueued schedulable
+        one window ahead (``Request.window_pending``: the tokens this
+        window holds for the row, clamped by its budget), so a step loop
+        that keeps this ticket in flight can form, pack and enqueue the
+        next window before it reads this one back. Not offered: with
+        ``overlap_steps`` off (the synchronous engine), and on a hybrid
+        model when a row's window ends on a page boundary — resolve
+        snapshots the recurrent state there, which must not have run a
+        window further."""
+        if not self.cfg.overlap_steps:
+            return False
+        if (
+            self._needs_state
+            and self.cache.enable_prefix_cache
+            and any(
+                (seg.context_len - 1 + steps) % self.cfg.page_size == 0
+                for seg in plan.seqs
+            )
+        ):
+            return False
+        left = [seg.budget_left for seg in plan.seqs]
+        if max(left) <= steps:
+            return False        # this window ends every row's budget
+        for seg, n in zip(plan.seqs, left):
+            seg.request.window_pending = max(0, min(steps, n))
+        return True
+
+    def _window_ahead(
+        self, plan: BatchPlan
+    ) -> tuple[BatchPlan, StepTicket | None]:
+        """``plan`` as dispatch may run it, and the in-flight window it
+        continues (or None). The scheduler plans rows of the window in
+        flight only when they are the whole batch; they continue it
+        when they are its rows in its order. Otherwise (a timeout took
+        a row, an ordering policy moved one) the plan is dropped: the
+        rows wait for the window's resolve, as without the hand-over."""
+        ahead = self._inflight[-1] if self._inflight else None
+        if ahead is None or ahead.ms_carry is None:
+            return plan, None
+        if not any(seg.device_token for seg in plan.seqs):
+            return plan, None
+        rows = ahead.plan.seqs
+        if len(rows) == len(plan.seqs) and all(
+            seg.device_token and seg.request is row.request
+            for seg, row in zip(plan.seqs, rows)
+        ):
+            return plan, ahead
+        return BatchPlan([]), None
 
     def _readback_span(self, plan: BatchPlan) -> host_span:
         """The blocking read-back of a visit's device results."""
@@ -3470,6 +3615,13 @@ class StageEngine:
         dispatch-time +1 computed advance is rolled back too."""
         plan = ticket.plan
         t_r0 = time.perf_counter()
+        # Hand-over: the next ticket took these rows off this window's
+        # carry (they stay un-schedulable, their marks are its own); a
+        # window that itself started from a carry did not advance the
+        # computed count at dispatch.
+        ahead = ticket.handed_over
+        fed_at_dispatch = 0 if ticket.chained else 1
+        ticket.ms_carry = None
         try:
             with self._readback_span(plan) as waited:
                 toks = np.concatenate(
@@ -3504,13 +3656,20 @@ class StageEngine:
                     gp_committed += committed
                     gp_window += int(toks.shape[0])
                 # Every committed token's predecessor was fed, so
-                # computed KV advances by the commit count; dispatch
-                # already counted one step (invariant: computed ==
-                # len(all_token_ids) - 1 while generating).
-                req.num_computed_tokens += committed - 1
-                req.ready_for_step = not req.status.is_finished
+                # computed KV advances by the commit count; a fresh
+                # window's dispatch already counted one step (invariant:
+                # computed == len(all_token_ids) - 1 while generating).
+                req.num_computed_tokens += committed - fed_at_dispatch
+                req.ready_for_step = not (
+                    ahead or req.status.is_finished
+                )
+                if not ahead:
+                    req.window_pending = 0
                 total += committed
-            if self._needs_state and self.cache.enable_prefix_cache:
+            if (
+                self._needs_state and self.cache.enable_prefix_cache
+                and not ahead
+            ):
                 # Opportunistic decode snapshots: the on-device state is
                 # at the window end; with the stop mask frozen rows'
                 # recurrence still ran surplus scan steps (state updates
@@ -4165,6 +4324,7 @@ class StageEngine:
         with host_span("sched.form_plan", self._h_visit_plan) as planning:
             sp_plan = self._take_sp_plan()
             plan = sp_plan if sp_plan is not None else self._form_plan()
+            plan, chain = self._window_ahead(plan)
             if plan.is_empty:
                 # No visit follows: its phases are observed for visits
                 # that parallax_step_host_ms counts, and no others.
@@ -4180,14 +4340,16 @@ class StageEngine:
             "engine.pack", self._h_visit_pack, rows=len(plan.seqs),
             tokens=plan.total_new_tokens,
         ):
-            return self._dispatch_plan(plan, sp_plan, t0)
+            return self._dispatch_plan(plan, sp_plan, t0, chain)
 
     def _dispatch_plan(
-        self, plan: BatchPlan, sp_plan: BatchPlan | None, t0: float
+        self, plan: BatchPlan, sp_plan: BatchPlan | None, t0: float,
+        chain: StepTicket | None = None,
     ) -> StepTicket:
         """The rest of ``dispatch`` once the plan is formed (the
         ``engine.pack`` span): host arrays, page tables, H2D and the
-        enqueue, up to and including the jit call's return."""
+        enqueue, up to and including the jit call's return. ``chain``:
+        the in-flight window this plan continues (``_window_ahead``)."""
 
         def _done(outputs: StepOutputs) -> StepTicket:
             return StepTicket(
@@ -4236,9 +4398,15 @@ class StageEngine:
         # speculation off it is the plain PR 6 window.
         fed_rows = any(seg.device_token for seg in plan.seqs)
         if sp_plan is None:
-            ticket = self._dispatch_multistep(plan, t0)
+            ticket = self._dispatch_multistep(plan, t0, chain)
             if ticket is not None:
                 return ticket
+            if chain is not None:
+                # The planner cannot page the next window (pool, or the
+                # context room at max_model_len): no hand-over. The rows
+                # wait for the in-flight window's resolve; the path
+                # below then owns the K=1 and preemption decisions.
+                return _done(StepOutputs(forward=[], finished=[]))
         # Host-sync verify fallback: K=1 (or a window the planner could
         # not page) still speculates, one round per host visit. Rows fed
         # from the device-resident last-token array are excluded — their
@@ -4663,6 +4831,7 @@ class StageEngine:
             if not req.status.is_finished:
                 req.abort("step_resolve_failed")
             req.device_feed_ready = False
+            req.window_pending = 0
 
     def _free_token_slot(self, request_id: str) -> None:
         slot = self._token_slots.pop(request_id, None)
@@ -4868,17 +5037,15 @@ class StageEngine:
                 any_seed = True
                 # A device-fed row's fed token may still be uncommitted
                 # (dispatch-time packing): the host-visible generated
-                # count then runs one behind the true output index this
-                # step samples. When a host-synchronous batch defers the
-                # packing to RESOLVE time, the driver has already
-                # resolved the previous ticket and committed that token
-                # (total_len == context_len), so origin already counts
-                # it — adding 1 there would shift the seeded key stream.
-                pending_fed = (
-                    seg.device_token
-                    and seg.request.total_len < seg.context_len
-                )
-                steps[i] = origin + (1 if pending_fed else 0)
+                # count then runs behind the true output index this
+                # step samples — by one, or by a window's tokens for a
+                # row fed from a window's carry. When a host-synchronous
+                # batch defers the packing to RESOLVE time, the driver
+                # has already resolved the previous ticket and committed
+                # that token (total_len == context_len), so origin
+                # already counts it — adding it there would shift the
+                # seeded key stream.
+                steps[i] = origin + seg.pending_fed
         return temp, top_k, top_p, min_p, seeds, steps, any_seed
 
     @staticmethod

@@ -15,7 +15,7 @@ pin multi-stage streams bit-identical to the direct-call path.
 from __future__ import annotations
 
 from parallax_tpu.obs.trace import visit_span
-from parallax_tpu.runtime.engine import StageEngine
+from parallax_tpu.runtime.engine import StageEngine, StepTicket, drive_step
 from parallax_tpu.runtime.request import Request
 
 
@@ -35,6 +35,10 @@ class InProcessPipeline:
         self.finished: list[Request] = []
         # Step rounds so far: the number of the ``parallax.visit`` span.
         self.visits = 0
+        # A single full stage steps through the one-in-flight loop
+        # (``drive_step``): the ticket a round left unresolved, which the
+        # next round resolves after it has dispatched its own.
+        self._pending: StepTicket | None = None
 
     @property
     def head(self) -> StageEngine:
@@ -44,7 +48,25 @@ class InProcessPipeline:
         return self.head.submit(request)
 
     def has_work(self) -> bool:
-        return any(e.has_work() for e in self.engines)
+        return self._pending is not None or any(
+            e.has_work() for e in self.engines
+        )
+
+    def settle(self, discard: bool = False) -> list[Request]:
+        """Leave no ticket in flight: resolve the one the last round
+        left (its tokens commit; the finished requests are returned), or
+        ``discard`` it where a failed step makes a resolve meaningless
+        (its rows are aborted)."""
+        ticket, self._pending = self._pending, None
+        if ticket is None:
+            return []
+        head = self.head
+        if discard or not head.is_inflight(ticket):
+            head.discard(ticket)
+            return []
+        finished = head.resolve(ticket).finished
+        self.finished.extend(finished)
+        return finished
 
     def _wire_roundtrip(self, ireq):
         """One packet through the full wire path: serialize (with the
@@ -84,9 +106,25 @@ class InProcessPipeline:
         return newly_finished
 
     def _step_stages(self) -> list[Request]:
+        if len(self.engines) == 1:
+            # One visit = dispatch(N+1), then resolve(N): the host forms,
+            # packs and enqueues the next step while the device computes
+            # this one. A ring of several in-process engines steps each
+            # synchronously (a stage's input is the stage before's output
+            # of the same round).
+            outs, self._pending = drive_step(self.head, self._pending)
+            return self._route(0, outs)
         newly_finished: list[Request] = []
         for i, engine in enumerate(self.engines):
-            out = engine.step()
+            newly_finished += self._route(i, [engine.step()])
+        return newly_finished
+
+    def _route(self, i: int, outs) -> list[Request]:
+        """Send stage ``i``'s packets on around the ring and release its
+        finished requests on the other stages."""
+        engine = self.engines[i]
+        newly_finished: list[Request] = []
+        for out in outs:
             for ireq in out.forward:
                 if self.wire:
                     ireq = self._wire_roundtrip(ireq)

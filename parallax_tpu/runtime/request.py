@@ -123,6 +123,14 @@ class Request:
     # the row is scheduled device-fed or when the token reaches the host
     # before being fed (sync tail).
     device_feed_ready: bool = False
+    # Overlapped decode windows: tokens an in-flight K-step window will
+    # have produced for this row (clamped by its generation budget), none
+    # of them committed yet. > 0 makes the row schedulable for the NEXT
+    # window, which starts from that window's device-resident carry (fed
+    # token, context, stop mask) — set by the engine when it enqueues a
+    # plain window, consumed by the scheduler when it plans the row, and
+    # dropped whenever the next plan is anything but that same batch.
+    window_pending: int = 0
     abort_reason: str | None = None
     # Per-request LoRA adapter name (reference ``Req.lora_path``,
     # forward.proto). None = base model. The local scheduler groups each

@@ -36,6 +36,26 @@ class ScheduledSeq:
     # replaces with an on-device gather (batch.substitute_device_tokens).
     device_token: bool = False
 
+    @property
+    def pending_fed(self) -> int:
+        """Tokens of this row that an in-flight step has sampled and the
+        host has not committed yet (one for a row fed from the last-token
+        array, up to a whole window for a row fed from a window's carry):
+        budgets, the min_new_tokens gate and the seeded step origin count
+        them. 0 once they are committed (resolve-time packing)."""
+        if not self.device_token:
+            return 0
+        return max(0, self.context_len - self.request.total_len)
+
+    @property
+    def budget_left(self) -> int:
+        """Tokens the row may still produce from this step on: its
+        ``max_new_tokens`` less what it generated, committed or pending.
+        <= 0 for a row whose budget the step in flight exhausts."""
+        req = self.request
+        return (req.sampling_params.max_new_tokens - req.num_generated
+                - self.pending_fed)
+
 
 @dataclasses.dataclass
 class BatchPlan:
@@ -332,6 +352,7 @@ class Scheduler:
         """
         self.check_timeouts()
         self.admit_requests()
+        self._settle_window_rows()
 
         # One LoRA adapter per batch (in-graph slot selection is scalar).
         # The batch's adapter rotates round-robin over the DISTINCT
@@ -347,7 +368,8 @@ class Scheduler:
                 and req.remaining_prompt_tokens() > 0
             ) or (
                 req.status is RequestStatus.DECODING
-                and (req.ready_for_step or req.device_feed_ready)
+                and (req.ready_for_step or req.device_feed_ready
+                     or req.window_pending)
             )
             if schedulable and req.lora_id not in groups:
                 groups.append(req.lora_id)
@@ -379,6 +401,28 @@ class Scheduler:
             if seqs:
                 return BatchPlan(seqs, lora_id=batch_lora)
         return BatchPlan([])
+
+    def _settle_window_rows(self) -> None:
+        """Rows of an in-flight decode window (``window_pending``) may be
+        planned one window ahead only as that same batch again: every
+        running request is such a row and still decoding, and nothing
+        waits to be admitted. An arrival, a prefill chunk, a finished,
+        aborted or migrating row ends the hand-over — the marks are
+        dropped, the rows stay un-schedulable until the window's resolve
+        commits them, and the plan is formed as without a window in
+        flight."""
+        running = self.running.values()
+        if not any(req.window_pending for req in running):
+            return
+        if not self.wait_queue and all(
+            req.window_pending
+            and req.status is RequestStatus.DECODING
+            and not req.migrating
+            for req in running
+        ):
+            return
+        for req in running:
+            req.window_pending = 0
 
     def _fill_batch(self, batch_lora: str | None) -> list[ScheduledSeq]:
         """The prefill-first loops for one adapter group."""
@@ -481,7 +525,8 @@ class Scheduler:
             req for req in self.running.values()
             if req.status is RequestStatus.DECODING
             and not req.migrating
-            and (req.ready_for_step or req.device_feed_ready)
+            and (req.ready_for_step or req.device_feed_ready
+                 or req.window_pending)
             and (any_adapter or req.lora_id == batch_lora)
         ]
         if self.qos is not None and candidates:
@@ -513,9 +558,20 @@ class Scheduler:
             # A device-fed row's next token was sampled by the in-flight
             # step and lives only on device: it occupies one more context
             # slot than the host-committed total.
-            fed = req.device_feed_ready and not req.ready_for_step
-            ctx = req.total_len + 1 if fed else req.total_len
-            if not self._ensure_capacity_or_preempt(
+            # A row of the window in flight: that window holds its next
+            # tokens (``window_pending`` of them, none committed yet).
+            ahead = req.window_pending
+            fed = ahead > 0 or (
+                req.device_feed_ready and not req.ready_for_step
+            )
+            ctx = req.total_len + (ahead or int(fed))
+            if ahead:
+                # The window reserved its pages up to here: nothing to
+                # evict or preempt for.
+                req.window_pending = 0
+                if not self.cache.ensure_capacity(req, ctx):
+                    continue
+            elif not self._ensure_capacity_or_preempt(
                 req, ctx, allow_self=True, exclude_scheduled=scheduled,
             ):
                 continue
@@ -569,25 +625,25 @@ class Scheduler:
         (windows past every row's ``max_new_tokens`` are pure waste —
         under speculation a window still commits at least ``k`` tokens
         per live row, so the plain-window clamp stays conservative);
-        device-fed rows count their pending uncommitted token.
+        device-fed rows count their pending uncommitted tokens (one,
+        or a window's worth for rows of the window in flight).
         """
         k_eff = k * (1 + max(0, spec))
         m = max(1, max_windows)
-        want = 1
+        want = 0
+        live = []
         for seg in plan.seqs:
+            left = seg.budget_left
+            if left <= 0:
+                # The window in flight exhausts this row's budget: it
+                # rides this one frozen and needs no room and no pages.
+                continue
             room = (max_model_len - seg.context_len) // k_eff
             if room < 1:
                 return 0
             m = min(m, room)
-            pending = int(
-                seg.device_token
-                and seg.request.total_len < seg.context_len
-            )
-            want = max(
-                want,
-                seg.request.sampling_params.max_new_tokens
-                - seg.request.num_generated - pending,
-            )
+            want = max(want, left)
+            live.append(seg)
         m = min(m, max(1, -(-want // k)))
 
         def _extra_pages(mm: int) -> int:
@@ -597,7 +653,7 @@ class Scheduler:
                     self.cache.pages_needed(seg.context_len + mm * k_eff)
                     - len(seg.request.page_ids),
                 )
-                for seg in plan.seqs
+                for seg in live
             )
 
         while m > 1 and _extra_pages(m) > self.cache.num_free_pages:
@@ -606,7 +662,7 @@ class Scheduler:
             self.cache.ensure_capacity(
                 seg.request, seg.context_len + m * k_eff
             )
-            for seg in plan.seqs
+            for seg in live
         ):
             return 0
         return m

@@ -531,18 +531,24 @@ def _drive(eng, n_guard=5000):
 
 
 def _drive_tokens(eng, req, n_tokens, n_guard=5000):
-    """Drive until the request has committed >= n_tokens, then resolve
-    the in-flight step WITHOUT dispatching another, so the row is
-    quiescent (extractable)."""
+    """Drive until the request has committed >= n_tokens, then park it
+    the way the node does: flag it ``migrating`` (the scheduler plans it
+    no further, so no window is handed over) and drive until no step in
+    flight holds it — the row is quiescent (extractable). The window
+    already enqueued behind the one that reached ``n_tokens`` still
+    commits: the row ends up to two windows past ``n_tokens``."""
     from parallax_tpu.runtime.engine import drive_step
 
     pending, guard = None, 0
     while len(req.output_ids) < n_tokens and guard < n_guard:
         guard += 1
         _outs, pending = drive_step(eng, pending)
-    if pending is not None:
-        eng.resolve(pending)
-    assert guard < n_guard
+    req.migrating = True
+    while pending is not None and guard < n_guard:
+        guard += 1
+        _outs, pending = drive_step(eng, pending)
+    req.migrating = False
+    assert guard < n_guard and not eng._inflight
 
 
 @pytest.mark.parametrize("sp_kw", [
@@ -555,7 +561,7 @@ def test_kv_image_migration_bit_identical(tiny_model_and_params, sp_kw):
     form, adopt on B (layout-identical stage), resume — the continuation
     matches an uninterrupted run token for token, with no re-prefill."""
     prompt = [3, 5, 7, 11, 13, 17, 19, 23] * 2
-    sp = SamplingParams(max_new_tokens=16, ignore_eos=True, **sp_kw)
+    sp = SamplingParams(max_new_tokens=32, ignore_eos=True, **sp_kw)
 
     # Uninterrupted baseline.
     eng0 = _mk_engine(tiny_model_and_params)
@@ -563,7 +569,7 @@ def test_kv_image_migration_bit_identical(tiny_model_and_params, sp_kw):
                    sampling_params=dataclasses.replace(sp))
     eng0.submit(base)
     _drive(eng0)
-    assert base.status.is_finished and len(base.output_ids) == 16
+    assert base.status.is_finished and len(base.output_ids) == 32
 
     # Source engine: run to mid-decode, park, harvest, checkpoint.
     eng_a = _mk_engine(tiny_model_and_params)
@@ -605,7 +611,7 @@ def test_adopt_falls_back_cleanly_on_layout_mismatch(
     eng_a = _mk_engine(tiny_model_and_params)
     mig = Request("m2", prompt_ids=[3, 5, 7, 11] * 3,
                   sampling_params=SamplingParams(temperature=0.0,
-                                                 max_new_tokens=12,
+                                                 max_new_tokens=32,
                                                  ignore_eos=True))
     eng_a.submit(mig)
     _drive_tokens(eng_a, mig, 5)
@@ -645,7 +651,7 @@ def test_adopt_falls_back_cleanly_on_layout_mismatch(
     eng0 = _mk_engine(tiny_model_and_params)
     base = Request("b2", prompt_ids=[3, 5, 7, 11] * 3,
                    sampling_params=SamplingParams(temperature=0.0,
-                                                  max_new_tokens=12,
+                                                  max_new_tokens=32,
                                                   ignore_eos=True))
     eng0.submit(base)
     _drive(eng0)

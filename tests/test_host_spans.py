@@ -180,9 +180,12 @@ def test_step_round_spans_in_order_with_one_compile(recorder):
     eng = build_engine()
     pipe = InProcessPipeline([eng])
     pipe.submit(request("spans-a"))
-    pipe.step_round()          # prefill: a new program
-    pipe.step_round()          # first decode window: another
-    pipe.step_round()          # the same window again: none
+    # A visit of the one-in-flight loop dispatches step N+1, then
+    # resolves step N.
+    pipe.step_round()          # prefill enqueued (a new program)
+    pipe.step_round()          # first decode window (another); prefill read
+    pipe.step_round()          # the same window again (none); window 1 read
+    pipe.step_round()
     names = [n.removeprefix("parallax.") for n, _ in recorder.entered()]
     per_visit = []
     for n in names:
@@ -192,9 +195,9 @@ def test_step_round_spans_in_order_with_one_compile(recorder):
             per_visit[-1].append(n)
     steady = ["sched.form_plan", "engine.pack", "engine.readback_wait",
               "engine.commit"]
-    assert per_visit[0] == per_visit[1] == (
-        steady[:2] + ["engine.compile"] + steady[2:])
-    assert per_visit[2] == steady
+    assert per_visit[0] == steady[:2] + ["engine.compile"]
+    assert per_visit[1] == steady[:2] + ["engine.compile"] + steady[2:]
+    assert per_visit[2] == per_visit[3] == steady
     # Every child carries its visit; pack says what it packed.
     for name, args in recorder.entered():
         if name == "parallax.visit":
@@ -304,6 +307,93 @@ def test_runner_loop_spans_gap_and_submit(recorder):
         (opened.add if what == "enter" else opened.discard)(name)
     gaps = [a for n, a in recorder.entered() if n.endswith("loop_gap")]
     assert [a["visit"] for a in gaps] == list(range(1, rounds + 1))
+
+
+def test_runner_loop_packs_the_next_window_before_it_reads_this_one(
+        recorder):
+    """``serve``'s loop keeps one step in flight: within one visit the
+    ``engine.pack`` of window N+1 comes before the
+    ``engine.readback_wait`` of window N, all four phases stay inside
+    the visit, and every such window counts 1 in
+    ``parallax_visit_window_ahead``."""
+    eng = build_engine()                      # adaptive K = 8
+    runner = LocalRunner(InProcessPipeline([eng]))
+    ahead0 = series(mnames.VISIT_WINDOW_AHEAD)
+    runner.start()
+    try:
+        done = runner.submit(request("ahead", max_tokens=81))
+        assert done.wait(120.0)
+    finally:
+        runner.stop()
+    assert not eng._inflight and runner.pipeline._pending is None
+    visits, inside = [], None
+    for what, name, args in recorder.log:
+        name = name.removeprefix("parallax.")
+        if name == "visit":
+            inside = [] if what == "enter" else None
+            if what == "enter":
+                visits.append((args["step_num"], inside))
+        elif what == "enter" and name.split(".")[0] in ("sched", "engine"):
+            assert inside is not None, (name, "outside every visit")
+            inside.append((name, args))
+    phases = ["sched.form_plan", "engine.pack", "engine.readback_wait",
+              "engine.commit"]
+    windows = 0
+    for n, children in visits:
+        assert all(a["visit"] == n for _, a in children), (n, children)
+        names = [c for c, _ in children if c != "engine.compile"]
+        if names == phases:                   # dispatch(N+1), resolve(N)
+            pack = dict(children)["engine.pack"]
+            windows += pack["rows"] == 1 and pack["tokens"] == 1
+    # 80 tokens after the prefill's one: ten windows, the first behind
+    # the prefill, nine behind a window each.
+    assert windows >= 9, visits
+    s1, c1 = series(mnames.VISIT_WINDOW_AHEAD)
+    assert (s1 - ahead0[0], c1 - ahead0[1]) == (9.0, 10)
+
+
+@pytest.mark.parametrize("how", ["stop", "fail"])
+def test_runner_leaves_no_ticket_in_flight(how):
+    """``stop()`` resolves the step the loop kept in flight (its tokens
+    commit); a failing step discards it (its rows abort)."""
+    eng = build_engine()
+    runner = LocalRunner(InProcessPipeline([eng]))
+    req = request("inflight", max_tokens=200)
+    if how == "fail":
+        real, calls = eng.resolve, []
+
+        def resolve(ticket):
+            calls.append(ticket)
+            if len(calls) == 4:
+                raise RuntimeError("boom")
+            return real(ticket)
+
+        eng.resolve = resolve
+    runner.start()
+    try:
+        done = runner.submit(req)
+        if how == "stop":
+            deadline = time.monotonic() + 120.0
+            while len(req.output_ids) < 20 and time.monotonic() < deadline:
+                time.sleep(0.001)
+        else:
+            assert done.wait(120.0)
+    finally:
+        runner.stop()
+    assert not eng._inflight and runner.pipeline._pending is None
+    if how == "stop":
+        assert 20 <= len(req.output_ids) < 200
+        assert not req.status.is_finished and runner.failure is None
+        # Nothing was lost: the stream is the uninterrupted one's start.
+        whole = request("whole", max_tokens=200)
+        pipe = InProcessPipeline([build_engine()])
+        pipe.submit(whole)
+        pipe.run_until_complete()
+        assert req.output_ids == whole.output_ids[:len(req.output_ids)]
+    else:
+        assert isinstance(runner.failure, RuntimeError)
+        assert req.status is RequestStatus.FINISHED_ABORT
+        assert req.window_pending == 0
 
 
 def test_admit_wait_is_observed_at_the_first_plan_of_every_request():
@@ -559,3 +649,77 @@ def test_profile_stop_reports_the_trace_it_wrote(tmp_path):
     assert body["xplane"].startswith(str(tmp_path))
     assert body["stop_seconds"] > 0
     assert json.dumps(body)
+
+
+# A trace the chip wrote (the benchmark's fixture): its device plane ends
+# between its two ``parallax.clock_sync`` marks.
+CHIP_TRACE = "benchmarks/fixtures/host_spans_v5e.xplane.pb"
+CHIP_TRACE_MARKS = (35553879917, 35585106466)
+CHIP_TRACE_DEVICE_END = 35576089416
+
+
+def test_traced_device_end_is_on_this_process_clock(tmp_path):
+    import os
+
+    here = os.path.join(os.path.dirname(os.path.dirname(__file__)), CHIP_TRACE)
+    end = obs_trace.traced_device_end_ns(here)
+    assert end == CHIP_TRACE_DEVICE_END
+    assert CHIP_TRACE_MARKS[0] < end < CHIP_TRACE_MARKS[1]
+    # The CPU backend writes no device plane: nothing to lay on the clock.
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        obs_trace.clock_sync()
+        jnp.ones((8, 8)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    [cpu] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert obs_trace.traced_device_end_ns(cpu) is None
+
+
+def test_profile_replies_bracket_the_trace(monkeypatch, tmp_path):
+    """start's reading is from before ``start_trace`` was called; stop's
+    is the end of the trace's last device event where that is later than
+    the call of ``stop_trace`` (a device that never idles)."""
+    import os
+    import shutil
+
+    here = os.path.join(os.path.dirname(os.path.dirname(__file__)), CHIP_TRACE)
+    seen = {}
+
+    def start(*a, **k):
+        seen["start_called"] = time.perf_counter_ns()
+
+    def stop(*a, **k):
+        seen["stop_called"] = time.perf_counter_ns()
+        shutil.copy(here, tmp_path / "host.xplane.pb")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", stop)
+    fe = OpenAIFrontend(SimpleTokenizer(), submit_fn=None)
+
+    real = obs_trace.traced_device_end_ns
+    shift = [0]
+    monkeypatch.setattr(obs_trace, "traced_device_end_ns",
+                        lambda path: real(path) + shift[0])
+
+    async def once(client):
+        resp = await client.post("/profile/start",
+                                 json={"dir": str(tmp_path)})
+        started = await resp.json()
+        resp = await client.post("/profile/stop")
+        return started, await resp.json()
+
+    async def fn(client):
+        # The trace's device events end long before this process's stop ...
+        shift[0] = -CHIP_TRACE_DEVICE_END
+        started, stopped = await once(client)
+        assert started["perf_counter_ns"] < seen["start_called"]
+        assert started["perf_counter_ns"] < stopped["perf_counter_ns"]
+        assert stopped["perf_counter_ns"] < seen["stop_called"]
+        assert stopped["xplane"] == str(tmp_path / "host.xplane.pb")
+        # ... and long after it: the reply follows the device.
+        shift[0] = time.perf_counter_ns() + 10**12 - CHIP_TRACE_DEVICE_END
+        started, stopped = await once(client)
+        assert stopped["perf_counter_ns"] > seen["stop_called"] + 10**11
+
+    with_client(fe.app, fn)

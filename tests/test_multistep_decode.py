@@ -303,7 +303,7 @@ def _hybrid_run(lookahead, prompts, max_new=10, pipeline=1, seed=None,
     windows = []
     orig = eng._dispatch_multistep
     eng._dispatch_multistep = (
-        lambda plan, t0: windows.append(1) or orig(plan, t0)
+        lambda plan, t0, *a: windows.append(1) or orig(plan, t0, *a)
     )
     pipe = InProcessPipeline([eng])
     reqs = []
@@ -585,8 +585,9 @@ def test_adaptive_lookahead_default_and_feature_windows():
         tickets = []
         orig = eng._dispatch_multistep
         eng._dispatch_multistep = (
-            lambda plan, t0: tickets.append(
-                (orig(plan, t0), [s.request.request_id for s in plan.seqs])
+            lambda plan, t0, *a: tickets.append(
+                (orig(plan, t0, *a),
+                 [s.request.request_id for s in plan.seqs])
             ) or tickets[-1][0]
         )
         clean = Request("c", prompt_ids=[3, 14, 15],
