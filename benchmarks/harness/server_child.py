@@ -13,9 +13,11 @@ Started by ``benchmarks/run.py`` (which never imports JAX). It
    tokenized;
 3. replaces ``parallax_tpu.models.loader.load_stage_params`` by a function
    that makes the stage's weights on the device in one jitted call from
-   ``--seed`` (``StageModel.init_params`` plus random q/k/v biases, in
-   bf16), runs the plain reference on them *before* ``serve`` sizes its KV
-   pool, and hands them to ``serve`` placed as the loader would;
+   ``--seed`` (``StageModel.init_params`` with every bias-like leaf drawn
+   too, in bf16), runs the configuration's plain reference on them
+   (``bench.reference``; ``harness/reference.py`` where the key is
+   absent) *before* ``serve`` sizes its KV pool, and hands them to
+   ``serve`` placed as the loader would;
 4. calls ``serve_main`` with the arguments ``cli.build_parser()`` gives
    for ``serve --model-path <dir> --port <p>`` plus the configuration
    file's ``serve_flags``.
@@ -27,14 +29,17 @@ gets: its KV sizing, its defaults, its frontend.
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
 import time
+import zlib
 
-REF_PROMPTS = 4
-REF_PROMPT_TOKENS = 48
-REF_NEW_TOKENS = 16
+from benchmarks.harness import spec
+
+# A rehearsal's reference rows are cut to what ``REHEARSE_FLAGS`` holds.
+REHEARSE_REF_ROW = {"prompt_tokens": 48, "new_tokens": 16}
 # serve's sizes in a rehearsal (toy widths on the CPU); the parent scales
 # the traffic to them (``loadgen.REHEARSE``).
 REHEARSE_FLAGS = ["--max-model-len", "512", "--max-batch-size", "8",
@@ -76,24 +81,51 @@ def seed_key(seed: int):
                               seed & 0xFFFFFF)
 
 
+QKV = ("q_proj", "k_proj", "v_proj")
+
+
+def path_names(path) -> tuple[str, ...]:
+    return tuple(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+
+
+def is_bias(names: tuple[str, ...]) -> bool:
+    return names[-1] == "bias" or names[-1].endswith("_bias")
+
+
 def make_params(model, seed: int, mesh=None):
     """The stage's weights, on the device, from the seed, in one jitted
-    call. q/k/v biases are drawn too (``init_params`` leaves them zero,
-    which would let a dropped bias pass the reference check)."""
+    call. ``init_params`` leaves every bias at a constant, which would
+    let a dropped bias pass the reference check, so every leaf named
+    ``bias`` or ``*_bias`` (a router's ``e_score_correction_bias``
+    among them) gets normal(0, 0.02) added, from a key folded from its
+    path. Nothing here knows a layer by name but for the q/k/v biases of
+    ``self_attn``, whose keys stay ``fold_in(k_bias, 3 * layer + j)``:
+    the dense configurations' weights are bit for bit what they were
+    before the draw became general (PERF.md, PR 27)."""
     import jax
     import jax.numpy as jnp
 
     def init(key):
         k_init, k_bias = jax.random.split(key)
+        k_rest = jax.random.fold_in(k_bias, 0x7FFFFFFF)
         params = model.init_params(k_init, dtype=jnp.bfloat16)
-        for i, layer in enumerate(params["layers"]):
-            for j, name in enumerate(("q_proj", "k_proj", "v_proj")):
-                proj = layer["self_attn"][name]
-                if "bias" in proj:
-                    kb = jax.random.fold_in(k_bias, 3 * i + j)
-                    proj["bias"] = (
-                        0.02 * jax.random.normal(kb, proj["bias"].shape)
-                    ).astype(jnp.bfloat16)
+
+        def draw(path, leaf):
+            names = path_names(path)
+            if not (is_bias(names)
+                    and jnp.issubdtype(leaf.dtype, jnp.floating)):
+                return leaf
+            if (len(names) == 5 and names[0] == "layers"
+                    and names[2] == "self_attn" and names[3] in QKV):
+                kb = jax.random.fold_in(
+                    k_bias, 3 * int(names[1]) + QKV.index(names[3]))
+            else:
+                kb = jax.random.fold_in(
+                    k_rest, zlib.crc32("/".join(names).encode()) >> 1)
+            noise = 0.02 * jax.random.normal(kb, leaf.shape)
+            return (leaf.astype(jnp.float32) + noise).astype(leaf.dtype)
+
+        params = jax.tree_util.tree_map_with_path(draw, params)
         return model.finalize_params(params)
 
     key = seed_key(seed)
@@ -107,31 +139,35 @@ def make_params(model, seed: int, mesh=None):
     col_vecs = getattr(model, "tp_column_vector_params", frozenset())
 
     def sharding_of(path, leaf):
-        names = tuple(
-            str(getattr(p, "key", getattr(p, "idx", p))) for p in path
-        )
-        return param_sharding(mesh, names, leaf, col_vecs=col_vecs)
+        return param_sharding(mesh, path_names(path), leaf,
+                              col_vecs=col_vecs)
 
     shardings = jax.tree_util.tree_map_with_path(sharding_of, shapes)
     return jax.jit(init, out_shardings=shardings)(key)
 
 
-def run_reference(params, hf_cfg: dict, seed: int, out_path: str) -> None:
+def run_reference(params, hf_cfg: dict, seed: int, out_path: str,
+                  ref: dict, rehearse: bool = False) -> None:
+    """The configuration's reference (``spec.reference_of``) over its
+    row shapes, each drawn from the seed in the order listed."""
     import jax
     import numpy as np
 
-    from benchmarks.harness import reference
-
     t0 = time.monotonic()
+    module = importlib.import_module(ref["import"])
     rng = np.random.default_rng([int(seed), 0x5EF])
-    prompts = rng.integers(
-        0, hf_cfg["vocab_size"], (REF_PROMPTS, REF_PROMPT_TOKENS)
-    ).tolist()
-    rows = reference.greedy_continuations(
-        params, hf_cfg, prompts, REF_NEW_TOKENS
-    )
+    rows = []
+    for shape in ref["rows"]:
+        if rehearse:
+            shape = {**shape, **{k: min(shape[k], v) for k, v
+                                 in REHEARSE_REF_ROW.items()}}
+        prompts = rng.integers(
+            0, hf_cfg["vocab_size"],
+            (shape["prompts"], shape["prompt_tokens"])).tolist()
+        rows += module.greedy_continuations(
+            params, hf_cfg, prompts, shape["new_tokens"])
     with open(out_path + ".tmp", "w") as f:
-        json.dump({"rows": rows,
+        json.dump({"module": ref["module"], "rows": rows,
                    "seconds": round(time.monotonic() - t0, 3)}, f)
     os.replace(out_path + ".tmp", out_path)
     # The reference's programs and temporaries must not be counted
@@ -153,6 +189,7 @@ def main() -> int:
         raw = json.load(f)
     meta = raw.pop("bench")
     hf_cfg = raw
+    ref = spec.reference_of(meta)
     flags = list(meta["serve_flags"])
     if args.rehearse:
         hf_cfg = dict(hf_cfg, **meta["rehearse"])
@@ -192,7 +229,8 @@ def main() -> int:
         params = make_params(model, args.seed, mesh=mesh)
         jax.block_until_ready(params)
         t1 = time.monotonic()
-        run_reference(params, hf_cfg, args.seed, ref_path)
+        run_reference(params, hf_cfg, args.seed, ref_path, ref,
+                      rehearse=args.rehearse)
         print(f"server_child: weights {t1 - t0:.1f}s, reference "
               f"{time.monotonic() - t1:.1f}s", file=sys.stderr, flush=True)
         return params

@@ -1,13 +1,16 @@
 """Loader for ``BENCHMARK.json`` and the files it names.
 
 Everything a cell needs is found by name: ``configs/<config>.json``,
-``traffic/<traffic>.json`` and ``layer_metrics/<metric>.json`` (or
-``.py``). ``load`` refuses a malformed benchmark before any run; later
-PRs add files and entries and edit nothing here.
+``traffic/<traffic>.json``, ``layer_metrics/<metric>.json`` (or ``.py``)
+and, where a configuration names one, ``references/<module>.py``.
+``load`` refuses a malformed benchmark before any run; later PRs add
+files and entries and edit nothing here.
 """
 
 from __future__ import annotations
 
+import argparse
+import ast
 import json
 import os
 import re
@@ -20,6 +23,11 @@ UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 # Keys of a configuration file that are not the model's config.json.
 CONFIG_META_KEY = "bench"
+# The rows a reference is run over where the configuration lists none:
+# 4 prompts of 48 tokens continued by 16.
+DEFAULT_REFERENCE_ROWS = [
+    {"prompts": 4, "prompt_tokens": 48, "new_tokens": 16}]
+REFERENCE_ENTRY = "greedy_continuations"
 
 
 class SpecError(ValueError):
@@ -62,6 +70,84 @@ def layer_metric_paths(name: str) -> tuple[str, str]:
     return base + ".json", base + ".py"
 
 
+def serve_sizes(serve_flags: list[str]) -> dict:
+    """The four sizes that shape the compile lattice, and the page size,
+    with ``serve``'s defaults (``cli.build_parser``)."""
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--max-batch-size", type=int, default=64)
+    ap.add_argument("--max-num-tokens-per-batch", type=int, default=2048)
+    ap.add_argument("--prefill-chunk-size", type=int, default=1024)
+    ap.add_argument("--max-model-len", type=int, default=8192)
+    ap.add_argument("--page-size", type=int, default=64)
+    ns, _ = ap.parse_known_args(serve_flags)
+    return vars(ns)
+
+
+def reference_path(module: str | None) -> str:
+    if module is None:
+        return os.path.join(BENCH_DIR, "harness", "reference.py")
+    return os.path.join(BENCH_DIR, "references", f"{module}.py")
+
+
+def _defines(path: str, name: str) -> bool:
+    """Whether the module at ``path`` binds ``name`` at its top level
+    (a ``def``, an assignment or an import). Read, not imported: the
+    parent never loads JAX."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), path)
+    for node in tree.body:
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            bound = [node.name]
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            bound = [a.asname or a.name for a in node.names]
+        elif isinstance(node, ast.Assign):
+            bound = [t.id for t in node.targets if isinstance(t, ast.Name)]
+        else:
+            continue
+        if name in bound:
+            return True
+    return False
+
+
+def reference_of(meta: dict, name: str = "") -> dict:
+    """A configuration's plain reference, from its ``bench`` group:
+    ``{"module", "import", "rows"}``. ``bench.reference`` is optional:
+    ``{"module": "<stem>", "rows": [{"prompts", "prompt_tokens",
+    "new_tokens"}, ...]}`` names ``references/<stem>.py``, which has
+    ``greedy_continuations(params, cfg, prompts, new_tokens)`` as
+    ``harness/reference.py`` has it; either key may be left out."""
+    given = meta.get("reference") or {}
+    _need(isinstance(given, dict) and set(given) <= {"module", "rows"},
+          f"config {name}: bench.reference has the keys 'module' and 'rows'")
+    module = given.get("module")
+    if module is not None:
+        _need(isinstance(module, str) and NAME_RE.match(module) is not None,
+              f"config {name}: bench.reference.module {module!r} is no name")
+    path = reference_path(module)
+    _need(os.path.isfile(path),
+          f"config {name}: missing file: {os.path.relpath(path, ROOT)}")
+    _need(_defines(path, REFERENCE_ENTRY),
+          f"config {name}: {os.path.relpath(path, ROOT)} has no "
+          f"{REFERENCE_ENTRY}")
+    rows = given.get("rows", DEFAULT_REFERENCE_ROWS)
+    _need(isinstance(rows, list) and rows,
+          f"config {name}: bench.reference.rows is a list of row shapes")
+    longest = serve_sizes(list(meta.get("serve_flags", [])))["max_model_len"]
+    for row in rows:
+        _need(isinstance(row, dict)
+              and set(row) == {"prompts", "prompt_tokens", "new_tokens"}
+              and all(isinstance(v, int) and v > 0 for v in row.values()),
+              f"config {name}: a reference row is {{prompts, prompt_tokens, "
+              f"new_tokens}} in whole numbers above 0, not {row!r}")
+        _need(row["prompt_tokens"] + row["new_tokens"] <= longest,
+              f"config {name}: a reference row of {row['prompt_tokens']} + "
+              f"{row['new_tokens']} tokens exceeds --max-model-len {longest}")
+    return {"module": module or "harness/reference",
+            "import": ("benchmarks.harness.reference" if module is None
+                       else f"benchmarks.references.{module}"),
+            "rows": rows}
+
+
 def load_config(name: str) -> dict:
     """``{"hf": config.json as run, "bench": the benchmark's notes}``."""
     raw = _read_json(config_path(name))
@@ -75,7 +161,8 @@ def load_config(name: str) -> dict:
         _need(hf.get(key) == change["run"],
               f"config {name}: reduced key {key} says run={change['run']}, "
               f"the file holds {hf.get(key)}")
-    return {"name": name, "hf": hf, "bench": meta}
+    return {"name": name, "hf": hf, "bench": meta,
+            "reference": reference_of(meta, name)}
 
 
 def load_traffic(name: str) -> dict:

@@ -5,7 +5,10 @@ device is a plane named ``/device:TPU:<n>``; on it the line ``XLA Ops``
 holds one event per executed operation (fusions, custom calls = Pallas
 kernels, copies), and ``XLA Modules`` one event per executed program.
 Busy time is the union of the ``XLA Ops`` intervals; per-name sums and
-counts are averaged over the chips used.
+counts are averaged over the chips used. Given a window on the program's
+clock (``perf_counter_ns``, which the program's ``parallax.clock_sync``
+marks lay on the trace's), every device event is cut to it: what lies
+outside counts for nothing, so busy time cannot pass the window.
 
 ``python -m benchmarks.harness.trace_reduce <file-or-dir>`` prints the
 reduction (used once, by hand, to choose the patterns in
@@ -26,6 +29,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 CONTAINERS = ("while", "conditional", "call")
+CLOCK_SYNC = "parallax.clock_sync"
 
 
 def find_xplane(path: str) -> str | None:
@@ -73,41 +77,80 @@ def family(name: str) -> str:
     return re.sub(r"[._]\d+$", "", re.sub(r"\.\d+(\.clone)?(\.\d+)?$", "", name))
 
 
+def program_clock_offset_ns(data, device_plane=DEVICE_PLANE) -> int | None:
+    """Trace clock minus the program's ``perf_counter_ns``, from the
+    ``parallax.clock_sync`` marks the program leaves in a host plane
+    (each carries its reading; a mark's timestamp can only lag it, so
+    the smallest difference is the nearest). None without a mark."""
+    offset = None
+    for plane in data.planes:
+        if device_plane.match(plane.name):
+            continue     # the device's events are most of the file
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == CLOCK_SYNC:
+                    off = int(ev.start_ns) - int(
+                        dict(ev.stats)["perf_counter_ns"])
+                    if offset is None or off < offset:
+                        offset = off
+    return offset
+
+
 def reduce_trace(path: str, device_plane=DEVICE_PLANE,
                  ops_line: str = OPS_LINE,
-                 modules_line: str = MODULES_LINE) -> dict | None:
-    """The reduction, or None where no device plane holds an operation."""
+                 modules_line: str = MODULES_LINE,
+                 window_ns: tuple[int, int] | None = None) -> dict | None:
+    """The reduction, or None where no device plane holds an operation.
+    ``window_ns``: two readings of the program's ``perf_counter_ns``;
+    every event is cut to the span between them (an event cut to a part
+    counts as that part of an execution). A trace without the program's
+    clock marks cannot be cut and is reduced whole (``clipped`` False)."""
     from jax.profiler import ProfileData
 
     file = find_xplane(path)
     if file is None:
         return None
     data = ProfileData.from_file(file)
+    lo, hi = float("-inf"), float("inf")
+    offset = (program_clock_offset_ns(data, device_plane) if window_ns
+              else None)
+    if offset is not None:
+        lo, hi = (t + offset for t in window_ns)
+
+    def cut(ev):
+        """The event's ``(start, seconds, share)`` inside the window."""
+        s, d = ev.start_ns, ev.duration_ns
+        a, b = max(s, lo), min(s + d, hi)
+        if b < a or (b == a and d > 0):
+            return None
+        return a * 1e-9, (b - a) * 1e-9, ((b - a) / d if d > 0 else 1.0)
+
     chips = 0
     busy = span = 0.0
     op_s: dict[str, float] = {}
-    op_n: dict[str, int] = {}
+    op_n: dict[str, float] = {}
     mod_s: dict[str, float] = {}
-    mod_n: dict[str, int] = {}
+    mod_n: dict[str, float] = {}
     gaps: list = []
     for plane in data.planes:
         if not device_plane.match(plane.name):
             continue
         intervals = []
         for line in plane.lines:
-            if line.name == ops_line:
-                for ev in line.events:
-                    s = ev.start_ns * 1e-9
-                    d = ev.duration_ns * 1e-9
+            if line.name not in (ops_line, modules_line):
+                continue
+            sums, counts = ((op_s, op_n) if line.name == ops_line
+                            else (mod_s, mod_n))
+            for ev in line.events:
+                part = cut(ev)
+                if part is None:
+                    continue
+                s, d, share = part
+                if line.name == ops_line:
                     intervals.append((s, s + d))
-                    name = _short(ev.name)
-                    op_s[name] = op_s.get(name, 0.0) + d
-                    op_n[name] = op_n.get(name, 0) + 1
-            elif line.name == modules_line:
-                for ev in line.events:
-                    name = _short(ev.name)
-                    mod_s[name] = mod_s.get(name, 0.0) + ev.duration_ns * 1e-9
-                    mod_n[name] = mod_n.get(name, 0) + 1
+                name = _short(ev.name)
+                sums[name] = sums.get(name, 0.0) + d
+                counts[name] = counts.get(name, 0) + share
         if not intervals:
             continue
         chips += 1
@@ -124,7 +167,7 @@ def reduce_trace(path: str, device_plane=DEVICE_PLANE,
         return {k: v / chips for k, v in d.items()}
 
     return {
-        "file": file, "chips": chips,
+        "file": file, "chips": chips, "clipped": offset is not None,
         "busy_s": busy / chips, "span_s": span / chips,
         "op_seconds": avg(op_s), "op_counts": avg(op_n),
         "module_seconds": avg(mod_s), "module_counts": avg(mod_n),

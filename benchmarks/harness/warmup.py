@@ -22,25 +22,11 @@ window still has to build or load is counted (``compiles_in_window``).
 
 from __future__ import annotations
 
-import argparse
 import asyncio
 
 from benchmarks.harness import loadgen
 
 DECODE_K = 8   # engine.ADAPTIVE_DECODE_LOOKAHEAD
-
-
-def serve_sizes(serve_flags: list[str]) -> dict:
-    """The four sizes that shape the compile lattice, and the page size,
-    with ``serve``'s defaults (``cli.build_parser``)."""
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--max-batch-size", type=int, default=64)
-    ap.add_argument("--max-num-tokens-per-batch", type=int, default=2048)
-    ap.add_argument("--prefill-chunk-size", type=int, default=1024)
-    ap.add_argument("--max-model-len", type=int, default=8192)
-    ap.add_argument("--page-size", type=int, default=64)
-    ns, _ = ap.parse_known_args(serve_flags)
-    return vars(ns)
 
 
 def buckets(max_value: int, floor: int = 8) -> list[int]:

@@ -44,6 +44,12 @@ def attn_decode_work(cfg: dict, context: int, dtype: str = "bfloat16") -> dict:
             "bytes": (context + 1) * kv_bytes_per_token(cfg, dtype) + io}
 
 
+def causal_pairs(start: int, end: int) -> int:
+    """(query, key) pairs of positions ``[start, end)`` under a causal
+    mask: position p attends to p + 1 keys."""
+    return (end * (end + 1) - start * (start + 1)) // 2
+
+
 def attn_prefill_work(cfg: dict, start: int, end: int,
                       dtype: str = "bfloat16") -> dict:
     """Prompt positions ``[start, end)`` under a causal mask, all layers:
@@ -51,7 +57,7 @@ def attn_prefill_work(cfg: dict, start: int, end: int,
     read once, q in and o out once, the chunk's K/V written once."""
     hq, d, layers = cfg["num_attention_heads"], head_dim(cfg), cfg["num_hidden_layers"]
     n = end - start
-    pairs = (end * (end + 1) - start * (start + 1)) // 2
+    pairs = causal_pairs(start, end)
     flops = 4 * hq * d * pairs * layers
     io = 2 * n * hq * d * BYTES[dtype] * layers
     return {"flops": flops,
@@ -85,10 +91,20 @@ def span_work(results: list, t0: float, t1: float, cfg: dict,
     span (chunks of one prompt straddling an end are credited to the
     end where it finished). Rows that decode inside a mixed
     prefill-and-decode step run through the prefill kernel; they are
-    counted here as decode work (see PERF.md, Open questions)."""
+    counted here as decode work (see PERF.md, Open questions).
+
+    ``attn_decode`` / ``attn_prefill`` are reckoned for grouped-query
+    attention (``kv_bytes_per_token``). Three sums are true of any
+    architecture, for a reader that brings its own kernel's bytes and
+    operations: ``decode_context_sum`` (over the decode tokens delivered
+    in the span, the context each attended to), ``prefill_new_tokens``
+    (uncached prompt tokens of the prompts finished in the span) and
+    ``prefill_pair_sum`` (the (query, key) pairs those attended to under
+    the causal mask)."""
     zero = {"flops": 0, "bytes": 0}
     dec, pre = dict(zero), dict(zero)
     decode_tokens = prompt_tokens = prompts = 0
+    decode_context_sum = prefill_pair_sum = 0
     for r in results:
         seen = 0
         plen = len(r.req.prompt)
@@ -100,11 +116,16 @@ def span_work(results: list, t0: float, t1: float, cfg: dict,
                     if t0 <= t < t1:
                         pre = add(pre, attn_prefill_work(cfg, cached, plen, dtype))
                         prompt_tokens += plen - cached
+                        prefill_pair_sum += causal_pairs(cached, plen)
                         prompts += 1
                 elif t0 <= t < t1:
                     dec = add(dec, attn_decode_work(cfg, plen + j, dtype))
                     decode_tokens += 1
+                    decode_context_sum += plen + j
             seen += n
     return {"attn_decode": dec, "attn_prefill": pre,
             "decode_tokens": decode_tokens, "prompt_tokens": prompt_tokens,
-            "prompt_ktok": prompt_tokens / 1e3, "prompts": prompts}
+            "prompt_ktok": prompt_tokens / 1e3, "prompts": prompts,
+            "decode_context_sum": decode_context_sum,
+            "prefill_new_tokens": prompt_tokens,
+            "prefill_pair_sum": prefill_pair_sum}

@@ -183,7 +183,7 @@ def main() -> int:
 
         hf.update(config["bench"]["rehearse"])
         flags += REHEARSE_FLAGS
-    sizes = warmup.serve_sizes(flags)
+    sizes = spec.serve_sizes(flags)
 
     work_dir = os.path.join(ROOT, ".bench_work", args.workload)
     shutil.rmtree(work_dir, ignore_errors=True)
@@ -273,19 +273,25 @@ def main() -> int:
         if not args.rehearse:
             from benchmarks.harness import host_spans, trace_reduce
 
-            red = trace_reduce.reduce_trace(trace_dir)
-            check(red is not None, "the trace holds no device operation")
-            device_out["busy_s"] = red["busy_s"]
-            device_out["window_s"] = got["t_trace1"] - got["t_trace0"]
-            breakdown = trace_reduce.breakdown(red)
+            # ``--trace 2``: the span as the program's own clock saw it
+            # (its two ``/profile`` readings). The reduction cuts the
+            # device's events to it, so ``busy_s`` cannot pass
+            # ``window_s`` whatever the readings bracket.
+            window_ns = None
             if args.trace == 2:
-                # The span as the program's own clock saw it, from
-                # ``start_trace``'s return to the call of ``stop_trace``;
-                # and the idle gaps by the host span that covers them.
                 t0, t1 = (got[k].get("perf_counter_ns")
                           for k in ("profile_start", "profile_stop"))
                 if t0 and t1:
-                    device_out["window_s"] = (t1 - t0) * 1e-9
+                    window_ns = (t0, t1)
+            red = trace_reduce.reduce_trace(trace_dir, window_ns=window_ns)
+            check(red is not None, "the trace holds no device operation")
+            device_out["busy_s"] = red["busy_s"]
+            device_out["window_s"] = (
+                (window_ns[1] - window_ns[0]) * 1e-9 if window_ns
+                else got["t_trace1"] - got["t_trace0"])
+            breakdown = trace_reduce.breakdown(red)
+            if args.trace == 2:
+                # The idle gaps by the host span that covers them.
                 by_span = host_spans.idle_gaps(red["file"])
                 if by_span is not None:
                     breakdown["idle_gaps"] = by_span

@@ -49,13 +49,18 @@ def test_gaps_offer_exactly_the_rate():
         assert g.sum() == pytest.approx(20.0) and (g > 0).all()
 
 
-def test_closed_loop_sends_a_blocker_and_the_same_sizes_for_every_seed():
-    t = spec.load_traffic("decode-probe8")
+@pytest.mark.parametrize("name, outputs", [
+    ("decode-probe8", (3072, 3328)), ("decode-probe8-5k", (5120, 5632))])
+def test_closed_loop_sends_a_blocker_and_the_same_sizes_for_every_seed(
+        name, outputs):
+    t = spec.load_traffic(name)
     tr = loadgen.Traffic(t, 1000, 5, 30.0)
     assert tr.blocker_tokens == 1024
     first = [tr.closed_next(0.02) for _ in range(tr.clients)]
-    assert all(64 <= len(r.prompt) <= 256 and 2048 <= r.max_tokens <= 2304
-               for r in first)
+    assert all(64 <= len(r.prompt) <= 256
+               and outputs[0] <= r.max_tokens <= outputs[1] for r in first)
+    # A row fits serve's --max-model-len (8,192) whole.
+    assert all(len(r.prompt) + r.max_tokens <= 8192 for r in first)
     # Latencies are judged only for requests due inside the window.
     assert not any(r.judged for r in first)
     assert tr.closed_next(tr.warm + 10.5).judged

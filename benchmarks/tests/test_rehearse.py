@@ -1,5 +1,6 @@
 """A ``--rehearse`` run of each cell ends in one well-formed last line
-saying ``"platform": "cpu"``; a later cell arrives as files and one entry."""
+saying ``"platform": "cpu"``; a later cell arrives as files and one entry,
+a later architecture as a configuration that names its own reference."""
 
 import json
 import os
@@ -19,12 +20,16 @@ def cells():
         return [w["name"] for w in json.load(f)["workloads"]]
 
 
-def rehearse(cell, trace, extra=()):
-    proc = subprocess.run(
+def run(cell, trace, extra=()):
+    return subprocess.run(
         [sys.executable, RUN, "--workload", cell, "--seed", "3000000019",
          "--seconds", "3", "--trace", str(trace), "--rehearse", *extra],
         cwd=spec.ROOT, capture_output=True, text=True, timeout=600,
     )
+
+
+def rehearse(cell, trace, extra=()):
+    proc = run(cell, trace, extra)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
@@ -80,3 +85,79 @@ def test_a_later_cell_is_files_and_one_entry(tmp_path):
         os.remove(traffic)
     # Every request shares the system prompt and later turns their history.
     assert line["metrics"]["prefix_hit_share"]["value"] > 20.0
+
+
+# A reference module as a ``model_config`` PR brings it: here the dense
+# block under another name, whole or with its last layer left out.
+REFERENCE = '''"""Written by tests/test_rehearse.py."""
+from benchmarks.harness import reference
+
+
+def greedy_continuations(params, cfg, prompts, n_new):
+    params = dict(params, layers=params["layers"][:len(params["layers"]) - DROP])
+    return reference.greedy_continuations(params, cfg, prompts, n_new)
+'''
+
+
+@pytest.fixture()
+def own_reference(tmp_path):
+    """``qwen2.5-3b-ownref``: the 3B's configuration with a reference
+    module and row shapes of its own. Three new files (configuration,
+    module, a copy of BENCHMARK.json with two more entries) and no edit
+    to a file that is there. Yields ``write(drop)`` -> (cell, extra)."""
+    name, stem = "qwen2.5-3b-ownref", "ownref_for_test"
+    cfg_path, mod_path = spec.config_path(name), spec.reference_path(stem)
+    assert not os.path.exists(cfg_path) and not os.path.exists(mod_path)
+    with open(spec.config_path("qwen2.5-3b")) as f:
+        cfg = json.load(f)
+    cfg["bench"]["reference"] = {"module": stem, "rows": [
+        {"prompts": 2, "prompt_tokens": 24, "new_tokens": 8},
+        {"prompts": 1, "prompt_tokens": 4096, "new_tokens": 4}]}
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    base = next(c for c in bench["configs"] if c["name"] == "qwen2.5-3b")
+    bench["configs"].append(dict(
+        base, name=name, file=os.path.relpath(cfg_path, spec.ROOT)))
+    cell = name + ".decode-probe8"
+    bench["workloads"].append({
+        "name": cell, "config": name, "traffic": "decode-probe8-5k",
+        "chips": 1, "why": "a configuration that names its own reference"})
+    bench_path = tmp_path / "BENCHMARK.json"
+    bench_path.write_text(json.dumps(bench))
+
+    def write(drop):
+        with open(cfg_path, "w") as f:
+            json.dump(cfg, f)
+        with open(mod_path, "w") as f:
+            f.write(REFERENCE.replace("DROP", str(drop)))
+        return cell, ("--benchmark-json", str(bench_path))
+
+    try:
+        yield write
+    finally:
+        for path in (cfg_path, mod_path):
+            if os.path.exists(path):
+                os.remove(path)
+
+
+def test_a_later_architecture_is_files_and_entries(own_reference):
+    cell, extra = own_reference(drop=0)
+    line = rehearse(cell, trace=2, extra=extra)
+    assert line["metrics"]["batch_tokens_per_visit"]["value"] > 0
+    with open(os.path.join(spec.ROOT, ".bench_work", cell,
+                           "reference.json")) as f:
+        ref = json.load(f)
+    assert ref["module"] == "ownref_for_test"
+    # Both row shapes ran (the long one cut to the rehearsal's sizes).
+    assert [(len(r["prompt"]), len(r["tokens"])) for r in ref["rows"]] == [
+        (24, 8), (24, 8), (48, 4)]
+
+
+def test_a_reference_that_leaves_out_a_layer_fails_the_run(own_reference):
+    """The comparison's own control at the rehearsal's size: with one
+    layer of the mathematics left out on one side, no result line."""
+    cell, extra = own_reference(drop=1)
+    proc = run(cell, trace=0, extra=extra)
+    assert proc.returncode == 1
+    assert "benchmark FAILED" in proc.stderr
+    assert '"correct"' not in proc.stdout

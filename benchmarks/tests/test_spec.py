@@ -54,3 +54,58 @@ def test_a_reduced_key_must_be_what_the_file_runs(tmp_path, monkeypatch):
     monkeypatch.setattr(spec, "config_path", lambda n: str(d / f"{n}.json"))
     with pytest.raises(spec.SpecError, match="reduced key"):
         spec.load_config("x")
+
+
+META = spec.load_config("qwen2.5-7b-d24")["bench"]
+
+
+def bench_with(reference, serve_flags=()):
+    meta = dict(META, serve_flags=list(serve_flags))
+    if reference is not None:
+        meta["reference"] = reference
+    return meta
+
+
+ROW = {"prompts": 4, "prompt_tokens": 48, "new_tokens": 16}
+
+
+def test_a_configuration_without_the_key_gets_the_dense_block():
+    for name in ("qwen2.5-7b-d24", "qwen2.5-3b"):
+        ref = spec.load_config(name)["reference"]
+        assert ref == {"module": "harness/reference",
+                       "import": "benchmarks.harness.reference",
+                       "rows": [ROW]}
+
+
+def test_a_reference_module_is_found_by_its_stem(tmp_path, monkeypatch):
+    monkeypatch.setattr(spec, "BENCH_DIR", str(tmp_path))
+    (tmp_path / "references").mkdir()
+    (tmp_path / "references" / "mine.py").write_text(
+        "def greedy_continuations(params, cfg, prompts, n_new):\n    return []\n")
+    (tmp_path / "references" / "empty.py").write_text("def forward():\n    pass\n")
+    long_row = {"prompts": 1, "prompt_tokens": 4096, "new_tokens": 8}
+    ref = spec.reference_of(bench_with({"module": "mine",
+                                        "rows": [ROW, long_row]}))
+    assert ref == {"module": "mine", "import": "benchmarks.references.mine",
+                   "rows": [ROW, long_row]}
+    # Rows alone: the module stays the dense block's (looked up in the
+    # patched directory here, so give it one).
+    (tmp_path / "harness").mkdir()
+    (tmp_path / "harness" / "reference.py").write_text(
+        "from x import greedy_continuations\n")
+    assert spec.reference_of(bench_with({"rows": [long_row]}))["rows"] == [long_row]
+    for reference, flags, message in [
+        ({"module": "absent"}, (), "missing file"),
+        ({"module": "empty"}, (), "has no greedy_continuations"),
+        ({"module": "../harness/reference"}, (), "is no name"),
+        ({"module": "mine", "child": "x"}, (), "has the keys"),
+        ({"module": "mine", "rows": []}, (), "list of row shapes"),
+        ({"module": "mine", "rows": [{"prompts": 1}]}, (), "a reference row is"),
+        ({"module": "mine", "rows": [
+            {"prompts": 1, "prompt_tokens": 8190, "new_tokens": 3}]}, (),
+         "exceeds --max-model-len 8192"),
+        ({"module": "mine", "rows": [long_row]}, ("--max-model-len", "4096"),
+         "exceeds --max-model-len 4096"),
+    ]:
+        with pytest.raises(spec.SpecError, match=message):
+            spec.reference_of(bench_with(reference, flags))

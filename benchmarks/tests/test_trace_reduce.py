@@ -69,5 +69,52 @@ def test_trace_readers_on_the_fixture():
         "work": "toy"}}, ctx) is None
 
 
+def test_device_events_are_cut_to_the_window_they_are_given():
+    """``fixtures/host_spans_v5e.xplane.pb`` carries the program's two
+    ``parallax.clock_sync`` marks. A window that ends in the middle of
+    a device event keeps that event's part inside it and nothing after:
+    busy time cannot pass the window (the refusal of PR 26)."""
+    from jax.profiler import ProfileData
+
+    fixture = os.path.join(spec.BENCH_DIR, "fixtures", "host_spans_v5e.xplane.pb")
+    data = ProfileData.from_file(fixture)
+    offset = trace_reduce.program_clock_offset_ns(data)
+    assert offset is not None
+    events = sorted(
+        (int(ev.start_ns), int(ev.duration_ns), trace_reduce._short(ev.name))
+        for plane in data.planes if trace_reduce.DEVICE_PLANE.match(plane.name)
+        for line in plane.lines if line.name == trace_reduce.OPS_LINE
+        for ev in line.events)
+    whole = trace_reduce.reduce_trace(fixture)
+    assert whole["clipped"] is False
+    # From before the first event to the middle of the longest one.
+    s, d, name = max(events, key=lambda e: e[1])
+    t0 = events[0][0] - 1000 - offset
+    t1 = s + d // 2 - offset
+    red = trace_reduce.reduce_trace(fixture, window_ns=(t0, t1))
+    assert red["clipped"] is True
+    window_s = (t1 - t0) * 1e-9
+    kept = [e for e in events if e[0] < s]
+    assert red["busy_s"] == pytest.approx(
+        sum(e[1] for e in kept) * 1e-9 + (d // 2) * 1e-9, rel=1e-9)
+    assert red["busy_s"] < window_s < whole["busy_s"] + whole["span_s"]
+    assert red["busy_s"] < whole["busy_s"]
+    # The cut event counts as the part of an execution that lies inside.
+    n_before = sum(1 for e in kept if e[2] == name)
+    assert red["op_counts"][name] == pytest.approx(n_before + (d // 2) / d)
+    # A window that holds every event changes nothing.
+    wide = trace_reduce.reduce_trace(
+        fixture, window_ns=(t0, events[-1][0] + events[-1][1] + 1000 - offset))
+    assert wide["busy_s"] == pytest.approx(whole["busy_s"], rel=1e-12)
+    assert wide["op_counts"] == whole["op_counts"]
+    # A window no wider than one event: busy equals the window, never more.
+    inside = trace_reduce.reduce_trace(
+        fixture, window_ns=(s + d // 4 - offset, s + d // 2 - offset))
+    assert inside["busy_s"] == pytest.approx((d // 2 - d // 4) * 1e-9)
+    # A trace without the program's marks cannot be cut: reduced whole.
+    toy = trace_reduce.reduce_trace(FIXTURE, window_ns=(0, 1))
+    assert toy["clipped"] is False and toy["busy_s"] > 0
+
+
 def test_a_trace_without_a_device_plane_reduces_to_nothing(tmp_path):
     assert trace_reduce.reduce_trace(str(tmp_path)) is None

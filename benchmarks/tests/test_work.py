@@ -9,17 +9,7 @@ from benchmarks.harness import spec, work
 PEAKS = spec.peaks_for("TPU v5 lite")
 
 
-# Qwen/Qwen2.5-3B-Instruct's published shapes: a second, tied-head model
-# for the formulas (its configuration file comes with its cell).
-QWEN25_3B = {"hidden_size": 2048, "intermediate_size": 11008,
-             "num_attention_heads": 16, "num_key_value_heads": 2,
-             "num_hidden_layers": 36, "vocab_size": 151936,
-             "tie_word_embeddings": True}
-
-
 def cfg(name):
-    if name == "qwen2.5-3b":
-        return QWEN25_3B
     return spec.load_config(name)["hf"]
 
 
@@ -84,6 +74,36 @@ def test_span_work_counts_tokens_in_the_span():
     sw = work.span_work([r], 0.5, 1.5, c)
     assert sw["prompts"] == 1 and sw["prompt_tokens"] == 100
     assert sw["attn_prefill"] == work.attn_prefill_work(c, 0, 100)
+
+
+def test_span_work_sums_that_hold_for_any_architecture():
+    from benchmarks.harness.loadgen import Req, Result
+
+    c = cfg("qwen2.5-3b")
+    a = Result(req=Req(due=0, prompt=[1] * 100, max_tokens=5, seed=0))
+    a.chunks = [(1.0, 1), (2.0, 2), (3.0, 2)]
+    a.usage = {"prompt_tokens_details": {"cached_tokens": 0}}
+    # A prompt of 300 of which 64 came from the prefix cache; its first
+    # token and two more arrive at t=2.2.
+    b = Result(req=Req(due=0, prompt=[1] * 300, max_tokens=9, seed=0))
+    b.chunks = [(2.2, 3), (4.0, 6)]
+    b.usage = {"prompt_tokens_details": {"cached_tokens": 64}}
+    sw = work.span_work([a, b], 1.5, 2.5, c)
+    # a's tokens 1, 2 at contexts 101, 102; b's tokens 1, 2 at 301, 302.
+    assert sw["decode_tokens"] == 4
+    assert sw["decode_context_sum"] == 101 + 102 + 301 + 302
+    # b's prompt finished in the span: positions 64..299 attend to p + 1.
+    assert sw["prompts"] == 1
+    assert sw["prefill_new_tokens"] == sw["prompt_tokens"] == 236
+    assert sw["prefill_pair_sum"] == sum(p + 1 for p in range(64, 300))
+    assert work.causal_pairs(0, 1024) == 524800
+    # The keys it had keep their values.
+    assert sw["attn_decode"]["flops"] == 4 * 16 * 128 * 806 * 36
+    assert sw["attn_prefill"] == work.attn_prefill_work(c, 64, 300)
+    assert sw["prompt_ktok"] == 0.236
+    empty = work.span_work([a, b], 10.0, 11.0, c)
+    assert (empty["decode_context_sum"], empty["prefill_new_tokens"],
+            empty["prefill_pair_sum"]) == (0, 0, 0)
 
 
 def test_unknown_device_kind_is_an_error():
