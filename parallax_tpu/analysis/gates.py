@@ -44,6 +44,27 @@ class Gate:
 GATE_TABLE: tuple[Gate, ...] = (
     Gate(
         feature="host_cache_bytes",
+        marker="host KV tier disabled: EVA rows hold summary",
+        doc="docs/memory.md",
+        reason="a page image does not say whether a page holds exact "
+               "entries, visible summaries or pending ones",
+    ),
+    Gate(
+        feature="enable_prefix_cache",
+        marker="prefix cache disabled: an EVA row releases",
+        doc="docs/memory.md",
+        reason="a window's exact pages are released at its rollover, so "
+               "a finished row has no page list of its prefix to donate",
+    ),
+    Gate(
+        feature="speculative_tokens",
+        marker="speculative decoding disabled: EVA rows roll",
+        doc="docs/decode_loop.md",
+        reason="only the plain K-step window switches page tables at a "
+               "window boundary",
+    ),
+    Gate(
+        feature="host_cache_bytes",
         marker="host KV tier disabled: hybrid linear-state KV",
         doc="docs/memory.md",
         reason="recurrent state has no page-granularity host image",

@@ -453,6 +453,16 @@ def serve_main(args) -> int:
     )
 
     page_size = args.page_size
+    if config.eva is not None:
+        # A chunk within one page, a window's summaries and tokens on
+        # whole pages (the published 16 / 2048 take the default 64).
+        page_size = config.eva.fit_page_size(page_size)
+        if page_size != args.page_size:
+            logger.info(
+                "--page-size %d -> %d: EVA chunks of %d in windows of %d",
+                args.page_size, page_size, config.eva.chunk_size,
+                config.eva.window_size,
+            )
     sp_mesh = None
     sp_threshold = None
     if sp_size > 1:

@@ -151,6 +151,51 @@ class LinearAttnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class EvaConfig:
+    """EVA chunked linearized attention (EvaByte; Zheng et al., ICLR 2023).
+
+    A query attends exactly and causally to its own ``window_size``
+    window and, through one summary entry per ``chunk_size`` tokens
+    (two softmax-weighted sums over the chunk's keys, by the learned
+    per-head vectors ``adaptive_mu_k`` / ``adaptive_phi``), to every
+    chunk of every *completed* window — one joint softmax over both
+    kinds of entry. A summary has a token's K/V shape, so both kinds
+    live in the same paged cache (docs/memory.md "EVA").
+    """
+
+    window_size: int
+    chunk_size: int
+    # Output heads held in ``lm_head`` (vocab rows each); head 0 is the
+    # next byte and the only one sampled. Heads 1.. feed multi-byte
+    # self-speculation, which is not on the path.
+    num_pred_heads: int = 1
+
+    @property
+    def summaries_per_window(self) -> int:
+        return self.window_size // self.chunk_size
+
+    def fit_page_size(self, requested: int) -> int:
+        """Largest page size <= ``requested`` that keeps a chunk inside
+        one page and a window's summaries and tokens on whole pages."""
+        spw = self.summaries_per_window
+        for p in range(min(requested, spw), 0, -1):
+            if (p % self.chunk_size == 0 and spw % p == 0
+                    and self.window_size % p == 0):
+                return p
+        raise ValueError(
+            f"no KV page size <= {requested} holds EVA chunks of "
+            f"{self.chunk_size} in windows of {self.window_size}"
+        )
+
+    def virtual_len(self, num_tokens: int) -> int:
+        """Entries the step that brings a row's context to
+        ``num_tokens`` (>= 1) attends: the summaries of the windows
+        completed before its last token, and that token's window so far."""
+        w, r = divmod(num_tokens - 1, self.window_size)
+        return w * self.summaries_per_window + r + 1
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Normalized, immutable model architecture description."""
 
@@ -182,6 +227,12 @@ class ModelConfig:
     dsa: DSAConfig | None = None
     msa: MSAConfig | None = None
     linear_attn: LinearAttnConfig | None = None
+    eva: EvaConfig | None = None
+    # RMSNorm scales by ``offset + w`` (EvaByte norm_add_unit_offset: 1.0).
+    norm_offset: float = 0.0
+    # The residual stream is carried and added in float32 (EvaByte
+    # fp32_skip_add); matmul inputs stay in the weights' dtype.
+    fp32_residual: bool = False
     dtype: str = "bfloat16"
     # Bytes per parameter after quantization (bf16 => 2.0).
     param_bytes_per_element: float = 2.0
@@ -402,6 +453,9 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
     is_glm_dsa = cfg.get("model_type") == "glm_moe_dsa"
     if is_glm_dsa and architecture == "UnknownForCausalLM":
         architecture = "GlmMoeDsaForCausalLM"
+    is_evabyte = cfg.get("model_type") == "evabyte"
+    if is_evabyte and architecture == "UnknownForCausalLM":
+        architecture = "EvaByteForCausalLM"
 
     hidden_size = int(_get(cfg, "hidden_size", "n_embd", "d_model"))
     num_layers = int(_get(cfg, "num_hidden_layers", "n_layer", "num_layers"))
@@ -593,6 +647,17 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
             head_v_dim=int(_get(cfg, "linear_value_head_dim", default=head_dim)),
         )
 
+    eva = None
+    if is_evabyte or cfg.get("attention_class") == "eva":
+        eva = EvaConfig(
+            window_size=int(_get(cfg, "window_size", default=2048)),
+            chunk_size=int(_get(cfg, "chunk_size", default=16)),
+            num_pred_heads=int(_get(cfg, "num_pred_heads", default=1) or 1),
+        )
+        if eva.window_size % eva.chunk_size:
+            raise ValueError("EVA window_size must be a multiple of "
+                             "chunk_size")
+
     # Per-layer types: explicit list (gpt-oss/qwen3-next style) or uniform.
     layer_types: tuple[str, ...]
     raw_types = cfg.get("layer_types")
@@ -651,6 +716,9 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
         dsa=dsa,
         msa=msa,
         linear_attn=linear_attn,
+        eva=eva,
+        norm_offset=1.0 if _get(cfg, "norm_add_unit_offset") else 0.0,
+        fp32_residual=bool(_get(cfg, "fp32_skip_add", default=False)),
         dtype=str(_get(cfg, "torch_dtype", "dtype", default="bfloat16")),
         param_bytes_per_element=pbpe,
         partial_rotary_factor=float(_get(cfg, "partial_rotary_factor", default=1.0)),

@@ -71,6 +71,19 @@ class BatchInputs:
     prefill_fused: bool = dataclasses.field(
         default=False, metadata=dict(static=True)
     )
+    # EVA models only (``ModelConfig.eva``; ops/eva.py): per lane, the
+    # flat slot of the first token of a chunk this step completes and
+    # the flat slot its summary is written to (< 0 = no chunk). With
+    # them ``kv_lens`` / ``page_indices`` / ``slot_mapping`` describe
+    # the row's *virtual* sequence (visible summaries, then the open
+    # window) while ``positions`` stay absolute for RoPE.
+    eva_src: jax.Array | None = None      # i32[NC]
+    eva_dst: jax.Array | None = None      # i32[NC]
+    # Decode windows only: what the K-step scan needs to cross a window
+    # boundary on the device (runtime/engine.py ``_eva_step``): the page
+    # table and pending summary pages after the rollover, and the window
+    # the primary table belongs to.
+    eva_window: dict | None = None
 
 
 class StageModel:
@@ -105,6 +118,12 @@ class StageModel:
         self.tp_size = tp_size
         # psum axis inside shard_map; None when running unsharded.
         self.axis_name = axis_name if tp_size > 1 else None
+        if config.norm_offset:
+            self.norm_offset = config.norm_offset
+        if config.eva is not None and tp_size > 1:
+            raise ValueError(
+                "EVA attention (per-head summary vectors) runs at tp-size 1"
+            )
         if tp_size > 1:
             for dim, name in (
                 (config.num_attention_heads, "num_attention_heads"),
@@ -164,7 +183,16 @@ class StageModel:
 
     def finalize_params(self, tree: dict) -> dict:
         """Loader hook: reshape/stack checkpoint weights into this model's
-        param layout (e.g. stacking MoE experts). Default: identity."""
+        param layout (e.g. stacking MoE experts). Default: identity,
+        but for EVA's per-head summary vectors, which checkpoints hold
+        with broadcast axes (``[1, H, 1, D]``)."""
+        if self.config.eva is not None:
+            for layer in tree.get("layers", []):
+                attn = layer["self_attn"]
+                for name in ("adaptive_mu_k", "adaptive_phi"):
+                    attn[name] = attn[name].reshape(
+                        -1, self.config.head_dim
+                    )
         return tree
 
     def init_params(self, rng: jax.Array, dtype=jnp.bfloat16) -> dict:
@@ -183,13 +211,25 @@ class StageModel:
                 p["bias"] = jnp.zeros((out_dim,), dtype)
             return p
 
+        def norm_weight(key, n):
+            if not cfg.norm_offset:
+                return jnp.ones((n,), dtype)
+            # ``x_hat * (1 + w)``: drawn around zero, so that a dropped
+            # offset changes every activation.
+            return (0.1 * jax.random.normal(key, (n,), jnp.float32)
+                    ).astype(dtype)
+
         params: dict = {"layers": []}
         for li in range(self.num_local_layers):
             k = jax.random.split(keys[li], 8)
             h, d = cfg.hidden_size, cfg.head_dim
             layer = {
-                "input_layernorm": {"weight": jnp.ones((h,), dtype)},
-                "post_attention_layernorm": {"weight": jnp.ones((h,), dtype)},
+                "input_layernorm": {
+                    "weight": norm_weight(jax.random.fold_in(keys[li], 8), h)
+                },
+                "post_attention_layernorm": {
+                    "weight": norm_weight(jax.random.fold_in(keys[li], 9), h)
+                },
                 "self_attn": {
                     "q_proj": dense(k[0], cfg.num_attention_heads * d, h,
                                     cfg.attention_bias),
@@ -208,6 +248,18 @@ class StageModel:
             if cfg.use_qk_norm:
                 layer["self_attn"]["q_norm"] = {"weight": jnp.ones((d,), dtype)}
                 layer["self_attn"]["k_norm"] = {"weight": jnp.ones((d,), dtype)}
+            if cfg.eva is not None:
+                # The learned, input-independent proposals of the chunk
+                # summaries: clip(normal, +-1) * d^-1/2 per head.
+                for name, kk in (
+                    ("adaptive_mu_k", jax.random.fold_in(keys[li], 10)),
+                    ("adaptive_phi", jax.random.fold_in(keys[li], 11)),
+                ):
+                    layer["self_attn"][name] = (
+                        jnp.clip(jax.random.normal(
+                            kk, (cfg.num_key_value_heads, d), jnp.float32
+                        ), -1.0, 1.0) * d**-0.5
+                    ).astype(dtype)
             params["layers"].append(layer)
 
         # The last stage of a tied-embedding model also needs the embedding
@@ -222,12 +274,20 @@ class StageModel:
                 ).astype(dtype)
             }
         if self.is_last:
-            params["norm"] = {"weight": jnp.ones((cfg.hidden_size,), dtype)}
+            params["norm"] = {
+                "weight": norm_weight(jax.random.fold_in(keys[-1], 1),
+                                      cfg.hidden_size)
+            }
             if not cfg.tie_word_embeddings:
+                # EVA models hold every prediction head's rows; head 0
+                # (the first vocab_size rows) is the one sampled.
+                heads = cfg.eva.num_pred_heads if cfg.eva else 1
                 params["lm_head"] = {
                     "weight": (
                         jax.random.normal(
-                            keys[-1], (cfg.vocab_size, cfg.hidden_size), jnp.float32
+                            keys[-1],
+                            (cfg.vocab_size * heads, cfg.hidden_size),
+                            jnp.float32,
                         )
                         * 0.02
                     ).astype(dtype)
@@ -254,6 +314,10 @@ class StageModel:
             x = L.embed_lookup(params["embed_tokens"], inputs.token_ids)
         else:
             x = inputs.hidden_states
+        if cfg.fp32_residual:
+            # The residual stream in float32 (EvaByte fp32_skip_add);
+            # ``_decoder_layer`` feeds the matmuls in the weights' dtype.
+            x = x.astype(jnp.float32)
 
         lora_sel = None
         if inputs.lora is not None:
@@ -285,7 +349,12 @@ class StageModel:
         x = self._rms(x, params["norm"]["weight"])
         x = x[inputs.logits_indices]
         head = params.get("lm_head") or params["embed_tokens"]
+        if cfg.fp32_residual:
+            x = x.astype(params["norm"]["weight"].dtype)
         logits = L.lm_head_logits(x, head)
+        if cfg.eva is not None and cfg.eva.num_pred_heads > 1:
+            # Head 0 of the multi-byte output matrix: the next byte.
+            logits = logits[:, : cfg.vocab_size]
         if self.axis_name is not None and self._lm_head_sharded:
             # Vocab-sharded head (tp.lm_head_vocab_sharded — set by
             # tp_stage_fn): gather the [S, V/tp] slices on ICI.
@@ -332,6 +401,8 @@ class StageModel:
             decode_only=inputs.decode_only,
             decode_fused=inputs.decode_fused,
             prefill_fused=inputs.prefill_fused,
+            eva_src=inputs.eva_src,
+            eva_dst=inputs.eva_dst,
         )
 
     def _decoder_layer(
@@ -343,10 +414,15 @@ class StageModel:
         window: int | None,
     ) -> tuple[jax.Array, jax.Array]:
         cfg = self.config
-        h = self._rms(x, lp["input_layernorm"]["weight"])
+        # With a float32 residual stream the norm reads it whole and
+        # hands the block its input in the weights' dtype; the adds
+        # promote back to float32.
+        act = (lp["input_layernorm"]["weight"].dtype
+               if cfg.fp32_residual else x.dtype)
+        h = self._rms(x, lp["input_layernorm"]["weight"]).astype(act)
         attn_out, kv = self._attention(lp, h, kv, inputs, window)
         x = x + attn_out
-        h = self._rms(x, lp["post_attention_layernorm"]["weight"])
+        h = self._rms(x, lp["post_attention_layernorm"]["weight"]).astype(act)
         x = x + self._mlp(lp, h)
         return x, kv
 

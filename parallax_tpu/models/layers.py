@@ -231,6 +231,8 @@ def paged_attention_block(
     decode_only: bool = False,
     decode_fused: bool = False,
     prefill_fused: bool = False,
+    eva_src: jax.Array | None = None,
+    eva_dst: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """GQA attention over the paged cache: project, rope, scatter, attend.
 
@@ -316,5 +318,15 @@ def paged_attention_block(
             decode_fused=decode_fused,
             prefill_fused=prefill_fused,
         )
+        if eva_dst is not None:
+            # EVA: the chunks this step completed get their summary
+            # entries, read back from the rows the append just wrote.
+            from parallax_tpu.ops.eva import eva_summarize
+
+            kv_pages = eva_summarize(
+                kv_pages, p["adaptive_mu_k"], p["adaptive_phi"],
+                eva_src, eva_dst, chunk_size=config.eva.chunk_size,
+                use_pallas=use_pallas,
+            )
     out = row_parallel_linear(out.reshape(t, hq * d), p["o_proj"], axis_name)
     return out, kv_pages

@@ -20,12 +20,15 @@ def register_model(*architectures: str):
 
 
 # The dense llama-family architectures share one block (config flags drive
-# qk-norm / bias / sliding-window differences).
+# qk-norm / bias / sliding-window differences). EvaByte is that block too:
+# its unit-offset norm, float32 residual, EVA summaries and multi-head
+# output matrix all come from the normalized config (``ModelConfig.eva``).
 for _arch in (
     "LlamaForCausalLM",
     "MistralForCausalLM",
     "Qwen2ForCausalLM",
     "Qwen3ForCausalLM",
+    "EvaByteForCausalLM",
 ):
     MODEL_REGISTRY[_arch] = StageModel
 

@@ -397,6 +397,11 @@ class NativeCacheManager:
         self.stats.tokens_hit_device += request.num_cached_tokens
         return True
 
+    def extra_pages(self, request, new_total_tokens: int) -> int:
+        return max(
+            0, self.pages_needed(new_total_tokens) - len(request.page_ids)
+        )
+
     def ensure_capacity(self, request, new_total_tokens: int) -> bool:
         need = self.pages_needed(new_total_tokens) - len(request.page_ids)
         if need <= 0:

@@ -46,6 +46,13 @@ VISIT_WINDOW_AHEAD = "parallax_visit_window_ahead"
 LOOP_GAP_MS = "parallax_loop_gap_ms"
 ADMIT_WAIT_MS = "parallax_admit_wait_ms"
 
+# -- EVA attention (runtime/engine.py, runtime/cache_manager.py) -----------
+EVA_ROLLOVER_MS = "parallax_eva_rollover_ms"
+EVA_ENTRIES_ATTENDED = "parallax_eva_entries_attended"
+EVA_CHUNKS_SUMMARIZED = "parallax_eva_chunks_summarized"
+EVA_WINDOW_ROLLOVERS = "parallax_eva_window_rollovers"
+EVA_PAGES_RELEASED = "parallax_eva_pages_released"
+
 # -- KV memory tier (runtime/engine.py) -------------------------------------
 KV_PAGE_OCCUPANCY = "parallax_kv_page_occupancy"
 KV_PREEMPTIONS_TOTAL = "parallax_kv_preemptions_total"
@@ -221,6 +228,28 @@ HELP: dict[str, str] = {
     ),
     ATTN_KERNEL_DISPATCH_TOTAL: (
         "Engine dispatches by attention kernel implementation"
+    ),
+    EVA_ROLLOVER_MS: (
+        "Milliseconds of host work per EVA window rollover (release of "
+        "the window's exact pages, page-table rebuild); span "
+        "parallax.engine.eva_rollover"
+    ),
+    EVA_ENTRIES_ATTENDED: (
+        "Cache entries (visible chunk summaries + open-window tokens) "
+        "attended by dispatched EVA decode steps, summed over steps and "
+        "rows, counted once (not per layer)"
+    ),
+    EVA_CHUNKS_SUMMARIZED: (
+        "EVA chunks whose summary entry a dispatched step wrote "
+        "(prefill and decode), counted once (not per layer)"
+    ),
+    EVA_WINDOW_ROLLOVERS: (
+        "EVA windows completed and rolled over: pending summary pages "
+        "made visible, the window's exact pages released"
+    ),
+    EVA_PAGES_RELEASED: (
+        "KV pages returned to the allocator by EVA window rollovers, "
+        "before their request ended"
     ),
     KV_PAGE_OCCUPANCY: "Fraction of KV pages in use (0..1)",
     KV_PREEMPTIONS_TOTAL: "Decode-OOM preemptions to the host KV tier",

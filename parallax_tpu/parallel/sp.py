@@ -165,6 +165,7 @@ def sp_eligible(config) -> bool:
         config.linear_attn is not None
         or config.dsa is not None
         or config.msa is not None
+        or config.eva is not None
     ):
         return False
     if get_model_class(config.architecture)._attention is not (

@@ -81,8 +81,14 @@ def assemble(
     gather_all_logits: bool = False,
     decode_fused: bool = False,
     prefill_fused: bool = False,
+    eva=None,
+    eva_tables: bool = False,
 ) -> BatchInputs:
     """Build fixed-shape arrays from a ragged plan.
+
+    ``eva`` (the model's ``EvaConfig``): rows are laid out as their
+    virtual sequences (:class:`_EvaFields`); ``eva_tables`` adds what a
+    decode window needs to cross a window boundary on the device.
 
     ``hidden_states`` replaces token ids on non-first stages; rows must be
     ordered exactly as the plan's segments (already padded to the token
@@ -109,6 +115,11 @@ def assemble(
     cu_q_lens = np.zeros((s + 1,), np.int32)
     logits_indices = np.zeros((s,), np.int32)
 
+    eva_fields = None
+    if eva is not None:
+        eva_fields = _EvaFields(eva, page_size, s, t, spec.pages_per_seq,
+                                decode_only, eva_tables)
+
     row = 0
     for i, seg in enumerate(seqs):
         n = seg.num_new_tokens
@@ -118,8 +129,14 @@ def assemble(
         positions[row : row + n] = np.arange(start_pos, seg.context_len)
         pages = np.asarray(req.page_ids, np.int32)
         pos = np.arange(start_pos, seg.context_len)
+        shift = 0
+        if eva_fields is not None:
+            # The row's virtual sequence: its open window's tokens sit
+            # behind the visible summaries of the windows before it.
+            shift = eva_fields.row(i, req, pos, pages)
+            pos = pos - shift
         slot_mapping[row : row + n] = pages[pos // page_size] * page_size + pos % page_size
-        kv_lens[i] = seg.context_len
+        kv_lens[i] = seg.context_len - shift
         page_indices[i, : len(pages)] = pages
         cu_q_lens[i + 1] = cu_q_lens[i] + n
         logits_indices[i] = row + n - 1
@@ -184,7 +201,79 @@ def assemble(
         num_seqs=jnp.asarray([s_real], jnp.int32),
         slot_mapping=jnp.asarray(slot_mapping),
         logits_indices=jnp.asarray(logits_indices),
+        **(eva_fields.arrays() if eva_fields is not None else {}),
     )
+
+
+class _EvaFields:
+    """The EVA side of one assembled batch (``ModelConfig.eva``,
+    ``cache_manager.EvaCacheManager``): per completed chunk the slot of
+    its first token and the slot its summary goes to, and for a decode
+    batch what a K-step window needs to cross a window boundary on the
+    device (the tables after the rollover; the engine's scan switches to
+    them at the step whose position enters the next window)."""
+
+    def __init__(self, eva, page_size: int, s: int, t: int,
+                 pages_per_seq: int, decode_only: bool, tables: bool):
+        self.eva, self.page = eva, page_size
+        # A row of n tokens completes at most n // C + 1 chunks.
+        lanes = s if decode_only else t // eva.chunk_size + s
+        self.src = np.zeros((lanes,), np.int32)
+        self.dst = np.full((lanes,), -1, np.int32)
+        self.lane = 0
+        self.window = None
+        if tables:
+            pp = eva.summaries_per_window // page_size
+            self.window = dict(
+                ctx=np.zeros((s,), np.int32),
+                w0=np.zeros((s,), np.int32),
+                pend=np.zeros((s, pp), np.int32),
+                next_pend=np.zeros((s, pp), np.int32),
+                next_pages=np.zeros((s, pages_per_seq), np.int32),
+            )
+
+    def row(self, i: int, req, pos: np.ndarray, pages: np.ndarray) -> int:
+        """Register row ``i`` (absolute positions ``pos``, virtual page
+        table ``pages``); returns ``absolute - virtual`` position."""
+        eva, page = self.eva, self.page
+        w = req.eva_window
+        if pos[0] // eva.window_size != w or pos[-1] // eva.window_size != w:
+            raise ValueError(
+                f"{req.request_id}: positions {pos[0]}..{pos[-1]} leave "
+                f"the open EVA window {w}"
+            )
+        shift = w * (eva.window_size - eva.summaries_per_window)
+        pend = np.asarray(req.eva_pending, np.int32)
+        ends = pos[(pos + 1) % eva.chunk_size == 0]     # chunks completed
+        first = ends - (eva.chunk_size - 1) - shift
+        chunk = (ends % eva.window_size) // eva.chunk_size
+        lanes = slice(self.lane, self.lane + len(ends))
+        self.src[lanes] = pages[first // page] * page + first % page
+        self.dst[lanes] = pend[chunk // page] * page + chunk % page
+        self.lane += len(ends)
+        if self.window is not None:
+            from parallax_tpu.runtime.cache_manager import eva_rolled_table
+
+            win = self.window
+            nxt = (
+                eva_rolled_table(req, len(req.eva_pending))
+                if req.eva_next_pending else req.page_ids
+            )
+            win["ctx"][i] = pos[-1] + 1
+            win["w0"][i] = w
+            win["pend"][i] = pend
+            win["next_pend"][i] = req.eva_next_pending or req.eva_pending
+            win["next_pages"][i, : len(nxt)] = nxt
+        return shift
+
+    def arrays(self) -> dict:
+        return dict(
+            eva_src=jnp.asarray(self.src), eva_dst=jnp.asarray(self.dst),
+            eva_window=(
+                None if self.window is None
+                else {k: jnp.asarray(v) for k, v in self.window.items()}
+            ),
+        )
 
 
 def _pad_rows(x: np.ndarray, t: int) -> np.ndarray:

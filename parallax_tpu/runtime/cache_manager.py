@@ -13,6 +13,7 @@ via lock refs for the request's lifetime.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 
@@ -63,6 +64,7 @@ def make_cache_manager(
     host_tier=None,
     track_digests: bool = False,
     prefill_chunk_skip: bool = True,
+    eva=None,
 ):
     """CacheManager factory: the C++ manager (ONE ABI crossing per
     admit/grow/release — ``native.NativeCacheManager``) by default — a
@@ -80,6 +82,13 @@ def make_cache_manager(
     inside C with no per-node observability."""
     import os
 
+    if eva is not None:
+        # Two kinds of entry in one pool and pages that go back before a
+        # request ends: the Python ``EvaCacheManager`` (the native
+        # structures model one append-only page list per request). The
+        # engine has switched prefix reuse and the host tier off.
+        return EvaCacheManager(page_size, num_pages, eva,
+                               max_model_len=max_model_len)
     if use_native is None:
         use_native = (
             not os.environ.get("PARALLAX_TPU_NO_NATIVE")
@@ -266,6 +275,12 @@ class CacheManager:
 
     def pages_needed(self, num_tokens: int) -> int:
         return math.ceil(num_tokens / self.page_size)
+
+    def extra_pages(self, request: Request, new_total_tokens: int) -> int:
+        """Pages :meth:`ensure_capacity` would have to find."""
+        return max(
+            0, self.pages_needed(new_total_tokens) - len(request.page_ids)
+        )
 
     def _reclaim(self, need: int) -> bool:
         """Free pages from the prefix cache until ``need`` are available.
@@ -690,3 +705,175 @@ class CacheManager:
         if not self.enable_prefix_cache:
             return None
         return self.prefix_cache.digest_payload(full=full)
+
+
+def eva_rolled_table(request: Request, summary_pages: int) -> list[int]:
+    """An EVA row's virtual page table once its open window is complete:
+    the pending summary pages join the visible ones, and the pages
+    prepared for the next window (if any) follow."""
+    visible = summary_pages * request.eva_window
+    return (request.page_ids[:visible] + request.eva_pending
+            + request.eva_next_open)
+
+
+class EvaCacheManager(CacheManager):
+    """Page bookkeeping for EVA attention (``config.EvaConfig``,
+    docs/memory.md "EVA"): a row at context ``c`` holds ``c mod W`` exact
+    entries of its open window and one summary per chunk of every
+    completed window, both in pages of the one pool.
+
+    ``request.page_ids`` is the row's *virtual* page table, what the
+    attention kernels read: the visible summary pages of windows
+    ``0 .. w-1`` (``W / C / page`` each) and then the open window's
+    pages. ``request.eva_pending`` are the pages the open window's
+    summaries are written into; they join the visible list when the
+    window is complete, and its ``W / page`` exact pages go back to the
+    allocator (:meth:`roll_window`). A step that writes on both sides
+    of a boundary (a K-step decode window) needs both tables at once:
+    :meth:`ensure_capacity` then prepares ``eva_next_open`` /
+    ``eva_next_pending`` beside the current ones, and the rollover
+    happens when the next plan starts past the boundary, by which time
+    every program that reads the old pages is already enqueued ahead of
+    whatever is given them next.
+
+    Pages held at context ``c`` after a plan that starts there:
+    ``pp * (c // W) + ceil((c mod W) / page) + pp`` with
+    ``pp = W / C / page``. No prefix reuse (a donated page list would
+    name released pages) and no host tier.
+    """
+
+    def __init__(self, page_size: int, num_pages: int, eva,
+                 max_model_len: int = 32768):
+        super().__init__(page_size, num_pages, enable_prefix_cache=False,
+                         max_model_len=max_model_len)
+        if eva.fit_page_size(page_size) != page_size:
+            raise ValueError(
+                f"page size {page_size} does not fit EVA chunks of "
+                f"{eva.chunk_size} in windows of {eva.window_size} "
+                f"(EvaConfig.fit_page_size)"
+            )
+        self.eva = eva
+        self.window = eva.window_size
+        self.summary_pages = eva.summaries_per_window // page_size  # a window's
+        # Monotonic totals (engine collectors publish them).
+        self.rollovers = 0
+        self.pages_released = 0
+        # ``with rollover_span():`` around a rollover's host work; the
+        # engine installs its ``engine.eva_rollover`` span here.
+        self.rollover_span = None
+
+    # -- arithmetic ---------------------------------------------------------
+
+    def pages_needed(self, num_tokens: int) -> int:
+        w, r = divmod(num_tokens, self.window)
+        return (self.summary_pages * (w + 1)
+                + math.ceil(r / self.page_size))
+
+    def pages_held(self, request: Request) -> int:
+        return (len(request.page_ids)
+                + len(getattr(request, "eva_pending", ()))
+                + len(getattr(request, "eva_next_open", ()))
+                + len(getattr(request, "eva_next_pending", ())))
+
+    def extra_pages(self, request: Request, new_total_tokens: int) -> int:
+        """Pages :meth:`ensure_capacity` would allocate."""
+        return sum(self._growth(request, new_total_tokens))
+
+    def _growth(self, request: Request, total: int) -> tuple[int, int, int]:
+        """(open pages, next pending pages, next open pages) still to
+        allocate so that positions below ``total`` can be written."""
+        w = request.eva_window
+        lo, hi = w * self.window, (w + 1) * self.window
+        have_open = len(request.page_ids) - self.summary_pages * w
+        open_need = math.ceil((min(total, hi) - lo) / self.page_size)
+        if total <= hi:
+            return max(0, open_need - have_open), 0, 0
+        if total > hi + self.window:
+            raise ValueError("a step spans more than one EVA window")
+        return (
+            max(0, open_need - have_open),
+            self.summary_pages - len(request.eva_next_pending),
+            max(0, math.ceil((total - hi) / self.page_size)
+                - len(request.eva_next_open)),
+        )
+
+    # -- request lifecycle --------------------------------------------------
+
+    def allocate_for_prompt(self, request: Request) -> bool:
+        """Admit: the first window's pages (or the prompt's, if shorter)
+        and its pending summary pages. Later windows grow chunk by chunk
+        through :meth:`ensure_capacity`: a window's rollover frees more
+        than the next one's pending pages take."""
+        first = min(request.num_prompt_tokens, self.window)
+        need = self.summary_pages + math.ceil(first / self.page_size)
+        try:
+            pages = self.allocator.alloc(need)
+        except OutOfPages:
+            return False
+        request.eva_window = 0
+        request.eva_pending = pages[: self.summary_pages]
+        request.eva_next_open = []
+        request.eva_next_pending = []
+        request.page_ids = pages[self.summary_pages:]
+        request.num_cached_tokens = 0
+        request.num_computed_tokens = 0
+        self._locked[request.request_id] = ([], 0)
+        self.stats.tokens_admitted += request.num_prompt_tokens
+        return True
+
+    def roll_window(self, request: Request, start_pos: int) -> None:
+        """The next step's first write is at ``start_pos``: if that lies
+        past the open window, the window is complete and every program
+        that reads its exact pages is enqueued. Its pending summary
+        pages become visible, its exact pages go back, the prepared (or
+        a fresh) next window opens. Frees before it allocates, so it
+        cannot run out of pages."""
+        if start_pos // self.window <= request.eva_window:
+            return
+        if start_pos // self.window != request.eva_window + 1:
+            raise ValueError("EVA rollover skipped a window")
+        with (self.rollover_span or contextlib.nullcontext)():
+            old = request.page_ids[self.summary_pages * request.eva_window:]
+            self.allocator.free(old)
+            request.page_ids = eva_rolled_table(request, self.summary_pages)
+            request.eva_pending = (
+                request.eva_next_pending
+                or self.allocator.alloc(self.summary_pages)
+            )
+            request.eva_next_open = []
+            request.eva_next_pending = []
+            request.eva_window += 1
+            self.rollovers += 1
+            self.pages_released += len(old)
+
+    def ensure_capacity(self, request: Request, new_total_tokens: int) -> bool:
+        grow_open, grow_pend, grow_next = self._growth(
+            request, new_total_tokens
+        )
+        need = grow_open + grow_pend + grow_next
+        if need <= 0:
+            return True
+        try:
+            pages = self.allocator.alloc(need)
+        except OutOfPages:
+            return False
+        request.page_ids.extend(pages[:grow_open])
+        request.eva_next_pending.extend(
+            pages[grow_open : grow_open + grow_pend]
+        )
+        request.eva_next_open.extend(pages[grow_open + grow_pend :])
+        return True
+
+    def trim_uncomputed_pages(self, request: Request) -> int:
+        return 0
+
+    def release(self, request: Request) -> None:
+        """Everything the request holds goes back; nothing is donated
+        (there is no prefix tree to donate to)."""
+        self._locked.pop(request.request_id, None)
+        pages = list(request.page_ids)
+        for name in ("eva_pending", "eva_next_open", "eva_next_pending"):
+            pages += getattr(request, name, [])
+            setattr(request, name, [])
+        self.allocator.free(pages)
+        request.page_ids = []

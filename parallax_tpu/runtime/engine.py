@@ -387,6 +387,53 @@ def drive_step(
     return outs, ticket
 
 
+def _eva_step(eva, page_size: int, inputs: BatchInputs, token_ids, ctx,
+              stopped) -> BatchInputs:
+    """One scan step's inputs of an EVA decode window, from the carried
+    absolute context: the fed token's position ``ctx - 1`` picks the
+    window, hence the page table (the row's own, or the one prepared
+    for after the rollover — ``batch._EvaFields``), the virtual
+    position behind the visible summaries, and, where the token closes
+    a chunk, the lane of the summary write (``ops/eva.py``)."""
+    import dataclasses as _dc
+
+    win = inputs.eva_window
+    w_size, c_size = eva.window_size, eva.chunk_size
+    pos = jnp.maximum(ctx - 1, 0)
+    w = pos // w_size
+    rolled = (w > win["w0"])[:, None]
+    table = jnp.where(rolled, win["next_pages"], inputs.page_indices)
+    pend = jnp.where(rolled, win["next_pend"], win["pend"])
+    in_window = pos % w_size
+    vpos = eva.summaries_per_window * w + in_window
+
+    def pick(pages, index):
+        return jnp.take_along_axis(pages, index[:, None], axis=1)[:, 0]
+
+    live = (ctx > 0) & ~stopped
+    slots = jnp.where(
+        live, pick(table, vpos // page_size) * page_size + vpos % page_size,
+        jnp.int32(-1),
+    )
+    chunk = in_window // c_size
+    closes = live & ((pos + 1) % c_size == 0)
+    return dataclasses.replace(
+        inputs,
+        token_ids=token_ids,
+        positions=ctx - 1,
+        kv_lens=jnp.where(ctx > 0, vpos + 1, 0),
+        slot_mapping=slots,
+        page_indices=table,
+        eva_src=slots - (c_size - 1),
+        eva_dst=jnp.where(
+            closes,
+            pick(pend, chunk // page_size) * page_size + chunk % page_size,
+            jnp.int32(-1),
+        ),
+        eva_window=None,
+    )
+
+
 @jax.jit
 def _scatter_last_tokens(last, slots, tokens):
     """Park this step's sampled tokens in the slot-indexed last-token
@@ -566,8 +613,24 @@ class StageEngine:
         # preemption; transfers read self.kv LIVE (the step loop donates
         # and replaces the arrays every dispatch).
         self.host_tier = None
+        # EVA models (``ModelConfig.eva``): summaries and exact entries
+        # share the pool and a window's exact pages go back when it is
+        # complete (cache_manager.EvaCacheManager, docs/memory.md "EVA").
+        self._eva = model.config.eva
+        if self._eva is not None and self.cfg.speculative_tokens > 0:
+            logger.warning(
+                "speculative decoding disabled: EVA rows roll their "
+                "window over inside plain decode windows only",
+            )
+            self.cfg.speculative_tokens = 0
         if self.cfg.host_cache_bytes > 0:
-            if self._needs_state:
+            if self._eva is not None:
+                logger.info(
+                    "host KV tier disabled: EVA rows hold summary and "
+                    "exact pages that the tier's page images do not "
+                    "tell apart",
+                )
+            elif self._needs_state:
                 logger.warning(
                     "host KV tier disabled: hybrid linear-state KV "
                     "cannot be paged to host (recurrent state has no "
@@ -594,9 +657,16 @@ class StageEngine:
                         "host KV tier disabled: unsupported KV layout "
                         "or budget below one page",
                     )
+        if self._eva is not None and self.cfg.enable_prefix_cache:
+            logger.info(
+                "prefix cache disabled: an EVA row releases its exact "
+                "pages window by window, so a finished row has no page "
+                "list of its prefix to donate",
+            )
         self.cache = make_cache_manager(
             self.cfg.page_size,
             self.cfg.num_pages,
+            eva=self._eva,
             enable_prefix_cache=(
                 self.cfg.enable_prefix_cache
                 and (not self._needs_state or n_prefix_slots > 0)
@@ -907,6 +977,10 @@ class StageEngine:
         from parallax_tpu.utils.request_metrics import StepTimingAggregator
 
         self._init_obs()
+        if self._eva is not None:
+            self.cache.rollover_span = lambda: host_span(
+                "engine.eva_rollover", self._h_eva_rollover
+            )
         self.step_timing = StepTimingAggregator(
             host_hist=self._h_step_host,
             per_token_hist=self._h_step_per_token,
@@ -1366,7 +1440,7 @@ class StageEngine:
         if type(model)._attention is not StageModel._attention:
             return False
         cfg = model.config
-        if cfg.use_attention_sinks:
+        if cfg.use_attention_sinks or cfg.eva is not None:
             return False
         return all(
             cfg.layer_type(gi) == LAYER_ATTENTION
@@ -1762,6 +1836,19 @@ class StageEngine:
             mnames.help_text(mnames.VISIT_WINDOW_AHEAD),
             buckets=(0.0, 1.0), labelnames=st,
         ).labels(**lbl)
+        # EVA models: what the decode steps read, what the summary op
+        # wrote, and the rollovers (collected from the cache manager).
+        self._h_eva_rollover = phase(mnames.EVA_ROLLOVER_MS)
+
+        def eva_counter(name):
+            return reg.counter(
+                name, mnames.help_text(name), labelnames=st
+            ).labels(**lbl)
+
+        self._c_eva_entries = eva_counter(mnames.EVA_ENTRIES_ATTENDED)
+        self._c_eva_chunks = eva_counter(mnames.EVA_CHUNKS_SUMMARIZED)
+        self._c_eva_rollovers = eva_counter(mnames.EVA_WINDOW_ROLLOVERS)
+        self._c_eva_released = eva_counter(mnames.EVA_PAGES_RELEASED)
         # Head stage: ids of submitted requests no plan has held yet;
         # their first plan observes parallax_admit_wait_ms. Empty in
         # steady decode, so dispatch pays one falsy check.
@@ -1952,6 +2039,9 @@ class StageEngine:
             self._c_chunk_skip.set_total(
                 getattr(stats, "tokens_chunk_skipped", 0)
             )
+        if self._eva is not None:
+            self._c_eva_rollovers.set_total(self.cache.rollovers)
+            self._c_eva_released.set_total(self.cache.pages_released)
         with self._spec_lock:
             acc = sum(s.get("accepted", 0)
                       for s in self._spec_stats.values())
@@ -2462,8 +2552,12 @@ class StageEngine:
 
         model = self.model
         page_size = self.cfg.page_size
+        eva = self._eva
 
         def step_inputs_at(inputs, token_ids, ctx, stopped):
+            if eva is not None:
+                return _eva_step(eva, page_size, inputs, token_ids, ctx,
+                                 stopped)
             pos = ctx - 1                           # fed token's slot
             page_of = jnp.maximum(pos, 0) // page_size
             phys = jnp.take_along_axis(
@@ -3375,6 +3469,7 @@ class StageEngine:
             plan, self.spec, self.cfg.page_size, decode_only=True,
             with_dense_map=self._needs_state,
             decode_fused=self._decode_fused,
+            eva=self._eva, eva_tables=True,
         )
         self._count_kernel_dispatch("multistep")
         lora = self._lora_field(plan, inputs)
@@ -3447,7 +3542,12 @@ class StageEngine:
         produced = self._carry_in(np.zeros((s,), np.int32))
         if chain is None:
             feed = self._carry_in(inputs.token_ids)
-            ctx = self._carry_in(inputs.kv_lens)
+            # The carried context is the absolute one; an EVA batch's
+            # ``kv_lens`` are virtual (``_eva_step`` derives them).
+            ctx = self._carry_in(
+                inputs.kv_lens if self._eva is None
+                else inputs.eva_window["ctx"]
+            )
             stopped = self._carry_in(limits <= 0)
             fextra = {
                 key: self._carry_in(val) for key, val in fextra.items()
@@ -3484,6 +3584,8 @@ class StageEngine:
                 if key in carry
             }
         self._last_fused_steps = m * k
+        if self._eva is not None:
+            self._count_eva(plan, m * k)
         for arr in (*windows, *(lps or ()), produced):
             # Start the D2H copies NOW so resolve()'s readback finds the
             # bytes pre-staged instead of blocking the step thread.
@@ -3562,6 +3664,29 @@ class StageEngine:
         for seg, n in zip(plan.seqs, left):
             seg.request.window_pending = max(0, min(steps, n))
         return True
+
+    def _count_eva(self, plan: BatchPlan, steps: int = 1) -> None:
+        """Count a dispatched EVA step or decode window once (not per
+        layer): the chunks whose summaries it writes, and over its
+        decode rows and steps the entries each step attends (the
+        virtual ``kv_len``). A window counts the steps a row's budget
+        leaves it, as planned at dispatch."""
+        eva = self._eva
+        chunks = entries = 0
+        for seg in plan.seqs:
+            first = seg.context_len - seg.num_new_tokens
+            n = seg.num_new_tokens
+            decode = n == 1 and seg.request.is_prefill_done
+            if steps > 1:
+                n = max(0, min(steps, seg.budget_left))
+            chunks += ((first + n) // eva.chunk_size
+                       - first // eva.chunk_size)
+            if decode:
+                entries += sum(
+                    eva.virtual_len(first + j + 1) for j in range(n)
+                )
+        self._c_eva_chunks.inc(chunks)
+        self._c_eva_entries.inc(entries)
 
     def _window_ahead(
         self, plan: BatchPlan
@@ -4496,7 +4621,10 @@ class StageEngine:
                 gather_all_logits=bool(spec_rows),
                 decode_fused=self._decode_fused and decode_only,
                 prefill_fused=self._prefill_fused and not decode_only,
+                eva=self._eva,
             )
+            if self._eva is not None:
+                self._count_eva(plan)
             self._count_kernel_dispatch(
                 "decode" if one_token else "prefill",
                 self._attn_impl if decode_only else self._prefill_impl,

@@ -110,6 +110,10 @@ class Scheduler:
         # never id(self), which CPython reuses after GC.
         self.conf_token = conformance.new_token()
         self.cache = cache_manager
+        # EVA models (``cache_manager.EvaCacheManager``): a prefill chunk
+        # ends on a window boundary and a step that starts past one
+        # rolls the window over first; None for every other model.
+        self._eva_window = getattr(cache_manager, "window", None)
         self.max_batch_size = max_batch_size
         self.max_num_tokens_per_batch = max_num_tokens_per_batch
         self.prefill_chunk_size = prefill_chunk_size
@@ -485,6 +489,11 @@ class Scheduler:
                      ) * self.snapshot_page_align
                 if start < a < start + n:
                     n = a - start
+            if self._eva_window:
+                # The chunk's queries read [visible summaries] ++ [own
+                # window so far]: it may not reach into the next window.
+                n = min(n, self._eva_window - start % self._eva_window)
+                self.cache.roll_window(req, start)
             # Mirror requests grow their prompt incrementally (chunks arrive
             # over the wire), so page capacity may lag the prompt length.
             if not self._ensure_capacity_or_preempt(req, start + n):
@@ -565,6 +574,8 @@ class Scheduler:
                 req.device_feed_ready and not req.ready_for_step
             )
             ctx = req.total_len + (ahead or int(fed))
+            if self._eva_window:
+                self.cache.roll_window(req, ctx - 1)
             if ahead:
                 # The window reserved its pages up to here: nothing to
                 # evict or preempt for.
@@ -639,6 +650,10 @@ class Scheduler:
                 # rides this one frozen and needs no room and no pages.
                 continue
             room = (max_model_len - seg.context_len) // k_eff
+            if self._eva_window:
+                # One window boundary per dispatch at most: the scan
+                # switches page tables once (engine ``_eva_step``).
+                room = min(room, self._eva_window // k_eff)
             if room < 1:
                 return 0
             m = min(m, room)
@@ -648,10 +663,8 @@ class Scheduler:
 
         def _extra_pages(mm: int) -> int:
             return sum(
-                max(
-                    0,
-                    self.cache.pages_needed(seg.context_len + mm * k_eff)
-                    - len(seg.request.page_ids),
+                self.cache.extra_pages(
+                    seg.request, seg.context_len + mm * k_eff
                 )
                 for seg in live
             )

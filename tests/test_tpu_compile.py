@@ -150,6 +150,76 @@ def test_bundled_ragged_attention_compiles_for_v5e(v5e, hq, hkv):
     )
 
 
+# EvaByte's geometry (PR 28): 32 KV heads with one query head each, a
+# vocabulary of 320 (not a multiple of 128 lanes), chunk summaries of 16
+# rows, 256 pages per sequence (--max-model-len 16384).
+def _evabyte_batch(dev, t, s):
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    h = 32
+    return dict(
+        q=a((t, h, HEAD_DIM), jnp.bfloat16),
+        cache=a((530, PAGE, 2 * h, HEAD_DIM), jnp.bfloat16),
+        kv_lens=a((s,), jnp.int32), pages=a((s, 256), jnp.int32),
+        cu=a((s + 1,), jnp.int32), nseq=a((1,), jnp.int32),
+        slots=a((t,), jnp.int32),
+    )
+
+
+def test_fused_decode_compiles_for_v5e_at_32_kv_heads(v5e):
+    b = _evabyte_batch(v5e, 8, 8)
+    _compile(
+        lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
+            q, k, v, cache, lens, pages, slots, None,
+            sm_scale=HEAD_DIM ** -0.5, interpret=False,
+        ),
+        b["q"], b["q"], b["q"], b["cache"], b["kv_lens"], b["pages"],
+        b["slots"],
+    )
+
+
+@pytest.mark.parametrize("t", [256, 2048])
+def test_fused_prefill_compiles_for_v5e_at_32_kv_heads(v5e, t):
+    b = _evabyte_batch(v5e, t, 8)
+    _compile(
+        lambda q, k, v, cache, lens, pages, cu, nseq, slots:
+        gqa_fused_prefill_pallas(
+            q, k, v, cache, lens, pages, cu, nseq, slots, None,
+            sm_scale=HEAD_DIM ** -0.5, interpret=False,
+        ),
+        b["q"], b["q"], b["q"], b["cache"], b["kv_lens"], b["pages"],
+        b["cu"], b["nseq"], b["slots"],
+    )
+
+
+def test_fused_sampler_compiles_for_v5e_at_vocab_320(v5e):
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    _compile(
+        functools.partial(fused_sample_topk_pallas, interpret=False),
+        a((8, 320), jnp.float32), a((8, 320), jnp.float32),
+        a((8,), jnp.float32), a((8,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("lanes", [8, 136])     # a decode step; T=2048
+def test_eva_summary_compiles_for_v5e(v5e, lanes):
+    from parallax_tpu.ops.eva import eva_summary_pallas
+
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    _compile(
+        functools.partial(eva_summary_pallas, chunk_size=16,
+                          interpret=False),
+        a((530, PAGE, 64, HEAD_DIM), jnp.bfloat16),
+        a((32, HEAD_DIM), jnp.bfloat16), a((32, HEAD_DIM), jnp.bfloat16),
+        a((lanes,), jnp.int32), a((lanes,), jnp.int32),
+    )
+
+
 def test_split_sink_decode_compiles_for_v5e(v5e):
     """The split GQA decode kernel (sinks + sliding window) at head_dim
     128; gpt-oss's own head_dim 64 is a recorded gap (docs/kernels.md)."""
