@@ -516,6 +516,11 @@ def drive_one_chip(srv: Server, sizes: dict, rehearse: bool) -> dict:
           "TPU-auto did not select the fused kernels", kernel=kernel)
     check(rehearse or kernel["interpret"] is False,
           "fused kernels ran in the Pallas interpreter", kernel=kernel)
+    # The decode kernel's page stream: 8 pages a block at this page
+    # shape ([64, 2 * 4, 128] bf16), derived, never set.
+    check(kernel["decode_pages_per_block"] == 8,
+          "the decode kernel's page stream is not at 8 pages a block",
+          kernel=kernel)
     for path in ("prefill", "decode", "multistep"):
         check(dispatch.get(f"{want_impl}/{path}", 0) > 0,
               f"no {path} dispatch on the fused kernels", dispatch=dispatch)
@@ -759,11 +764,16 @@ def child_kernels(out_path: str, rehearse: bool) -> None:
         )
 
     room = page * pps
-    for hq, hkv in ((28, 4), (7, 1)):
+    # Decode: one token per row, contexts from 1 token to 12 pages: a
+    # page-exact boundary, the fused kernel's block boundary (it streams
+    # 8 pages a block at the Qwen shapes, 2 at 32 KV heads) crossed by
+    # one token — that row's append lands in the first page of its last
+    # block — and a third block. The benchmark's three head shapes and
+    # one TP=4 shard.
+    for hq, hkv in ((28, 4), (7, 1), (16, 2), (32, 32)):
         tag = f"{hq}q{hkv}kv"
-        # Decode: one token per row, contexts from 1 token to ~4 pages
-        # and one page-exact boundary.
-        lens = [1, page, page + 1, 3 * page + 17, min(room, 5 * page), 9]
+        lens = [1, page, page + 1, 3 * page + 17, min(room, 5 * page), 9,
+                8 * page + 1, 12 * page + 5]
         c = case(hq, hkv, [1] * len(lens), [n - 1 for n in lens])
         cache_x = reshape_and_cache(c["cache"], c["k"], c["v"], c["slots"])
         want = _ragged_paged_attention_xla(
@@ -779,6 +789,8 @@ def child_kernels(out_path: str, rehearse: bool) -> None:
         n = c["t_real"]
         close(f"decode_{tag}", got[:n], want[:n], 3e-2)
 
+    for hq, hkv in ((28, 4), (7, 1)):
+        tag = f"{hq}q{hkv}kv"
         # Prefill: ragged chunk lengths over cached prefixes, crossing
         # page and query-block boundaries.
         q_lens = [70, 33, 1, 100, 17]

@@ -32,12 +32,25 @@ Two grid disciplines live here:
 
 - **Streamed (fused) kernels** — grid ``(num_seqs,)``; each program
   DMAs only the row's *valid* pages HBM->VMEM (``ceil(kv_len/page)``
-  of them, window-clipped when sliding) and folds each into a VMEM
-  accumulator. The split kernels' grid ``(S, pages_per_seq)`` visits —
-  and block-copies — every page slot of every row, valid or not; on
-  ragged decode batches the streamed form does strictly less memory
-  traffic, and the fused append (a one-row DMA into the page the
-  table already names) replaces a full-cache XLA scatter.
+  of them, window-clipped when sliding) and folds them into a VMEM
+  accumulator. The stream's discipline (:func:`paged_decode_stream`):
+  pages move in **blocks of B** consecutive page-table entries, one
+  DMA a page started back to back, into one of **two VMEM buffers**;
+  block ``b + 1``'s copies are started before block ``b`` is waited
+  on and folded, so copies are in flight while the fold computes, and
+  ``fold`` sees ``B * page`` tokens at once. ``B`` is **derived**
+  (:func:`decode_pages_per_block`: the largest power of two, at most
+  8, whose pages fit 2 MB as VMEM pads them — 8 at 64/128 KB pages,
+  2 at 1 MB pages). The row's append is waited on **before the copies
+  of its last block start** (that block holds the slot's page), and
+  **rows prefetch across the grid**: a row's last fold runs beside the
+  next row's first fetch, except when that fetch would read the page
+  the next row's own append is about to write. The split kernels'
+  grid ``(S, pages_per_seq)`` visits — and block-copies — every page
+  slot of every row, valid or not; on ragged decode batches the
+  streamed form does strictly less memory traffic, and the fused
+  append (a one-row DMA into the page the table already names)
+  replaces a full-cache XLA scatter.
 - **Legacy page-grid helpers** — :func:`decode_page_grid_spec` and the
   :func:`online_softmax_update` / :func:`online_softmax_finish` pair
   are the shared scaffold for the split decode kernels
@@ -57,7 +70,8 @@ through the *output* alias so the appended row is visible to the same
 program's attention (the new token attends to itself). Appends target
 each row's private tail slot (``slot_mapping``), never a shared
 prefix page, so sequential grid iteration needs no cross-row
-synchronization. ``slot < 0`` (padding / frozen multi-step rows)
+synchronization beyond the prefetch rule above. ``slot < 0``
+(padding / frozen multi-step rows)
 skips the append while attention still runs over the row's committed
 context.
 """
@@ -82,6 +96,8 @@ _SAMPLE_NEG_INF = -1e10
 # O(vocab log vocab) sort regardless of k. Past this bound the engine
 # keeps the split sampler (fused attention stays active).
 FUSED_SAMPLE_TOPK_MAX = 64
+
+_LANES = 128
 
 
 # --------------------------------------------------------------------------
@@ -145,6 +161,33 @@ def online_softmax_finish(l_ref, o_ref, out_ref) -> None:
 # --------------------------------------------------------------------------
 
 
+# One buffer of the streamed core's page blocks may take this much VMEM
+# (two are allocated). v5e gives a kernel 16 MB of scoped VMEM; 2 x 2 MB
+# of block buffers leave the accumulators, the operands' double-buffered
+# blocks and the fold's temporaries three quarters of it.
+_STREAM_BLOCK_BYTES = 2 << 20
+_MAX_PAGES_PER_BLOCK = 8      # static unrolling: one DMA a page
+
+
+def decode_pages_per_block(
+    page_size: int, c: int, w: int, dtype
+) -> int:
+    """How many pages the streamed core moves and folds at once — derived
+    from the page's shape, never set: the largest power of two (at most
+    ``_MAX_PAGES_PER_BLOCK``) whose pages fit ``_STREAM_BLOCK_BYTES`` *as
+    VMEM holds them*: the trailing ``(c, w)`` dims pad to the dtype's
+    tile (8 x 128 of 32 bits, 16 x 128 of bf16, 32 x 128 of 8 bits), so
+    a ``[64, 4, 128]`` bf16 page of 64 KB takes 256 KB there."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    padded = (
+        page_size * -(-c // sublanes) * sublanes * -(-w // _LANES) * _LANES
+        * itemsize
+    )
+    b = max(1, min(_MAX_PAGES_PER_BLOCK, _STREAM_BLOCK_BYTES // padded))
+    return 1 << (b.bit_length() - 1)
+
+
 def paged_decode_stream(
     cache: jax.Array,          # [P, page, C, W]
     kv_lens: jax.Array,        # i32[S] context length INCLUDING new token
@@ -163,18 +206,45 @@ def paged_decode_stream(
 ):
     """Build + invoke the streamed decode program.
 
-    One grid step per row: (1) if ``append`` is given and the row's
-    slot is live, DMA its new-token row into the cache page the slot
-    names; (2) ``fori_loop`` over the row's valid pages, DMAing each
-    into a VMEM scratch page and calling ``fold``; (3) ``finalize``
-    writes the row's output block(s). Returns ``(outs..., cache)``
-    when appending (cache input/output-aliased — donate it), else
-    ``outs...``; single-element outputs are unwrapped.
+    One grid step per row, rows in order. The row's valid pages
+    (window-clipped from ``first_page``) move HBM->VMEM in blocks of
+    ``B = decode_pages_per_block(...)`` consecutive page-table entries,
+    one DMA a page started back to back, into one of two VMEM buffers:
+    block ``b + 1``'s copies are started before block ``b`` is waited
+    on and folded, so copies are in flight while ``fold`` computes.
+    ``fold`` receives ``rows``, the block's ``[B * page, C, W]`` tokens
+    from position ``base`` on, and masks what lies at or past
+    ``kv_len`` itself. A short last block re-reads the row's last
+    valid page into the slots it has no page for (their positions are
+    >= ``kv_len``, so the mask drops them): every slot of a buffer
+    holds real cache rows, at most ``B - 1`` pages a row are moved
+    twice, and no page-table entry past the last valid page is ever
+    dereferenced.
+
+    Append ordering. The row's new-token DMA (``append``; skipped when
+    the slot is < 0) is started first and waited on *before the copies
+    of the row's last block are started*: the slot is position
+    ``kv_len - 1`` (the new token attends to itself), so its page is
+    the row's last valid page and only the last block reads it. A row
+    of two or more blocks overlaps the append with its first fetch.
+
+    Rows prefetch across the grid: while a row's last block is folded,
+    the next row's first block is already on its way into the other
+    buffer (the two SMEM words of ``carry_ref`` hand the buffer parity
+    and the "already started" mark to the next grid step) — unless
+    that block is the next row's only one and the row appends, for its
+    append is started only at its own grid step and its page lies in
+    that block.
+
+    ``finalize`` writes the row's output block(s). Returns
+    ``(outs..., cache)`` when appending (cache input/output-aliased —
+    donate it), else ``outs...``; single-element outputs are unwrapped.
     """
     s, pages_per_seq = page_indices.shape
     _, page_size, c, w = cache.shape
     n_ops = len(operands)
     with_append = append is not None
+    bp = decode_pages_per_block(page_size, c, w, cache.dtype)
 
     def kernel(pages_ref, lens_ref, slots_ref, *refs):
         qs = refs[:n_ops]
@@ -193,40 +263,124 @@ def paged_decode_stream(
             cache_ref = cache_in_ref
         n_acc = len(acc_shapes)
         accs = refs[pos : pos + n_acc]
-        page_scratch = refs[pos + n_acc]
-        read_sem = refs[pos + n_acc + 1]
+        blocks = refs[pos + n_acc]      # [2, B * page, C, W]
+        read_sems = refs[pos + n_acc + 1]
+        # Across grid steps: [0] blocks streamed so far (its parity names
+        # the buffer a row's first block lands in), [1] whether the row
+        # before already started this row's first block.
+        carry_ref = refs[pos + n_acc + 2]
         i = pl.program_id(0)
-        n = lens_ref[i]
+
+        @pl.when(i == 0)
+        def _():
+            carry_ref[0] = 0
+            carry_ref[1] = 0
+
+        def extent(row):
+            n = lens_ref[row]
+            n_pages = (n + page_size - 1) // page_size
+            start = first_page(n) if first_page is not None else 0
+            return n, n_pages, start, (n_pages - start + bp - 1) // bp
+
+        n, n_pages, start, n_blocks = extent(i)
+        first_buf = carry_ref[0]
+        prefetched = carry_ref[1] == 1
+        carry_ref[0] = first_buf + n_blocks
+        carry_ref[1] = 0
+
+        def block_copies(row, b, buf, start, n_pages):
+            """Block ``b`` of ``row``: one DMA a page into buffer
+            ``buf % 2``. A short last block re-reads the row's last
+            valid page into the slots it has no page for."""
+            return [
+                pltpu.make_async_copy(
+                    cache_ref.at[pages_ref[
+                        row, jnp.minimum(start + b * bp + k, n_pages - 1)
+                    ]],
+                    blocks.at[buf % 2, pl.ds(k * page_size, page_size)],
+                    read_sems.at[buf % 2],
+                )
+                for k in range(bp)
+            ]
+
+        def start_block(b):
+            for cp in block_copies(i, b, first_buf + b, start, n_pages):
+                cp.start()
+
+        def wait_append():
+            pass
 
         if with_append:
-            write_sem = refs[pos + n_acc + 2]
+            write_sem = refs[pos + n_acc + 3]
             slot = slots_ref[i]
 
-            @pl.when(slot >= 0)
-            def _append():
-                cp = pltpu.make_async_copy(
+            def append_copy():
+                return pltpu.make_async_copy(
                     append_ref.at[0],
                     cache_ref.at[slot // page_size, slot % page_size],
                     write_sem,
                 )
-                cp.start()
-                cp.wait()
+
+            @pl.when(slot >= 0)
+            def _append():
+                append_copy().start()
+
+            def wait_append():
+                @pl.when(slot >= 0)
+                def _():
+                    append_copy().wait()
+
+        # The row after this one: its first block is started while this
+        # row's last block is folded, unless that block holds the page
+        # its own append (started only at its own grid step) writes.
+        nxt = jnp.minimum(i + 1, s - 1)
+        _, nxt_pages, nxt_start, nxt_blocks = extent(nxt)
+        prefetch_next = jnp.logical_and(i + 1 < s, nxt_blocks >= 1)
+        if with_append:
+            prefetch_next = jnp.logical_and(
+                prefetch_next,
+                jnp.logical_or(nxt_blocks >= 2, slots_ref[nxt] < 0),
+            )
 
         init(accs, qs, outs)
-        start = first_page(n) if first_page is not None else 0
 
-        def body(j, carry):
-            cp = pltpu.make_async_copy(
-                cache_ref.at[pages_ref[i, j]], page_scratch, read_sem
+        # The last block reads the appended page: a row of one block (or
+        # none) waits for its append here, a longer one inside the loop.
+        @pl.when(n_blocks <= 1)
+        def _():
+            wait_append()
+
+        @pl.when(jnp.logical_and(n_blocks > 0, jnp.logical_not(prefetched)))
+        def _():
+            start_block(0)
+
+        def body(b, carry):
+            @pl.when(b + 1 < n_blocks)
+            def _():
+                @pl.when(b + 2 == n_blocks)
+                def _():
+                    wait_append()
+
+                start_block(b + 1)
+
+            @pl.when(jnp.logical_and(b + 1 == n_blocks, prefetch_next))
+            def _():
+                for cp in block_copies(
+                    nxt, 0, first_buf + n_blocks, nxt_start, nxt_pages
+                ):
+                    cp.start()
+                carry_ref[1] = 1
+
+            for cp in block_copies(i, b, first_buf + b, start, n_pages):
+                cp.wait()
+            fold(
+                accs, qs, outs,
+                blocks[(first_buf + b) % 2],
+                (start + b * bp) * page_size, n,
             )
-            cp.start()
-            cp.wait()
-            fold(accs, qs, outs, page_scratch[...], j * page_size, n)
             return carry
 
-        jax.lax.fori_loop(
-            start, (n + page_size - 1) // page_size, body, 0
-        )
+        jax.lax.fori_loop(0, n_blocks, body, 0)
         finalize(accs, qs, outs, n)
 
     in_specs = []
@@ -278,8 +432,9 @@ def paged_decode_stream(
         aliases = {3 + n_ops + 1: len(out_shapes)}
 
     scratch = [pltpu.VMEM(shape, dtype) for shape, dtype in acc_shapes]
-    scratch.append(pltpu.VMEM((page_size, c, w), cache.dtype))
-    scratch.append(pltpu.SemaphoreType.DMA)
+    scratch.append(pltpu.VMEM((2, bp * page_size, c, w), cache.dtype))
+    scratch.append(pltpu.SemaphoreType.DMA((2,)))
+    scratch.append(pltpu.SMEM((2,), jnp.int32))
     if with_append:
         scratch.append(pltpu.SemaphoreType.DMA)
 
@@ -295,6 +450,10 @@ def paged_decode_stream(
         grid_spec=grid_spec,
         out_shape=out_shape_structs,
         input_output_aliases=aliases,
+        # Rows hand their prefetch to the next grid step: in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(page_indices, kv_lens, slot_mapping, *inputs)
     if len(out) == 1:
@@ -359,18 +518,25 @@ def gqa_fused_decode_pallas(
     def fold(accs, qs, outs, rows, base, n):
         m_ref, l_ref, o_ref = accs
         qrow = qs[0][0]                               # [Hq, D]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        pos = base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows.shape[0]), 1
+        )
         valid = pos < n
         if sliding_window is not None:
             valid = jnp.logical_and(valid, pos >= n - sliding_window)
+        # One transposition a block puts every head's keys and values
+        # in whole tiles; slicing head by head out of [N, 2*Hkv, D]
+        # (one sublane of each token's tile) costs 1.3-3x as much as
+        # the dots it feeds (docs/kernels.md).
+        heads = jnp.swapaxes(rows, 0, 1)              # [2*Hkv, N, D]
         score_rows = []
         for h in range(num_kv_heads):
             qh = qrow[h * group:(h + 1) * group]
-            kh = rows[:, 2 * h, :]                    # [page, D]
+            kh = heads[2 * h]                         # [N, D]
             score_rows.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ))                                        # [G, page]
+            ))                                        # [G, N]
         scores = jnp.concatenate(score_rows, axis=0) * sm_scale
         if soft_cap is not None:
             scores = soft_cap * jnp.tanh(scores / soft_cap)
@@ -379,7 +545,7 @@ def gqa_fused_decode_pallas(
             out_rows = []
             for h in range(num_kv_heads):
                 ph = p[h * group:(h + 1) * group]
-                vh = rows[:, 2 * h + 1, :]            # [page, D]
+                vh = heads[2 * h + 1]                 # [N, D]
                 out_rows.append(jax.lax.dot_general(
                     ph.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -450,9 +616,9 @@ def mla_fused_decode_pallas(
 
     def fold(accs, qs, outs, rows, base, n):
         m_ref, l_ref, o_ref = accs
-        page_rows = rows[:, 0, :]                     # [page, W]
-        latent = page_rows[:, :kv_lora_rank]
-        rope = page_rows[:, kv_lora_rank:]
+        block_rows = rows[:, 0, :]                    # [N, W]
+        latent = block_rows[:, :kv_lora_rank]
+        rope = block_rows[:, kv_lora_rank:]
         ql = qs[0][0]                                 # [Hq, R]
         qp = qs[1][0]                                 # [Hq, Dr]
         scores = (
@@ -464,8 +630,10 @@ def mla_fused_decode_pallas(
                 qp, rope, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        ) * sm_scale                                  # [Hq, page]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        ) * sm_scale                                  # [Hq, N]
+        pos = base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows.shape[0]), 1
+        )
         valid = pos < n
 
         def weighted(p):
@@ -522,35 +690,37 @@ def indexer_scores_fused_pallas(
     exact ``-inf`` beyond each row's context (the top-k facades'
     dense-row detection relies on it)."""
     s, hi, d = q.shape
-    _, page_size, _, _ = index_cache.shape
+    _, page_size, c, w = index_cache.shape
     _, pages_per_seq = page_indices.shape
     kv_cap = pages_per_seq * page_size
+    # The stream folds whole blocks: the score row is padded to a block
+    # multiple so that a row's last block has room, and cut back below.
+    block = page_size * decode_pages_per_block(
+        page_size, c, w, index_cache.dtype
+    )
+    kv_pad = -(-kv_cap // block) * block
     append = k_new.astype(index_cache.dtype)[:, None, :]   # [S, 1, D]
     operands = [(q, True)]
     if reduce_kind == "dsa":
         operands.append((weights.astype(jnp.float32), True))
 
     def init(accs, qs, outs):
-        outs[0][...] = jnp.full((1, kv_cap), _NEG_INF, jnp.float32)
+        outs[0][...] = jnp.full((1, kv_pad), _NEG_INF, jnp.float32)
 
     def fold(accs, qs, outs, rows, base, n):
-        keys = rows[:, 0, :]                          # [page, D]
+        keys = rows[:, 0, :]                          # [N, D]
         dots = jax.lax.dot_general(
             qs[0][0], keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                             # [Hi, page]
+        )                                             # [Hi, N]
         if reduce_kind == "dsa":
             w = qs[1][0]                              # [Hi]
             sc = jnp.sum(w[:, None] * jnp.maximum(dots, 0.0), axis=0)
         else:
             # Max over index heads; the (positive) scale commutes.
             sc = jnp.max(dots, axis=0) * sm_scale
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size,), 0
-        )
-        outs[0][0, pl.ds(base, page_size)] = jnp.where(
-            pos < n, sc, _NEG_INF
-        )
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
+        outs[0][0, pl.ds(base, block)] = jnp.where(pos < n, sc, _NEG_INF)
 
     def finalize(accs, qs, outs, n):
         pass
@@ -558,20 +728,17 @@ def indexer_scores_fused_pallas(
     scores, index_cache = paged_decode_stream(
         index_cache, kv_lens, page_indices, slot_mapping,
         operands,
-        out_shapes=[((kv_cap,), jnp.float32)],
+        out_shapes=[((kv_pad,), jnp.float32)],
         acc_shapes=[],
         init=init, fold=fold, finalize=finalize,
         append=append, interpret=interpret,
     )
-    return scores, index_cache
+    return scores[:, :kv_cap], index_cache
 
 
 # --------------------------------------------------------------------------
 # Fused sampling: sort-free greedy / filtered top-k in one kernel.
 # --------------------------------------------------------------------------
-
-
-_LANES = 128
 
 
 def _sample_kernel(temp_ref, topk_ref, logits_ref, gumbel_ref, out_ref,

@@ -2171,13 +2171,29 @@ class StageEngine:
         /cluster/status: the active decode impl + per-(impl, path)
         dispatch counts, so a silent fallback to the split or XLA path
         is operator-visible."""
+        from parallax_tpu.ops.decode_fused_pallas import (
+            decode_pages_per_block,
+        )
         from parallax_tpu.ops.kernel_select import fused_interpret
 
         with self._kernel_lock:
             counts = dict(self._kernel_counts)
+        # What the fused decode kernel's page stream runs at: pages a
+        # block, derived from the page one device holds of the first
+        # paged cache (None with the fused kernels off).
+        pages_per_block = None
+        if self._decode_fused:
+            for leaf in jax.tree_util.tree_leaves(self.kv):
+                if getattr(leaf, "ndim", 0) == 4:
+                    shape = leaf.sharding.shard_shape(leaf.shape)
+                    pages_per_block = decode_pages_per_block(
+                        *shape[1:], leaf.dtype
+                    )
+                    break
         return {
             "impl": self._attn_impl,
             "decode_fused": self._decode_fused,
+            "decode_pages_per_block": pages_per_block,
             "prefill_impl": self._prefill_impl,
             "prefill_fused": self._prefill_fused,
             # Fused kernels running in the Pallas interpreter (a forced

@@ -14,6 +14,7 @@ from parallax_tpu.models.base import StageModel
 from parallax_tpu.models.registry import create_stage_model
 from parallax_tpu.ops.attention import _ragged_paged_attention_xla
 from parallax_tpu.ops.decode_fused_pallas import (
+    decode_pages_per_block,
     fused_sample_topk_pallas,
     gqa_fused_decode_pallas,
     indexer_scores_fused_pallas,
@@ -164,6 +165,227 @@ def test_indexer_fused_parity_and_append(kind):
         ))
     # Beyond-context slots must be EXACT -inf on both (the top-k
     # facades' dense-row detection depends on it).
+    assert np.array_equal(np.isfinite(sc), np.isfinite(ref))
+    mask = np.isfinite(ref)
+    np.testing.assert_allclose(sc[mask], ref[mask], atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The blocked stream's edges. The core moves B pages a block into one of
+# two buffers (B derived from the page's shape: 8 at every toy shape
+# here, 2 at EvaByte's real page); these rows sit on every edge of that
+# discipline. Page-table entries past a row's valid pages name a page of
+# NaNs (a dereference would poison the row through 0 * NaN) or lie
+# outside the pool altogether.
+# ---------------------------------------------------------------------------
+
+B = 8                       # decode_pages_per_block at the toy shapes
+BLOCK = B * PAGE
+#   context            what it is an edge of
+EDGE_LENS = [
+    1,                    # one token: the append is the whole context
+    PAGE,                 # exactly one page; append in the row's only page
+    BLOCK,                # exactly B pages; append in the block's last page
+    0,                    # padding row between live rows
+    BLOCK + 1,            # B pages + 1: append in the first page of the last block
+    2 * BLOCK - PAGE,     # 2B - 1 pages, page-exact
+    2 * BLOCK - PAGE - 3,  # 2B - 1 pages, ragged
+    37,                   # frozen row (slot -1) between live rows
+    2 * BLOCK,            # two full blocks; append in the last page of the last
+    2 * BLOCK + 5,        # a third block of one page
+    BLOCK - 1,            # one block, last page one short
+    3,
+]
+EDGE_FROZEN = 7
+POISON = 1                  # page of NaNs; page 0 is the null page
+
+
+def _edge_geometry(lens=EDGE_LENS, frozen=EDGE_FROZEN, page=PAGE):
+    """(num_pages, kv_lens, table for the kernel, table for the XLA
+    oracle, slots): every row its own pages from 2 on; the entries past
+    a row's valid pages alternate between the NaN page and an index far
+    outside the pool (the oracle's copy names the null page there: it
+    gathers every entry and masks)."""
+    pps = max(-(-n // page) for n in lens) + 3
+    pages = np.zeros((len(lens), pps), np.int32)
+    safe = np.zeros_like(pages)
+    used = 2
+    slot = np.full((len(lens),), -1, np.int32)
+    for i, n in enumerate(lens):
+        npg = -(-n // page)
+        pages[i, :npg] = safe[i, :npg] = np.arange(used, used + npg)
+        pages[i, npg:] = [POISON if j % 2 == 0 else 2 ** 30
+                          for j in range(pps - npg)]
+        used += npg
+        if n > 0 and i != frozen:
+            slot[i] = pages[i, (n - 1) // page] * page + (n - 1) % page
+    return (used, jnp.asarray(np.asarray(lens, np.int32)),
+            jnp.asarray(pages), jnp.asarray(safe), jnp.asarray(slot))
+
+
+def _poisoned(rng, shape, dtype=jnp.float32):
+    cache = rng.normal(size=shape).astype(np.float32)
+    cache[POISON] = np.nan
+    return jnp.asarray(cache, dtype)
+
+
+def test_edge_rows_sit_on_the_block_edges_they_name():
+    """The geometry above is only worth its name while B is what the
+    core derives at these shapes."""
+    for c, w in ((4, 16), (8, 16), (64, 16), (1, 40)):
+        assert decode_pages_per_block(PAGE, c, w, jnp.float32) == B
+
+
+@pytest.mark.parametrize(
+    "window,sinks_on,cap",
+    [(None, False, None),
+     (44, False, None),            # window starts mid-block, mid-page
+     (None, True, 30.0),
+     (BLOCK + 3, True, 30.0)],     # window longer than one block
+    ids=["plain", "window-mid-block", "sinks-cap", "window-sinks-cap"],
+)
+@pytest.mark.parametrize(
+    "hq,hkv", [(16, 2), (28, 4), (32, 32)], ids=["16q2kv", "28q4kv", "32q32kv"]
+)
+def test_gqa_fused_block_edges(hq, hkv, window, sinks_on, cap):
+    rng = np.random.default_rng(7)
+    d = 16
+    num_pages, lens, pages, safe, slot = _edge_geometry()
+    s = len(EDGE_LENS)
+    q = jnp.asarray(rng.normal(size=(s, hq, d)), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(s, hkv, d)), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=(s, hkv, d)), jnp.float32)
+    cache = _poisoned(rng, (num_pages, PAGE, 2 * hkv, d))
+    sinks = (
+        jnp.asarray(rng.normal(size=(hq,)), jnp.float32)
+        if sinks_on else None
+    )
+    out, cache_f = gqa_fused_decode_pallas(
+        q, k_new, v_new, cache, lens, pages, slot, sinks,
+        sm_scale=d ** -0.5, sliding_window=window, soft_cap=cap,
+        use_sinks=sinks_on, interpret=True,
+    )
+    cache_ref = reshape_and_cache(cache, k_new, v_new, slot)
+    # The oracle gathers every table entry: hand it the pool without
+    # the NaN page's payload, which no valid entry names.
+    ref = _ragged_paged_attention_xla(
+        q, cache_ref.at[POISON].set(0.0), lens, safe,
+        jnp.arange(s + 1, dtype=jnp.int32), jnp.asarray([s], jnp.int32),
+        sm_scale=d ** -0.5, sliding_window=window, soft_cap=cap,
+        sinks=sinks,
+    )
+    assert np.array_equal(
+        np.asarray(cache_f), np.asarray(cache_ref), equal_nan=True
+    )
+    out = np.asarray(out)
+    assert np.all(np.isfinite(out)), "a page past the valid ones was read"
+    np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5, rtol=2e-5)
+    assert np.all(out[EDGE_LENS.index(0)] == 0.0)
+
+
+def test_gqa_fused_block_edges_at_two_pages_a_block():
+    """EvaByte's real page ([64, 64, 128] bf16 = 1 MB) derives B = 2:
+    contexts of one page, B pages, B pages + 1, 2B pages - 1 and a
+    third block, at 32 KV heads with one query head each."""
+    rng = np.random.default_rng(8)
+    h, d, page = 32, 128, 64
+    assert decode_pages_per_block(page, 2 * h, d, jnp.bfloat16) == 2
+    lens = [page, 2 * page, 2 * page + 1, 0, 3 * page - 5, 4 * page + 9, 1]
+    num_pages, kv_lens, pages, safe, slot = _edge_geometry(
+        lens, frozen=4, page=page
+    )
+    s = len(lens)
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+
+    q, k_new, v_new = arr(s, h, d), arr(s, h, d), arr(s, h, d)
+    cache = _poisoned(rng, (num_pages, page, 2 * h, d), jnp.bfloat16)
+    out, cache_f = gqa_fused_decode_pallas(
+        q, k_new, v_new, cache, kv_lens, pages, slot, None,
+        sm_scale=d ** -0.5, interpret=True,
+    )
+    cache_ref = reshape_and_cache(cache, k_new, v_new, slot)
+    ref = _ragged_paged_attention_xla(
+        q, cache_ref.at[POISON].set(0.0), kv_lens, safe,
+        jnp.arange(s + 1, dtype=jnp.int32), jnp.asarray([s], jnp.int32),
+        sm_scale=d ** -0.5, sliding_window=None, soft_cap=None, sinks=None,
+    )
+    assert np.array_equal(
+        np.asarray(cache_f, np.float32), np.asarray(cache_ref, np.float32),
+        equal_nan=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=2e-2, rtol=2e-2,
+    )
+
+
+def test_mla_fused_block_edges():
+    rng = np.random.default_rng(9)
+    hq, r, dr = 4, 32, 8
+    num_pages, lens, pages, safe, slot = _edge_geometry()
+    s = len(EDGE_LENS)
+    ql = jnp.asarray(rng.normal(size=(s, hq, r)), jnp.float32)
+    qp = jnp.asarray(rng.normal(size=(s, hq, dr)), jnp.float32)
+    lat = jnp.asarray(rng.normal(size=(s, r)), jnp.float32)
+    kpe = jnp.asarray(rng.normal(size=(s, dr)), jnp.float32)
+    cache = _poisoned(rng, (num_pages, PAGE, 1, r + dr))
+    out, cache_f = mla_fused_decode_pallas(
+        ql, qp, lat, kpe, cache, lens, pages, slot,
+        sm_scale=0.17, kv_lora_rank=r, interpret=True,
+    )
+    cache_ref = store_mla_cache(cache, lat, kpe, slot)
+    ref = mla_ragged_attention_xla(
+        ql, qp, cache_ref.at[POISON].set(0.0), lens, safe,
+        jnp.arange(s + 1, dtype=jnp.int32), jnp.asarray([s], jnp.int32),
+        sm_scale=0.17, kv_lora_rank=r,
+    )
+    assert np.array_equal(
+        np.asarray(cache_f), np.asarray(cache_ref), equal_nan=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+    )
+
+
+@pytest.mark.parametrize("kind", ["dsa", "msa"])
+def test_indexer_fused_block_edges(kind):
+    """The score row is as long as the page table (19 pages here: not a
+    multiple of B) though the stream writes whole blocks."""
+    rng = np.random.default_rng(10)
+    hi, di = 4, 16
+    num_pages, lens, pages, safe, slot = _edge_geometry()
+    s = len(EDGE_LENS)
+    assert pages.shape[1] % B
+    q = jnp.asarray(rng.normal(size=(s, hi, di)), jnp.float32)
+    w = jnp.asarray(np.abs(rng.normal(size=(s, hi))), jnp.float32)
+    k_new = jnp.asarray(rng.normal(size=(s, di)), jnp.float32)
+    cache = _poisoned(rng, (num_pages, PAGE, 1, di))
+    sc, cache_f = indexer_scores_fused_pallas(
+        q, w if kind == "dsa" else None, k_new, cache, lens, pages, slot,
+        reduce_kind=kind, sm_scale=0.25, interpret=True,
+    )
+    cache_ref = store_index_cache(cache, k_new, slot)
+    assert np.array_equal(
+        np.asarray(cache_f), np.asarray(cache_ref), equal_nan=True
+    )
+    clean = cache_ref.at[POISON].set(0.0)
+    if kind == "dsa":
+        ref = np.asarray(dsa_indexer_scores_xla(
+            q, w, clean, lens, safe, jnp.arange(s + 1, dtype=jnp.int32),
+        ))
+    else:
+        from parallax_tpu.ops.msa_pallas import (
+            msa_token_scores_decode_pallas,
+        )
+
+        ref = np.asarray(msa_token_scores_decode_pallas(
+            q, clean, lens, safe, sm_scale=0.25, interpret=True,
+        ))
+    sc = np.asarray(sc)
+    assert sc.shape == ref.shape == (s, pages.shape[1] * PAGE)
+    assert not np.any(np.isnan(sc)), "a page past the valid ones was read"
     assert np.array_equal(np.isfinite(sc), np.isfinite(ref))
     mask = np.isfinite(ref)
     np.testing.assert_allclose(sc[mask], ref[mask], atol=2e-5, rtol=2e-5)
@@ -375,6 +597,13 @@ def test_kernel_dispatch_summary_and_counter(gqa_model):
     summary = eng.kernel_dispatch_summary()
     assert summary["impl"] == "pallas-fused"
     assert summary["decode_fused"] is True
+    # What the page stream ran at, derived from this stage's page
+    # ([8, 2 * 2, 16] float32): nothing set it.
+    assert summary["decode_pages_per_block"] == decode_pages_per_block(
+        8, 4, 16, jnp.float32
+    ) == 8
+    off = _run_engine(model, params, fused=False, lookahead=8)[1]
+    assert off.kernel_dispatch_summary()["decode_pages_per_block"] is None
     assert any(k.startswith("pallas-fused/") for k in
                summary["dispatch_total"])
     # The registry counter carries the same series for /metrics.

@@ -31,6 +31,7 @@ from parallax_tpu.ops import kernel_select
 from parallax_tpu.ops.attention import _rpa_block_sizes
 from parallax_tpu.ops.attention_pallas import gqa_decode_attention_pallas
 from parallax_tpu.ops.decode_fused_pallas import (
+    decode_pages_per_block,
     fused_sample_topk_pallas,
     gqa_fused_decode_pallas,
 )
@@ -39,6 +40,11 @@ from parallax_tpu.ops.prefill_fused_pallas import gqa_fused_prefill_pallas
 HEAD_DIM, PAGE, PAGES_PER_SEQ, NUM_PAGES, VOCAB = 128, 64, 129, 1024, 152064
 HEADS = [(28, 4), (7, 1)]          # unsharded; one TP=4 shard
 HEAD_IDS = ["28q4kv", "tp4-7q1kv"]
+# The decode kernel also at Qwen2.5-3B's 16/2 heads (the second cell).
+DECODE_HEADS = HEADS + [(16, 2)]
+DECODE_HEAD_IDS = HEAD_IDS + ["16q2kv"]
+# A page table long enough for 16k tokens (``--max-model-len 16384``).
+PAGES_16K = 16384 // PAGE
 
 
 @pytest.fixture(scope="module")
@@ -72,7 +78,7 @@ def _compile(fn, *shapes):
     return text
 
 
-def _batch(dev, hq, hkv, t, s):
+def _batch(dev, hq, hkv, t, s, pages_per_seq=PAGES_PER_SEQ):
     def a(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
@@ -82,17 +88,22 @@ def _batch(dev, hq, hkv, t, s):
         v=a((t, hkv, HEAD_DIM), jnp.bfloat16),
         cache=a((NUM_PAGES, PAGE, 2 * hkv, HEAD_DIM), jnp.bfloat16),
         kv_lens=a((s,), jnp.int32),
-        pages=a((s, PAGES_PER_SEQ), jnp.int32),
+        pages=a((s, pages_per_seq), jnp.int32),
         cu=a((s + 1,), jnp.int32),
         nseq=a((1,), jnp.int32),
         slots=a((t,), jnp.int32),
     )
 
 
+@pytest.mark.parametrize("pages_per_seq", [PAGES_PER_SEQ, PAGES_16K],
+                         ids=["8k", "16k"])
 @pytest.mark.parametrize("s", [8, 64])
-@pytest.mark.parametrize("hq,hkv", HEADS, ids=HEAD_IDS)
-def test_fused_decode_compiles_for_v5e(v5e, hq, hkv, s):
-    b = _batch(v5e, hq, hkv, s, s)
+@pytest.mark.parametrize("hq,hkv", DECODE_HEADS, ids=DECODE_HEAD_IDS)
+def test_fused_decode_compiles_for_v5e(v5e, hq, hkv, s, pages_per_seq):
+    """Both block buffers of the page stream (``decode_pages_per_block``
+    pages each), the accumulators and the fold's temporaries inside
+    scoped VMEM, at the shapes the benchmark's Qwen cells run."""
+    b = _batch(v5e, hq, hkv, s, s, pages_per_seq)
     _compile(
         lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
             q, k, v, cache, lens, pages, slots, None,
@@ -167,8 +178,9 @@ def _evabyte_batch(dev, t, s):
     )
 
 
-def test_fused_decode_compiles_for_v5e_at_32_kv_heads(v5e):
-    b = _evabyte_batch(v5e, 8, 8)
+@pytest.mark.parametrize("s", [8, 64])
+def test_fused_decode_compiles_for_v5e_at_32_kv_heads(v5e, s):
+    b = _evabyte_batch(v5e, s, s)
     _compile(
         lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
             q, k, v, cache, lens, pages, slots, None,
@@ -177,6 +189,19 @@ def test_fused_decode_compiles_for_v5e_at_32_kv_heads(v5e):
         b["q"], b["q"], b["q"], b["cache"], b["kv_lens"], b["pages"],
         b["slots"],
     )
+
+
+@pytest.mark.parametrize(
+    "hkv,want", [(2, 8), (4, 8), (32, 2)], ids=["3b", "7b", "evabyte"]
+)
+def test_decode_pages_per_block_at_the_cells_page_shapes(hkv, want):
+    """The page stream's block, derived from the page as VMEM holds it
+    (2 * Hkv rows pad to bf16's 16-sublane tile): 8 pages at the 3B's
+    64 KB and the 7B's 128 KB pages, 2 at EvaByte's 1 MB — what the
+    compiles above and at 32 KV heads prove inside scoped VMEM."""
+    assert decode_pages_per_block(
+        PAGE, 2 * hkv, HEAD_DIM, jnp.bfloat16
+    ) == want
 
 
 @pytest.mark.parametrize("t", [256, 2048])
