@@ -6,7 +6,7 @@ ARG TPU=0
 WORKDIR /app
 COPY pyproject.toml README.md ./
 COPY parallax_tpu ./parallax_tpu
-COPY bench.py __graft_entry__.py ./
+COPY __graft_entry__.py ./
 
 RUN pip install --no-cache-dir -e . && \
     if [ "$TPU" = "1" ]; then \

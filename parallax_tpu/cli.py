@@ -6,7 +6,6 @@ Capability parity target: reference ``src/parallax/cli.py:26-473``
 - ``serve``  — single-host OpenAI-compatible server (model + layer range)
 - ``run``    — launch the global scheduler + HTTP frontend
 - ``join``   — join a swarm as a worker node
-- ``bench``  — run the offline throughput benchmark
 """
 
 from __future__ import annotations
@@ -448,9 +447,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="LoRA hot-load LRU cap (0 = unbounded)",
     )
 
-    bench = sub.add_parser("bench", help="offline throughput benchmark")
-    bench.add_argument("--config", default="qwen2-7b")
-
     gen = sub.add_parser(
         "generate",
         help="offline one-shot generation, no server (reference "
@@ -551,11 +547,6 @@ def main(argv: list[str] | None = None) -> int:
         from parallax_tpu.p2p.join import join_main
 
         return join_main(args)
-    if args.command == "bench":
-        import bench
-
-        bench.main()
-        return 0
     if args.command == "chat":
         return chat_main(args)
     if args.command == "chat-host":

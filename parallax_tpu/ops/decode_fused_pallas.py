@@ -61,8 +61,8 @@ Two grid disciplines live here:
 Everything supports ``interpret=True`` (Pallas interpreter), which is
 how the CPU CI proves parity against the XLA reference paths
 (``ops/attention.py::_ragged_paged_attention_xla``,
-``ops/sampling.py``) and how ``bench.py``'s ``detail.kernel``
-microbench compares fused vs split vs XLA off-TPU.
+``ops/sampling.py``); ``chip_smoke.py`` repeats the comparison with
+the compiled kernels on the chip.
 
 Cache-write safety: the cache rides through the kernel as an
 input/output-aliased ``ANY``-memory-space ref; all page reads go

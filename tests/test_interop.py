@@ -261,14 +261,26 @@ def test_pipeline_through_protobuf_wire_matches_native():
     assert got.output_ids == want.output_ids
 
 
-def test_worker_node_accepts_protobuf_payloads():
-    """WorkerNode's rpc handlers take raw protobuf bytes directly."""
+def _handler_only_node():
+    """A WorkerNode with only what ``_on_forward`` / ``_on_abort`` touch:
+    the inbox and its wake event, and the receive counters with their
+    lock (no engine, no transport, no threads)."""
+    import queue
+    import threading
+
     from parallax_tpu.p2p.node import WorkerNode
 
-    node = WorkerNode.__new__(WorkerNode)   # handler-only instance
-    import queue
-
+    node = WorkerNode.__new__(WorkerNode)
     node._inbox = queue.Queue()
+    node._wake = threading.Event()
+    node._rx_stats = {}
+    node._rx_lock = threading.Lock()
+    return node
+
+
+def test_worker_node_accepts_protobuf_payloads():
+    """WorkerNode's rpc handlers take raw protobuf bytes directly."""
+    node = _handler_only_node()
     from safetensors.torch import save
 
     msg = pb.ForwardRequest()
@@ -372,14 +384,10 @@ def test_protobuf_payload_over_real_tcp_transport():
     sends raw protobuf bytes as the rpc_pp_forward payload; the worker's
     handler decodes and enqueues it. Malformed bytes error the RPC
     loudly without killing the worker's loop."""
-    import queue
-
-    from parallax_tpu.p2p.node import WorkerNode
     from parallax_tpu.p2p.transport import TcpTransport
     from safetensors.torch import save
 
-    node = WorkerNode.__new__(WorkerNode)
-    node._inbox = queue.Queue()
+    node = _handler_only_node()
 
     server = TcpTransport("worker", "127.0.0.1")
     server.register("rpc_pp_forward", node._on_forward)

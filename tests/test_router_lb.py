@@ -1,30 +1,14 @@
-"""Benchmark harness + router LB tests against a live local serving app.
-
-Capability parity: reference benchmark_serving metrics math + router
-endpoint registry/strategy tests.
-"""
+"""Router LB tests: endpoint registry, strategies and session affinity,
+and the proxy against a live local serving app."""
 
 import asyncio
-import json
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from parallax_tpu.backend.http_server import SimpleTokenizer
 from parallax_tpu.backend.serve import build_local_frontend
-from parallax_tpu.benchmark.serving import (
-    RequestResult,
-    arrival_times,
-    compute_metrics,
-    run_benchmark,
-    sample_hf_requests,
-    sample_random_requests,
-    sample_sharegpt_requests,
-    sample_wildchat_requests,
-)
 from parallax_tpu.config import normalize_config
 from parallax_tpu.models.base import StageModel
 from parallax_tpu.router.lb import Endpoint, Performance, Router, RoundRobin
@@ -45,174 +29,6 @@ def tiny_frontend():
                      kv_dtype="float32"),
     )
     return build_local_frontend([eng], SimpleTokenizer(), model_name="tiny")
-
-
-class TestMetricsMath:
-    def test_stats_and_throughput(self):
-        results = [
-            RequestResult(ok=True, prompt_len=10, output_len=5,
-                          ttft_s=0.1, latency_s=0.5, itls=[0.1] * 4),
-            RequestResult(ok=True, prompt_len=20, output_len=5,
-                          ttft_s=0.2, latency_s=0.6, itls=[0.1] * 4),
-            RequestResult(ok=False, error="boom"),
-        ]
-        m = compute_metrics(results, duration_s=2.0)
-        assert m["completed"] == 2 and m["failed"] == 1
-        assert m["output_token_throughput"] == 5.0
-        assert m["total_token_throughput"] == 20.0
-        np.testing.assert_allclose(m["ttft_s"]["mean"], 0.15)
-        np.testing.assert_allclose(m["tpot_s"]["mean"], 0.1)
-
-    def test_goodput_slo(self):
-        results = [
-            RequestResult(ok=True, output_len=5, ttft_s=0.1, latency_s=0.5),
-            RequestResult(ok=True, output_len=5, ttft_s=9.0, latency_s=9.4),
-        ]
-        m = compute_metrics(results, 1.0, goodput_slo={"ttft_s": 1.0})
-        assert m["goodput_requests_per_s"] == 1.0
-
-    def test_poisson_arrivals_monotonic(self):
-        times = arrival_times(100, request_rate=10.0, seed=1)
-        assert all(b >= a for a, b in zip(times, times[1:]))
-        # ~10 rps over 100 requests => ~10s span, loose bounds
-        assert 3.0 < times[-1] < 30.0
-
-    def test_inf_rate_all_at_zero(self):
-        assert arrival_times(5, float("inf")) == [0.0] * 5
-
-
-class TestDatasetLoaders:
-    """ShareGPT / WildChat / HF samplers (reference
-    benchmark_serving.py:147-287 semantics)."""
-
-    @staticmethod
-    def _sharegpt_records():
-        long_prompt = " ".join(["word"] * 40)
-        reply = " ".join(["out"] * 12)
-        return [
-            # usable: 40-word prompt, 12-word reply
-            {"conversations": [{"value": long_prompt}, {"value": reply}]},
-            # pruned: prompt too short (<4 tokens)
-            {"conversations": [{"value": "hi"}, {"value": reply}]},
-            # pruned: reply too short when output length is data-derived
-            {"conversations": [{"value": long_prompt}, {"value": "ok"}]},
-            # pruned: single turn
-            {"conversations": [{"value": long_prompt}]},
-            # pruned: prompt over 1024 tokens
-            {"conversations": [{"value": " ".join(["w"] * 1100)},
-                               {"value": reply}]},
-        ]
-
-    def test_sharegpt_filters_and_lengths(self, tmp_path):
-        path = tmp_path / "sharegpt.json"
-        path.write_text(json.dumps(self._sharegpt_records()))
-        specs = sample_sharegpt_requests(str(path), num=10)
-        assert len(specs) == 1
-        assert specs[0].prompt_len == 40
-        assert specs[0].max_tokens == 12   # derived from the reply
-
-    def test_sharegpt_fixed_output_len_keeps_short_replies(self, tmp_path):
-        path = tmp_path / "sharegpt.json"
-        path.write_text(json.dumps(self._sharegpt_records()))
-        specs = sample_sharegpt_requests(str(path), num=10,
-                                         fixed_output_len=7)
-        # fixed output budget: the short-reply record survives too
-        assert len(specs) == 2
-        assert all(s.max_tokens == 7 for s in specs)
-
-    def test_sharegpt_respects_num_cap(self, tmp_path):
-        long_prompt = " ".join(["word"] * 20)
-        recs = [
-            {"conversations": [{"value": f"{i} {long_prompt}"},
-                               {"value": long_prompt}]}
-            for i in range(30)
-        ]
-        path = tmp_path / "sharegpt.json"
-        path.write_text(json.dumps(recs))
-        assert len(sample_sharegpt_requests(str(path), num=5)) == 5
-
-    def test_wildchat_from_local_fixture(self, monkeypatch):
-        import datasets as hf_datasets
-
-        import parallax_tpu.benchmark.serving as serving
-
-        rows = [
-            {"conversation": [
-                {"role": "user", "content": " ".join(["q"] * 16)},
-                {"role": "assistant", "content": " ".join(["a"] * 9)},
-            ]},
-            {"conversation": [
-                {"role": "user", "content": "too short"},
-            ]},
-        ]
-        fixture = hf_datasets.Dataset.from_list(rows)
-        monkeypatch.setattr(
-            serving, "_load_hf_dataset",
-            lambda path, subset, split, streaming=False: fixture,
-        )
-        specs = sample_wildchat_requests("any", num=5)
-        assert len(specs) == 1
-        assert specs[0].prompt_len == 16 and specs[0].max_tokens == 9
-
-    def test_hf_requires_conversations_column(self, monkeypatch):
-        import datasets as hf_datasets
-
-        import parallax_tpu.benchmark.serving as serving
-
-        fixture = hf_datasets.Dataset.from_list([{"text": "nope"}])
-        monkeypatch.setattr(
-            serving, "_load_hf_dataset",
-            lambda *a, **k: fixture,
-        )
-        with pytest.raises(ValueError, match="conversations"):
-            sample_hf_requests("any", None, "train", num=5)
-
-    def test_hf_sharegpt_shaped_rows(self, monkeypatch):
-        import datasets as hf_datasets
-
-        import parallax_tpu.benchmark.serving as serving
-
-        rows = [
-            {"conversations": [{"value": " ".join(["q"] * 10)},
-                               {"value": " ".join(["a"] * 6)}]},
-            {"conversations": [{"value": "solo"}]},
-        ]
-        fixture = hf_datasets.Dataset.from_list(rows)
-        monkeypatch.setattr(
-            serving, "_load_hf_dataset",
-            lambda *a, **k: fixture,
-        )
-        specs = sample_hf_requests("any", None, "train", num=5)
-        assert len(specs) == 1
-        assert specs[0].prompt_len == 10 and specs[0].max_tokens == 6
-
-
-def test_benchmark_against_live_server():
-    fe, runner = tiny_frontend()
-
-    async def go():
-        server = TestServer(fe.app)
-        client = TestClient(server)
-        await client.start_server()
-        try:
-            base = f"http://{client.host}:{client.port}"
-            specs = sample_random_requests(6, input_len=8, output_len=5)
-            return await run_benchmark(
-                base, specs, request_rate=float("inf"), max_concurrency=3
-            )
-        finally:
-            await client.close()
-
-    loop = asyncio.new_event_loop()
-    try:
-        metrics = loop.run_until_complete(go())
-    finally:
-        loop.close()
-        runner.stop()
-    assert metrics["failed"] == 0, metrics["errors"]
-    assert metrics["completed"] == 6
-    assert metrics["output_token_throughput"] > 0
-    assert metrics["ttft_s"]["mean"] > 0
 
 
 class TestRouterStrategies:

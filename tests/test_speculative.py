@@ -406,9 +406,9 @@ def test_sync_fallback_random_prompts_exact():
 def test_speculative_windows_compress_host_rounds():
     """With the adaptive default, a speculating engine commits many
     tokens per host round (spec windows where proposals hit, plain
-    windows otherwise) — far fewer rounds than tokens. The wall-clock
-    speedup claim on a genuinely repetitive stream is pinned by the
-    bench ``detail.spec`` probe."""
+    windows otherwise) — far fewer rounds than tokens. Whether that is
+    faster on the chip is not measured: no benchmark cell speculates
+    (ROADMAP D16)."""
     eng = _engine(6)                       # adaptive K
     _adversarialize(eng, [1, 2, 3])
     pipe = InProcessPipeline([eng])
