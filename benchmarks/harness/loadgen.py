@@ -12,9 +12,12 @@ Timing rule: a request is timed from when it was *due*, not from when
 it was sent, so a stall that delays later requests counts against them;
 ``sent - due`` is reported as the generator's lag.
 
-Copied in spirit from ``parallax_tpu/benchmark/serving.py``
-(``arrival_times``: Poisson/gamma gaps), whose clock started at the send
-and whose token counts came from whitespace.
+A probe's premise (``"ramp_whole": true`` in the traffic file): every
+client's row has had its first token when the window opens. A run in
+which one has not - the program's admission race split the ramp
+(``warmup.py``), or a program was still compiling - is not the cell's
+traffic: ``Run.go`` raises ``RampSplit`` at the window's opening and the
+caller starts again with a new child (``run.py``).
 """
 
 from __future__ import annotations
@@ -33,6 +36,16 @@ import numpy as np
 REHEARSE = {"prompt_max": 40, "output_max": 10, "clients_max": 4,
             "prefix_max": 32, "warm_seconds_max": 1.0, "rate_max": 6.0,
             "blocker_max": 48}
+
+
+class RampSplit(Exception):
+    """At the opening of the window a client's row had no first token:
+    ``detail`` says how many were in flight, how many were streaming,
+    and how long after its send each row's first token came."""
+
+    def __init__(self, detail: dict):
+        super().__init__(json.dumps(detail))
+        self.detail = detail
 
 
 @dataclasses.dataclass
@@ -128,6 +141,8 @@ def _scaled(traffic: dict, rehearse: bool) -> dict:
                             REHEARSE["warm_seconds_max"])
     t["ramp_blocker_tokens"] = min(t.get("ramp_blocker_tokens", 1024),
                                    REHEARSE["blocker_max"])
+    # Rows of ten tokens end and are replaced all through a rehearsal.
+    t["ramp_whole"] = False
     sh = t.get("sharing") or {}
     if sh.get("prefix_tokens"):
         sh["prefix_tokens"] = min(sh["prefix_tokens"], REHEARSE["prefix_max"])
@@ -162,6 +177,7 @@ class Traffic:
         self.prefixes = [self._tokens(self.prefix_tokens) for _ in range(pool)]
         self.sessions = t.get("sessions")
         self.closed = t["loop"] == "closed"
+        self.ramp_whole = self.closed and bool(t.get("ramp_whole", False))
         if self.closed:
             self.clients = int(t["clients"])
             self._closed_sizes()
@@ -346,15 +362,21 @@ async def send_one(http, base: str, body: dict, res: Result,
 class Run:
     """One run's clock, results and hooks. ``origin`` is the monotonic
     time of schedule second 0; the window is ``[w0, w1)``; the traffic
-    goes on until ``end`` (``w1`` plus the traffic's tail)."""
+    goes on until ``end`` (``w1`` plus the traffic's tail).
+    ``hold_back_s`` (tests only) keeps one client of a closed loop from
+    sending for that long and holds the run to a whole ramp."""
 
-    def __init__(self, traffic: Traffic, base: str, hooks: dict | None = None):
+    def __init__(self, traffic: Traffic, base: str, hooks: dict | None = None,
+                 hold_back_s: float = 0.0):
         self.traffic = traffic
         self.base = base
         self.results: list[Result] = []
         self.hooks = hooks or {}
         self.origin = self.w0 = self.w1 = self.end = 0.0
         self.in_flight_at = {}
+        self.hold_back_s = float(hold_back_s)
+        self.ramp_whole = traffic.ramp_whole or self.hold_back_s > 0
+        self.ramp: dict | None = None
 
     async def _at(self, t: float, coro_fn):
         await asyncio.sleep(max(0.0, t - time.monotonic()))
@@ -366,6 +388,22 @@ class Run:
 
     def note_in_flight(self, name: str) -> None:
         self.in_flight_at[name] = self._in_flight()
+
+    def check_ramp(self) -> None:
+        """A probe's premise, at the opening of the window: every
+        client's row is in flight and has had its first token (and
+        nothing else is in flight: the blocker has long ended)."""
+        flying = [r for r in self.results
+                  if r.sent_t and r.usage is None and r.error is None]
+        streaming = [r for r in flying if r.first_t is not None]
+        self.ramp = {
+            "clients": self.traffic.clients, "in_flight": len(flying),
+            "streaming": len(streaming),
+            "first_token_after_send_s": sorted(
+                round(r.first_t - r.sent_t, 4) for r in streaming),
+            "errors": [r.error for r in self.results if r.error][:3]}
+        if not (len(flying) == len(streaming) == self.traffic.clients):
+            raise RampSplit(self.ramp)
 
     def end_tail(self) -> None:
         """The tail has served its purpose: send nothing more."""
@@ -399,8 +437,8 @@ class Run:
         self.results.append(res)
         await send_one(http, self.base, tr.body(req), res)
 
-    async def _client(self, http) -> None:
-        await asyncio.sleep(0.02)      # behind the blocker
+    async def _client(self, http, hold_back_s: float = 0.0) -> None:
+        await asyncio.sleep(0.02 + hold_back_s)      # behind the blocker
         while True:
             now = time.monotonic()
             if now >= self.end:
@@ -428,21 +466,25 @@ class Run:
             marks = [asyncio.ensure_future(self._at(t, self._mark(name)))
                      for name, t in (("w0", self.w0), ("w1", self.w1))]
             if tr.closed:
-                work = [asyncio.ensure_future(self._client(http))
-                        for _ in range(tr.clients)]
+                work = [asyncio.ensure_future(self._client(
+                    http, self.hold_back_s if i == 0 else 0.0))
+                    for i in range(tr.clients)]
                 work.append(asyncio.ensure_future(self._blocker(http)))
             else:
                 work = [asyncio.ensure_future(self._session(http, r))
                         for r in tr.schedule]
+            # Until all is sent and answered, or a hook, a mark or a
+            # client raises: that fails the run there and then.
             done, pending = await asyncio.wait(
                 work + side + marks,
                 timeout=tr.warm + tr.seconds + tr.tail + drain_s,
+                return_when=asyncio.FIRST_EXCEPTION,
             )
             for task in pending:
                 task.cancel()
             await asyncio.gather(*pending, return_exceptions=True)
             for task in done:
-                task.result()    # a hook or client that raised fails the run
+                task.result()
             for r in self.results:
                 if r.usage is None and r.error is None:
                     r.error = "not finished when the drain ended"
@@ -450,6 +492,8 @@ class Run:
     def _mark(self, name: str):
         async def mark():
             self.note_in_flight(name)
+            if name == "w0" and self.ramp_whole:
+                self.check_ramp()
             fn = self.hooks.get(name)
             if fn is not None:
                 await fn()

@@ -1,8 +1,7 @@
 """Arithmetic from timestamps, scrapes and the reduced trace to metrics.
 
-TTFT/TPOT arithmetic follows ``parallax_tpu/benchmark/serving.py``
-(``compute_metrics``) with two changes: the clock starts when a request
-was *due*, and tokens are the tokens the stream delivered.
+TTFT and TPOT: the clock starts when a request was *due*, not when it
+was sent, and tokens are the tokens the stream delivered.
 """
 
 from __future__ import annotations

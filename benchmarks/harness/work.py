@@ -22,14 +22,105 @@ def kv_bytes_per_token(cfg: dict, dtype: str = "bfloat16") -> int:
             * cfg["num_hidden_layers"])
 
 
+def _layer_elements(cfg: dict) -> int:
+    """One layer: q/k/v and output projections, q/k/v biases where the
+    configuration has them (``attention_bias``), the gated MLP's three
+    matrices, two norms."""
+    h, inter, d = cfg["hidden_size"], cfg["intermediate_size"], head_dim(cfg)
+    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
+    bias = q + 2 * kv if cfg.get("attention_bias") else 0
+    return h * (q + 2 * kv) + bias + q * h + 3 * h * inter + 2 * h
+
+
 def weight_bytes(cfg: dict, dtype: str = "bfloat16") -> int:
     """Bytes of the stage's weights (all layers, embedding, head)."""
-    h, inter, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    d = head_dim(cfg)
-    q, kv = cfg["num_attention_heads"] * d, cfg["num_key_value_heads"] * d
-    layer = h * (q + 2 * kv) + (q + 2 * kv) + q * h + 3 * h * inter + 2 * h
+    h, v = cfg["hidden_size"], cfg["vocab_size"]
     embed = v * h * (1 if cfg.get("tie_word_embeddings") else 2)
-    return (cfg["num_hidden_layers"] * layer + embed + h) * BYTES[dtype]
+    return (cfg["num_hidden_layers"] * _layer_elements(cfg) + embed
+            + h) * BYTES[dtype]
+
+
+def decode_step_weight_elements(cfg: dict) -> int:
+    """Elements of the weights one decode step must read: every layer's
+    matrices, biases and norms, the final norm and ONE head matrix
+    ``vocab x hidden`` (head 0's rows where the head predicts several
+    tokens). Tied or not, the embedding table is not read at decode (a
+    step looks up a row a token), which is what this leaves out of
+    ``weight_bytes``' count of storage."""
+    h, v = cfg["hidden_size"], cfg["vocab_size"]
+    return cfg["num_hidden_layers"] * _layer_elements(cfg) + h + v * h
+
+
+def decode_step_weight_bytes(cfg: dict, dtype: str = "bfloat16") -> int:
+    """Their bytes: under ``weight_bytes`` by one embedding table where
+    the head is untied, equal to it where the head is the embedding."""
+    return decode_step_weight_elements(cfg) * BYTES[dtype]
+
+
+def decode_step_work(cfg: dict, steps: float, tokens: int, attn: dict,
+                     dtype: str = "bfloat16") -> dict:
+    """``steps`` decode steps that together produce ``tokens`` tokens
+    (``tokens / steps`` rows a step): the weights are read once a step
+    and multiplied into every row (2 operations an element and row),
+    and the steps' attention (``attn``, ``decode_read_work``) is added."""
+    elements = decode_step_weight_elements(cfg)
+    return add(attn, {"flops": 2 * elements * tokens,
+                      "bytes": steps * elements * BYTES[dtype]})
+
+
+def entry_bytes(cfg: dict, dtype: str = "bfloat16") -> int:
+    """Bytes of one cache entry in one layer: K and V of every KV head.
+    An entry is what a decode step attends: a cached position or, for
+    EVA, the summary of one chunk of a completed window."""
+    return 2 * cfg["num_key_value_heads"] * head_dim(cfg) * BYTES[dtype]
+
+
+def decode_read_work(cfg: dict, entries: int, steps: int = 0,
+                     dtype: str = "bfloat16") -> dict:
+    """Decode steps that together attend ``entries`` entries, all layers:
+    QK^T and PV are 2 * Hq * D multiply-adds per entry each, and every
+    entry is read once. ``steps`` (row-steps, where known) adds each
+    step's query and output row and the new token's K/V write. With
+    ``entries`` the sum of the steps' contexts this is term for term the
+    sum of ``attn_decode_work`` over them."""
+    hq, d = cfg["num_attention_heads"], head_dim(cfg)
+    layers = cfg["num_hidden_layers"]
+    per_step = 2 * hq * d * BYTES[dtype] + entry_bytes(cfg, dtype)
+    return {"flops": 4 * hq * d * entries * layers,
+            "bytes": (entries * entry_bytes(cfg, dtype)
+                      + steps * per_step) * layers}
+
+
+ENTRIES_SERIES = "parallax_eva_entries_attended"
+
+
+def span_decode_attention(cfg: dict, sw: dict, scrape_t0, scrape_t1,
+                          dtype: str = "bfloat16") -> dict | None:
+    """What the traced span's decode steps made the attention kernel
+    compute (``decode_read_work`` of the entries they attended), or None
+    where nothing honest can be read.
+
+    The entries are the program's own count (``ENTRIES_SERIES`` between
+    the span's two scrapes: per step and row the entries attended, once,
+    not per layer) where it exports one and the count fits what the
+    clients saw: a decode token at context ``c`` attends between
+    ``c / chunk_size`` entries (all summaries) and ``c`` (no summary).
+    Otherwise the clients' ``decode_context_sum``: a grouped-query row
+    attends every cached position. A configuration with a ``chunk_size``
+    does not, so without a count inside the bracket it reads nothing."""
+    seen = entries = sw["decode_context_sum"]
+    if seen <= 0:
+        return None
+    counted = None
+    if scrape_t0 is not None and ENTRIES_SERIES in (scrape_t1 or {}):
+        counted = (scrape_t1[ENTRIES_SERIES]
+                   - scrape_t0.get(ENTRIES_SERIES, 0.0))
+    if (counted is not None
+            and seen / cfg.get("chunk_size", 1) <= counted <= seen):
+        entries = counted
+    elif "chunk_size" in cfg:
+        return None
+    return decode_read_work(cfg, entries, sw["decode_tokens"], dtype)
 
 
 def attn_decode_work(cfg: dict, context: int, dtype: str = "bfloat16") -> dict:

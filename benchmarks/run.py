@@ -29,6 +29,11 @@ size): it says ``"platform": "cpu"`` and prints no device metric.
 Phases: spawn -> ready -> reference check -> warm-up (lattice walk, then
 the traffic's own warm phase) -> window -> (``--trace 2``: tail) -> drain
 -> stop. ``setup_s`` runs from process start to the opening of the window.
+
+Where a probe's ramp is not whole when the window opens (a traffic file's
+``"ramp_whole"``; ``loadgen.RampSplit``) the child is stopped and all of
+it is done once more on a new child, set-up running on; a second split
+ends the run with no result.
 """
 
 from __future__ import annotations
@@ -85,7 +90,7 @@ async def http_json(http, method: str, url: str, body=None, timeout=120.0):
 
 
 async def measure(srv: Server, traffic: loadgen.Traffic, trace: int,
-                  trace_dir: str) -> dict:
+                  trace_dir: str, hold_back_s: float = 0.0) -> dict:
     """The window (and ``--trace 2``'s tail). Returns the run's raw
     material."""
     got: dict = {}
@@ -153,7 +158,7 @@ async def measure(srv: Server, traffic: loadgen.Traffic, trace: int,
         hooks["at"] = [(off, trace_start), (off + span, trace_stop)]
     elif trace == 2:
         hooks["at"] = [(traffic.warm + traffic.seconds, trace_tail)]
-    run = loadgen.Run(traffic, srv.base, hooks)
+    run = loadgen.Run(traffic, srv.base, hooks, hold_back_s=hold_back_s)
     await run.go()
     got["run"] = run
     return got
@@ -169,6 +174,9 @@ def main() -> int:
                     help="CPU dress rehearsal at toy widths (no chip run)")
     ap.add_argument("--benchmark-json", default=None, help=argparse.SUPPRESS)
     ap.add_argument("--keep-work", action="store_true", help=argparse.SUPPRESS)
+    # Tests: in the first attempt one client sends this much later.
+    ap.add_argument("--split-first-ramp", type=float, default=0.0,
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
 
     bench = spec.load(args.benchmark_json)
@@ -186,57 +194,77 @@ def main() -> int:
     sizes = spec.serve_sizes(flags)
 
     work_dir = os.path.join(ROOT, ".bench_work", args.workload)
-    shutil.rmtree(work_dir, ignore_errors=True)
-    os.makedirs(work_dir)
     trace_dir = os.path.join(work_dir, "trace")
 
-    traffic = loadgen.Traffic(
-        traffic_spec, hf["vocab_size"], args.seed, args.seconds,
-        rehearse=args.rehearse,
-        tail=TRACE2_TAIL_S if args.trace == 2 else 0.0)
-    srv = Server(work_dir, spec.config_path(cell["config"]), args.seed,
-                 cell["chips"], args.rehearse)
-    phases = {}
+    def serve_once(hold_back_s: float) -> dict:
+        """One child, from its spawn to its stop: the run's material."""
+        shutil.rmtree(work_dir, ignore_errors=True)
+        os.makedirs(work_dir)
+        traffic = loadgen.Traffic(
+            traffic_spec, hf["vocab_size"], args.seed, args.seconds,
+            rehearse=args.rehearse,
+            tail=TRACE2_TAIL_S if args.trace == 2 else 0.0)
+        srv = Server(work_dir, spec.config_path(cell["config"]), args.seed,
+                     cell["chips"], args.rehearse)
+        phases = {}
+        try:
+            phases["ready_s"] = srv.wait_ready(1100)
+            device = srv.device()
+            if not args.rehearse:
+                check(device["platform"] == "tpu"
+                      and device["count"] == cell["chips"],
+                      "not the chips the cell asks for", device=device)
+                peaks = spec.peaks_for(device["kind"])
+            else:
+                peaks = None
+
+            t = time.monotonic()
+            with open(os.path.join(work_dir, "reference.json")) as f:
+                ref = json.load(f)
+            ref_check = replay_reference(srv, ref["rows"])
+            repeat_ok = repeat_agrees(
+                srv, traffic._tokens(3 * 64 + 5), 2 * warmup.DECODE_K)
+            phases["reference_s"] = time.monotonic() - t
+            log(phase="reference", **ref_check, repeat_identical=repeat_ok,
+                reference_seconds=ref["seconds"])
+
+            t = time.monotonic()
+            walked = asyncio.run(warmup.walk(srv.base, traffic, sizes))
+            phases["walk_s"] = time.monotonic() - t
+            log(phase="warmup", **walked)
+            check(walked["failed"] == 0, "warm-up requests failed", **walked)
+
+            got = asyncio.run(measure(srv, traffic, args.trace, trace_dir,
+                                      hold_back_s))
+            status = srv.status()
+        except BaseException:
+            srv.close()
+            raise
+        log(phase="stopped", server_exit=srv.close())
+        return {"got": got, "phases": phases, "device": device,
+                "peaks": peaks, "ref_check": ref_check,
+                "repeat_ok": repeat_ok, "status": status}
+
+    splits = []
     try:
-        phases["ready_s"] = srv.wait_ready(1100)
-        device = srv.device()
-        if not args.rehearse:
-            check(device["platform"] == "tpu"
-                  and device["count"] == cell["chips"],
-                  "not the chips the cell asks for", device=device)
-            peaks = spec.peaks_for(device["kind"])
-        else:
-            peaks = None
-
-        t = time.monotonic()
-        with open(os.path.join(work_dir, "reference.json")) as f:
-            ref = json.load(f)
-        ref_check = replay_reference(srv, ref["rows"])
-        repeat_ok = repeat_agrees(
-            srv, traffic._tokens(3 * 64 + 5), 2 * warmup.DECODE_K)
-        phases["reference_s"] = time.monotonic() - t
-        log(phase="reference", **ref_check, repeat_identical=repeat_ok,
-            reference_seconds=ref["seconds"])
-
-        t = time.monotonic()
-        walked = asyncio.run(warmup.walk(srv.base, traffic, sizes))
-        phases["walk_s"] = time.monotonic() - t
-        log(phase="warmup", **walked)
-        check(walked["failed"] == 0, "warm-up requests failed", **walked)
-
-        got = asyncio.run(measure(srv, traffic, args.trace, trace_dir))
-        run = got["run"]
-        setup_s = run.w0 - T_START
-        status = srv.status()
+        for attempt in (1, 2):
+            try:
+                once = serve_once(
+                    args.split_first_ramp if attempt == 1 else 0.0)
+                break
+            except loadgen.RampSplit as e:
+                # Not the cell's traffic: once more, on a new child.
+                splits.append(e.detail)
+                log(phase="ramp_split", attempt=attempt, **e.detail)
+                check(attempt == 1, "the ramp split twice", splits=splits)
     except BenchFailed as e:
         print(f"benchmark FAILED: {e}", file=sys.stderr, flush=True)
-        srv.close()
         return 1
-    except BaseException:
-        srv.close()
-        raise
-    code = srv.close()
-    log(phase="stopped", server_exit=code)
+    got, phases, device, peaks, ref_check, repeat_ok, status = (
+        once[k] for k in ("got", "phases", "device", "peaks", "ref_check",
+                          "repeat_ok", "status"))
+    run = got["run"]
+    setup_s = run.w0 - T_START
 
     client = metrics.end_to_end(run.results, run.w0, run.w1, cell["chips"])
     # How much of the preallocated KV pool the traffic's live context
@@ -336,11 +364,17 @@ def main() -> int:
                 "stop_seconds": got["profile_stop"].get("stop_seconds"),
                 "span_out_tok_s": sum(
                     metrics.tokens_in_window(r.chunks, t0, t1)
-                    for r in run.results) / (t1 - t0) / cell["chips"]}
+                    for r in run.results) / (t1 - t0) / cell["chips"],
+                # A probe's margin: how long after the traced span its
+                # first row ended (PERF.md, Cells).
+                "first_end_after_span_s": min(
+                    (r.last_t - t1 for r in
+                     metrics.live_in_window(run.results, t0, t1)
+                     if r.usage is not None), default=None)}
     log(phase="summary", phases=phases, setup_s=setup_s, client=client,
         live_context_tokens=live,
         in_flight=run.in_flight_at, not_ok=len(not_ok),
-        trace2=tail,
+        ramp=run.ramp, ramp_splits=splits, trace2=tail,
         first_errors=[r.error for r in not_ok if r.error][:3],
         kv_pages=status["stages"][0]["num_pages"],
         kv_occupancy={k: got["scrape_" + k].get("parallax_kv_page_occupancy")
@@ -354,6 +388,7 @@ def main() -> int:
         "metrics": {k: {"value": v, "unit": units[k]}
                     for k, v in values.items()},
         "device": device_out,
+        "ramp_attempts": len(splits) + 1,
     }
     if breakdown is not None:
         result["breakdown"] = breakdown

@@ -1,5 +1,7 @@
 """The generator's steadiness rule, its due-time clock, the percentiles."""
 
+import asyncio
+
 import numpy as np
 import pytest
 
@@ -49,18 +51,22 @@ def test_gaps_offer_exactly_the_rate():
         assert g.sum() == pytest.approx(20.0) and (g > 0).all()
 
 
-@pytest.mark.parametrize("name, outputs", [
-    ("decode-probe8", (3072, 3328)), ("decode-probe8-5k", (5120, 5632))])
+@pytest.mark.parametrize("name, prompts, outputs, longest", [
+    ("decode-probe8", (64, 256), (3072, 3328), 8192),
+    ("decode-probe8-5k", (64, 256), (7424, 7936), 8192),
+    ("decode-probe8-8k", (6144, 10240), (5632, 6144), 16384)])
 def test_closed_loop_sends_a_blocker_and_the_same_sizes_for_every_seed(
-        name, outputs):
+        name, prompts, outputs, longest):
     t = spec.load_traffic(name)
     tr = loadgen.Traffic(t, 1000, 5, 30.0)
     assert tr.blocker_tokens == 1024
     first = [tr.closed_next(0.02) for _ in range(tr.clients)]
-    assert all(64 <= len(r.prompt) <= 256
+    assert all(prompts[0] <= len(r.prompt) <= prompts[1]
                and outputs[0] <= r.max_tokens <= outputs[1] for r in first)
-    # A row fits serve's --max-model-len (8,192) whole.
-    assert all(len(r.prompt) + r.max_tokens <= 8192 for r in first)
+    # A row fits its configuration's --max-model-len whole (serve's
+    # default 8,192; EvaByte's serve_flags say 16,384), the rows a
+    # finished client would send next too.
+    assert all(p + o <= longest for p, o, _ in tr._pool)
     # Latencies are judged only for requests due inside the window.
     assert not any(r.judged for r in first)
     assert tr.closed_next(tr.warm + 10.5).judged
@@ -151,3 +157,122 @@ def test_live_context_counts_prompt_and_delivered_tokens_of_unfinished_requests(
     assert metrics.live_context_tokens([r], 0.5) == 0       # not sent yet
     assert metrics.live_context_tokens([r], 2.5) == 10 + 3
     assert metrics.live_context_tokens([r], 3.5) == 0       # finished
+
+
+# -- a probe's ramp is whole, or the run says so at the window's opening ---
+
+PROBE = {
+    "loop": "closed", "clients": 3, "warm_seconds": 0.3,
+    "ramp_blocker_tokens": 16, "ramp_whole": True,
+    "prompt_tokens": {"dist": "uniform", "min": 4, "max": 8},
+    "output_tokens": {"dist": "fixed", "value": 400},
+    "sampling": {"temperature": 0.7, "seed": "per_request"},
+}
+
+
+@pytest.mark.parametrize("name", ["decode-probe8", "decode-probe8-5k",
+                                  "decode-probe8-8k"])
+def test_a_probe_asks_for_a_whole_ramp_and_a_rehearsal_does_not(name):
+    t = spec.load_traffic(name)
+    assert loadgen.Traffic(t, 1000, 5, 30.0).ramp_whole
+    assert not loadgen.Traffic(t, 1000, 5, 4.0, rehearse=True).ramp_whole
+    # The key draws nothing anew: every size is what it was without it.
+    bare = {k: v for k, v in t.items() if k != "ramp_whole"}
+    a, b = (loadgen.Traffic(x, 1000, 3_000_000_007, 30.0) for x in (t, bare))
+    assert a._pool == b._pool and not b.ramp_whole
+    assert not loadgen.Traffic(OPEN, 1000, 5, 20.0).ramp_whole
+
+
+def _row(sent, first=None, done=False, error=None):
+    r = loadgen.Result(req=loadgen.Req(0.0, [1], 400, 1), sent_t=sent,
+                       first_t=first, error=error)
+    r.usage = {"completion_tokens": 400} if done else None
+    return r
+
+
+@pytest.mark.parametrize("rows, whole", [
+    # Every client's row streams, the blocker has ended.
+    ([_row(1.0, 1.2, done=True)] + [_row(1.02, 1.3 + i / 100) for i in range(3)],
+     True),
+    # One row is still waiting for its first token (admitted late).
+    ([_row(1.02, 1.3), _row(1.02, 1.3), _row(1.02)], False),
+    # One client has not sent at all.
+    ([_row(1.02, 1.3), _row(1.02, 1.3), _row(0.0)], False),
+    # The blocker itself is still in flight.
+    ([_row(1.0)] + [_row(1.02, 1.3) for _ in range(3)], False),
+    # A row failed.
+    ([_row(1.02, 1.3), _row(1.02, 1.3), _row(1.02, error="HTTP 500")], False),
+])
+def test_the_ramp_is_whole_when_every_clients_row_streams(rows, whole):
+    run = loadgen.Run(loadgen.Traffic(PROBE, 1000, 5, 1.0), "http://x")
+    assert run.ramp_whole
+    run.results = rows
+    if whole:
+        run.check_ramp()
+        assert run.ramp["streaming"] == run.ramp["in_flight"] == 3
+        assert run.ramp["first_token_after_send_s"] == [0.28, 0.29, 0.3]
+    else:
+        with pytest.raises(loadgen.RampSplit) as e:
+            run.check_ramp()
+        assert e.value.detail["clients"] == 3
+        assert e.value.detail["streaming"] < 3 or e.value.detail["in_flight"] > 3
+
+
+async def _fake_serve(tokens_every_s):
+    """A server that streams ``max_tokens`` one-token chunks."""
+    import json
+
+    from aiohttp import web
+
+    async def completions(request):
+        body = await request.json()
+        resp = web.StreamResponse()
+        await resp.prepare(request)
+        n = body["max_tokens"]
+        for i in range(n):
+            chunk = {"choices": [{"text": f" t{i}", "finish_reason": None}]}
+            if i == n - 1:
+                chunk["usage"] = {"completion_tokens": n}
+            await resp.write(b"data: " + json.dumps(chunk).encode() + b"\n\n")
+            await asyncio.sleep(tokens_every_s)
+        await resp.write(b"data: [DONE]\n\n")
+        return resp
+
+    app = web.Application()
+    app.router.add_post("/v1/completions", completions)
+    runner = web.AppRunner(app)
+    await runner.setup()
+    site = web.TCPSite(runner, "127.0.0.1", 0)
+    await site.start()
+    port = site._server.sockets[0].getsockname()[1]
+    return runner, f"http://127.0.0.1:{port}"
+
+
+@pytest.mark.parametrize("hold_back_s, split", [(0.0, False), (5.0, True)])
+def test_a_run_whose_ramp_split_ends_at_the_windows_opening(hold_back_s, split):
+    """Rows of 400 tokens at 5 ms a token outlast warm phase and window;
+    with one client held back past the opening the run raises there and
+    then, and does not wait for the rows to end."""
+    import time
+
+    async def go():
+        server, base = await _fake_serve(0.005)
+        run = loadgen.Run(loadgen.Traffic(PROBE, 1000, 5, 0.5), base,
+                          hold_back_s=hold_back_s)
+        t = time.monotonic()
+        try:
+            await run.go()
+        finally:
+            await server.cleanup()
+        return run, time.monotonic() - t
+
+    if split:
+        t = time.monotonic()
+        with pytest.raises(loadgen.RampSplit) as e:
+            asyncio.run(go())
+        assert time.monotonic() - t < 1.5
+        assert e.value.detail["in_flight"] == e.value.detail["streaming"] == 2
+    else:
+        run, took = asyncio.run(go())
+        assert run.ramp["streaming"] == 3 and run.in_flight_at["w1"] == 3
+        assert all(r.ok for r in run.results) and took > 2.0

@@ -44,10 +44,37 @@ def rehearse(cell, trace, extra=()):
 @pytest.mark.parametrize("cell", cells())
 def test_rehearse_each_cell(cell):
     line = rehearse(cell, trace=1)
-    assert line["metrics"]["prefix_hit_share"]["value"] == 0.0
-    assert line["metrics"]["kv_preemptions"]["value"] == 0.0
-    assert line["metrics"]["batch_tokens_per_visit"]["value"] > 0
-    assert 0 < line["metrics"]["kv_live_share"]["value"] <= line["metrics"]["kv_pool_used_share"]["value"] <= 100
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    # A rehearsal prints the counters of the cell, and of no other cell.
+    assert set(got) == {
+        name for name, m in spec.load()["per_layer"].items()
+        if cell in m["cells"] and m["source"] == "program_counter"}
+    assert got["prefix_hit_share"] == 0.0
+    assert got["kv_preemptions"] == 0.0
+    assert got["batch_tokens_per_visit"] > 0
+    assert 0 < got["kv_pool_used_share"] <= 100
+    if "kv_live_share" in got:
+        assert 0 < got["kv_live_share"] <= got["kv_pool_used_share"]
+
+
+def test_a_split_ramp_starts_the_run_again_on_a_new_child():
+    """One client of the first attempt sends after the window has opened
+    (what the program's admission race does to a row that loses it): the
+    run says so, stops that child, does everything once more and ends in
+    the same well-formed line, ``ramp_attempts`` 2; the second attempt
+    sends what the first would have sent."""
+    cell = cells()[0]
+    proc = run(cell, 0, ("--split-first-ramp", "2.0"))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["ramp_attempts"] == 2
+    log = [json.loads(x) for x in proc.stderr.splitlines()
+           if x.startswith("{")]
+    (split,) = [x for x in log if x["phase"] == "ramp_split"]
+    assert split["attempt"] == 1 and split["in_flight"] < split["clients"]
+    assert [x["phase"] for x in log].count("reference") == 2
+    assert rehearse(cell, 0)["ramp_attempts"] == 1
 
 
 def test_a_later_cell_is_files_and_one_entry(tmp_path):

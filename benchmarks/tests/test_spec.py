@@ -45,6 +45,40 @@ def test_refusals(raw, tmp_path, mutate, message):
         load(tmp_path, bad)
 
 
+def _configs():
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        return [c["name"] for c in json.load(f)["configs"]]
+
+
+@pytest.mark.parametrize("config", _configs())
+def test_a_new_cell_reports_the_whole_yardstick(raw, tmp_path, config):
+    """A claim on an added cell must report every share of a roofline or
+    a peak that moves the claimed end-to-end metric (the driver refuses
+    it otherwise: ledger, PR 31). So such a metric lists no cells, and a
+    cell added from files on any configuration is among its cells."""
+    paired = {w["traffic"] for w in raw["workloads"] if w["config"] == config}
+    traffic = next(
+        stem for stem in sorted(
+            os.path.splitext(f)[0]
+            for f in os.listdir(os.path.join(spec.BENCH_DIR, "traffic")))
+        if stem not in paired)
+    cell = f"{config}.added-{traffic}"
+    raw["workloads"].append({
+        "name": cell, "config": config, "traffic": traffic, "chips": 1,
+        "why": "a cell a later PR adds from files that are there"})
+    b = load(tmp_path, raw)
+    reports = {k for k, m in b["end_to_end"].items() if cell in m["cells"]}
+    assert "out_tok_s" in reports
+    yardstick = [m for name, m in b["per_layer"].items()
+                 if ("roofline" in name or "mfu" in name)
+                 and m["moves"] in reports]
+    assert {m["name"] for m in yardstick} >= {
+        "attn_decode_roofline", "decode_step_roofline"}
+    for m in yardstick:
+        assert cell in m["cells"], (
+            f"{m['name']} shuts {cell} out: no claim could be made there")
+
+
 def test_a_reduced_key_must_be_what_the_file_runs(tmp_path, monkeypatch):
     cfg = json.load(open(spec.config_path("qwen2.5-7b-d24")))
     cfg["num_hidden_layers"] = 28
