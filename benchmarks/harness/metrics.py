@@ -6,10 +6,9 @@ was sent, and tokens are the tokens the stream delivered.
 
 from __future__ import annotations
 
-import importlib.util
 import re
 
-from benchmarks.harness import work
+from benchmarks.harness import spec, work
 
 
 def percentile(values: list[float], p: float) -> float:
@@ -159,14 +158,11 @@ def read_layer_metric(reader: dict, ctx: dict):
     window's ends), ``scrape_t0``/``scrape_t1`` (at the traced span's
     ends), ``client`` (``end_to_end``'s dict), ``trace`` (the reduced
     trace, or None), ``span_work`` (what the traced span computed, from
-    ``work.span_work``), ``model`` (the config.json run), ``peaks``."""
+    ``work.span_work``), ``model`` (the config.json run), ``work`` (the
+    stage layer by layer as its work is counted, ``work.stage`` of the
+    configuration's work file), ``peaks``."""
     if "py" in reader:
-        spec = importlib.util.spec_from_file_location(
-            "layer_metric_" + reader["name"].replace(".", "_").replace("-", "_"),
-            reader["py"])
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.reduce(ctx)
+        return spec.import_file("layer_metric_", reader["py"]).reduce(ctx)
     src = reader["source"]
     kind = src["kind"]
     if kind == "metrics_series":

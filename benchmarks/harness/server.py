@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import math
+import operator
 import os
 import signal
 import socket
@@ -182,19 +183,17 @@ def greedy(srv, prompt_ids: list[int], n: int, **extra):
     return choice["token_ids"], choice["logprobs"]["token_logprobs"]
 
 
-def tied_logprob(srv, context: list[int], token: int) -> float:
+def tied_logprob(srv, context: list[int], token: int) -> float | None:
     """``token``'s logprob after ``context`` if it ties the server's own
-    maximum there, else fail. With ``LOGPROB_TOL`` added to that one logit
+    maximum there, else None. With ``LOGPROB_TOL`` added to that one logit
     (``logit_bias``) a greedy step picks ``token`` exactly when it was
     within the tolerance of the maximum. The logprob comes back with the
     bias in it (``q = p e^B / (1 - p + p e^B)``) and is returned with it
     taken out."""
     bias = LOGPROB_TOL
     ids, (biased,) = greedy(srv, context, 1, logit_bias={str(token): bias})
-    check(ids == [token],
-          "a divergence that is no tie: the reference's token is not "
-          f"within {bias} of the server's maximum",
-          position=len(context), reference=token, chosen_even_so=ids)
+    if ids != [token]:
+        return None
     q = math.exp(biased)
     return biased - math.log(math.exp(bias) * (1.0 - q) + q)
 
@@ -206,9 +205,20 @@ def replay_reference(srv, rows: list[dict]) -> dict:
     its stream has left the reference's context, so it starts again from
     there: every position of every row is compared in the reference's
     context. A tie is believed only where the reference itself saw its
-    two best logits within the tolerance."""
-    agreed = ties = 0
-    worst = 0.0
+    two best logits within the tolerance.
+
+    Returns what was compared (``compared`` reads it): the positions
+    that agreed, the ties, the largest logprob gap, the largest gap of
+    the reference's two best logits at a divergence and the divergences
+    that were no tie; the replay ends at the first position that fails,
+    and ``failed`` says where and why (None where none did)."""
+    out = {"positions_agreed": 0, "ties": 0, "max_logprob_gap": 0.0,
+           "max_tie_top2_gap": 0.0, "untied": 0, "failed": None}
+
+    def failed(what: str, **ctx) -> dict:
+        out["failed"] = f"{what} {json.dumps(ctx, default=str)[:500]}"
+        return out
+
     for i, row in enumerate(rows):
         prompt, ref_ids, ref_lps = row["prompt"], row["tokens"], row["logprobs"]
         done = 0
@@ -218,25 +228,35 @@ def replay_reference(srv, rows: list[dict]) -> dict:
             for tok, lp in zip(ids, lps):
                 want = ref_ids[done]
                 if tok != want:
-                    check(row["top2_gap"][done] <= 2 * LOGPROB_TOL,
-                          f"row {i} position {done}: the server left a "
-                          "token the reference was sure of",
-                          reference=want, server=tok,
-                          reference_top2_gap=row["top2_gap"][done])
+                    top2 = row["top2_gap"][done]
+                    out["max_tie_top2_gap"] = max(out["max_tie_top2_gap"], top2)
+                    if top2 > 2 * LOGPROB_TOL:
+                        return failed(
+                            f"row {i} position {done}: the server left a "
+                            "token the reference was sure of",
+                            reference=want, server=tok,
+                            reference_top2_gap=top2)
                     lp = tied_logprob(srv, prompt + ref_ids[:done], want)
+                    if lp is None:
+                        out["untied"] += 1
+                        return failed(
+                            f"row {i} position {done}: a divergence that is "
+                            "no tie: the reference's token is not within "
+                            f"{LOGPROB_TOL} of the server's maximum",
+                            reference=want, server=tok)
                 gap = abs(lp - ref_lps[done])
-                worst = max(worst, gap)
-                check(gap <= LOGPROB_TOL,
-                      f"row {i} position {done}: token {want} has another "
-                      "logprob than the reference gives it",
-                      reference=ref_lps[done], server=lp)
+                out["max_logprob_gap"] = max(out["max_logprob_gap"], gap)
+                if gap > LOGPROB_TOL:
+                    return failed(
+                        f"row {i} position {done}: token {want} has another "
+                        "logprob than the reference gives it",
+                        reference=ref_lps[done], server=lp)
                 done += 1
                 if tok != want:
-                    ties += 1
+                    out["ties"] += 1
                     break        # resume from the reference's context
-                agreed += 1
-    return {"positions_agreed": agreed, "ties": ties,
-            "max_logprob_gap": worst}
+                out["positions_agreed"] += 1
+    return out
 
 
 def repeat_agrees(srv, prompt: list[int], n: int) -> bool:
@@ -254,4 +274,41 @@ def repeat_agrees(srv, prompt: list[int], n: int) -> bool:
     i = next(j for j in range(n) if a[j] != b[j])
     if any(abs(x - y) > LOGPROB_TOL for x, y in zip(lps_a[:i], lps_b[:i])):
         return False
-    return abs(tied_logprob(srv, prompt + a[:i], a[i]) - lps_a[i]) <= LOGPROB_TOL
+    tied = tied_logprob(srv, prompt + a[:i], a[i])
+    return tied is not None and abs(tied - lps_a[i]) <= LOGPROB_TOL
+
+
+RULES = {"<=": operator.le, ">=": operator.ge, "==": operator.eq}
+
+
+def compared(ref_check: dict, repeat_ok: bool | None = None,
+             client: dict | None = None) -> dict:
+    """Every number that decides ``correct``, each beside its limit:
+    ``{name: {"value", "limit", "rule"}}``, ``value`` None for what the
+    run did not reach (the replay failed, so no repeat was sent and no
+    window opened). ``holds`` says whether all of it holds."""
+    def entry(value, limit, rule):
+        return {"value": value, "limit": limit, "rule": rule}
+
+    return {
+        "positions": entry(
+            ref_check["positions_agreed"] + ref_check["ties"], 1, ">="),
+        "logprob_gap_max": entry(
+            ref_check["max_logprob_gap"], LOGPROB_TOL, "<="),
+        "ties": entry(ref_check["ties"], None, None),
+        "tie_top2_gap_max": entry(
+            ref_check["max_tie_top2_gap"], 2 * LOGPROB_TOL, "<="),
+        "divergences_untied": entry(ref_check["untied"], 0, "=="),
+        "repeat_identical": entry(
+            None if repeat_ok is None else int(repeat_ok), 1, "=="),
+        "requests_attempted": entry(
+            client and client["attempted"], 1, ">="),
+        "requests_failed_or_short": entry(
+            client and client["failed"], 0, "=="),
+    }
+
+
+def holds(numbers: dict) -> bool:
+    return all(
+        n["value"] is not None and RULES[n["rule"]](n["value"], n["limit"])
+        for n in numbers.values() if n["rule"] is not None)

@@ -2,7 +2,8 @@
 
 Everything a cell needs is found by name: ``configs/<config>.json``,
 ``traffic/<traffic>.json``, ``layer_metrics/<metric>.json`` (or ``.py``)
-and, where a configuration names one, ``references/<module>.py``.
+and, where a configuration names them, ``references/<module>.py`` and
+``works/<module>.py``.
 ``load`` refuses a malformed benchmark before any run; later PRs add
 files and entries and edit nothing here.
 """
@@ -11,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import ast
+import importlib.util
 import json
 import os
 import re
@@ -28,6 +30,7 @@ CONFIG_META_KEY = "bench"
 DEFAULT_REFERENCE_ROWS = [
     {"prompts": 4, "prompt_tokens": 48, "new_tokens": 16}]
 REFERENCE_ENTRY = "greedy_continuations"
+WORK_ENTRY = "layers"
 
 
 class SpecError(ValueError):
@@ -109,6 +112,17 @@ def _defines(path: str, name: str) -> bool:
     return False
 
 
+def import_file(prefix: str, path: str):
+    """The module at ``path``, loaded by its path (a reader, a work
+    file: found by name in a directory that is no package)."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    found = importlib.util.spec_from_file_location(
+        prefix + re.sub(r"\W", "_", stem), path)
+    module = importlib.util.module_from_spec(found)
+    found.loader.exec_module(module)
+    return module
+
+
 def reference_of(meta: dict, name: str = "") -> dict:
     """A configuration's plain reference, from its ``bench`` group:
     ``{"module", "import", "rows"}``. ``bench.reference`` is optional:
@@ -148,6 +162,29 @@ def reference_of(meta: dict, name: str = "") -> dict:
             "rows": rows}
 
 
+def work_of(meta: dict, name: str = "") -> dict:
+    """A configuration's work file, from its ``bench`` group:
+    ``{"module", "path"}``. ``bench.work`` is optional: ``{"module":
+    "<stem>"}`` names ``works/<stem>.py``, which describes the stage
+    layer by layer for the shares of a roofline (``layers(cfg)``;
+    ``harness/work.py`` ``stage`` has the contract). Without the key the
+    stage is the dense block (``path`` None)."""
+    given = meta.get("work")
+    if given is None:
+        return {"module": "harness/work", "path": None}
+    _need(isinstance(given, dict) and set(given) == {"module"},
+          f"config {name}: bench.work has the one key 'module'")
+    module = given["module"]
+    _need(isinstance(module, str) and NAME_RE.match(module) is not None,
+          f"config {name}: bench.work.module {module!r} is no name")
+    path = os.path.join(BENCH_DIR, "works", f"{module}.py")
+    _need(os.path.isfile(path),
+          f"config {name}: missing file: {os.path.relpath(path, ROOT)}")
+    _need(_defines(path, WORK_ENTRY),
+          f"config {name}: {os.path.relpath(path, ROOT)} has no {WORK_ENTRY}")
+    return {"module": module, "path": path}
+
+
 def load_config(name: str) -> dict:
     """``{"hf": config.json as run, "bench": the benchmark's notes}``."""
     raw = _read_json(config_path(name))
@@ -162,7 +199,8 @@ def load_config(name: str) -> dict:
               f"config {name}: reduced key {key} says run={change['run']}, "
               f"the file holds {hf.get(key)}")
     return {"name": name, "hf": hf, "bench": meta,
-            "reference": reference_of(meta, name)}
+            "reference": reference_of(meta, name),
+            "work": work_of(meta, name)}
 
 
 def load_traffic(name: str) -> dict:

@@ -9,8 +9,11 @@ files, starts the child that holds the chip
 the seed), checks it against the plain reference, warms it up, offers the
 cell's traffic for ``--seconds`` over HTTP, and does all the arithmetic.
 The LAST line of standard output is one JSON object (``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, with
-``--trace 1`` or ``2``, ``breakdown``). Without a TPU holding the chips
+``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
+or ``2`` ``breakdown``, and last ``compared``: every number that decides
+``correct`` beside its limit, which are the last lines of standard error
+too). A run whose replay of the reference fails opens no window and ends
+in that line with ``"correct": false``. Without a TPU holding the chips
 the cell asks for, the exit code is not 0 and no result is printed.
 
 ``--trace 0`` measures the end-to-end metrics with the profiler off.
@@ -57,6 +60,8 @@ from benchmarks.harness.server import (  # noqa: E402
     BenchFailed,
     Server,
     check,
+    compared,
+    holds,
     repeat_agrees,
     replay_reference,
 )
@@ -222,20 +227,26 @@ def main() -> int:
             with open(os.path.join(work_dir, "reference.json")) as f:
                 ref = json.load(f)
             ref_check = replay_reference(srv, ref["rows"])
-            repeat_ok = repeat_agrees(
-                srv, traffic._tokens(3 * 64 + 5), 2 * warmup.DECODE_K)
+            repeat_ok = got = None
+            if ref_check["failed"] is None:
+                repeat_ok = repeat_agrees(
+                    srv, traffic._tokens(3 * 64 + 5), 2 * warmup.DECODE_K)
             phases["reference_s"] = time.monotonic() - t
             log(phase="reference", **ref_check, repeat_identical=repeat_ok,
                 reference_seconds=ref["seconds"])
 
-            t = time.monotonic()
-            walked = asyncio.run(warmup.walk(srv.base, traffic, sizes))
-            phases["walk_s"] = time.monotonic() - t
-            log(phase="warmup", **walked)
-            check(walked["failed"] == 0, "warm-up requests failed", **walked)
+            # A replay that failed opens no window: the run ends in its
+            # line, ``correct`` false, with what was compared.
+            if ref_check["failed"] is None:
+                t = time.monotonic()
+                walked = asyncio.run(warmup.walk(srv.base, traffic, sizes))
+                phases["walk_s"] = time.monotonic() - t
+                log(phase="warmup", **walked)
+                check(walked["failed"] == 0, "warm-up requests failed",
+                      **walked)
 
-            got = asyncio.run(measure(srv, traffic, args.trace, trace_dir,
-                                      hold_back_s))
+                got = asyncio.run(measure(srv, traffic, args.trace,
+                                          trace_dir, hold_back_s))
             status = srv.status()
         except BaseException:
             srv.close()
@@ -263,6 +274,29 @@ def main() -> int:
     got, phases, device, peaks, ref_check, repeat_ok, status = (
         once[k] for k in ("got", "phases", "device", "peaks", "ref_check",
                           "repeat_ok", "status"))
+    hw = status["hardware"]
+    peak = max((d.get("peak_bytes_in_use") or 0) for d in hw["devices"])
+    device_out = {"platform": device["platform"], "kind": device["kind"],
+                  "count": device["count"], "memory_peak_bytes": peak}
+
+    def finish(result: dict, numbers: dict) -> int:
+        """The run's last words: each number ``correct`` compared beside
+        its limit as the last lines of standard error, and the result's
+        line, which carries them under ``compared``, its last key."""
+        for name, n in numbers.items():
+            limit = (f"limit: {n['rule']} {n['limit']}" if n["rule"]
+                     else "counted, no limit of its own")
+            print(f"compared {name}: {n['value']} ({limit})",
+                  file=sys.stderr, flush=True)
+        print(json.dumps(dict(result, compared=numbers)), flush=True)
+        return 0
+
+    if got is None:
+        log(phase="summary", phases=phases, not_correct=ref_check["failed"])
+        return finish({"correct": False, "attempted": 0, "failed": 0,
+                       "metrics": {}, "device": device_out,
+                       "ramp_attempts": len(splits) + 1},
+                      compared(ref_check))
     run = got["run"]
     setup_s = run.w0 - T_START
 
@@ -273,15 +307,8 @@ def main() -> int:
             for k, t in (("w0", run.w0), ("w1", run.w1))}
     pool_tokens = status["stages"][0]["num_pages"] * sizes["page_size"]
     client["kv_live_share"] = 100.0 * live["w1"] / pool_tokens
-    hw = status["hardware"]
-    peak = max((d.get("peak_bytes_in_use") or 0) for d in hw["devices"])
-    device_out = {"platform": device["platform"], "kind": device["kind"],
-                  "count": device["count"], "memory_peak_bytes": peak}
     not_ok = [r for r in run.results if not r.ok]
-    correct = bool(
-        ref_check["positions_agreed"] + ref_check["ties"] > 0
-        and repeat_ok and client["failed"] == 0 and client["attempted"] > 0
-    )
+    numbers = compared(ref_check, repeat_ok, client)
 
     values: dict[str, float] = {}
     breakdown = None
@@ -323,14 +350,16 @@ def main() -> int:
                 by_span = host_spans.idle_gaps(red["file"])
                 if by_span is not None:
                     breakdown["idle_gaps"] = by_span
+        stage = work.load_stage(hf, config["work"]["path"])
         ctx = {
             "scrape_w0": got.get("scrape_w0"), "scrape_w1": got.get("scrape_w1"),
             "scrape_t0": got.get("scrape_t0"), "scrape_t1": got.get("scrape_t1"),
             "status_w0": got.get("status_w0"), "status_w1": got.get("status_w1"),
-            "client": client, "trace": red, "model": hf, "peaks": peaks,
+            "client": client, "trace": red, "model": hf, "work": stage,
+            "peaks": peaks,
             "span_work": work.span_work(
                 run.results, got.get("t_trace0", 0), got.get("t_trace1", 0),
-                hf) if red is not None else None,
+                stage) if red is not None else None,
         }
         for name, m in bench["per_layer"].items():
             if not reported(m):
@@ -382,7 +411,7 @@ def main() -> int:
         compile=status["device"]["compile"]["compiles_total"],
         cache_hits=status["device"]["compile"]["cache_hits_total"])
     result = {
-        "correct": correct,
+        "correct": holds(numbers),
         "attempted": client["attempted"],
         "failed": client["failed"],
         "metrics": {k: {"value": v, "unit": units[k]}
@@ -392,8 +421,7 @@ def main() -> int:
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
-    print(json.dumps(result), flush=True)
-    return 0
+    return finish(result, numbers)
 
 
 if __name__ == "__main__":

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import spec
+from benchmarks.harness import server, spec
 from benchmarks.references import evabyte
 
 CONFIG = "evabyte-6.5b-d16"
@@ -102,7 +102,8 @@ def test_a_reference_without_summaries_fails_the_rehearsal(tmp_path):
     """The comparison's own control: the child runs a reference that
     leaves the summaries out, the server keeps them, and the replay of
     the 48 + 16 row (window 32: summaries visible from position 32 on)
-    ends the run with no result line."""
+    ends the run before any window, ``correct`` false, the number that
+    failed beside its limit in the line's ``compared``."""
     mod_path = os.path.join(spec.BENCH_DIR, "references",
                             "evabyte_nosummary_for_test.py")
     cfg_path = spec.config_path("evabyte-nosummary-for-test")
@@ -137,6 +138,12 @@ def test_a_reference_without_summaries_fails_the_rehearsal(tmp_path):
         for path in (cfg_path, mod_path):
             if os.path.exists(path):
                 os.remove(path)
-    assert proc.returncode == 1, proc.stderr[-2000:]
-    assert "benchmark FAILED" in proc.stderr
-    assert '"correct"' not in proc.stdout
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["metrics"] == {}
+    numbers = line["compared"]
+    assert list(line)[-1] == "compared" and not server.holds(numbers)
+    assert (numbers["logprob_gap_max"]["value"] > server.LOGPROB_TOL
+            or numbers["tie_top2_gap_max"]["value"] > 2 * server.LOGPROB_TOL
+            or numbers["divergences_untied"]["value"] == 1)
+    assert numbers["requests_attempted"]["value"] is None   # no window

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from benchmarks.harness import spec
+from benchmarks.harness import server, spec
 
 RUN = os.path.join(spec.ROOT, "benchmarks", "run.py")
 E2E_TIMES = {"out_tok_s", "ttft_p50_ms", "ttft_p95_ms", "tpot_p95_ms", "setup_s"}
@@ -38,7 +38,25 @@ def rehearse(cell, trace, extra=()):
     assert not E2E_TIMES & set(line["metrics"])    # no time, no rate
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
+    assert_compared(line, proc.stderr)
+    assert server.holds(line["compared"])
     return line
+
+
+def assert_compared(line, stderr):
+    """Every number ``correct`` compared, beside its limit: the last key
+    of the last line, and the last lines of standard error."""
+    numbers = line["compared"]
+    assert list(line)[-1] == "compared"
+    assert set(numbers) == {
+        "positions", "logprob_gap_max", "ties", "tie_top2_gap_max",
+        "divergences_untied", "repeat_identical", "requests_attempted",
+        "requests_failed_or_short"}
+    assert numbers["logprob_gap_max"]["limit"] == server.LOGPROB_TOL
+    assert numbers["tie_top2_gap_max"]["limit"] == 2 * server.LOGPROB_TOL
+    last = stderr.strip().splitlines()[-len(numbers):]
+    assert [x.split(":")[0] for x in last] == [
+        f"compared {name}" for name in numbers]
 
 
 @pytest.mark.parametrize("cell", cells())
@@ -182,9 +200,27 @@ def test_a_later_architecture_is_files_and_entries(own_reference):
 
 def test_a_reference_that_leaves_out_a_layer_fails_the_run(own_reference):
     """The comparison's own control at the rehearsal's size: with one
-    layer of the mathematics left out on one side, no result line."""
+    layer of the mathematics left out on one side the run opens no
+    window and ends in its line, ``correct`` false, with the number that
+    failed beside its limit."""
     cell, extra = own_reference(drop=1)
     proc = run(cell, trace=0, extra=extra)
-    assert proc.returncode == 1
-    assert "benchmark FAILED" in proc.stderr
-    assert '"correct"' not in proc.stdout
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False
+    assert (line["attempted"], line["failed"], line["metrics"]) == (0, 0, {})
+    assert line["device"]["platform"] == "cpu"
+    assert_compared(line, proc.stderr)
+    numbers = line["compared"]
+    assert not server.holds(numbers)
+    # What failed says so: a logprob further from the reference's than
+    # the tolerance, or a token the reference was sure of left.
+    assert (numbers["logprob_gap_max"]["value"] > server.LOGPROB_TOL
+            or numbers["tie_top2_gap_max"]["value"] > 2 * server.LOGPROB_TOL
+            or numbers["divergences_untied"]["value"] == 1)
+    # The repeat and the window were not reached.
+    assert numbers["repeat_identical"]["value"] is None
+    assert numbers["requests_attempted"]["value"] is None
+    summary = [json.loads(x) for x in proc.stderr.splitlines()
+               if x.startswith("{") and '"summary"' in x]
+    assert "position" in summary[-1]["not_correct"]

@@ -6,7 +6,8 @@ import os
 
 import pytest
 
-from benchmarks.harness import spec
+from benchmarks.harness import metrics, spec, work
+from benchmarks.tests import toy_hybrid
 
 
 @pytest.fixture()
@@ -79,6 +80,41 @@ def test_a_new_cell_reports_the_whole_yardstick(raw, tmp_path, config):
             f"{m['name']} shuts {cell} out: no claim could be made there")
 
 
+def test_a_hybrid_with_a_work_file_reports_the_whole_yardstick(
+        raw, tmp_path, monkeypatch):
+    """What the next ``model_config`` PR does, from new files alone: a
+    configuration that names its work file, one entry under ``configs``
+    and one under ``workloads``. The cell is among the cells of both
+    shares of a roofline, and both read a number there."""
+    bench_dir = tmp_path / "benchmarks"
+    bench_dir.mkdir()
+    name = toy_hybrid.write(str(bench_dir))
+    monkeypatch.setattr(spec, "BENCH_DIR", str(bench_dir))
+    monkeypatch.setattr(spec, "ROOT", str(tmp_path))
+    raw["configs"].append({
+        "name": name, "source": toy_hybrid.CONFIG["bench"]["source"],
+        "file": f"benchmarks/configs/{name}.json",
+        "reduced": ["experts_held"], "why": "a hybrid from files"})
+    cell = f"{name}.decode-probe8"
+    raw["workloads"].append({
+        "name": cell, "config": name, "traffic": "decode-probe8", "chips": 1,
+        "why": "a cell a later PR adds with its configuration"})
+    b = load(tmp_path, raw)
+    config = b["configs"][name]
+    assert config["work"]["module"] == "toy_hybrid"
+    stage = work.load_stage(config["hf"], config["work"]["path"])
+    ctx = toy_hybrid.hand_ctx(stage, toy_hybrid.SPAN_WORK,
+                              *toy_hybrid.counts(1_900, 5_600))
+    for metric in ("attn_decode_roofline", "decode_step_roofline"):
+        m = b["per_layer"][metric]
+        assert "workloads" not in m and cell in m["cells"]
+        assert 0 < metrics.read_layer_metric(m["reader"], ctx) < 100
+    # Without the program's count of the experts its steps read: nothing.
+    ctx["scrape_t1"] = {}
+    assert metrics.read_layer_metric(
+        b["per_layer"]["decode_step_roofline"]["reader"], ctx) is None
+
+
 def test_a_reduced_key_must_be_what_the_file_runs(tmp_path, monkeypatch):
     cfg = json.load(open(spec.config_path("qwen2.5-7b-d24")))
     cfg["num_hidden_layers"] = 28
@@ -143,3 +179,35 @@ def test_a_reference_module_is_found_by_its_stem(tmp_path, monkeypatch):
     ]:
         with pytest.raises(spec.SpecError, match=message):
             spec.reference_of(bench_with(reference, flags))
+
+
+def test_a_configuration_without_a_work_file_is_the_dense_block():
+    for name in ("qwen2.5-7b-d24", "qwen2.5-3b"):
+        assert spec.load_config(name)["work"] == {
+            "module": "harness/work", "path": None}
+    eva = spec.load_config("evabyte-6.5b-d16")["work"]
+    assert eva == {"module": "evabyte", "path": os.path.join(
+        spec.BENCH_DIR, "works", "evabyte.py")}
+
+
+def test_a_work_file_is_found_by_its_stem(tmp_path, monkeypatch):
+    monkeypatch.setattr(spec, "BENCH_DIR", str(tmp_path))
+    (tmp_path / "works").mkdir()
+    (tmp_path / "works" / "mine.py").write_text(
+        "def layers(cfg):\n    return []\n")
+    (tmp_path / "works" / "assigned.py").write_text(
+        "from benchmarks.harness import work\nlayers = work.dense_layers\n")
+    (tmp_path / "works" / "empty.py").write_text(
+        "def head_elements(cfg):\n    return 0\n")
+    for stem in ("mine", "assigned"):
+        assert spec.work_of(dict(META, work={"module": stem})) == {
+            "module": stem, "path": str(tmp_path / "works" / f"{stem}.py")}
+    for given, message in [
+        ({"module": "absent"}, "missing file"),
+        ({"module": "empty"}, "has no layers"),
+        ({"module": "../harness/work"}, "is no name"),
+        ({"module": "mine", "kernel": "x"}, "the one key"),
+        ("mine", "the one key"),
+    ]:
+        with pytest.raises(spec.SpecError, match=message):
+            spec.work_of(dict(META, work=given))
