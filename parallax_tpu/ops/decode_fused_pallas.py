@@ -38,9 +38,11 @@ Two grid disciplines live here:
   DMA a page started back to back, into one of **two VMEM buffers**;
   block ``b + 1``'s copies are started before block ``b`` is waited
   on and folded, so copies are in flight while the fold computes, and
-  ``fold`` sees ``B * page`` tokens at once. ``B`` is **derived**
+  ``fold`` is handed the landed buffer, ``B * page`` tokens, as a ref
+  (the GQA fold reads each KV head out of it with one strided load,
+  :func:`_kv_head_strided`). ``B`` is **derived**
   (:func:`decode_pages_per_block`: the largest power of two, at most
-  8, whose pages fit 2 MB as VMEM pads them — 8 at 64/128 KB pages,
+  8, whose pages fit 2 MB as VMEM holds them — 8 at 64/128 KB pages,
   2 at 1 MB pages). The row's append is waited on **before the copies
   of its last block start** (that block holds the slot's page), and
   **rows prefetch across the grid**: a row's last fold runs beside the
@@ -175,17 +177,40 @@ def decode_pages_per_block(
     """How many pages the streamed core moves and folds at once — derived
     from the page's shape, never set: the largest power of two (at most
     ``_MAX_PAGES_PER_BLOCK``) whose pages fit ``_STREAM_BLOCK_BYTES`` *as
-    VMEM holds them*: the trailing ``(c, w)`` dims pad to the dtype's
-    tile (8 x 128 of 32 bits, 16 x 128 of bf16, 32 x 128 of 8 bits), so
-    a ``[64, 4, 128]`` bf16 page of 64 KB takes 256 KB there."""
-    itemsize = jnp.dtype(dtype).itemsize
-    sublanes = 8 * max(1, 4 // itemsize)
-    padded = (
-        page_size * -(-c // sublanes) * sublanes * -(-w // _LANES) * _LANES
-        * itemsize
-    )
-    b = max(1, min(_MAX_PAGES_PER_BLOCK, _STREAM_BLOCK_BYTES // padded))
+    VMEM holds a block buffer*: Mosaic tiles a ``[N, c, w]`` ref by
+    ``c`` rows at ``c`` of 1, 2 or 4 and by 8 rows otherwise, whatever
+    the dtype, and ``w`` pads to the 128 lanes — a ``[64, 4, 128]``
+    bf16 page takes its 64 KB, 6 float32 rows take 8
+    (``tests/test_tpu_compile.py``). Only a *loaded* block pads to the
+    dtype's whole sublane tile."""
+    rows = c if c in (1, 2, 4) else -(-c // 8) * 8
+    lanes = -(-w // _LANES) * _LANES
+    page_bytes = page_size * rows * lanes * jnp.dtype(dtype).itemsize
+    b = max(1, min(_MAX_PAGES_PER_BLOCK, _STREAM_BLOCK_BYTES // page_bytes))
     return 1 << (b.bit_length() - 1)
+
+
+def _kv_head_strided(rows_ref, h: int):
+    """Keys and values of KV head ``h`` for every token of a block,
+    ``[N, D]`` each, by a strided load from ``rows_ref`` (``[N, 2*Hkv,
+    D]``, K at even and V at odd combined heads) seen as the dense 2-D
+    ``[N * 2*Hkv, D]``. Under bf16's sublane packing one 32-bit word of
+    that view is the pair (K_h[d], V_h[d]): K is ``word << 16`` and V
+    ``word & 0xFFFF0000`` read as float32 — exact, a bf16 is the top
+    half of a float32 — and narrowed back (``strided_load_kv`` of JAX's
+    bundled ragged paged attention). Any other dtype takes the plain
+    strided pair, rows ``2h`` and ``2h + 1`` of every ``2*Hkv``."""
+    n, c, d = rows_ref.shape
+    flat = rows_ref.reshape(n * c, d)
+    if rows_ref.dtype == jnp.bfloat16:
+        word = flat.bitcast(jnp.uint32)[pl.ds(h, n, stride=c // 2), :]
+        k = pltpu.bitcast(word << 16, jnp.float32)
+        v = pltpu.bitcast(word & jnp.uint32(0xFFFF0000), jnp.float32)
+        return k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    return (
+        flat[pl.ds(2 * h, n, stride=c), :],
+        flat[pl.ds(2 * h + 1, n, stride=c), :],
+    )
 
 
 def paged_decode_stream(
@@ -198,7 +223,7 @@ def paged_decode_stream(
     out_shapes: list,          # [(per-row block shape sans leading 1, dtype)]
     acc_shapes: list,          # [(shape, dtype)] VMEM accumulators
     init,                      # fn(accs, qs, outs) -> None
-    fold,                      # fn(accs, qs, outs, rows, base, kv_len) -> None
+    fold,                      # fn(accs, qs, outs, rows_ref, base, kv_len)
     finalize,                  # fn(accs, qs, outs, kv_len) -> None
     append: jax.Array | None = None,   # [S, C, W] rows (cache dtype)
     first_page=None,           # fn(kv_len) -> first page index (window clip)
@@ -212,12 +237,13 @@ def paged_decode_stream(
     one DMA a page started back to back, into one of two VMEM buffers:
     block ``b + 1``'s copies are started before block ``b`` is waited
     on and folded, so copies are in flight while ``fold`` computes.
-    ``fold`` receives ``rows``, the block's ``[B * page, C, W]`` tokens
-    from position ``base`` on, and masks what lies at or past
-    ``kv_len`` itself. A short last block re-reads the row's last
-    valid page into the slots it has no page for (their positions are
-    >= ``kv_len``, so the mask drops them): every slot of a buffer
-    holds real cache rows, at most ``B - 1`` pages a row are moved
+    ``fold`` receives ``rows_ref``, a VMEM *ref* to the block's
+    ``[B * page, C, W]`` tokens from position ``base`` on (it loads it
+    whole, ``rows_ref[...]``, or reads it in parts), and masks what
+    lies at or past ``kv_len`` itself. A short last block re-reads the
+    row's last valid page into the slots it has no page for (their
+    positions are >= ``kv_len``, so the mask drops them): every slot of
+    a buffer holds real cache rows, at most ``B - 1`` pages a row are moved
     twice, and no page-table entry past the last valid page is ever
     dereferenced.
 
@@ -375,7 +401,7 @@ def paged_decode_stream(
                 cp.wait()
             fold(
                 accs, qs, outs,
-                blocks[(first_buf + b) % 2],
+                blocks.at[(first_buf + b) % 2],
                 (start + b * bp) * page_size, n,
             )
             return carry
@@ -515,24 +541,22 @@ def gqa_fused_decode_pallas(
             l_ref[:] = jnp.zeros_like(l_ref)
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    def fold(accs, qs, outs, rows, base, n):
+    def fold(accs, qs, outs, rows_ref, base, n):
         m_ref, l_ref, o_ref = accs
         qrow = qs[0][0]                               # [Hq, D]
         pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, rows.shape[0]), 1
+            jnp.int32, (1, rows_ref.shape[0]), 1
         )
         valid = pos < n
         if sliding_window is not None:
             valid = jnp.logical_and(valid, pos >= n - sliding_window)
-        # One transposition a block puts every head's keys and values
-        # in whole tiles; slicing head by head out of [N, 2*Hkv, D]
-        # (one sublane of each token's tile) costs 1.3-3x as much as
-        # the dots it feeds (docs/kernels.md).
-        heads = jnp.swapaxes(rows, 0, 1)              # [2*Hkv, N, D]
+        # Each KV head's keys and values come straight out of the
+        # landed block, [N, D] each (docs/kernels.md, "The page stream").
+        heads = [_kv_head_strided(rows_ref, h) for h in range(num_kv_heads)]
         score_rows = []
         for h in range(num_kv_heads):
             qh = qrow[h * group:(h + 1) * group]
-            kh = heads[2 * h]                         # [N, D]
+            kh = heads[h][0]                          # [N, D]
             score_rows.append(jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -545,7 +569,7 @@ def gqa_fused_decode_pallas(
             out_rows = []
             for h in range(num_kv_heads):
                 ph = p[h * group:(h + 1) * group]
-                vh = heads[2 * h + 1]                 # [N, D]
+                vh = heads[h][1]                      # [N, D]
                 out_rows.append(jax.lax.dot_general(
                     ph.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -614,9 +638,9 @@ def mla_fused_decode_pallas(
         l_ref[:] = jnp.zeros_like(l_ref)
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    def fold(accs, qs, outs, rows, base, n):
+    def fold(accs, qs, outs, rows_ref, base, n):
         m_ref, l_ref, o_ref = accs
-        block_rows = rows[:, 0, :]                    # [N, W]
+        block_rows = rows_ref[...][:, 0, :]           # [N, W]
         latent = block_rows[:, :kv_lora_rank]
         rope = block_rows[:, kv_lora_rank:]
         ql = qs[0][0]                                 # [Hq, R]
@@ -632,7 +656,7 @@ def mla_fused_decode_pallas(
             )
         ) * sm_scale                                  # [Hq, N]
         pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, rows.shape[0]), 1
+            jnp.int32, (1, rows_ref.shape[0]), 1
         )
         valid = pos < n
 
@@ -707,8 +731,8 @@ def indexer_scores_fused_pallas(
     def init(accs, qs, outs):
         outs[0][...] = jnp.full((1, kv_pad), _NEG_INF, jnp.float32)
 
-    def fold(accs, qs, outs, rows, base, n):
-        keys = rows[:, 0, :]                          # [N, D]
+    def fold(accs, qs, outs, rows_ref, base, n):
+        keys = rows_ref[...][:, 0, :]                 # [N, D]
         dots = jax.lax.dot_general(
             qs[0][0], keys, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,

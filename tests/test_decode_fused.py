@@ -13,6 +13,7 @@ from parallax_tpu.config import normalize_config
 from parallax_tpu.models.base import StageModel
 from parallax_tpu.models.registry import create_stage_model
 from parallax_tpu.ops.attention import _ragged_paged_attention_xla
+from parallax_tpu.ops import decode_fused_pallas
 from parallax_tpu.ops.decode_fused_pallas import (
     decode_pages_per_block,
     fused_sample_topk_pallas,
@@ -37,6 +38,22 @@ PAGE = 8
 S = 6
 LENS = np.array([5, 17, 48, 0, 9, 16], np.int32)   # 48, 16: page-exact
 FROZEN_ROW = 4
+
+
+# The cache dtypes the engine allocates (``kv_dtype``): the GQA fold reads
+# a float32 block by a plain strided pair of loads and a bfloat16 one
+# through its packed 32-bit words; the float32 cases hold the kernel to
+# the oracle tightly, the bfloat16 ones to bf16's grain.
+CACHE_DTYPES = [jnp.float32, jnp.bfloat16]
+CACHE_DTYPE_IDS = ["f32", "bf16"]
+TOL = {
+    jnp.float32: dict(atol=2e-5, rtol=2e-5),
+    jnp.bfloat16: dict(atol=2e-2, rtol=2e-2),
+}
+
+
+def _f32(x) -> np.ndarray:
+    return np.asarray(x, np.float32)
 
 
 def _geometry(num_extra_pages: int = 0):
@@ -66,15 +83,16 @@ def _geometry(num_extra_pages: int = 0):
     [(None, False, None), (16, False, None), (None, True, None),
      (None, False, 30.0), (16, True, None)],
 )
-def test_gqa_fused_parity_and_append(window, sinks_on, cap):
+@pytest.mark.parametrize("dtype", CACHE_DTYPES, ids=CACHE_DTYPE_IDS)
+def test_gqa_fused_parity_and_append(window, sinks_on, cap, dtype):
     rng = np.random.default_rng(0)
     hq, hkv, d = 4, 2, 16
     num_pages, lens, pages, slot = _geometry()
-    q = jnp.asarray(rng.normal(size=(S, hq, d)), jnp.float32)
-    k_new = jnp.asarray(rng.normal(size=(S, hkv, d)), jnp.float32)
-    v_new = jnp.asarray(rng.normal(size=(S, hkv, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(S, hq, d)), dtype)
+    k_new = jnp.asarray(rng.normal(size=(S, hkv, d)), dtype)
+    v_new = jnp.asarray(rng.normal(size=(S, hkv, d)), dtype)
     cache = jnp.asarray(
-        rng.normal(size=(num_pages, PAGE, 2 * hkv, d)), jnp.float32
+        rng.normal(size=(num_pages, PAGE, 2 * hkv, d)), dtype
     )
     sinks = (
         jnp.asarray(rng.normal(size=(hq,)), jnp.float32)
@@ -95,12 +113,10 @@ def test_gqa_fused_parity_and_append(window, sinks_on, cap):
     )
     # KV-append fusion == the kv_cache_ops scatter, bit for bit
     # (including the skipped frozen/padding rows).
-    assert np.array_equal(np.asarray(cache_f), np.asarray(cache_ref))
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
-    )
+    assert np.array_equal(_f32(cache_f), _f32(cache_ref))
+    np.testing.assert_allclose(_f32(out), _f32(ref), **TOL[dtype])
     # Padding row outputs exact zeros.
-    assert np.all(np.asarray(out)[3] == 0.0)
+    assert np.all(_f32(out)[3] == 0.0)
 
 
 def test_mla_fused_parity_and_append():
@@ -229,6 +245,20 @@ def _poisoned(rng, shape, dtype=jnp.float32):
     return jnp.asarray(cache, dtype)
 
 
+EDGE_HEADS = [(16, 2), (28, 4), (32, 32), (7, 1)]
+EDGE_HEAD_IDS = ["16q2kv", "28q4kv", "32q32kv", "tp4-7q1kv"]
+
+
+def _edge_inputs(rng, hq, hkv, num_pages, dtype, d=16):
+    """(q, k_new, v_new, poisoned cache) for the EDGE_LENS rows."""
+    s = len(EDGE_LENS)
+    q = jnp.asarray(rng.normal(size=(s, hq, d)), dtype)
+    k_new = jnp.asarray(rng.normal(size=(s, hkv, d)), dtype)
+    v_new = jnp.asarray(rng.normal(size=(s, hkv, d)), dtype)
+    cache = _poisoned(rng, (num_pages, PAGE, 2 * hkv, d), dtype)
+    return q, k_new, v_new, cache
+
+
 def test_edge_rows_sit_on_the_block_edges_they_name():
     """The geometry above is only worth its name while B is what the
     core derives at these shapes."""
@@ -244,18 +274,14 @@ def test_edge_rows_sit_on_the_block_edges_they_name():
      (BLOCK + 3, True, 30.0)],     # window longer than one block
     ids=["plain", "window-mid-block", "sinks-cap", "window-sinks-cap"],
 )
-@pytest.mark.parametrize(
-    "hq,hkv", [(16, 2), (28, 4), (32, 32)], ids=["16q2kv", "28q4kv", "32q32kv"]
-)
-def test_gqa_fused_block_edges(hq, hkv, window, sinks_on, cap):
+@pytest.mark.parametrize("hq,hkv", EDGE_HEADS, ids=EDGE_HEAD_IDS)
+@pytest.mark.parametrize("dtype", CACHE_DTYPES, ids=CACHE_DTYPE_IDS)
+def test_gqa_fused_block_edges(dtype, hq, hkv, window, sinks_on, cap):
     rng = np.random.default_rng(7)
     d = 16
     num_pages, lens, pages, safe, slot = _edge_geometry()
     s = len(EDGE_LENS)
-    q = jnp.asarray(rng.normal(size=(s, hq, d)), jnp.float32)
-    k_new = jnp.asarray(rng.normal(size=(s, hkv, d)), jnp.float32)
-    v_new = jnp.asarray(rng.normal(size=(s, hkv, d)), jnp.float32)
-    cache = _poisoned(rng, (num_pages, PAGE, 2 * hkv, d))
+    q, k_new, v_new, cache = _edge_inputs(rng, hq, hkv, num_pages, dtype)
     sinks = (
         jnp.asarray(rng.normal(size=(hq,)), jnp.float32)
         if sinks_on else None
@@ -274,13 +300,50 @@ def test_gqa_fused_block_edges(hq, hkv, window, sinks_on, cap):
         sm_scale=d ** -0.5, sliding_window=window, soft_cap=cap,
         sinks=sinks,
     )
-    assert np.array_equal(
-        np.asarray(cache_f), np.asarray(cache_ref), equal_nan=True
-    )
-    out = np.asarray(out)
+    assert np.array_equal(_f32(cache_f), _f32(cache_ref), equal_nan=True)
+    out = _f32(out)
     assert np.all(np.isfinite(out)), "a page past the valid ones was read"
-    np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out, _f32(ref), **TOL[dtype])
     assert np.all(out[EDGE_LENS.index(0)] == 0.0)
+
+
+def _kv_head_transposed(rows_ref, h):
+    """The read the strided loads replaced (PR 29's), kept here as their
+    witness: the block loaded whole, its heads brought to the front."""
+    by_head = jnp.swapaxes(rows_ref[...], 0, 1)       # [2*Hkv, N, D]
+    return by_head[2 * h], by_head[2 * h + 1]
+
+
+@pytest.mark.parametrize("hq,hkv", EDGE_HEADS, ids=EDGE_HEAD_IDS)
+@pytest.mark.parametrize("dtype", CACHE_DTYPES, ids=CACHE_DTYPE_IDS)
+def test_gqa_strided_head_read_is_the_transposed_read(
+    monkeypatch, dtype, hq, hkv
+):
+    """The strided loads hand the two dots a head the very bits the
+    block's transposition did: same output, same cache, bit for bit,
+    over the block-edge rows with a window, sinks and a soft cap."""
+    rng = np.random.default_rng(11)
+    d = 16
+    num_pages, lens, pages, _, slot = _edge_geometry()
+    q, k_new, v_new, cache = _edge_inputs(rng, hq, hkv, num_pages, dtype)
+    sinks = jnp.asarray(rng.normal(size=(hq,)), jnp.float32)
+
+    def run():
+        # Unjitted: the fold looks its read up while it is traced.
+        return gqa_fused_decode_pallas.__wrapped__(
+            q, k_new, v_new, cache, lens, pages, slot, sinks,
+            sm_scale=d ** -0.5, sliding_window=BLOCK + 3, soft_cap=30.0,
+            use_sinks=True, interpret=True,
+        )
+
+    out_s, cache_s = run()
+    monkeypatch.setattr(
+        decode_fused_pallas, "_kv_head_strided", _kv_head_transposed
+    )
+    out_t, cache_t = run()
+    assert np.array_equal(_f32(out_s), _f32(out_t))
+    assert np.array_equal(_f32(cache_s), _f32(cache_t), equal_nan=True)
+    assert np.all(np.isfinite(_f32(out_s)))
 
 
 def test_gqa_fused_block_edges_at_two_pages_a_block():
@@ -602,8 +665,10 @@ def test_kernel_dispatch_summary_and_counter(gqa_model):
     assert summary["decode_pages_per_block"] == decode_pages_per_block(
         8, 4, 16, jnp.float32
     ) == 8
-    off = _run_engine(model, params, fused=False, lookahead=8)[1]
-    assert off.kernel_dispatch_summary()["decode_pages_per_block"] is None
+    off = _run_engine(
+        model, params, fused=False, lookahead=8
+    )[1].kernel_dispatch_summary()
+    assert off["decode_pages_per_block"] is None
     assert any(k.startswith("pallas-fused/") for k in
                summary["dispatch_total"])
     # The registry counter carries the same series for /metrics.

@@ -78,20 +78,28 @@ def _compile(fn, *shapes):
     return text
 
 
-def _batch(dev, hq, hkv, t, s, pages_per_seq=PAGES_PER_SEQ):
+def _batch(dev, hq, hkv, t, s, pages_per_seq=PAGES_PER_SEQ,
+           dtype=jnp.bfloat16):
     def a(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
     return dict(
-        q=a((t, hq, HEAD_DIM), jnp.bfloat16),
-        k=a((t, hkv, HEAD_DIM), jnp.bfloat16),
-        v=a((t, hkv, HEAD_DIM), jnp.bfloat16),
-        cache=a((NUM_PAGES, PAGE, 2 * hkv, HEAD_DIM), jnp.bfloat16),
+        q=a((t, hq, HEAD_DIM), dtype),
+        k=a((t, hkv, HEAD_DIM), dtype),
+        v=a((t, hkv, HEAD_DIM), dtype),
+        cache=a((NUM_PAGES, PAGE, 2 * hkv, HEAD_DIM), dtype),
         kv_lens=a((s,), jnp.int32),
         pages=a((s, pages_per_seq), jnp.int32),
         cu=a((s + 1,), jnp.int32),
         nseq=a((1,), jnp.int32),
         slots=a((t,), jnp.int32),
+    )
+
+
+def _decode(q, k, v, cache, lens, pages, slots):
+    return gqa_fused_decode_pallas(
+        q, k, v, cache, lens, pages, slots, None,
+        sm_scale=HEAD_DIM ** -0.5, interpret=False,
     )
 
 
@@ -105,13 +113,61 @@ def test_fused_decode_compiles_for_v5e(v5e, hq, hkv, s, pages_per_seq):
     scoped VMEM, at the shapes the benchmark's Qwen cells run."""
     b = _batch(v5e, hq, hkv, s, s, pages_per_seq)
     _compile(
-        lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
-            q, k, v, cache, lens, pages, slots, None,
-            sm_scale=HEAD_DIM ** -0.5, interpret=False,
-        ),
+        _decode,
         b["q"], b["k"], b["v"], b["cache"], b["kv_lens"], b["pages"],
         b["slots"],
     )
+
+
+@pytest.mark.parametrize("hq,hkv", DECODE_HEADS, ids=DECODE_HEAD_IDS)
+def test_fused_decode_compiles_for_v5e_with_a_float32_cache(v5e, hq, hkv):
+    """``kv_dtype`` float32: the fold's plain strided pair of loads a
+    head (no packed words to split) lowers too."""
+    b = _batch(v5e, hq, hkv, 8, 8, dtype=jnp.float32)
+    _compile(
+        _decode, b["q"], b["k"], b["v"], b["cache"], b["kv_lens"],
+        b["pages"], b["slots"],
+    )
+
+
+@pytest.mark.parametrize(
+    "hq,hkv,dtype,fits,refused",
+    [(7, 1, jnp.bfloat16, 192, 288), (16, 2, jnp.bfloat16, 96, 144),
+     (12, 12, jnp.bfloat16, 16, 24), (9, 3, jnp.float32, 24, 36)],
+    ids=["c2-bf16", "c4-bf16", "c24-bf16", "c6-f32-pads-to-8"],
+)
+def test_stream_block_buffers_take_the_vmem_the_block_size_counts(
+    v5e, monkeypatch, hq, hkv, dtype, fits, refused
+):
+    """What ``decode_pages_per_block`` counts a page at, held from both
+    sides against the 16 MB of scoped VMEM: two buffers of ``fits``
+    pages are 12 MB by its rule and compile, two of ``refused`` pages
+    18 MB and do not. A ``[N, 4, 128]`` bf16 buffer takes its own bytes
+    (padded to bf16's 16-sublane tile it would take four times as
+    many), 24 bf16 rows stay 24 (not 32), 6 float32 rows take 8."""
+    from parallax_tpu.ops import decode_fused_pallas
+
+    b = _batch(v5e, hq, hkv, 8, 8, dtype=dtype)
+
+    def compile_at(pages_per_block):
+        monkeypatch.setattr(
+            decode_fused_pallas, "decode_pages_per_block",
+            lambda *_: pages_per_block,
+        )
+        # Unjitted and a new function each time: the block size is read
+        # while the kernel is traced, and jit would hand back the trace
+        # of another block size.
+        _compile(
+            lambda *args: gqa_fused_decode_pallas.__wrapped__(
+                *args, None, sm_scale=HEAD_DIM ** -0.5, interpret=False,
+            ),
+            b["q"], b["k"], b["v"], b["cache"], b["kv_lens"], b["pages"],
+            b["slots"],
+        )
+
+    compile_at(fits)
+    with pytest.raises(Exception, match="exceeded scoped vmem limit"):
+        compile_at(refused)
 
 
 @pytest.mark.parametrize("t,s", [(256, 8), (2048, 64)])
@@ -182,26 +238,31 @@ def _evabyte_batch(dev, t, s):
 def test_fused_decode_compiles_for_v5e_at_32_kv_heads(v5e, s):
     b = _evabyte_batch(v5e, s, s)
     _compile(
-        lambda q, k, v, cache, lens, pages, slots: gqa_fused_decode_pallas(
-            q, k, v, cache, lens, pages, slots, None,
-            sm_scale=HEAD_DIM ** -0.5, interpret=False,
-        ),
+        _decode,
         b["q"], b["q"], b["q"], b["cache"], b["kv_lens"], b["pages"],
         b["slots"],
     )
 
 
 @pytest.mark.parametrize(
-    "hkv,want", [(2, 8), (4, 8), (32, 2)], ids=["3b", "7b", "evabyte"]
+    "page,hkv,dtype,want",
+    [(PAGE, 2, jnp.bfloat16, 8), (PAGE, 4, jnp.bfloat16, 8),
+     (PAGE, 32, jnp.bfloat16, 2), (80, 12, jnp.bfloat16, 4),
+     (100, 5, jnp.float32, 2)],
+    ids=["3b", "7b", "evabyte", "c24-bf16-stays-24", "c10-f32-pads-to-16"],
 )
-def test_decode_pages_per_block_at_the_cells_page_shapes(hkv, want):
-    """The page stream's block, derived from the page as VMEM holds it
-    (2 * Hkv rows pad to bf16's 16-sublane tile): 8 pages at the 3B's
-    64 KB and the 7B's 128 KB pages, 2 at EvaByte's 1 MB — what the
-    compiles above and at 32 KV heads prove inside scoped VMEM."""
-    assert decode_pages_per_block(
-        PAGE, 2 * hkv, HEAD_DIM, jnp.bfloat16
-    ) == want
+def test_decode_pages_per_block_at_the_cells_page_shapes(
+    page, hkv, dtype, want
+):
+    """The page stream's block, derived from the bytes VMEM holds a
+    page in (the test of the block buffers above): 8 pages, the cap, at
+    the 3B's 64 KB and the 7B's 128 KB pages, 2 at EvaByte's 1 MB —
+    what the compiles above and at 32 KV heads prove inside scoped
+    VMEM. Off the cells' shapes: 24 bf16 rows count as 24 (a page of 80
+    tokens is 480 KB, 4 a block; at bf16's tile of 16 it would be 640
+    KB and 2), 10 float32 rows as 16 (100 tokens are 800 KB, 2 a block;
+    dense they would be 500 KB and 4)."""
+    assert decode_pages_per_block(page, 2 * hkv, HEAD_DIM, dtype) == want
 
 
 @pytest.mark.parametrize("t", [256, 2048])
@@ -325,10 +386,11 @@ def _refused_indexer(dev, kind, fused):
 _TILE_8_128 = "last two dimensions of your block shape are divisible by 8"
 REFUSED = {
     # id: (kernel and shapes, what the compiler says)
+    # The decode fold's strided load wants 128 lanes (the prefill
+    # kernel at this head_dim is refused for its 64-lane slice).
     "fused-gqa-head-dim-64": (
         _refused_fused_gqa_head_dim_64,
-        r"Slice shape along dimension 2 must be aligned to tiling \(128\), "
-        "but is 64",
+        "The last dim size is not 128 in original base memref",
     ),
     "fused-mla": (
         _refused_fused_mla,
