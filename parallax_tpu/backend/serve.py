@@ -7,6 +7,7 @@ share the process; a runner thread steps the pipeline continuously.
 
 from __future__ import annotations
 
+import collections
 import gc
 import threading
 import time
@@ -17,7 +18,7 @@ from parallax_tpu.backend.http_server import (
     load_tokenizer,
 )
 from parallax_tpu.obs import names as mnames
-from parallax_tpu.obs.registry import get_registry
+from parallax_tpu.obs.registry import DEFAULT_COUNT_BUCKETS, get_registry
 from parallax_tpu.obs.trace import host_span
 from parallax_tpu.runtime.engine import (
     EngineConfig,
@@ -27,14 +28,25 @@ from parallax_tpu.runtime.engine import (
 from parallax_tpu.runtime.pipeline import InProcessPipeline
 from parallax_tpu.runtime.request import Request
 from parallax_tpu.utils import get_logger
-from parallax_tpu.analysis.sanitizer import make_lock
 
 logger = get_logger(__name__)
 
 
+# What the idle loop waits for at most before it looks again: it wakes
+# at once for an arrival or ``stop()``; the time-out only keeps the
+# watchdog's beat coming (its poll interval is 1 s).
+IDLE_WAIT_S = 0.5
+
+
 class LocalRunner:
     """Steps an in-process pipeline on a background thread and completes
-    per-request events."""
+    per-request events.
+
+    The loop's thread is the pipeline's only owner: ``submit`` and
+    ``stop_request`` put an entry on the inbox and return, and the loop
+    takes the entries in at the top of every round. No other thread
+    calls ``pipeline.submit``, ``head.stop_request`` or ``step_round``,
+    and nothing here takes a lock that is held while a round runs."""
 
     def __init__(self, pipeline: InProcessPipeline, watchdog=None):
         self.pipeline = pipeline
@@ -48,12 +60,23 @@ class LocalRunner:
         # the HTTP server so the process exits non-zero — is called once.
         self.failure: BaseException | None = None
         self.on_failure = None
-        # request id -> (request, completion event) of unfinished work.
+        # request id -> (request, completion event) of unfinished work
+        # the loop has taken in. The loop's own (and ``stop()``'s once
+        # the loop has ended).
         self._pending: dict[str, tuple[Request, threading.Event]] = {}
-        self._lock = make_lock("backend.serve")
+        # ``(request, event)`` of a submit, a request id of a stop: put
+        # by any thread, taken by the loop (``deque.append`` and
+        # ``popleft`` are atomic). ``_arrival`` wakes the idle loop.
+        self._inbox: collections.deque = collections.deque()
+        self._arrival = threading.Event()
         self._stop = threading.Event()
-        self._h_loop_gap = get_registry().histogram(
+        reg = get_registry()
+        self._h_loop_gap = reg.histogram(
             mnames.LOOP_GAP_MS, mnames.help_text(mnames.LOOP_GAP_MS)
+        )
+        self._h_inbox_drained = reg.histogram(
+            mnames.INBOX_DRAINED, mnames.help_text(mnames.INBOX_DRAINED),
+            buckets=DEFAULT_COUNT_BUCKETS,
         )
         # Programs the engines had built when the heap was last settled.
         self._settled_programs = 0
@@ -92,41 +115,102 @@ class LocalRunner:
 
     def stop(self) -> None:
         self._stop.set()
+        self._arrival.set()
         self._thread.join(timeout=3.0)
         # The loop keeps one step in flight; a loop that has ended
-        # leaves none (a failed loop discarded its own in ``_fail``).
+        # leaves none (a failed loop discarded its own in ``_fail``),
+        # and with it ended this thread is the pipeline's only one.
         if not self._thread.is_alive() and self.failure is None:
-            with self._lock:
-                try:
-                    self._wake(self.pipeline.settle())
-                except Exception as e:
-                    self._fail(e)
+            try:
+                self._wake(self.pipeline.settle())
+            except Exception as e:
+                self._fail(e)
+        self._refuse_inbox("server stopped")
 
     def submit(self, request: Request) -> threading.Event:
+        """Hand a request to the loop; returns at once with the event
+        its finish sets. What can be refused without the loop's state
+        is refused here, as ``StageEngine.submit`` and the scheduler
+        would (the same exceptions, so the same HTTP answers): a failed
+        loop, a prompt the engine's configuration rules out, a full
+        wait queue (counted with what the inbox still holds)."""
         ev = threading.Event()
-        # The frontend's thread, the wait for the runner's lock included.
-        with host_span("http.submit"), self._lock:
-            if self.failure is not None:
-                raise BackendUnavailable(
-                    f"step loop failed: {self.failure!r}"
-                )
-            self._pending[request.request_id] = (request, ev)
-            if not self.pipeline.submit(request):
-                self._pending.pop(request.request_id, None)
+        with host_span("http.submit"):
+            self._refuse_if_failed()
+            head = self.pipeline.head
+            head.check_prompt(request)
+            sched = head.scheduler
+            if (len(self._inbox) + len(sched.wait_queue)
+                    >= sched.max_queue_size):
                 raise RuntimeError("engine queue full")
+            self._post((request, ev))
+            # A loop that died between the check and the post may have
+            # swept the inbox already (``_fail``).
+            self._refuse_if_failed()
         return ev
 
+    def _refuse_if_failed(self) -> None:
+        if self.failure is not None:
+            reason = f"step loop failed: {self.failure!r}"
+            self._refuse_inbox(reason)
+            raise BackendUnavailable(reason)
+
     def stop_request(self, request_id: str) -> None:
-        """Gracefully finish a request early (stop-string match): the next
-        step round collects and releases it."""
-        with self._lock:
-            self.pipeline.head.stop_request(request_id)
+        """Gracefully finish a request early (stop-string match): the
+        loop's next round marks it, collects and releases it."""
+        self._post(request_id)
+
+    def _post(self, entry) -> None:
+        self._inbox.append(entry)
+        self._arrival.set()
+
+    def _take_inbox(self):
+        """The inbox's entries in arrival order, until it is empty
+        (whoever else takes from it meanwhile: each entry goes once)."""
+        while True:
+            try:
+                yield self._inbox.popleft()
+            except IndexError:
+                return
+
+    def _drain_inbox(self) -> None:
+        """The loop's thread, top of a round: take in what arrived (a
+        stop behind its own submit finds the request in the wait
+        queue)."""
+        drained = 0
+        for entry in self._take_inbox():
+            drained += 1
+            if isinstance(entry, str):
+                self.pipeline.head.stop_request(entry)
+                continue
+            request, ev = entry
+            # Registered first: a submit that raises fails the loop, and
+            # ``_fail`` wakes what is registered.
+            self._pending[request.request_id] = entry
+            if not self.pipeline.submit(request):
+                # Two submits passed the count together at the last
+                # free place: the later one learns it here.
+                del self._pending[request.request_id]
+                request.abort("engine queue full")
+                ev.set()
+        if drained:
+            self._h_inbox_drained.observe(drained)
+
+    def _refuse_inbox(self, reason: str) -> None:
+        """No loop will take the inbox's entries in: wake the waiters of
+        its submits with an aborted request."""
+        for entry in self._take_inbox():
+            if not isinstance(entry, str):
+                request, ev = entry
+                if not request.status.is_finished:
+                    request.abort(reason)
+                ev.set()
 
     def _loop(self) -> None:
         # ``runner.loop_gap``: from the end of one step round to the
-        # start of the next (waking finished requests, releasing the
-        # lock, the watchdog's beat, ``has_work``, the wait for the
-        # lock). It ends where the loop finds no work instead.
+        # start of the next (waking finished requests, the watchdog's
+        # beat, the inbox, ``has_work``). It ends where the loop finds
+        # no work instead.
         gap = None
 
         def end_gap():
@@ -138,25 +222,28 @@ class LocalRunner:
         while not self._stop.is_set():
             if self.watchdog is not None:
                 self.watchdog.beat("step_loop")
-            if not self.pipeline.has_work():
+            # Cleared before the inbox is read: an entry put after this
+            # sets it again, and the idle wait below returns at once.
+            self._arrival.clear()
+            try:
+                self._drain_inbox()
+                if not self.pipeline.has_work():
+                    end_gap()
+                    self._settle_heap()
+                    with host_span("runner.idle"):
+                        self._arrival.wait(IDLE_WAIT_S)
+                    continue
                 end_gap()
-                self._settle_heap()
-                with host_span("runner.idle"):
-                    self._stop.wait(0.002)
-                continue
-            with self._lock:
-                end_gap()
-                try:
-                    with host_span("runner.step_round"):
-                        finished = self.pipeline.step_round()
-                except Exception as e:
-                    self._fail(e)
-                    break
-                gap = host_span(
-                    "runner.loop_gap", self._h_loop_gap,
-                    visit=self.pipeline.visits,
-                ).__enter__()
-                self._wake(finished)
+                with host_span("runner.step_round"):
+                    finished = self.pipeline.step_round()
+            except Exception as e:
+                self._fail(e)
+                break
+            gap = host_span(
+                "runner.loop_gap", self._h_loop_gap,
+                visit=self.pipeline.visits,
+            ).__enter__()
+            self._wake(finished)
         end_gap()
         if self.failure is not None and self.on_failure is not None:
             self.on_failure(self.failure)
@@ -169,10 +256,11 @@ class LocalRunner:
                 ev.set()
 
     def _fail(self, exc: BaseException) -> None:
-        """The step loop died (lock held): record why and release every
-        waiter with an aborted request, so HTTP handlers answer 5xx
-        instead of waiting out their timeout against a dead thread. The
-        step the loop kept in flight is discarded with it."""
+        """The step loop died (its own thread, or ``stop()``'s after it
+        ended): record why and release every waiter with an aborted
+        request, so HTTP handlers answer 5xx instead of waiting out
+        their timeout against a dead thread. The step the loop kept in
+        flight is discarded with it."""
         logger.error("step loop failed; failing %d pending request(s)",
                      len(self._pending), exc_info=exc)
         self.failure = exc
@@ -186,6 +274,7 @@ class LocalRunner:
                 req.abort(reason)
             ev.set()
         self._pending.clear()
+        self._refuse_inbox(reason)
 
     def health(self) -> dict | None:
         """``/healthz`` component for the step loop: None while it runs."""

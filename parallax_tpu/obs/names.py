@@ -45,6 +45,7 @@ VISIT_COMMIT_MS = "parallax_visit_commit_ms"
 VISIT_WINDOW_AHEAD = "parallax_visit_window_ahead"
 LOOP_GAP_MS = "parallax_loop_gap_ms"
 ADMIT_WAIT_MS = "parallax_admit_wait_ms"
+INBOX_DRAINED = "parallax_inbox_drained"
 
 # -- EVA attention (runtime/engine.py, runtime/cache_manager.py) -----------
 EVA_ROLLOVER_MS = "parallax_eva_rollover_ms"
@@ -224,13 +225,17 @@ HELP: dict[str, str] = {
         "sum/count is the share of windows ahead"
     ),
     LOOP_GAP_MS: (
-        "Milliseconds between the end of one locked step round of the "
+        "Milliseconds between the end of one step round of the "
         "single-host step loop and the start of the next; span "
         "parallax.runner.loop_gap"
     ),
     ADMIT_WAIT_MS: (
         "Milliseconds from a request's arrival at the frontend to its "
         "first appearance in a plan of the head stage"
+    ),
+    INBOX_DRAINED: (
+        "Entries (submits and stops) the single-host step loop took "
+        "from its inbox at the top of a round, per round that took any"
     ),
     ATTN_KERNEL_DISPATCH_TOTAL: (
         "Engine dispatches by attention kernel implementation"

@@ -292,9 +292,11 @@ class CacheManager:
     def _reclaim(self, need: int) -> bool:
         """Free pages from the prefix cache until ``need`` are available.
 
-        With a host tier attached, evicted pages demote into it (batched
-        D2H) instead of losing their KV; prefix reuse then extends past
-        HBM capacity."""
+        With a host tier attached, evicted pages demote into it instead
+        of losing their KV; prefix reuse then extends past HBM capacity.
+        This runs inside the scheduler's plan, so the demotion only
+        enqueues its copy (``HostKVTier.demote``); the engine settles
+        it after the next read-back."""
         if self.allocator.num_free >= need:
             return True
         deficit = need - self.allocator.num_free
@@ -547,6 +549,10 @@ class CacheManager:
         handles = self.host_tier.demote(owned, pinned=True)
         if handles is None:
             return False
+        # The one demotion that takes its bytes at once: the image is
+        # what the row resumes (or migrates) from, so it is whole on the
+        # host before the row's pages go back.
+        self.host_tier.settle()
         request.host_page_handles = handles  # type: ignore[attr-defined]
         self.allocator.free(owned)
         del request.page_ids[num_shared:]

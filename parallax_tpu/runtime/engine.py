@@ -1461,9 +1461,10 @@ class StageEngine:
 
     # -- intake -----------------------------------------------------------
 
-    def submit(self, request: Request) -> bool:
-        """Head node: accept a fresh user request."""
-        assert self.model.is_first, "submit() is for the head stage"
+    def check_prompt(self, request: Request) -> None:
+        """Raise ``ValueError`` for a prompt this engine's configuration
+        rules out. Reads nothing that changes after construction, so a
+        frontend thread may call it beside a running step."""
         if not request.prompt_ids:
             raise ValueError("prompt must contain at least one token")
         if request.num_prompt_tokens >= self.cfg.max_model_len:
@@ -1471,6 +1472,11 @@ class StageEngine:
                 f"prompt length {request.num_prompt_tokens} exceeds "
                 f"max_model_len {self.cfg.max_model_len}"
             )
+
+    def submit(self, request: Request) -> bool:
+        """Head node: accept a fresh user request."""
+        assert self.model.is_first, "submit() is for the head stage"
+        self.check_prompt(request)
         # Clamp generation to the context budget so oversized max_tokens
         # finish at the length limit instead of dying on KV exhaustion.
         # A resumed request's prompt already holds ``output_offset``
@@ -4756,6 +4762,15 @@ class StageEngine:
         """Phase 2: block on the ticket's device outputs, sample/verify,
         emit tokens or hidden states, and advance finish bookkeeping.
         Tickets must resolve in dispatch order."""
+        out = self._resolve(ticket)
+        if self.host_tier is not None:
+            # Evictions of the plans formed since the last read-back
+            # enqueued their gathers behind the step just read (or an
+            # earlier one): their copies are done or nearly.
+            self.host_tier.settle()
+        return out
+
+    def _resolve(self, ticket: StepTicket) -> StepOutputs:
         if ticket in self._inflight:
             self._inflight.remove(ticket)
         if ticket.outputs is not None:

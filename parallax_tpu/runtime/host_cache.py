@@ -24,8 +24,9 @@ output corruption, so the only way out of the pool for those is
 Device transfers are injected (``gather_fn``/``scatter_fn``) so the
 bookkeeping is testable without an accelerator; the engine wires jitted
 implementations built on ``ops/kv_cache_ops.py`` (gather_pages /
-scatter_pages) whose D2H copies start asynchronously and overlap the
-in-flight step.
+scatter_pages). A demotion only *enqueues* its gather and starts the
+D2H copies; the bytes are taken later (:meth:`HostKVTier.settle`),
+where the step loop would not otherwise wait.
 """
 
 from __future__ import annotations
@@ -35,9 +36,14 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from parallax_tpu.obs.trace import host_span
 from parallax_tpu.utils import get_logger
 
 logger = get_logger(__name__)
+
+# Payload of a handle whose bytes are still on their way from the
+# device (``HostKVTier.demote`` reserved it, ``settle`` fills it).
+_IN_FLIGHT = object()
 
 
 class HostPagePool:
@@ -112,6 +118,13 @@ class HostPagePool:
             self._pinned.add(h)
         return h
 
+    def fill(self, handle: int, data) -> None:
+        """Give a reserved handle its payload, unless the pool shed or
+        freed it meanwhile (its place in the LRU order is the
+        reservation's)."""
+        if handle in self._pages:
+            self._pages[handle] = data
+
     def load(self, handle: int):
         """Read a page's payload (touches LRU recency)."""
         data = self._pages[handle]
@@ -132,25 +145,31 @@ class HostPagePool:
 class HostKVTier:
     """Device<->host page movement over a :class:`HostPagePool`.
 
-    ``gather_fn(page_ids) -> [per-layer np.ndarray with leading dim n]``
-    reads device pages to host (the engine's implementation batches the
-    gather into one staging buffer per layer and starts the D2H copy
-    asynchronously); ``scatter_fn(page_ids, layers)`` writes host pages
-    back into device pages. One handle = one page's KV across every
-    local attention layer.
+    ``gather_fn(page_ids) -> [staged array [layers, >= n, ...]]``
+    enqueues the read of device pages into staging buffers and *starts*
+    their copies to the host; it must not wait for them (materializing
+    a staged array is the wait, and :meth:`settle` is the one place
+    that does). Each staged array holds some consecutive layers on its
+    leading axis; one after the other they are the layers in order.
+    ``scatter_fn(page_ids, layers)`` writes host pages back into device
+    pages. One handle = one page's KV across every local attention
+    layer.
     """
 
     def __init__(
         self,
         budget_bytes: int,
         page_nbytes: int,
-        gather_fn: Callable[[list[int]], list[np.ndarray]],
+        gather_fn: Callable[[list[int]], list],
         scatter_fn: Callable[[list[int], list[np.ndarray]], None],
         low_watermark: float = 0.85,
     ):
         self.pool = HostPagePool(budget_bytes, page_nbytes, low_watermark)
         self._gather = gather_fn
         self._scatter = scatter_fn
+        # Demotions whose bytes have not been taken yet, oldest first:
+        # (staged arrays, the handles reserved for their rows).
+        self._unsettled: list[tuple[list, list[int]]] = []
         self.pages_demoted = 0
         self.pages_swapped_in = 0
 
@@ -175,7 +194,17 @@ class HostKVTier:
         pinned: bool = False,
         partial: bool = False,
     ) -> list[int] | None:
-        """Copy device pages to host; returns their handles.
+        """Start copying device pages to host; returns their handles.
+
+        Returns without waiting for the device: the handles are
+        reserved in the pool (they hold their room and their place in
+        the LRU order from now on), the gather is enqueued and its
+        copies started, and the caller may hand the device pages back to
+        the allocator at once — whatever overwrites them is enqueued
+        after the gather, so the device's own order protects the read.
+        :meth:`settle` takes the bytes. A handle the pool sheds before
+        that (``evict_cb``, as for any other) is simply never filled.
+        ``pages_demoted`` counts here: pages handed to the tier.
 
         All-or-nothing by default: None (no side effects beyond pool
         eviction) when the tier cannot hold every page — a preempted
@@ -195,15 +224,32 @@ class HostKVTier:
         fit = min(want, self.pool.num_free)
         if fit <= 0:
             return [None] * n if partial else None
-        kept = list(page_ids[n - fit:])
-        layers = self._gather(kept)
-        handles: list[int | None] = [None] * (n - fit)
-        for j in range(fit):
-            handles.append(self.pool.store(
-                tuple(layer[j] for layer in layers), pinned=pinned
-            ))
+        with host_span("cache.demote_enqueue", pages=fit):
+            staged = self._gather(list(page_ids[n - fit:]))
+            handles = [
+                self.pool.store(_IN_FLIGHT, pinned=pinned)
+                for _ in range(fit)
+            ]
+            self._unsettled.append((staged, handles))
         self.pages_demoted += fit
-        return handles
+        return [None] * (n - fit) + handles
+
+    def settle(self) -> None:
+        """Take the bytes of every demotion still in flight into the
+        handles reserved for them (the blocking device-to-host read).
+        The engine calls it after the read-back of the step the gathers
+        were enqueued behind, when the copies are done or nearly;
+        :meth:`promote` and a preemption, which need the bytes now, call
+        it themselves."""
+        if not self._unsettled:
+            return
+        batches, self._unsettled = self._unsettled, []
+        with host_span("cache.demote_settle",
+                       pages=sum(len(h) for _, h in batches)):
+            for staged, handles in batches:
+                layers = [layer for s in staged for layer in np.asarray(s)]
+                for j, h in enumerate(handles):
+                    self.pool.fill(h, tuple(layer[j] for layer in layers))
 
     def promote(
         self, handles: Sequence[int], device_page_ids: Sequence[int]
@@ -213,6 +259,11 @@ class HostKVTier:
         if not handles:
             return
         datas = [self.pool.load(h) for h in handles]
+        if any(d is _IN_FLIGHT for d in datas):
+            # Demoted and matched again before any read-back came
+            # between: the swap-in needs the bytes now.
+            self.settle()
+            datas = [self.pool.load(h) for h in handles]
         layers = [
             np.stack([d[i] for d in datas])
             for i in range(len(datas[0]))
@@ -270,13 +321,17 @@ def tier_from_paged_kv(
     the KV layout is unsupported (hybrid linear-state tuples, sharded
     leaves without ``nbytes``) or the budget is below one page.
 
-    The gather enqueues ONE jitted slice per layer (``gather_pages``)
-    and starts the D2H copies asynchronously before materializing.
-    Note the gather reads the live KV list, which after a dispatch is
-    the in-flight step's *output* buffers — so a demotion triggered
-    while a step is in flight waits for that step before the copies can
-    start (device-ordered correctness; the async start only overlaps
-    the per-layer copies with each other). The swap-in is a jitted
+    The gather enqueues ONE jitted program (``gather_pages`` of every
+    layer, consecutive layers of one shape stacked into one staging
+    array: a dense model's 24 layers are one output and one D2H copy,
+    0.6 ms of host time a call on a v5e where 24 outputs, 24 copies and
+    a device array of ids took 8.7; PERF.md, PR 43), starts the copies
+    and returns the staged device arrays. It
+    reads the live KV list, which after a dispatch is the in-flight
+    step's *output* buffers: on the device the gather runs after that
+    step and before whatever is enqueued next, which is all the order
+    a demotion needs, so the host does not wait for either
+    (:meth:`HostKVTier.settle` does, later). The swap-in is a jitted
     donated scatter (``scatter_pages``).
     """
     import jax
@@ -296,8 +351,19 @@ def tier_from_paged_kv(
     if budget_bytes < page_nbytes:
         return None
 
+    # Runs of consecutive layers with one page shape and dtype.
+    runs: list[list[int]] = []
+    for i, a in enumerate(kv_arrays):
+        last = kv_arrays[runs[-1][-1]] if runs else None
+        if last is not None and (a.shape, a.dtype) == (last.shape, last.dtype):
+            runs[-1].append(i)
+        else:
+            runs.append([i])
     _jit_gather = jax.jit(
-        lambda kv, ids: [gather_pages(layer, ids) for layer in kv]
+        lambda kv, ids: [
+            jnp.stack([gather_pages(kv[i], ids) for i in run])
+            for run in runs
+        ]
     )
     _jit_scatter = jax.jit(
         lambda kv, ids, datas: [
@@ -318,16 +384,13 @@ def tier_from_paged_kv(
         ids[: len(page_ids)] = page_ids
         return ids
 
-    def gather_fn(page_ids: list[int]) -> list[np.ndarray]:
-        ids = _bucket_ids(page_ids)
-        staged = _jit_gather(get_kv(), jnp.asarray(ids))
+    def gather_fn(page_ids: list[int]) -> list:
+        # The ids go in as they are: the jit call places a numpy
+        # argument itself, far cheaper than a device array made first.
+        staged = _jit_gather(get_kv(), _bucket_ids(page_ids))
         for s in staged:
-            # Start every layer's D2H before materializing any of them,
-            # so the per-layer copies overlap each other (they still
-            # order after the in-flight step that produced these
-            # buffers).
             s.copy_to_host_async()
-        return [np.asarray(s)[: len(page_ids)] for s in staged]
+        return staged
 
     def scatter_fn(page_ids: list[int], layers: list[np.ndarray]) -> None:
         n = len(page_ids)
@@ -338,7 +401,7 @@ def tier_from_paged_kv(
                 pad = np.repeat(data[:1], ids.shape[0] - n, axis=0)
                 data = np.concatenate([data, pad], axis=0)
             padded.append(data)
-        set_kv(_jit_scatter(get_kv(), jnp.asarray(ids), padded))
+        set_kv(_jit_scatter(get_kv(), ids, padded))
 
     return HostKVTier(
         budget_bytes, page_nbytes, gather_fn, scatter_fn, low_watermark

@@ -140,20 +140,36 @@ def partial_demoter(tier):
     return lambda ids: tier.demote(ids, partial=True)
 
 
+class Staged:
+    """A staged 'device' array of the fake tier: it holds what the
+    device held when the gather was enqueued (the device's order), and
+    ``np.asarray`` of it — the blocking read — is noted in ``reads``."""
+
+    def __init__(self, data, reads):
+        self._data, self._reads = data, reads
+
+    def __array__(self, dtype=None, copy=None):
+        self._reads.append(self._data.shape[1])
+        return self._data
+
+
 def make_cm(host_pages=8, num_pages=PAGES):
-    """CacheManager over a numpy 'device' (one layer, 2 floats/token)."""
+    """CacheManager over a numpy 'device' (one layer, 2 floats/token).
+    ``tier.reads``: pages of every staged array materialised so far."""
     dev = np.arange(num_pages * PAGE * 2, dtype=np.float32).reshape(
         num_pages, PAGE * 2
     )
+    reads = []
 
     def gather(ids):
-        return [dev[np.asarray(ids)].copy()]
+        return [Staged(dev[np.asarray(ids)].copy()[None], reads)]
 
     def scatter(ids, layers):
         dev[np.asarray(ids)] = layers[0]
 
     nbytes = dev[0].nbytes
     tier = HostKVTier(host_pages * nbytes, nbytes, gather, scatter)
+    tier.reads = reads
     cm = CacheManager(page_size=PAGE, num_pages=num_pages, host_tier=tier)
     return cm, tier, dev
 
@@ -285,6 +301,137 @@ class TestRadixHostTier:
         assert cm.prefix_cache.num_host_pages == 0
 
 
+class TestDemotionEnqueuesAndSettles:
+    """An eviction's demotion returns before its bytes are read; they
+    are taken by ``settle`` (the engine, after a read-back) or by
+    whoever needs them first."""
+
+    def _cached(self, cm, n_tokens=12, rid="r1"):
+        req = Request(rid, prompt_ids=list(range(n_tokens)))
+        assert cm.allocate_for_prompt(req)
+        pages = list(req.page_ids)
+        finish(cm, req)
+        return pages
+
+    def test_partial_demote_returns_before_anything_is_materialised(self):
+        cm, tier, _dev = make_cm()
+        self._cached(cm)
+        freed = cm.prefix_cache.evict(3, demoter=partial_demoter(tier))
+        assert len(freed) == 3 and tier.reads == []
+        # Handed to the tier, counted and holding their room already.
+        assert tier.pages_demoted == 3 and tier.num_host_pages == 3
+        assert cm.prefix_cache.num_host_pages == 3
+        tier.settle()
+        assert tier.reads == [3]
+        tier.settle()                       # nothing left in flight
+        assert tier.reads == [3]
+
+    def test_reclaim_inside_a_plan_reads_nothing(self):
+        """The path the scheduler's plan takes: ``_reclaim`` under an
+        admission frees the pages at once and waits for no copy."""
+        cm, tier, _dev = make_cm(num_pages=8)
+        self._cached(cm)                    # 3 of 7 pages stay cached
+        r = Request("c", prompt_ids=list(range(100, 124)))
+        assert cm.allocate_for_prompt(r)    # needs 6: evicts
+        assert tier.pages_demoted > 0 and tier.reads == []
+        assert cm.stats.pages_evicted == tier.pages_demoted
+
+    def test_promote_of_a_handle_in_flight_settles_first(self):
+        cm, tier, dev = make_cm()
+        orig = dev.copy()
+        p1 = self._cached(cm)
+        cm.allocator.free(
+            cm.prefix_cache.evict(3, demoter=partial_demoter(tier)))
+        for p in p1:
+            dev[p] = -1.0                   # the freed pages, overwritten
+        assert tier.reads == []
+        r2 = Request("r2", prompt_ids=list(range(12)) + [50, 51, 52])
+        assert cm.allocate_for_prompt(r2)   # swap-in: promote
+        assert tier.reads == [3] and tier.pages_swapped_in == 3
+        assert r2.num_cached_tokens == 12
+        for pg, op in zip(r2.page_ids[:3], p1):
+            assert (dev[pg] == orig[op]).all()
+        assert tier.num_host_pages == 0
+
+    def test_a_page_shed_before_its_settle_goes_through_evict_cb(self):
+        """A reservation holds its room, so a settle never finds the
+        pool without any; what can happen is that the pool sheds a
+        reserved page for a newer one before its bytes came. The radix
+        node goes through ``evict_cb`` as any other, and the settle
+        does not bring the page back."""
+        cm, tier, _dev = make_cm(host_pages=2)
+        self._cached(cm)
+        dropped = []
+        real = tier.pool.evict_cb
+        tier.set_evict_cb(lambda h: dropped.append(h) or real(h))
+        cm.allocator.free(
+            cm.prefix_cache.evict(2, demoter=partial_demoter(tier)))
+        in_flight = list(tier.pool._pages)
+        assert len(in_flight) == 2 and cm.prefix_cache.num_host_pages == 2
+        # A parked image (pinned, all-or-nothing) needs both places.
+        req = Request("p1", prompt_ids=list(range(100, 108)))
+        assert cm.allocate_for_prompt(req)
+        req.status = RequestStatus.DECODING
+        req.num_computed_tokens = 8
+        assert cm.preempt_to_host(req)
+        assert sorted(dropped) == sorted(in_flight)
+        assert cm.prefix_cache.num_host_pages == 0
+        tier.settle()
+        assert not set(in_flight) & set(tier.pool._pages)
+        assert tier.num_host_pages == 2     # the image alone
+        assert cm.resume_from_host(req)
+        cm.release(req)
+
+    def test_reset_frees_pages_still_in_flight(self):
+        cm, tier, _dev = make_cm()
+        self._cached(cm)
+        cm.allocator.free(
+            cm.prefix_cache.evict(3, demoter=partial_demoter(tier)))
+        cm.reset_prefix_cache()
+        assert tier.num_host_pages == 0
+        tier.settle()                       # fills nothing
+        assert tier.num_host_pages == 0 and not tier.pool._pages
+
+    def test_bytes_survive_a_later_jitted_write_over_the_freed_pages(self):
+        """The real transfers (CPU jit): the program enqueued after the
+        gather overwrites the demoted pages before anything is read
+        back, and the tier still holds what they held."""
+        import jax
+        import jax.numpy as jnp
+
+        from parallax_tpu.runtime.host_cache import tier_from_paged_kv
+
+        # Two layers of one page shape and a third of another: the
+        # gather stacks the first two into one staging array.
+        n, layers = 8, 3
+        state = {"kv": [
+            jnp.arange(n * 8, dtype=jnp.float32).reshape(n, 4, 2) + 1000 * i
+            for i in range(2)
+        ] + [jnp.arange(n * 6, dtype=jnp.float32).reshape(n, 3, 2) - 500]}
+        orig = [np.array(a) for a in state["kv"]]
+        tier = tier_from_paged_kv(
+            1 << 20, lambda: state["kv"],
+            lambda kv: state.__setitem__("kv", kv), n,
+        )
+        victims = [5, 2, 7]                 # 3 ids in a bucket of 4
+        handles = tier.demote(victims, partial=True)
+        assert all(h is not None for h in handles)
+        ids = jnp.asarray(victims)
+        state["kv"] = jax.jit(
+            lambda kv: [a.at[ids].set(-1.0) for a in kv]
+        )(state["kv"])
+        assert (np.asarray(state["kv"][0])[victims] == -1.0).all()
+        tier.settle()
+        for h, p in zip(handles, victims):
+            for i, page in enumerate(tier.pool.load(h)):
+                assert (page == orig[i][p]).all()
+        tier.promote(handles, [0, 1, 3])
+        for i in range(layers):
+            got = np.asarray(state["kv"][i])
+            assert (got[[0, 1, 3]] == orig[i][victims]).all()
+        assert tier.num_host_pages == 0 and tier.pages_swapped_in == 3
+
+
 class TestPreemptionBookkeeping:
     def _decoding_request(self, cm, rid, n_prompt=8):
         req = Request(rid, prompt_ids=list(range(100, 100 + n_prompt)))
@@ -331,6 +478,31 @@ class TestPreemptionBookkeeping:
         req.abort("timeout")
         cm.release(req)
         assert tier.num_host_pages == 0
+
+    @pytest.mark.parametrize("host_pages, parked", [(8, True), (1, False)])
+    def test_preempt_is_all_or_nothing_and_has_its_bytes(
+            self, host_pages, parked):
+        """The one demotion that settles at once: a parked image is on
+        the host, whole, before the row's pages are freed — or nothing
+        moved at all."""
+        cm, tier, _dev = make_cm(host_pages=host_pages)
+        req = self._decoding_request(cm, "p1")      # 2 pages
+        pages = list(req.page_ids)
+        free = cm.num_free_pages
+        assert cm.preempt_to_host(req) is parked
+        if parked:
+            assert tier.reads == [2] and req.page_ids == []
+            assert all(
+                tier.pool.load(h)[0] is not None
+                and isinstance(tier.pool.load(h)[0], np.ndarray)
+                for h in req.host_page_handles
+            )
+            assert cm.num_free_pages == free + 2
+        else:
+            assert tier.reads == [] and req.page_ids == pages
+            assert tier.num_host_pages == 0 and tier.pages_demoted == 0
+            assert cm.num_free_pages == free
+            assert not hasattr(req, "host_page_handles")
 
     def test_preempt_without_tier_is_refused(self):
         cm = CacheManager(page_size=PAGE, num_pages=PAGES)
@@ -474,3 +646,88 @@ class TestEngineEndToEnd:
         assert stats["tokens_hit_host"] > 0
         assert stats["pages_demoted"] > 0
         assert stats["pages_swapped_in"] > 0
+
+    def test_an_eviction_inside_a_plan_settles_after_the_read_back(
+            self, model_and_params, monkeypatch):
+        """A pool the prefix cache fills, one row at a time (so nothing
+        is preempted): every admission evicts, inside the scheduler's
+        plan. The demotion enqueues there (``cache.demote_enqueue``) and
+        its blocking read (``cache.demote_settle``, the tier's one
+        ``np.asarray`` of staged arrays) never runs under
+        ``sched.form_plan`` or ``engine.pack``: it comes after a
+        read-back, outside both. Second turns then hit the demoted pages
+        and the streams equal an unpressured engine's."""
+        from parallax_tpu.obs import trace as obs_trace
+        from parallax_tpu.runtime.engine import (
+            EngineConfig,
+            StageEngine,
+            drive_step,
+        )
+
+        log = []
+
+        class Annotation:
+            def __init__(self, name, **args):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(obs_trace, "_annotations",
+                            (Annotation, Annotation))
+        model, params = model_and_params
+
+        def serve(num_pages, host_bytes):
+            eng = StageEngine(model, params, EngineConfig(
+                page_size=8, num_pages=num_pages, max_model_len=256,
+                kv_dtype="float32", host_cache_bytes=host_bytes,
+            ))
+            turns = []
+            for turn in range(2):
+                for i in range(6):
+                    prompt = [7 + i] * 24
+                    if turn:
+                        prompt = turns[i].all_token_ids + [9, 9, 9, 9]
+                    r = Request(f"t{turn}-{i}", prompt_ids=prompt,
+                                sampling_params=SamplingParams(
+                                    temperature=0.0, max_new_tokens=16,
+                                    ignore_eos=True))
+                    eng.submit(r)
+                    pending, guard = None, 0
+                    while ((eng.has_work() or pending is not None)
+                           and guard < 5000):
+                        guard += 1
+                        _outs, pending = drive_step(eng, pending)
+                    turns.append(r)
+            return turns, eng
+
+        base, _ = serve(256, 0)
+        del log[:]
+        on, eng = serve(20, 1 << 24)
+        stats = eng.cache_stats()
+        assert stats["preemptions"] == 0 and stats["kv_oom_aborts"] == 0
+        assert stats["pages_demoted"] > 0 and stats["tokens_hit_host"] > 0
+        assert [r.output_ids for r in on] == [r.output_ids for r in base]
+        assert not eng.host_tier._unsettled
+
+        plan = {"parallax.sched.form_plan", "parallax.engine.pack"}
+        open_spans, enqueues, settles, last_exit = [], 0, 0, None
+        for what, name in log:
+            if what == "exit":
+                open_spans.remove(name)
+                last_exit = name
+                continue
+            if name == "parallax.cache.demote_enqueue":
+                enqueues += 1
+                assert plan & set(open_spans), open_spans
+            elif name == "parallax.cache.demote_settle":
+                settles += 1
+                assert not plan & set(open_spans), open_spans
+                # resolve's last span has closed: the step is read back.
+                assert last_exit == "parallax.engine.commit", last_exit
+            open_spans.append(name)
+        assert enqueues > 0 and 0 < settles <= enqueues
+
