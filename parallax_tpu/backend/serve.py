@@ -19,7 +19,11 @@ from parallax_tpu.backend.http_server import (
 )
 from parallax_tpu.obs import names as mnames
 from parallax_tpu.obs.registry import DEFAULT_COUNT_BUCKETS, get_registry
-from parallax_tpu.obs.trace import host_span
+from parallax_tpu.obs.trace import (
+    HostPauseMeter,
+    get_slow_visits,
+    host_span,
+)
 from parallax_tpu.runtime.engine import (
     EngineConfig,
     StageEngine,
@@ -78,6 +82,11 @@ class LocalRunner:
             mnames.INBOX_DRAINED, mnames.help_text(mnames.INBOX_DRAINED),
             buckets=DEFAULT_COUNT_BUCKETS,
         )
+        # The loop gap is held against its baseline like an engine's
+        # phases (obs/trace.py ``SlowVisits``), and while the loop runs
+        # a meter counts the moments the whole process stood still.
+        get_slow_visits().bind_registry()
+        self.pause_meter = HostPauseMeter()
         # Programs the engines had built when the heap was last settled.
         self._settled_programs = 0
         self._thread = threading.Thread(
@@ -111,12 +120,14 @@ class LocalRunner:
         )
 
     def start(self) -> None:
+        self.pause_meter.start()
         self._thread.start()
 
     def stop(self) -> None:
         self._stop.set()
         self._arrival.set()
         self._thread.join(timeout=3.0)
+        self.pause_meter.stop()
         # The loop keeps one step in flight; a loop that has ended
         # leaves none (a failed loop discarded its own in ``_fail``),
         # and with it ended this thread is the pipeline's only one.

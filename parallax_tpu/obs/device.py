@@ -53,6 +53,7 @@ import time
 from parallax_tpu.utils import get_logger
 from parallax_tpu.analysis.sanitizer import make_lock
 from parallax_tpu.obs import names as mnames
+from parallax_tpu.obs.trace import current_visit, jit_trace_seconds
 
 logger = get_logger(__name__)
 
@@ -322,6 +323,7 @@ class CompileObservatory:
     # A note not consumed within this window is stale (persistent-cache
     # HIT: the build never fired a backend compile).
     NOTE_TTL_S = 120.0
+    RECENT = 16
 
     def __init__(self, registry=None, clock=time.monotonic,
                  storm_window_s: float = 30.0, storm_threshold: int = 5):
@@ -334,6 +336,7 @@ class CompileObservatory:
         # Programs served from the persistent compilation cache: not
         # compiles, so they stay out of every count above.
         self.cache_hits = 0
+        self._recent = collections.deque(maxlen=self.RECENT)
         self._live_execs: dict[str, int] = {}
         self._window: dict[str, collections.deque] = {}
         self._window_s = float(storm_window_s)
@@ -403,7 +406,10 @@ class CompileObservatory:
         with self._lock:
             cause = self._diff_cause(self._prev_key.get(family), key)
             self._prev_key[family] = key
-            self._pending.append((family, cause, now))
+            # With the visit and the thread's trace seconds so far: the
+            # build's own are what they grow by until its event.
+            self._pending.append((family, cause, now, key, current_visit(),
+                                  jit_trace_seconds()))
         return cause
 
     def set_live_executables(self, family: str, count: int) -> None:
@@ -424,25 +430,51 @@ class CompileObservatory:
 
     # -- compile events ---------------------------------------------------
 
-    def on_cache_hit(self) -> None:
+    def _take_note(self, now: float, duration_s: float, cache_hit: bool,
+                   fun: str) -> tuple[str, str]:
+        """The newest live note's (family, cause) — LIFO: the event
+        fires inside the most recently noted jit invocation; notes
+        older than the TTL are dropped — and the build's record among
+        the recent ones (``fun``: JAX's own name for the function
+        built, so a build nobody noted has a name too). Caller holds
+        the lock."""
+        # (A build nobody noted still fell in the visit it fell in.)
+        family, cause, key, visit = "other", "unknown", {}, current_visit()
+        traced = 0.0
+        while self._pending:
+            fam, c, t, k, v, trace0 = self._pending.pop()
+            if now - t <= self.NOTE_TTL_S:
+                family, cause, key, visit = fam, c, k, v
+                traced = max(0.0, jit_trace_seconds() - trace0)
+                break
+        self._recent.append({
+            "program": family,
+            "cause": cause,
+            "key": key,
+            "fun": fun,
+            "compile_ms": round(duration_s * 1e3, 3),
+            "trace_ms": round(traced * 1e3, 3),
+            "visit": visit,
+            "perf_counter_ns": time.perf_counter_ns(),
+            "cache_hit": cache_hit,
+        })
+        return family, cause
+
+    def on_cache_hit(self, duration_s: float = 0.0, fun: str = "") -> None:
         """One program loaded from the persistent compilation cache
-        instead of being compiled."""
+        instead of being compiled (``duration_s``: the load)."""
         with self._lock:
             self.cache_hits += 1
+            self._take_note(self._clock(), duration_s, True, fun)
 
-    def on_compile(self, duration_s: float) -> None:
+    def on_compile(self, duration_s: float, fun: str = "") -> None:
         """Attribute one ``backend_compile`` event (called from the JAX
         monitoring listener in utils/compile_cache.py). LIFO match: the
         event fires synchronously inside the most recently noted jit
         invocation; stale notes (persistent-cache hits) expire."""
         now = self._clock()
-        family, cause = "other", "unknown"
         with self._lock:
-            while self._pending:
-                fam, c, t = self._pending.pop()
-                if now - t <= self.NOTE_TTL_S:
-                    family, cause = fam, c
-                    break
+            family, cause = self._take_note(now, duration_s, False, fun)
             k = (family, cause)
             self.compiles[k] = self.compiles.get(k, 0) + 1
             self.compile_ms[family] = (
@@ -548,6 +580,9 @@ class CompileObservatory:
                 ),
                 "storms": dict(self.storms),
                 "storms_total": sum(self.storms.values()),
+                # Oldest first; ``perf_counter_ns`` is the end of the
+                # build on this process's clock.
+                "recent": [dict(r) for r in self._recent],
             }
 
     def payload(self) -> dict:

@@ -47,6 +47,19 @@ LOOP_GAP_MS = "parallax_loop_gap_ms"
 ADMIT_WAIT_MS = "parallax_admit_wait_ms"
 INBOX_DRAINED = "parallax_inbox_drained"
 
+# -- what disturbed a window (obs/trace.py SlowVisits and HostPauseMeter,
+# utils/compile_cache.py, runtime/engine.py) --------------------------------
+SLOW_VISITS_TOTAL = "parallax_slow_visits_total"
+SLOW_VISIT_EXCESS_MS_TOTAL = "parallax_slow_visit_excess_ms_total"
+LOOP_OFFCPU_MS_TOTAL = "parallax_loop_offcpu_ms_total"
+HOST_PAUSE_MS_TOTAL = "parallax_host_pause_ms_total"
+HOST_PAUSES_TOTAL = "parallax_host_pauses_total"
+JIT_TRACE_MS_TOTAL = "parallax_jit_trace_ms_total"
+WINDOW_NOT_AHEAD_TOTAL = "parallax_window_not_ahead_total"
+VISIT_WINDOW_AHEAD_AVOIDABLE_MISS = (
+    "parallax_visit_window_ahead_avoidable_miss"
+)
+
 # -- EVA attention (runtime/engine.py, runtime/cache_manager.py) -----------
 EVA_ROLLOVER_MS = "parallax_eva_rollover_ms"
 EVA_ENTRIES_ATTENDED = "parallax_eva_entries_attended"
@@ -58,7 +71,6 @@ EVA_PAGES_RELEASED = "parallax_eva_pages_released"
 STATE_SLOTS_IN_USE = "parallax_state_slots_in_use"
 STATE_SLOTS_TOTAL = "parallax_state_slots_total"
 STATE_SNAPSHOT_MS = "parallax_state_snapshot_ms"
-STATE_SNAPSHOTS = "parallax_state_snapshots"
 
 # -- KV memory tier (runtime/engine.py) -------------------------------------
 KV_PAGE_OCCUPANCY = "parallax_kv_page_occupancy"
@@ -237,6 +249,48 @@ HELP: dict[str, str] = {
         "Entries (submits and stops) the single-host step loop took "
         "from its inbox at the top of a round, per round that took any"
     ),
+    SLOW_VISITS_TOTAL: (
+        "Host spans of the step loop that ran longer than "
+        "max(floor, k x the running baseline of their phase and "
+        "program), by phase"
+    ),
+    SLOW_VISIT_EXCESS_MS_TOTAL: (
+        "Milliseconds those slow spans ran over their baseline, by "
+        "phase and cause (compile, trace, gc, off_cpu, python; paused "
+        "and device for the read-back wait); the causes of one slow "
+        "visit sum to its excess"
+    ),
+    LOOP_OFFCPU_MS_TOTAL: (
+        "Milliseconds the step loop's thread was off the CPU (wall "
+        "minus thread CPU time) inside the CPU-bound phases of every "
+        "visit: plan, pack, commit, loop gap"
+    ),
+    HOST_PAUSE_MS_TOTAL: (
+        "Milliseconds by which a short fixed sleep of the pause "
+        "meter's thread overran its threshold: the whole process (or "
+        "machine) stood still"
+    ),
+    HOST_PAUSES_TOTAL: (
+        "Oversleeps the pause meter counted as pauses (the ms over "
+        "the count is a pause's mean length: a machine that freezes "
+        "reads ~0.1 s, a starved host many short ones)"
+    ),
+    JIT_TRACE_MS_TOTAL: (
+        "Milliseconds JAX spent tracing functions to jaxprs and "
+        "lowering them to MLIR (a retrace is no compile and costs "
+        "Python time all the same)"
+    ),
+    WINDOW_NOT_AHEAD_TOTAL: (
+        "Decode windows that were not enqueued off the carry of the "
+        "window in flight, by reason; the reasons sum to the zeros of "
+        "parallax_visit_window_ahead"
+    ),
+    VISIT_WINDOW_AHEAD_AVOIDABLE_MISS: (
+        "Per decode window enqueued: 1 where it waited for a resolve "
+        "for a reason that no change of the batch's membership or "
+        "configuration forced (snapshot_due, reordered, no_pages, "
+        "other), else 0"
+    ),
     ATTN_KERNEL_DISPATCH_TOTAL: (
         "Engine dispatches by attention kernel implementation"
     ),
@@ -274,10 +328,6 @@ HELP: dict[str, str] = {
         "Milliseconds of host work per snapshot or restore of a row's "
         "recurrent state (the enqueue of the on-device slot-to-slot "
         "copy); span parallax.engine.state_snapshot"
-    ),
-    STATE_SNAPSHOTS: (
-        "Slot-to-slot copies of recurrent state enqueued: snapshots at "
-        "page boundaries and restores on prefix hits"
     ),
     KV_PAGE_OCCUPANCY: "Fraction of KV pages in use (0..1)",
     KV_PREEMPTIONS_TOTAL: "Decode-OOM preemptions to the host KV tier",

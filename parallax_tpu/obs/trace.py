@@ -28,14 +28,28 @@ runs, and nowhere while none does. A span may also feed a registry
 histogram, from the same two instants. :func:`clock_sync` marks a
 ``perf_counter`` reading on the profiler's clock, so the per-request
 spans above can be laid on a device trace by whoever reads both.
+
+What disturbed a visit is said by the same spans: the phases of a visit
+that have a series are held against a running baseline of their own
+duration (:class:`SlowVisits`: a span far over it is a *slow visit*, its
+excess split by cause from process-wide clocks read at the span's two
+instants), the loop thread's off-CPU time is summed over every visit,
+and :class:`HostPauseMeter` counts the moments at which the whole
+process stood still.
 """
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import OrderedDict
+
 from parallax_tpu.analysis.sanitizer import make_lock
+from parallax_tpu.obs import names as mnames
+from parallax_tpu.utils import get_logger
+
+logger = get_logger(__name__)
 
 # Adjacent same-name spans on the same stage closer than this merge into
 # one epoch span (decode steps arrive every few ms; a scheduling gap
@@ -288,20 +302,32 @@ class host_span:
     ``time.perf_counter`` at the annotation's own two ends; setting
     ``span.series = None`` inside the block withdraws that. ``span.ms``
     holds the duration after exit.
+
+    A span with a series whose name is one of :data:`SLOW_PHASES` is
+    also held against the baseline of its phase and ``span.kind`` (the
+    program or plan kind, set any time before exit) by
+    :class:`SlowVisits`.
     """
 
-    __slots__ = ("series", "ms", "_annotation", "_t0")
+    __slots__ = ("series", "ms", "kind", "name", "args", "_annotation",
+                 "_t0", "_cpu_bound", "_marks")
 
     def __init__(self, name: str, series=None, **args):
-        visit = getattr(_current, "visit", None)
+        visit = current_visit()
         if visit is not None:
             args.setdefault("visit", visit)
         self._annotation = _annotation_types()[0](SPAN_PREFIX + name, **args)
         self.series = series
         self.ms = 0.0
+        self.kind = ""
+        self.name = name
+        self.args = args
+        self._cpu_bound = None if series is None else SLOW_PHASES.get(name)
 
     def __enter__(self) -> "host_span":
         self._annotation.__enter__()
+        if self._cpu_bound is not None:
+            self._marks = _clock_marks(self._cpu_bound)
         self._t0 = time.perf_counter()
         return self
 
@@ -310,6 +336,13 @@ class host_span:
         self._annotation.__exit__(*exc)
         if self.series is not None:
             self.series.observe(self.ms)
+            if self._cpu_bound is not None:
+                _SLOW_VISITS.close(self)
+
+    @property
+    def perf_counter_ns(self) -> int:
+        """``time.perf_counter_ns()`` at the span's start."""
+        return int(self._t0 * 1e9)
 
 
 class visit_span:
@@ -336,6 +369,18 @@ class visit_span:
         _current.visit = None
 
 
+def current_visit() -> int | None:
+    """The visit the calling thread is inside (``visit_span``), or None."""
+    return getattr(_current, "visit", None)
+
+
+def _marker(name: str, **args) -> None:
+    """A zero-length ``parallax.<name>`` span: on the trace while a
+    profile runs, a flag check while none does."""
+    with _annotation_types()[0](SPAN_PREFIX + name, **args):
+        pass
+
+
 def clock_sync() -> int:
     """Mark this instant on the profiler's clock: a zero-length
     ``parallax.clock_sync`` span whose ``perf_counter_ns`` argument is
@@ -343,10 +388,7 @@ def clock_sync() -> int:
     Emitted when a profile starts and stops; the offset between the two
     clocks is the marker's trace timestamp minus its argument."""
     now = time.perf_counter_ns()
-    with _annotation_types()[0](
-        SPAN_PREFIX + "clock_sync", perf_counter_ns=now
-    ):
-        pass
+    _marker("clock_sync", perf_counter_ns=now)
     return now
 
 
@@ -377,6 +419,371 @@ def traced_device_end_ns(xplane: str) -> int | None:
     if end is None or offset is None:
         return None
     return int(end - offset)
+
+
+# -- what disturbed a visit ----------------------------------------------------
+
+# The phases of a visit held against a baseline -> whether the phase is
+# CPU-bound by design (the read-back wait is the one that is not).
+SLOW_PHASES = {
+    "sched.form_plan": True,
+    "engine.pack": True,
+    "engine.commit": True,
+    "runner.loop_gap": True,
+    "engine.readback_wait": False,
+}
+# A span is slow where it ran longer than max(floor, factor x baseline).
+# Chosen on the chip (PERF.md, PR 44) so that the clean probes read ~0:
+# the floor is over a hybrid's snapshot commits (3 copies of 1.7 ms
+# where 0.4 is normal), the factor over the spread of a pack.
+SLOW_FLOOR_MS = 10.0
+SLOW_FACTOR = 1.5
+# ... and, for a (phase, kind) whose spans spread by structure, longer
+# than baseline + this many mean deviations: a prefill step's read-back
+# wait is ~5 ms or ~100 as a window was queued ahead of it or not
+# (serve's full batch on the chip, PERF.md, PR 44), which is no
+# disturbance. A steady kind's deviation is a fraction of its floor.
+SLOW_DEVIATIONS = 4.0
+# Spans of one (phase, kind) before its baseline is trusted: until then
+# the baseline is the least seen and only what a known cause took
+# (compile, trace, gc) can make a span slow.
+SLOW_WARM_SPANS = 8
+_BASELINE_SHARE = 1.0 / 16
+# Causes of a slow CPU-bound phase, and of a slow read-back wait, in
+# the order in which they are taken out of the excess; the last is the
+# rest.
+CPU_CAUSES = ("compile", "trace", "gc", "off_cpu", "python")
+WAIT_CAUSES = ("gc", "paused", "device")
+# One WARNING line at most every so often, but always for a visit that
+# ran this far over a trusted baseline: the stall someone will look for.
+_WARN_EVERY_S = 10.0
+_WARN_ALWAYS_MS = 100.0
+
+
+class _JitSeconds(threading.local):
+    """Seconds the JAX monitoring listener (utils/compile_cache.py) saw
+    on the calling thread: backend compiles (loads from the persistent
+    cache with them), and traces to jaxprs with lowerings to MLIR."""
+
+    compile = 0.0
+    trace = 0.0
+
+
+_jit = _JitSeconds()
+# Process-wide: seconds inside the cyclic collector, and the start of
+# the pass in progress (``gc.callbacks``).
+_gc = [0.0, 0.0]
+# The running pause meter (``HostPauseMeter.start``), or None.
+_pause_meter = None
+
+
+def note_jit_seconds(kind: str, seconds: float) -> None:
+    """``kind`` is ``"compile"`` or ``"trace"``: called by the monitoring
+    listener on the thread that compiled or traced."""
+    if kind == "compile":
+        _jit.compile += seconds
+    else:
+        _jit.trace += seconds
+        _SLOW_VISITS.count_trace(seconds * 1e3)
+
+
+def jit_trace_seconds() -> float:
+    """Trace and lowering seconds seen on the calling thread so far."""
+    return _jit.trace
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc[1] = time.perf_counter()
+    else:
+        _gc[0] += time.perf_counter() - _gc[1]
+
+
+def _clock_marks(cpu_bound: bool) -> tuple:
+    """The clocks a slow span's excess is split by, as they read now:
+    this thread's CPU seconds (a CPU-bound phase) or the pause meter's
+    ms (the read-back wait), then compile, trace and collector
+    seconds."""
+    meter = _pause_meter
+    return (
+        time.thread_time() if cpu_bound
+        else 0.0 if meter is None else meter.read(),
+        _jit.compile, _jit.trace, _gc[0],
+    )
+
+
+class SlowVisits:
+    """The slow-visit ledger of the step loop's host spans.
+
+    Per (phase, kind) a running baseline of the span's duration; a span
+    longer than ``max(SLOW_FLOOR_MS, SLOW_FACTOR * baseline)`` is a slow
+    visit:
+    its excess over the baseline goes to
+    ``parallax_slow_visit_excess_ms_total{phase,cause}`` split by cause
+    (the causes sum to the excess), the visit to
+    ``parallax_slow_visits_total{phase}``, and one record to the flight
+    recorder's event ring (``slow_visit``), a rate-limited WARNING and
+    a ``parallax.slow_visit`` marker on the trace. Beside it
+    ``parallax_loop_offcpu_ms_total`` grows by wall minus thread CPU
+    over every CPU-bound span, slow or not.
+
+    Baseline and deviation follow a slow span only as far as the limit
+    it broke, so one stall does not raise them and a lasting change is absorbed
+    after some tens of spans; the limit also stands
+    ``SLOW_DEVIATIONS`` mean deviations over the baseline, so a kind
+    whose spans spread by structure is not slow half the time. The hot path takes no lock: a phase's
+    spans come from the loop's one thread.
+    """
+
+    def __init__(self):
+        # (phase, kind) -> [spans seen, baseline ms, baseline off-CPU ms,
+        # mean deviation from the baseline ms]
+        self._base: dict[tuple[str, str], list] = {}
+        self._c_slow: dict[str, object] = {}
+        self._c_excess: dict[tuple[str, str], object] = {}
+        self._c_offcpu = None
+        self._c_trace = None
+        self._warned_at = 0.0
+        self._unwarned = 0
+
+    def bind_registry(self, registry=None) -> None:
+        """Create every series at 0 (a scrape that lacks a series reads
+        as nothing, not as none), and hook the collector's clock."""
+        if self._c_offcpu is not None and registry is None:
+            return
+        if registry is None:
+            from parallax_tpu.obs.registry import get_registry
+
+            registry = get_registry()
+        slow = registry.counter(
+            mnames.SLOW_VISITS_TOTAL,
+            mnames.help_text(mnames.SLOW_VISITS_TOTAL),
+            labelnames=("phase",),
+        )
+        excess = registry.counter(
+            mnames.SLOW_VISIT_EXCESS_MS_TOTAL,
+            mnames.help_text(mnames.SLOW_VISIT_EXCESS_MS_TOTAL),
+            labelnames=("phase", "cause"),
+        )
+        for phase, cpu_bound in SLOW_PHASES.items():
+            self._c_slow[phase] = slow.labels(phase=phase)
+            for cause in CPU_CAUSES if cpu_bound else WAIT_CAUSES:
+                self._c_excess[phase, cause] = excess.labels(
+                    phase=phase, cause=cause
+                )
+        self._c_trace = registry.counter(
+            mnames.JIT_TRACE_MS_TOTAL,
+            mnames.help_text(mnames.JIT_TRACE_MS_TOTAL),
+        ).labels()
+        self._c_offcpu = registry.counter(
+            mnames.LOOP_OFFCPU_MS_TOTAL,
+            mnames.help_text(mnames.LOOP_OFFCPU_MS_TOTAL),
+        ).labels()
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def count_trace(self, ms: float) -> None:
+        if self._c_trace is not None:
+            self._c_trace.inc(ms)
+
+    def baseline_ms(self, phase: str, kind: str = "") -> float | None:
+        b = self._base.get((phase, kind))
+        return None if b is None else b[1]
+
+    def close(self, span: host_span) -> None:
+        """Hold a finished span against its baseline."""
+        m0, m1 = span._marks, _clock_marks(span._cpu_bound)
+        ms = span.ms
+        compile_ms, trace_ms, gc_ms = (
+            max(0.0, (m1[i] - m0[i]) * 1e3) for i in (1, 2, 3)
+        )
+        off_ms = 0.0
+        if span._cpu_bound:
+            off_ms = max(0.0, ms - (m1[0] - m0[0]) * 1e3)
+            if self._c_offcpu is not None:
+                self._c_offcpu.inc(off_ms)
+        key = span.name, span.kind
+        b = self._base.get(key)
+        if b is None:
+            b = self._base[key] = [0, ms, off_ms, 0.0]
+        n, base, base_off, dev = b
+        if n < SLOW_WARM_SPANS:
+            # No baseline yet: slow only by what a known cause took (a
+            # new program's first span holds its compile), and such a
+            # span says nothing of the normal one.
+            took = min(ms, compile_ms + trace_ms + gc_ms)
+            if took > SLOW_FLOOR_MS:
+                self._slow(span, ms - took,
+                           (compile_ms, trace_ms, gc_ms, 0.0), known=False)
+                if n == 0:
+                    del self._base[key]
+            else:
+                # The least seen, and the widest it was left by.
+                low = min(base, ms)
+                b[:] = (n + 1, low, min(base_off, off_ms),
+                        max(dev + base, ms) - low)
+            return
+        limit = max(SLOW_FLOOR_MS, SLOW_FACTOR * base,
+                    base + SLOW_DEVIATIONS * dev)
+        b[3] = dev + (abs(min(ms, limit) - base) - dev) * _BASELINE_SHARE
+        if ms > limit:
+            self._slow(span, base, (
+                (compile_ms, trace_ms, gc_ms, off_ms - base_off)
+                if span._cpu_bound else (gc_ms, m1[0] - m0[0])
+            ))
+            ms = limit
+        else:
+            b[2] = base_off + (off_ms - base_off) * _BASELINE_SHARE
+        b[1] = base + (ms - base) * _BASELINE_SHARE
+
+    def _slow(self, span: host_span, base: float, amounts: tuple,
+              known: bool = True) -> None:
+        """Count one slow visit: its excess over ``base`` split by
+        cause — ``amounts`` is what each cause but the last could have
+        taken, in the order of ``CPU_CAUSES`` (or ``WAIT_CAUSES``); each
+        takes what is left at most, the last the rest. ``known``: the
+        baseline is a trusted one (not a new program's first spans,
+        whose builds set-up is full of)."""
+        ms = span.ms
+        rest = excess = ms - base
+        causes = CPU_CAUSES if span._cpu_bound else WAIT_CAUSES
+        split = {}
+        for cause, amount in zip(causes, amounts):
+            split[cause] = part = min(rest, max(0.0, amount))
+            rest -= part
+        split[causes[-1]] = rest
+        phase = span.name
+        if self._c_offcpu is not None:
+            self._c_slow[phase].inc()
+            for cause, part in split.items():
+                if part > 0.0:
+                    self._c_excess[phase, cause].inc(part)
+        args = span.args
+        record = {
+            "visit": args.get("visit", -1),
+            "phase": phase,
+            "ms": round(ms, 3),
+            "baseline_ms": round(base, 3),
+            "excess_ms": round(excess, 3),
+            **{c: round(part, 3) for c, part in split.items()},
+            "rows": args.get("rows", -1),
+            "tokens": args.get("tokens", -1),
+            "program": span.kind,
+            "perf_counter_ns": span.perf_counter_ns,
+        }
+        _marker("slow_visit", **record)
+        from parallax_tpu.obs.flight import get_flight
+
+        get_flight().event("slow_visit", **record)
+        now = time.monotonic()
+        if (not (known and excess >= _WARN_ALWAYS_MS)
+                and now - self._warned_at < _WARN_EVERY_S):
+            self._unwarned += 1
+            return
+        logger.warning(
+            "slow visit %s: %s took %.1f ms where %.1f is normal (%s); "
+            "%d more since the last such line",
+            record["visit"], phase, ms, base,
+            ", ".join(f"{c} {part:.1f}" for c, part in split.items()
+                      if part > 0.0),
+            self._unwarned,
+        )
+        self._warned_at, self._unwarned = now, 0
+
+
+_SLOW_VISITS = SlowVisits()
+
+
+def get_slow_visits() -> SlowVisits:
+    """The process-wide slow-visit ledger (every ``host_span`` closes
+    into it)."""
+    return _SLOW_VISITS
+
+
+class HostPauseMeter:
+    """Counts the moments at which the whole process stood still.
+
+    A daemon thread sleeps ``INTERVAL_S`` again and again; the part of
+    an oversleep beyond ``THRESHOLD_MS`` is a pause
+    (``parallax_host_pause_ms_total``, ``parallax_host_pauses_total``,
+    a ``parallax.host_pause`` marker with ``ms``): the machine froze
+    every process, the process was stopped, or every core was taken for
+    that long. The threshold stands over what waiting for the GIL behind
+    a busy step loop costs the thread (PERF.md, PR 44, has the
+    oversleeps it was set against). :meth:`read` counts the sleep in
+    progress too, so a span that ends before this thread has woken
+    reads its pause all the same.
+    """
+
+    INTERVAL_S = 0.01
+    THRESHOLD_MS = 20.0
+
+    def __init__(self, clock=time.perf_counter, sleep=None, registry=None):
+        self._clock = clock
+        self._stop = threading.Event()
+        self._sleep = sleep or self._stop.wait
+        # (pause ms counted so far, when the sleep in progress is due to
+        # end or None): replaced whole, so a reader sees one or the other.
+        self._state = (0.0, None)
+        self._thread = None
+        if registry is None:
+            from parallax_tpu.obs.registry import get_registry
+
+            registry = get_registry()
+        self._c_ms = registry.counter(
+            mnames.HOST_PAUSE_MS_TOTAL,
+            mnames.help_text(mnames.HOST_PAUSE_MS_TOTAL),
+        ).labels()
+        self._c_pauses = registry.counter(
+            mnames.HOST_PAUSES_TOTAL,
+            mnames.help_text(mnames.HOST_PAUSES_TOTAL),
+        ).labels()
+
+    def read(self) -> float:
+        """Pause ms so far, with what the sleep in progress has already
+        overrun its threshold by."""
+        total, due = self._state
+        if due is None:
+            return total
+        return total + max(
+            0.0, (self._clock() - due) * 1e3 - self.THRESHOLD_MS
+        )
+
+    def tick(self) -> None:
+        """One sleep, and the count of what it overran."""
+        due = self._clock() + self.INTERVAL_S
+        self._state = (self._state[0], due)
+        self._sleep(self.INTERVAL_S)
+        pause = (self._clock() - due) * 1e3 - self.THRESHOLD_MS
+        if pause <= 0.0:
+            self._state = (self._state[0], None)
+            return
+        self._state = (self._state[0] + pause, None)
+        self._c_ms.inc(pause)
+        self._c_pauses.inc()
+        _marker("host_pause", ms=round(pause, 3))
+
+    def start(self) -> None:
+        global _pause_meter
+        if self._thread is not None:
+            return
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="host-pause-meter"
+        )
+        _pause_meter = self
+        self._thread.start()
+
+    def _run(self) -> None:
+        while not self._stop.is_set():
+            self.tick()
+
+    def stop(self) -> None:
+        global _pause_meter
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(timeout=1.0)
+        if _pause_meter is self:
+            _pause_meter = None
 
 
 _STORE = TraceStore()

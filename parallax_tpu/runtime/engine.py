@@ -36,12 +36,45 @@ from parallax_tpu.runtime.request import (
 from parallax_tpu.runtime.scheduler import BatchPlan, ScheduledSeq, Scheduler
 from parallax_tpu.utils import get_logger
 from parallax_tpu.obs import names as mnames
-from parallax_tpu.obs.trace import host_span
+from parallax_tpu.obs.trace import get_slow_visits, host_span
 
 logger = get_logger(__name__)
 
 # What ``_note_program`` hands back for a jit key it has seen before.
 _NO_SPAN = contextlib.nullcontext()
+
+# Why a decode window was not enqueued off the carry of the window in
+# flight (``parallax_window_not_ahead_total{reason}``). Inherent: a
+# change of the batch's membership or configuration forces the wait.
+WINDOW_MISS_INHERENT = (
+    "no_window_in_flight",  # the rows' first window, or a driver that
+                            # keeps none in flight
+    "row_ended",            # a row finished, was aborted or migrates
+    "row_joined",           # an arrival, a prefill chunk, a new row
+    "budget_ends",          # the window in flight ends every row's budget
+    "speculation",          # speculative windows hand nothing over
+    "overlap_off",          # ``overlap_steps`` off
+)
+# Avoidable: nothing about the batch changed.
+WINDOW_MISS_AVOIDABLE = (
+    "snapshot_due",         # a hybrid row's state is snapshot at resolve
+    "reordered",            # the same rows in another order
+    "no_pages",             # the next window could not be paged
+    "other",
+)
+WINDOW_MISS_REASONS = WINDOW_MISS_INHERENT + WINDOW_MISS_AVOIDABLE
+
+
+def visit_kind(plan: BatchPlan, program: str = "") -> str:
+    """What a visit's spans are held against a baseline by
+    (obs/trace.py ``SlowVisits``): the program, and for a plan with
+    prompt tokens their count's power-of-two bucket — a decode window,
+    a small and a large prefill chunk have different normal packs and
+    waits."""
+    tokens = plan.total_new_tokens
+    if tokens <= len(plan.seqs):
+        return program or "decode"
+    return f"{program or 'prefill'}/{1 << (tokens - 1).bit_length()}"
 
 # Adaptive multi-step decode: K used per host visit when
 # ``EngineConfig.decode_lookahead`` is None and the batch qualifies.
@@ -1854,6 +1887,32 @@ class StageEngine:
             mnames.help_text(mnames.VISIT_WINDOW_AHEAD),
             buckets=(0.0, 1.0), labelnames=st,
         ).labels(**lbl)
+        # Beside it, per window: why a 0 (the reasons sum to the zeros)
+        # and whether the reason was one no change of the batch forced.
+        self._h_window_avoidable = reg.histogram(
+            mnames.VISIT_WINDOW_AHEAD_AVOIDABLE_MISS,
+            mnames.help_text(mnames.VISIT_WINDOW_AHEAD_AVOIDABLE_MISS),
+            buckets=(0.0, 1.0), labelnames=st,
+        ).labels(**lbl)
+        not_ahead = reg.counter(
+            mnames.WINDOW_NOT_AHEAD_TOTAL,
+            mnames.help_text(mnames.WINDOW_NOT_AHEAD_TOTAL),
+            labelnames=("stage", "reason"),
+        )
+        self._c_not_ahead = {
+            reason: not_ahead.labels(reason=reason, **lbl)
+            for reason in WINDOW_MISS_REASONS
+        }
+        # The newest decode window dispatched: its rows' request ids,
+        # and why the window after it will not start from its carry
+        # (one of ``WINDOW_MISS_REASONS``) — decided at its offer or
+        # where the hand-over broke later, None while it may. The next
+        # window's dispatch counts the miss under it.
+        self._window_rows: frozenset[str] = frozenset()
+        self._window_miss: str | None = None
+        # The slow-visit ledger the phases' spans close into
+        # (obs/trace.py): its series at 0 from the first scrape.
+        get_slow_visits().bind_registry()
         # EVA models: what the decode steps read, what the summary op
         # wrote, and the rollovers (collected from the cache manager).
         self._h_eva_rollover = phase(mnames.EVA_ROLLOVER_MS)
@@ -1870,7 +1929,6 @@ class StageEngine:
         # Hybrid stages: the state slots held and the slot-to-slot
         # copies (snapshots and restores) the prefix cache asks for.
         self._h_state_snapshot = phase(mnames.STATE_SNAPSHOT_MS)
-        self._c_state_snapshots = stage_counter(mnames.STATE_SNAPSHOTS)
 
         def state_gauge(name):
             return reg.gauge(
@@ -3282,9 +3340,10 @@ class StageEngine:
                 host_feed[i] = seg.token_ids[0]
         feed = jnp.asarray(host_feed)
         if any_fed:
-            feed = gather_device_feed(
-                feed, self._last_token_dev, jnp.asarray(feed_slots)
-            )
+            with self._note_program("feed_gather", tokens=s):
+                feed = gather_device_feed(
+                    feed, self._last_token_dev, jnp.asarray(feed_slots)
+                )
         ms = dict(
             stop_tokens=jnp.asarray(stop_tokens),
             limit=jnp.asarray(limits),
@@ -3367,7 +3426,7 @@ class StageEngine:
             except AttributeError:  # stubbed jit call in tests
                 pass
         self.scheduler.on_batch_computed(plan)
-        self._h_window_ahead.observe(0.0)
+        self._observe_window_ahead(plan, None)
         step_idx = self._step_count
         self._step_count += 1
         ticket = StepTicket(
@@ -3383,6 +3442,7 @@ class StageEngine:
             dispatch_seq=self._dispatch_seq,
             program="spec_window",
         )
+        self._note_window(plan, "speculation")
         ticket.host_ms = (time.perf_counter() - t0) * 1000.0
         self._inflight.append(ticket)
         return ticket
@@ -3665,7 +3725,7 @@ class StageEngine:
             # back, no phantom KV to donate when resolve(N) releases it).
             chain.handed_over = True
             chain.ms_carry = None
-        self._h_window_ahead.observe(0.0 if chain is None else 1.0)
+        self._observe_window_ahead(plan, chain)
         step_idx = self._step_count
         self._step_count += 1
         ticket = StepTicket(
@@ -3676,7 +3736,12 @@ class StageEngine:
             program="decode_window",
             chained=chain is not None,
         )
-        if spec_off and self._offer_window_rows(plan, m * k):
+        self._note_window(
+            plan,
+            self._offer_window_rows(plan, m * k) if spec_off
+            else "speculation",
+        )
+        if self._window_miss is None:
             ticket.ms_carry = dict(
                 feed=feed, ctx=ctx, stopped=stopped, **fextra
             )
@@ -3695,7 +3760,35 @@ class StageEngine:
             return jnp.asarray(x)
         return jax.device_put(x, self._carry_sharding)
 
-    def _offer_window_rows(self, plan: BatchPlan, steps: int) -> bool:
+    def _observe_window_ahead(
+        self, plan: BatchPlan, chain: StepTicket | None
+    ) -> None:
+        """One decode window enqueued: ahead (off ``chain``'s carry) or
+        not, and then why — the reason the rows' window before left
+        (``_window_miss``), where these rows had one."""
+        if chain is not None:
+            self._h_window_ahead.observe(1.0)
+            self._h_window_avoidable.observe(0.0)
+            return
+        reason = "no_window_in_flight"
+        if self._window_miss is not None and any(
+            seg.request.request_id in self._window_rows for seg in plan.seqs
+        ):
+            reason = self._window_miss
+        self._h_window_ahead.observe(0.0)
+        self._c_not_ahead[reason].inc()
+        self._h_window_avoidable.observe(
+            1.0 if reason in WINDOW_MISS_AVOIDABLE else 0.0
+        )
+
+    def _note_window(self, plan: BatchPlan, miss: str | None) -> None:
+        """The decode window just enqueued is the newest."""
+        self._window_rows = frozenset(
+            seg.request.request_id for seg in plan.seqs
+        )
+        self._window_miss = miss
+
+    def _offer_window_rows(self, plan: BatchPlan, steps: int) -> str | None:
         """Make the rows of the plain window just enqueued schedulable
         one window ahead (``Request.window_pending``: the tokens this
         window holds for the row, clamped by its budget), so a step loop
@@ -3705,9 +3798,10 @@ class StageEngine:
         model when a row's window ends on a page boundary at which
         resolve will snapshot the recurrent state
         (``_decode_snapshot_due``) — the state must not have run a
-        window further by then."""
+        window further by then. Returns None where the rows are
+        offered, else the reason they are not (``_window_miss``)."""
         if not self.cfg.overlap_steps:
-            return False
+            return "overlap_off"
         if (
             self._needs_state
             and self.cache.enable_prefix_cache
@@ -3718,13 +3812,13 @@ class StageEngine:
                 for seg in plan.seqs
             )
         ):
-            return False
+            return "snapshot_due"
         left = [seg.budget_left for seg in plan.seqs]
         if max(left) <= steps:
-            return False        # this window ends every row's budget
+            return "budget_ends"
         for seg, n in zip(plan.seqs, left):
             seg.request.window_pending = max(0, min(steps, n))
-        return True
+        return None
 
     def _count_eva(self, plan: BatchPlan, steps: int = 1) -> None:
         """Count a dispatched EVA step or decode window once (not per
@@ -3757,10 +3851,17 @@ class StageEngine:
         flight only when they are the whole batch; they continue it
         when they are its rows in its order. Otherwise (a timeout took
         a row, an ordering policy moved one) the plan is dropped: the
-        rows wait for the window's resolve, as without the hand-over."""
+        rows wait for the window's resolve, as without the hand-over.
+        Whatever ends the hand-over leaves its reason in
+        ``_window_miss`` (a ticket that holds a carry is the newest
+        window's)."""
         ahead = self._inflight[-1] if self._inflight else None
+        sched = self.scheduler
+        broke, sched.window_break = sched.window_break, None
         if ahead is None or ahead.ms_carry is None:
             return plan, None
+        if broke is not None:
+            self._window_miss = broke
         if not any(seg.device_token for seg in plan.seqs):
             return plan, None
         rows = ahead.plan.seqs
@@ -3769,20 +3870,39 @@ class StageEngine:
             for seg, row in zip(plan.seqs, rows)
         ):
             return plan, ahead
+        planned = {id(seg.request) for seg in plan.seqs}
+        missing = [row.request for row in rows
+                   if id(row.request) not in planned]
+        if len(planned) + len(missing) > len(rows):
+            reason = "row_joined"
+        elif not missing:
+            reason = "reordered"
+        elif all(r.status.is_finished or r.migrating for r in missing):
+            reason = "row_ended"
+        else:
+            reason = "no_pages"     # a row's next window found no room
+        self._window_miss = reason
         return BatchPlan([]), None
 
-    def _readback_span(self, plan: BatchPlan) -> host_span:
+    def _resolve_span(self, name: str, series,
+                      ticket: StepTicket) -> host_span:
+        plan = ticket.plan
+        span = host_span(name, series, rows=len(plan.seqs),
+                         tokens=plan.total_new_tokens)
+        span.kind = visit_kind(plan, ticket.program)
+        return span
+
+    def _readback_span(self, ticket: StepTicket) -> host_span:
         """The blocking read-back of a visit's device results."""
-        return host_span(
-            "engine.readback_wait", self._h_visit_readback,
-            rows=len(plan.seqs),
+        return self._resolve_span(
+            "engine.readback_wait", self._h_visit_readback, ticket
         )
 
-    def _commit_span(self, plan: BatchPlan) -> host_span:
+    def _commit_span(self, ticket: StepTicket) -> host_span:
         """From the read-back to the return of ``resolve``, which closes
         it (the resolvers enter it on ``resolve``'s ``spans`` stack)."""
-        return host_span(
-            "engine.commit", self._h_visit_commit, rows=len(plan.seqs)
+        return self._resolve_span(
+            "engine.commit", self._h_visit_commit, ticket
         )
 
     def _resolve_multistep(
@@ -3809,7 +3929,7 @@ class StageEngine:
         fed_at_dispatch = 0 if ticket.chained else 1
         ticket.ms_carry = None
         try:
-            with self._readback_span(plan) as waited:
+            with self._readback_span(ticket) as waited:
                 toks = np.concatenate(
                     [np.asarray(w) for w in ticket.ms_windows], axis=0
                 )                                       # [m*k, S]
@@ -3820,7 +3940,7 @@ class StageEngine:
                     if ticket.ms_lp else None
                 )
                 produced = np.asarray(ticket.ms_state[1])   # i32[S]
-            spans.enter_context(self._commit_span(plan))
+            spans.enter_context(self._commit_span(ticket))
             total = 0
             gp_committed = gp_window = 0
             for i, seg in enumerate(plan.seqs):
@@ -3938,7 +4058,7 @@ class StageEngine:
         meta = ticket.spec_meta or {}
         sources = meta.get("sources") or []
         try:
-            with self._readback_span(plan) as waited:
+            with self._readback_span(ticket) as waited:
                 toks = np.concatenate(
                     [np.asarray(x) for x in ticket.ms_windows], axis=0
                 )                                       # [m*k, S, w]
@@ -3960,7 +4080,7 @@ class StageEngine:
                         self._count_constrained(
                             spec_mask_rejections=rej_total
                         )
-            spans.enter_context(self._commit_span(plan))
+            spans.enter_context(self._commit_span(ticket))
             w = int(toks.shape[2])
             iters = int(toks.shape[0])
             total = 0
@@ -4293,7 +4413,7 @@ class StageEngine:
                 and seg.request.sampling_params.seed is None
                 for seg in spec_segs
             )
-            with self._readback_span(plan) as waited:
+            with self._readback_span(ticket) as waited:
                 if all_greedy:
                     verified = np.asarray(greedy_tokens(ticket.out))
                 else:
@@ -4325,7 +4445,7 @@ class StageEngine:
                         ticket.out, key, temp, top_k, top_p, min_p,
                         seeds=seeds, out_steps=steps,
                     ))
-            spans.enter_context(self._commit_span(plan))
+            spans.enter_context(self._commit_span(ticket))
             total = 0
             fed_total = accepted_total = 0
             row = 0
@@ -4515,6 +4635,8 @@ class StageEngine:
                 # No visit follows: its phases are observed for visits
                 # that parallax_step_host_ms counts, and no others.
                 planning.series = None
+            else:
+                planning.kind = visit_kind(plan)
         if plan.is_empty:
             return StepTicket(
                 plan=plan, step_idx=self._step_count, t0=t0,
@@ -4525,8 +4647,10 @@ class StageEngine:
         with host_span(
             "engine.pack", self._h_visit_pack, rows=len(plan.seqs),
             tokens=plan.total_new_tokens,
-        ):
-            return self._dispatch_plan(plan, sp_plan, t0, chain)
+        ) as pack:
+            ticket = self._dispatch_plan(plan, sp_plan, t0, chain)
+            pack.kind = visit_kind(plan, ticket.program)
+            return ticket
 
     def _dispatch_plan(
         self, plan: BatchPlan, sp_plan: BatchPlan | None, t0: float,
@@ -4592,6 +4716,7 @@ class StageEngine:
                 # context room at max_model_len): no hand-over. The rows
                 # wait for the in-flight window's resolve; the path
                 # below then owns the K=1 and preemption decisions.
+                self._window_miss = "no_pages"
                 return _done(StepOutputs(forward=[], finished=[]))
         # Host-sync verify fallback: K=1 (or a window the planner could
         # not page) still speculates, one round per host visit. Rows fed
@@ -4816,10 +4941,10 @@ class StageEngine:
                 None if ticket.spec_rows else ticket.tokens_dev
             )
             if fetch is not None:
-                with self._readback_span(plan) as waited:
+                with self._readback_span(ticket) as waited:
                     fetched = np.asarray(fetch)
                 readback_wait_ms = waited.ms
-            spans.enter_context(self._commit_span(plan))
+            spans.enter_context(self._commit_span(ticket))
             if not self.model.is_last:
                 forwards = self._emit_hidden(plan, fetched)
             elif ticket.spec_rows:
@@ -4988,17 +5113,20 @@ class StageEngine:
         sampler and this step's forward — no host round trip)."""
         from parallax_tpu.runtime.batch import substitute_device_tokens
 
-        feed_slots = np.full(
-            (int(inputs.token_ids.shape[0]),), -1, np.int32
-        )
+        tokens = int(inputs.token_ids.shape[0])
+        feed_slots = np.full((tokens,), -1, np.int32)
         row = 0
         for seg in plan.seqs:
             if seg.device_token:
                 feed_slots[row] = self._token_slots[seg.request.request_id]
             row += seg.num_new_tokens
-        return substitute_device_tokens(
-            inputs, self._last_token_dev, jnp.asarray(feed_slots)
-        )
+        # The gather is its own small program a token bucket, first met
+        # where a chunk rides beside device-fed rows: mid-window, on
+        # serve's full batch (PERF.md, PR 44).
+        with self._note_program("feed_gather", tokens=tokens):
+            return substitute_device_tokens(
+                inputs, self._last_token_dev, jnp.asarray(feed_slots)
+            )
 
     def is_inflight(self, ticket: StepTicket) -> bool:
         """True while the ticket has been dispatched but not resolved
@@ -5561,7 +5689,6 @@ class StageEngine:
                 self.kv = self._jit_copy_state(
                     self.kv, jnp.int32(src), jnp.int32(dst)
                 )
-        self._c_state_snapshots.inc()
 
     def _on_prefix_slot_free(self, slot: int) -> None:
         """The radix cache evicted (or could not attach) a snapshot slot."""

@@ -114,6 +114,8 @@ class Scheduler:
         # ends on a window boundary and a step that starts past one
         # rolls the window over first; None for every other model.
         self._eva_window = getattr(cache_manager, "window", None)
+        # Set where ``_settle_window_rows`` ended a hand-over: why.
+        self.window_break: str | None = None
         self.max_batch_size = max_batch_size
         self.max_num_tokens_per_batch = max_num_tokens_per_batch
         self.prefill_chunk_size = prefill_chunk_size
@@ -425,6 +427,13 @@ class Scheduler:
             for req in running
         ):
             return
+        # Why the hand-over ends, for whoever counts the window it
+        # costs (``StageEngine._window_ahead`` takes it).
+        self.window_break = "row_ended" if any(
+            req.window_pending
+            and (req.migrating or req.status is not RequestStatus.DECODING)
+            for req in running
+        ) else "row_joined"
         for req in running:
             req.window_pending = 0
 

@@ -45,10 +45,17 @@ _DEFAULT_DIR = os.path.join(
     ".jax_cache",
 )
 _OFF = ("off", "0", "none", "disabled")
-# JAX duration events fired once per backend compilation (jaxpr tracing
-# and MLIR lowering fire their own events; only the backend compile is
-# the expensive storm signal).
+# JAX duration events fired once per backend compilation (only the
+# backend compile is the expensive storm signal).
 _COMPILE_EVENT = "backend_compile"
+# Tracing a function to a jaxpr and lowering it to MLIR fire their own:
+# a retrace that ends in no compile costs seconds of Python all the same
+# (nested jit functions report at every level, so the sum can count an
+# inner trace twice).
+_TRACE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+)
 # JAX wraps the whole compile-or-load-from-cache call in that duration
 # event, so it fires for persistent-cache hits too; a hit announces
 # itself first, on the same thread, with this plain event.
@@ -104,7 +111,10 @@ def register_compile_counter() -> None:
     HITS are counted apart (``cache_hits_total``) — the compile series
     measures real compile work only. Each event is attributed to the program family /
     cause most recently declared via ``note_program`` and its duration
-    lands in the goodput ledger's ``compile`` bucket."""
+    lands in the goodput ledger's ``compile`` bucket. Compile seconds
+    (loads with them) and trace-and-lowering seconds also go to the
+    calling thread's clocks in obs/trace.py, by which a slow visit's
+    excess is split, and the latter to ``parallax_jit_trace_ms_total``."""
     global _counter_registered
     with _lock:
         if _counter_registered:
@@ -114,7 +124,9 @@ def register_compile_counter() -> None:
 
     from parallax_tpu.obs.device import get_device_plane
     from parallax_tpu.obs.goodput import get_goodput
+    from parallax_tpu.obs.trace import get_slow_visits, note_jit_seconds
 
+    get_slow_visits().bind_registry()
     plane = get_device_plane()
     plane.bind_registry()
     goodput = get_goodput()
@@ -126,12 +138,16 @@ def register_compile_counter() -> None:
             hit.pending = True
 
     def _on_duration(event: str, duration: float, **kw) -> None:
-        if _COMPILE_EVENT in event:
+        if event in _TRACE_EVENTS:
+            note_jit_seconds("trace", duration)
+        elif _COMPILE_EVENT in event:
+            note_jit_seconds("compile", duration)
+            fun = str(kw.get("fun_name", ""))
             if getattr(hit, "pending", False):
                 hit.pending = False
-                plane.compile.on_cache_hit()
+                plane.compile.on_cache_hit(duration, fun)
                 return
-            plane.compile.on_compile(duration)
+            plane.compile.on_compile(duration, fun)
             # Goodput time split: compile seconds are not serve
             # seconds — a recompile storm shows up as a goodput dip
             # instead of hiding inside step latency.

@@ -1,6 +1,7 @@
 """Tier-1's guard of the code that judges every PR: ``pytest tests/``
 collects the cases of the six fast, pure-Python files of
-``benchmarks/tests/``, each as its own test and nothing copied. The slow
+``benchmarks/tests/``, each as its own test and nothing copied, and of
+``test_disturbance_readers`` (one short rehearsal a cell). The slow
 reference, architecture and rehearsal files run in CI."""
 
 import importlib
@@ -14,7 +15,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 for _stem in ("test_loadgen", "test_spec", "test_work", "test_trace_reduce",
-              "test_idle_by_span", "test_jamba_work"):
+              "test_idle_by_span", "test_jamba_work",
+              "test_disturbance_readers"):
     _mod = importlib.import_module(f"benchmarks.tests.{_stem}")
     for _name, _obj in vars(_mod).items():
         if _name.startswith("test_"):

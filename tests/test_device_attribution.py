@@ -217,6 +217,58 @@ class TestCompileObservatory:
         assert snap["unexplained_compiles"] == 1
         assert "decode" not in snap["programs"]
 
+    def test_recent_holds_the_key_of_every_build_and_is_bounded(self):
+        """``device.compile.recent``: the last 16 builds with the key
+        their note gave, the trace and lowering ms since that note, the
+        visit they fell in, and whether the persistent cache served
+        them — so a count of compiles inside a window has names."""
+        from parallax_tpu.obs.trace import note_jit_seconds, visit_span
+
+        clock = FakeClock()
+        obs = CompileObservatory(registry=MetricsRegistry(), clock=clock)
+        note_jit_seconds("trace", 9.0)      # before any note: nobody's
+        with visit_span(41):
+            obs.note_program("prefill", {"tokens": 512, "seq": 16})
+            note_jit_seconds("trace", 0.25)     # the listener's calls,
+            note_jit_seconds("trace", 0.05)     # on this thread
+            t0 = time.perf_counter_ns()
+            obs.on_compile(4.0, "jit(_stage_fn)")
+        (rec,) = obs.snapshot()["recent"]
+        assert rec["program"] == "prefill" and rec["cause"] == "first"
+        assert rec["fun"] == "jit(_stage_fn)"
+        assert rec["key"] == {"tokens": 512, "seq": 16}
+        assert rec["compile_ms"] == 4000.0
+        assert rec["trace_ms"] == pytest.approx(300.0)
+        assert rec["visit"] == 41 and rec["cache_hit"] is False
+        assert t0 <= rec["perf_counter_ns"] <= time.perf_counter_ns()
+        # A load from the persistent cache takes its note with it (a
+        # later compile nobody noted does not inherit it) and is no
+        # compile, but it is a build with a name.
+        obs.note_program("prefill", {"tokens": 1024, "seq": 16})
+        obs.on_cache_hit(0.125)
+        with visit_span(43):
+            obs.on_compile(0.5, "jit(_gather_feed)")
+        snap = obs.snapshot()
+        assert snap["cache_hits_total"] == 1 and snap["compiles_total"] == 2
+        hit, unknown = snap["recent"][-2:]
+        assert (hit["program"], hit["cause"], hit["cache_hit"]) == (
+            "prefill", "new_shape_bucket", True)
+        assert hit["key"]["tokens"] == 1024 and hit["compile_ms"] == 125.0
+        assert hit["visit"] is None
+        assert (unknown["program"], unknown["cause"], unknown["key"]) == (
+            "other", "unknown", {})
+        # Un-noted, but in its visit and under JAX's name for it.
+        assert unknown["visit"] == 43
+        assert unknown["fun"] == "jit(_gather_feed)"
+        assert snap["unexplained_compiles"] == 1
+        # Bounded: the newest 16, oldest first.
+        for seq in range(40):
+            obs.note_program("decode_window", {"seq": seq})
+            obs.on_compile(0.01)
+        recent = obs.snapshot()["recent"]
+        assert len(recent) == CompileObservatory.RECENT == 16
+        assert [r["key"]["seq"] for r in recent] == list(range(24, 40))
+
     def test_storm_detection_and_probe_freeze(self):
         clock = FakeClock()
         obs = CompileObservatory(registry=MetricsRegistry(), clock=clock,

@@ -183,10 +183,18 @@ def test_step_round_spans_in_order_with_one_compile(recorder):
     # A visit of the one-in-flight loop dispatches step N+1, then
     # resolves step N.
     pipe.step_round()          # prefill enqueued (a new program)
-    pipe.step_round()          # first decode window (another); prefill read
+    pipe.step_round()          # first decode window (another, behind the
+                               # gather of its device-fed row); prefill read
     pipe.step_round()          # the same window again (none); window 1 read
     pipe.step_round()
     names = [n.removeprefix("parallax.") for n, _ in recorder.entered()]
+    # A pack that compiled is a slow visit (its own test below): its
+    # marker follows the pack's end and is no span of the visit.
+    slow = [a for n, a in recorder.entered() if n == "parallax.slow_visit"]
+    assert [(a["phase"], a["visit"]) for a in slow
+            if a.get("compile", 0) > 0] == [
+        ("engine.pack", 1), ("engine.pack", 2)]
+    names = [n for n in names if n != "slow_visit"]
     per_visit = []
     for n in names:
         if n == "visit":
@@ -196,7 +204,7 @@ def test_step_round_spans_in_order_with_one_compile(recorder):
     steady = ["sched.form_plan", "engine.pack", "engine.readback_wait",
               "engine.commit"]
     assert per_visit[0] == steady[:2] + ["engine.compile"]
-    assert per_visit[1] == steady[:2] + ["engine.compile"] + steady[2:]
+    assert per_visit[1] == steady[:2] + ["engine.compile"] * 2 + steady[2:]
     assert per_visit[2] == per_visit[3] == steady
     # Every child carries its visit; pack says what it packed.
     for name, args in recorder.entered():
@@ -208,7 +216,7 @@ def test_step_round_spans_in_order_with_one_compile(recorder):
     assert packs[0]["rows"] == 1 and packs[0]["tokens"] == 5
     compiles = [a["program"] for n, a in recorder.entered()
                 if n == "parallax.engine.compile"]
-    assert compiles == ["prefill", "decode_window"]
+    assert compiles == ["prefill", "feed_gather", "decode_window"]
 
 
 def test_phase_series_sum_to_step_host_ms():
@@ -270,6 +278,8 @@ def test_xplane_holds_the_visit_its_phases_and_the_clock_marker(tmp_path):
     for _, v0, v1, stats in visits:
         inside = {name: st for name, s, e, st in events
                   if name != "parallax.visit" and v0 <= s and e <= v1}
+        # (A pack the profiler slowed past its baseline leaves a marker.)
+        inside.pop("parallax.slow_visit", None)
         assert set(inside) == {
             "parallax.sched.form_plan", "parallax.engine.pack",
             "parallax.engine.readback_wait", "parallax.engine.commit"}
@@ -723,3 +733,457 @@ def test_profile_replies_bracket_the_trace(monkeypatch, tmp_path):
         assert stopped["perf_counter_ns"] > seen["stop_called"] + 10**11
 
     with_client(fe.app, fn)
+
+
+# -- what disturbed a visit: the slow-visit ledger --------------------------------
+
+
+class ScriptedSpan:
+    """A finished span as ``SlowVisits.close`` reads it."""
+
+    def __init__(self, name, ms, kind="decode_window", cpu_bound=True, **args):
+        self.name, self.ms, self.kind, self.args = name, ms, kind, args
+        self._cpu_bound = cpu_bound
+        self._marks = (0.0, 0.0, 0.0, 0.0)
+        self.perf_counter_ns = 123
+
+
+def slow_counts(reg):
+    """{(phase, cause): ms} and {phase: visits} of a registry's render."""
+    ms, visits = {}, {}
+    for line in reg.render().splitlines():
+        if line.startswith(mnames.SLOW_VISIT_EXCESS_MS_TOTAL + "{"):
+            labels, value = line.split("{")[1].split("} ")
+            pairs = dict(p.split("=") for p in labels.split(","))
+            ms[pairs["phase"].strip('"'), pairs["cause"].strip('"')] = (
+                float(value))
+        elif line.startswith(mnames.SLOW_VISITS_TOTAL + "{"):
+            labels, value = line.split("{")[1].split("} ")
+            visits[labels.split("=")[1].strip('"')] = float(value)
+    return ms, visits
+
+
+@pytest.fixture
+def ledger(monkeypatch, recorder):
+    """A ledger of its own on a registry of its own, its clocks
+    scripted: ``clocks(cpu_s=, compile_s=, trace_s=, gc_s=)`` is what
+    the next span's exit reads (its entry read zeros)."""
+    reg = MetricsRegistry()
+    led = obs_trace.SlowVisits()
+    led.bind_registry(reg)
+    marks = [(0.0, 0.0, 0.0, 0.0)]
+    monkeypatch.setattr(obs_trace, "_clock_marks",
+                        lambda cpu_bound: marks[0])
+
+    def clocks(first=0.0, compile_s=0.0, trace_s=0.0, gc_s=0.0):
+        marks[0] = (first, compile_s, trace_s, gc_s)
+
+    led.reg, led.clocks = reg, clocks
+    return led
+
+
+def warm(ledger, name, ms, kind="decode_window", cpu_bound=True):
+    for _ in range(obs_trace.SLOW_WARM_SPANS):
+        # On the CPU all of it: no off-CPU time in the baseline.
+        ledger.clocks(first=ms / 1e3 if cpu_bound else 0.0)
+        ledger.close(ScriptedSpan(name, ms, kind, cpu_bound))
+    assert ledger.baseline_ms(name, kind) == pytest.approx(ms)
+
+
+def test_both_families_and_the_totals_read_zero_before_any_visit():
+    reg = MetricsRegistry()
+    obs_trace.SlowVisits().bind_registry(reg)
+    obs_trace.HostPauseMeter(registry=reg)
+    text = reg.render()
+    ms, visits = slow_counts(reg)
+    assert set(visits) == set(obs_trace.SLOW_PHASES)
+    assert set(visits.values()) == {0.0}
+    assert {p for p, _ in ms} == set(obs_trace.SLOW_PHASES)
+    assert {c for p, c in ms if p == "engine.pack"} == set(
+        obs_trace.CPU_CAUSES)
+    assert {c for p, c in ms if p == "engine.readback_wait"} == set(
+        obs_trace.WAIT_CAUSES)
+    assert set(ms.values()) == {0.0}
+    for total in (mnames.LOOP_OFFCPU_MS_TOTAL, mnames.HOST_PAUSE_MS_TOTAL,
+                  mnames.HOST_PAUSES_TOTAL, mnames.JIT_TRACE_MS_TOTAL):
+        assert f"\n{total} 0\n" in text, total
+    # The process's own registry has them from an engine's start.
+    build_engine()
+    live = get_registry().render()
+    for name in (mnames.SLOW_VISITS_TOTAL, mnames.SLOW_VISIT_EXCESS_MS_TOTAL,
+                 mnames.LOOP_OFFCPU_MS_TOTAL, mnames.JIT_TRACE_MS_TOTAL,
+                 mnames.WINDOW_NOT_AHEAD_TOTAL):
+        assert f"\n{name}" in live, name
+
+
+def test_a_span_over_its_baseline_is_counted_once_and_one_under_is_not(
+        ledger, recorder):
+    warm(ledger, "engine.pack", 9.0)
+    # Under max(floor, factor x baseline) = 13.5: no slow visit.
+    ledger.clocks(first=0.013)
+    ledger.close(ScriptedSpan("engine.pack", 13.0))
+    assert slow_counts(ledger.reg)[1]["engine.pack"] == 0
+    base = ledger.baseline_ms("engine.pack", "decode_window")
+    assert 9.0 < base < 9.5                  # it follows, a sixteenth a span
+    # Over it: one visit, its excess over the baseline, all of it Python
+    # (the thread was on the CPU throughout).
+    ledger.clocks(first=0.109)
+    ledger.close(ScriptedSpan("engine.pack", 109.0, rows=8, tokens=8,
+                              visit=41))
+    ms, visits = slow_counts(ledger.reg)
+    assert visits == {**dict.fromkeys(obs_trace.SLOW_PHASES, 0.0),
+                      "engine.pack": 1.0}
+    assert ms["engine.pack", "python"] == pytest.approx(109.0 - base)
+    assert sum(ms.values()) == pytest.approx(109.0 - base)
+    # The stall did not become the baseline: as far as its limit only.
+    limit = obs_trace.SLOW_FACTOR * base
+    assert ledger.baseline_ms("engine.pack", "decode_window") == (
+        pytest.approx(base + (limit - base) / 16))
+    # A floor under small baselines: 8 ms of loop gap where 0.004 is
+    # normal is no slow visit, 12 ms is.
+    warm(ledger, "runner.loop_gap", 0.004, kind="")
+    ledger.clocks(first=0.008)
+    ledger.close(ScriptedSpan("runner.loop_gap", 8.0, kind=""))
+    ledger.clocks(first=0.012)
+    ledger.close(ScriptedSpan("runner.loop_gap", 12.0, kind=""))
+    assert slow_counts(ledger.reg)[1]["runner.loop_gap"] == 1
+    # A kind whose spans spread by structure (a prefill step's wait is
+    # ~5 ms or ~100 as a window was queued ahead of it or not) is not
+    # slow half the time: the limit stands four mean deviations over
+    # the baseline too, and learns them within some tens of spans.
+    kind = "prefill/512"
+    for i in range(120):
+        ms = 100.0 if i % 3 == 0 else 5.0
+        ledger.close(ScriptedSpan("engine.readback_wait", ms, kind,
+                                  cpu_bound=False))
+        if i == 59:
+            early = slow_counts(ledger.reg)[1]["engine.readback_wait"]
+    late = slow_counts(ledger.reg)[1]["engine.readback_wait"] - early
+    assert early <= 20 and late == 0, (early, late)
+    # ... while a stall far outside the spread still is.
+    ledger.close(ScriptedSpan("engine.readback_wait", 900.0, kind,
+                              cpu_bound=False))
+    assert slow_counts(ledger.reg)[1]["engine.readback_wait"] == early + 1
+    # Another program has another baseline: a prefill chunk's pack of
+    # 40 ms beside decode windows of 9 is its own normal.
+    warm(ledger, "engine.pack", 40.0, kind="prefill/1024")
+    ledger.clocks(first=0.045)
+    ledger.close(ScriptedSpan("engine.pack", 45.0, kind="prefill/1024"))
+    assert slow_counts(ledger.reg)[1]["engine.pack"] == 1
+    # One record a slow visit: the marker on the trace and the flight
+    # ring's event say the same.
+    marks = [a for n, a in recorder.entered() if n == "parallax.slow_visit"
+             and a["phase"] != "engine.readback_wait"]
+    assert [m["phase"] for m in marks] == ["engine.pack", "runner.loop_gap"]
+    rec = marks[0]
+    assert (rec["visit"], rec["rows"], rec["tokens"], rec["program"]) == (
+        41, 8, 8, "decode_window")
+    assert rec["perf_counter_ns"] == 123
+    assert rec["ms"] == 109.0 and rec["baseline_ms"] == round(base, 3)
+    from parallax_tpu.obs.flight import get_flight
+
+    (event,) = [e for e in get_flight().snapshot()["events"]
+                if e["kind"] == "slow_visit" and e["visit"] == 41
+                and e["perf_counter_ns"] == 123]
+    assert {k: event[k] for k in rec} == rec
+
+
+@pytest.mark.parametrize("name, cpu_bound, clocks, want", [
+    # 100 ms over a 10 ms pack: 30 compiling, 20 tracing, 10 collecting,
+    # 25 off the CPU (110 of wall, 85 of CPU, 60 of them in the three
+    # before), the rest Python.
+    ("engine.pack", True,
+     dict(first=0.085, compile_s=0.030, trace_s=0.020, gc_s=0.010),
+     {"compile": 30.0, "trace": 20.0, "gc": 10.0, "off_cpu": 25.0,
+      "python": 15.0}),
+    # Clocks that say more than the excess holds (nested traces count
+    # twice): each cause takes what is left, at most.
+    ("engine.commit", True,
+     dict(first=0.110, compile_s=0.070, trace_s=0.090),
+     {"compile": 70.0, "trace": 30.0, "gc": 0.0, "off_cpu": 0.0,
+      "python": 0.0}),
+    # The read-back wait is off the CPU by design: a pause of the
+    # machine is what the meter counted meanwhile, the rest the device.
+    ("engine.readback_wait", False,
+     dict(first=80.0, gc_s=0.005),
+     {"gc": 5.0, "paused": 80.0, "device": 15.0}),
+])
+def test_the_causes_of_a_slow_visit_sum_to_its_excess(
+        ledger, recorder, name, cpu_bound, clocks, want):
+    warm(ledger, name, 10.0, cpu_bound=cpu_bound)
+    ledger.clocks(**clocks)
+    ledger.close(ScriptedSpan(name, 110.0, cpu_bound=cpu_bound))
+    ms, visits = slow_counts(ledger.reg)
+    got = {c: v for (p, c), v in ms.items() if p == name}
+    assert got == pytest.approx(want)
+    assert sum(got.values()) == pytest.approx(100.0)
+    assert visits[name] == 1
+    (rec,) = [a for n, a in recorder.entered() if n == "parallax.slow_visit"]
+    assert sum(rec[c] for c in want) == pytest.approx(rec["excess_ms"])
+
+
+def test_a_new_programs_first_span_is_slow_by_its_compile_only(ledger):
+    """No baseline yet: what a known cause took is the excess, and the
+    baseline starts from the rest."""
+    ledger.clocks(first=5.2, compile_s=4.0, trace_s=1.0)
+    ledger.close(ScriptedSpan("engine.pack", 5230.0, kind="prefill/512"))
+    ms, visits = slow_counts(ledger.reg)
+    assert visits["engine.pack"] == 1
+    assert ms["engine.pack", "compile"] == pytest.approx(4000.0)
+    assert ms["engine.pack", "trace"] == pytest.approx(1000.0)
+    assert sum(ms.values()) == pytest.approx(5000.0)
+    # A build's span says nothing of the normal one: the spans after
+    # it, slower or faster, settle the baseline unseen.
+    assert ledger.baseline_ms("engine.pack", "prefill/512") is None
+    for ms in (25.0, 31.0, 22.0):
+        ledger.clocks(first=ms / 1e3)
+        ledger.close(ScriptedSpan("engine.pack", ms, kind="prefill/512"))
+    assert slow_counts(ledger.reg)[1]["engine.pack"] == 1
+    assert ledger.baseline_ms("engine.pack", "prefill/512") == 22.0
+
+
+def test_the_loops_off_cpu_time_grows_over_every_cpu_bound_span(ledger):
+    for ms, cpu_s in ((9.0, 0.007), (9.0, 0.008), (0.3, 0.0003)):
+        ledger.clocks(first=cpu_s)
+        ledger.close(ScriptedSpan("engine.pack", ms))
+    ledger.clocks(first=0.0)
+    ledger.close(ScriptedSpan("engine.readback_wait", 60.0, cpu_bound=False))
+    text = ledger.reg.render()
+    (line,) = [x for x in text.splitlines()
+               if x.startswith(mnames.LOOP_OFFCPU_MS_TOTAL + " ")]
+    assert float(line.split()[1]) == pytest.approx(2.0 + 1.0 + 0.0)
+
+
+# -- ... injected into a toy engine's pack -------------------------------------
+
+
+def burn(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def collect_garbage():
+    """Garbage made while the collector is off, and then one pass: both
+    inside the pack, so that nobody else's pass finds it first."""
+    import gc
+
+    gc.disable()
+    try:
+        junk = [[i] for i in range(400_000)]
+        for a, b in zip(junk, junk[1:]):
+            a.append(b)                 # cycles: the collector's to find
+        del junk, a, b
+        t0 = time.perf_counter()
+        gc.collect()
+        collect_garbage.ms = (time.perf_counter() - t0) * 1e3
+    finally:
+        gc.enable()
+
+
+def compile_something_new():
+    jax.jit(lambda x: jnp.tanh(x) * 3 + 1)(jnp.arange(7.0))
+
+
+@pytest.mark.parametrize("inject, causes", [
+    (lambda: time.sleep(0.08), ("off_cpu",)),
+    (lambda: burn(0.08), ("python",)),
+    (collect_garbage, ("gc",)),
+    (compile_something_new, ("trace", "compile")),
+], ids=["sleep", "busy-loop", "gc-collect", "jit-on-a-new-key"])
+def test_a_stall_injected_into_pack_reads_its_cause(monkeypatch, inject,
+                                                    causes):
+    from parallax_tpu.obs.flight import get_flight
+
+    # The ledger is the process's: what other tests' engines taught it
+    # of a toy window's pack is not this engine's normal.
+    obs_trace.get_slow_visits()._base.clear()
+    eng = build_engine()
+    pipe = InProcessPipeline([eng])
+    pipe.submit(request("stalled", max_tokens=240))
+    for _ in range(obs_trace.SLOW_WARM_SPANS + 18):
+        # The window's baseline is warm, and its deviation has come
+        # down from the first spans' range to this machine's own.
+        pipe.step_round()
+    ledger = obs_trace.get_slow_visits()
+    base = ledger.baseline_ms("engine.pack", "decode_window")
+    assert base is not None and base < obs_trace.SLOW_FLOOR_MS
+    real = eng._dispatch_plan
+
+    def stalled(*a, **kw):
+        monkeypatch.setattr(eng, "_dispatch_plan", real)
+        t0 = time.perf_counter()
+        inject()
+        stalled.ms = (time.perf_counter() - t0) * 1e3
+        return real(*a, **kw)
+
+    monkeypatch.setattr(eng, "_dispatch_plan", stalled)
+    seq0 = get_flight().snapshot()["events"][-1]["seq"]
+    pipe.step_round()
+    pipe.step_round()
+    slow = [e for e in get_flight().snapshot()["events"]
+            if e["kind"] == "slow_visit" and e["seq"] > seq0]
+    (rec,) = [e for e in slow if e["phase"] == "engine.pack"]
+    assert rec["program"] == "decode_window" and rec["rows"] == 1
+    assert rec["visit"] == pipe.visits - 1
+    # The slow visit reads the stall within a tenth of its length (and
+    # what a busy machine added to the rest of the pack) ...
+    assert stalled.ms > obs_trace.SLOW_FLOOR_MS
+    assert 0.9 * stalled.ms - 3.0 <= rec["excess_ms"] <= (
+        1.1 * stalled.ms + 30.0), (stalled.ms, rec)
+    # ... its causes sum to it, and the cause injected reads what was
+    # injected: 80 ms off the CPU, 80 ms of this thread's CPU time (a
+    # busy machine's share of the wall is off the CPU, rightly), the
+    # collector's pass, the trace and the compile.
+    split = {c: rec[c] for c in obs_trace.CPU_CAUSES}
+    assert sum(split.values()) == pytest.approx(rec["excess_ms"], abs=0.01)
+    if causes == ("gc",):
+        assert split["gc"] >= 0.8 * collect_garbage.ms, split
+    elif causes == ("trace", "compile"):
+        assert split["trace"] > 0 and split["compile"] > 0
+        assert split["trace"] + split["compile"] >= 0.5 * rec["excess_ms"]
+    else:
+        assert split[causes[0]] >= 0.8 * 80.0, split
+
+
+# -- the pause meter --------------------------------------------------------------
+
+
+def test_the_pause_meter_counts_only_what_is_over_its_threshold(recorder):
+    class Clock:
+        t = 100.0
+
+        def __call__(self):
+            return self.t
+
+    clock, reg = Clock(), MetricsRegistry()
+    oversleeps = iter([0.000, 0.006, 0.019, 0.120, 0.0005, 0.520])
+
+    def sleep(interval):
+        clock.t += interval + next(oversleeps)
+
+    meter = obs_trace.HostPauseMeter(clock=clock, sleep=sleep, registry=reg)
+    for _ in range(3):
+        meter.tick()
+    # GIL hand-over noise: under the threshold.
+    assert meter.read() == 0.0
+    assert f"\n{mnames.HOST_PAUSES_TOTAL} 0\n" in reg.render()
+    meter.tick()                        # 120 ms late: 100 over
+    meter.tick()
+    meter.tick()                        # 520 ms late: 500 over
+    assert meter.read() == pytest.approx(600.0)
+    text = reg.render()
+    assert f"\n{mnames.HOST_PAUSES_TOTAL} 2\n" in text
+    (line,) = [x for x in text.splitlines()
+               if x.startswith(mnames.HOST_PAUSE_MS_TOTAL + " ")]
+    assert float(line.split()[1]) == pytest.approx(600.0)
+    marks = [a["ms"] for n, a in recorder.entered()
+             if n == "parallax.host_pause"]
+    assert marks == pytest.approx([100.0, 500.0])
+
+
+def test_the_meter_reads_a_pause_before_its_thread_has_woken():
+    """A span that ends while the meter still sleeps through the same
+    pause reads it: ``read`` counts the sleep in progress."""
+    class Clock:
+        t = 0.0
+
+        def __call__(self):
+            return self.t
+
+    clock = Clock()
+    seen = []
+
+    def sleep(interval):
+        clock.t += interval + 0.3      # the machine stood still 300 ms
+        seen.append(meter.read())       # ... and a span ends, now
+
+    meter = obs_trace.HostPauseMeter(clock=clock, sleep=sleep,
+                                     registry=MetricsRegistry())
+    meter.tick()
+    assert seen == [pytest.approx(280.0)]
+    assert meter.read() == pytest.approx(280.0)     # counted once
+
+
+def test_the_meters_thread_lives_and_ends_with_the_runner():
+    def meters():
+        return [t for t in threading.enumerate()
+                if t.name == "host-pause-meter"]
+
+    before = len(meters())
+    runner = LocalRunner(InProcessPipeline([build_engine()]))
+    assert len(meters()) == before and obs_trace._pause_meter is None
+    runner.start()
+    try:
+        assert len(meters()) == before + 1
+        assert obs_trace._pause_meter is runner.pause_meter
+        done = runner.submit(request("metered", max_tokens=8))
+        assert done.wait(120.0)
+    finally:
+        runner.stop()
+    assert len(meters()) == before and obs_trace._pause_meter is None
+
+
+def test_the_endpoints_say_what_disturbed_the_loop():
+    """Single-host serve: ``/metrics`` has every miss's reason and the
+    pause meter's two counters, and ``/cluster/status_json`` and
+    ``/debug/device`` the key of a real compile beside JAX's own name
+    for the function built."""
+    fe, runner = build_local_frontend(
+        [build_engine()], SimpleTokenizer(), model_name="tiny")
+
+    async def fn(client):
+        resp = await client.post("/v1/completions", json={
+            "prompt": "hello", "max_tokens": 40, "temperature": 0})
+        assert resp.status == 200, await resp.text()
+        status = await (await client.get("/cluster/status_json")).json()
+        device = await (await client.get("/debug/device")).json()
+        scrape = await (await client.get("/metrics")).text()
+        return status, device, scrape
+
+    try:
+        status, device, scrape = with_client(fe.app, fn)
+    finally:
+        runner.stop()
+    series = {}
+    for line in scrape.splitlines():
+        if line.startswith("parallax_"):
+            name, value = line.rsplit(" ", 1)
+            series[name] = float(value)
+    assert series[mnames.HOST_PAUSES_TOTAL] >= 0
+    assert series[mnames.HOST_PAUSE_MS_TOTAL] >= 0
+    assert series[mnames.JIT_TRACE_MS_TOTAL] > 0
+    misses = {k: v for k, v in series.items()
+              if k.startswith(mnames.WINDOW_NOT_AHEAD_TOTAL + "{")}
+    (first,) = [v for k, v in misses.items()
+                if 'reason="no_window_in_flight"' in k]
+    assert first >= 1
+    for payload in (status["device"], device):
+        recent = payload["compile"]["recent"]
+        assert 0 < len(recent) <= 16
+        for r in recent:
+            assert {"program", "cause", "key", "fun", "compile_ms",
+                    "trace_ms", "visit", "perf_counter_ns",
+                    "cache_hit"} == set(r)
+            assert r["fun"]
+        (window,) = [r for r in recent if r["program"] == "decode_window"][-1:]
+        assert window["key"]["k"] >= 1 and "seq" in window["key"]
+
+
+def test_the_feed_gather_of_a_step_is_a_program_with_a_name():
+    """A one-step decode over device-fed rows swaps their token ids in
+    by a small jitted gather of its own, one program a token bucket:
+    the build nobody had declared inside serve's windows (PERF.md,
+    PR 44). It is noted under ``feed_gather`` with its bucket."""
+    eng = build_engine(decode_lookahead=1)
+    runner = LocalRunner(InProcessPipeline([eng]))
+    runner.start()
+    try:
+        done = [runner.submit(request(f"fed-{i}", max_tokens=12))
+                for i in range(2)]
+        assert all(d.wait(120.0) for d in done)
+    finally:
+        runner.stop()
+    noted = [dict(key) for family, key in eng._noted_program_keys
+             if family == "feed_gather"]
+    assert noted and all(set(k) == {"tokens"} for k in noted)

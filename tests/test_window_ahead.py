@@ -389,3 +389,98 @@ CASES = {
 @pytest.mark.parametrize("case", list(CASES))
 def test_window_ahead_bit_identical(case):
     CASES[case]()
+
+
+# -- why a window was not ahead -----------------------------------------------
+
+
+def _miss_ledger():
+    """The process's ``parallax_window_not_ahead_total`` by reason, the
+    zeros of ``parallax_visit_window_ahead`` and (sum, count) of the
+    avoidable-miss series, each summed over its label sets."""
+    from parallax_tpu.obs import names as mnames
+    from parallax_tpu.obs.registry import get_registry
+
+    reg = get_registry()
+    reasons = {}
+    for line in reg.render().splitlines():
+        if line.startswith(mnames.WINDOW_NOT_AHEAD_TOTAL + "{"):
+            labels, value = line.split("{")[1].split("} ")
+            reason = dict(p.split("=") for p in labels.split(","))["reason"]
+            reasons[reason.strip('"')] = (
+                reasons.get(reason.strip('"'), 0) + float(value))
+    snaps = reg.histogram_snapshots()
+
+    def total(name):
+        s = snaps.get(name, {}).values()
+        return sum(x["sum"] for x in s), sum(x["count"] for x in s)
+
+    ahead, windows = total(mnames.VISIT_WINDOW_AHEAD)
+    return reasons, windows - ahead, total(
+        mnames.VISIT_WINDOW_AHEAD_AVOIDABLE_MISS), windows
+
+
+# case -> the reasons its misses must name, and those they may besides.
+MISS_CASES = {
+    "steady-greedy": ({"no_window_in_flight"}, set()),
+    "stop-budget": ({"no_window_in_flight", "row_ended"}, {"budget_ends"}),
+    "race-abort": ({"no_window_in_flight", "row_ended"}, {"budget_ends"}),
+    "arrival-mid-chain": ({"no_window_in_flight", "row_joined"},
+                          {"row_ended", "budget_ends"}),
+    "page-boundary-context-room": ({"no_window_in_flight", "no_pages"},
+                                   {"row_ended", "budget_ends"}),
+    "hybrid": ({"no_window_in_flight", "snapshot_due"},
+               {"row_ended", "budget_ends"}),
+    "old-path-overlap-off": ({"no_window_in_flight", "overlap_off"}, set()),
+    "old-path-speculative": ({"no_window_in_flight", "speculation"},
+                             {"budget_ends"}),
+    "old-path-finish-every-round": ({"no_window_in_flight", "row_ended"},
+                                    {"budget_ends"}),
+}
+
+
+@pytest.mark.parametrize("case", list(MISS_CASES))
+def test_every_miss_of_the_hand_over_has_exactly_one_reason(case):
+    """On a toy dense engine and the toy hybrid: the reasons sum to the
+    zeros of ``parallax_visit_window_ahead``; a snapshot that is due (or
+    a window that found no room) is avoidable, a finished, aborted or
+    arriving row and a configuration that hands nothing over are not."""
+    from parallax_tpu.runtime.engine import (
+        WINDOW_MISS_AVOIDABLE,
+        WINDOW_MISS_INHERENT,
+        WINDOW_MISS_REASONS,
+    )
+
+    r0, zeros0, (av0, n0), w0 = _miss_ledger()
+    CASES[case]()
+    r1, zeros1, (av1, n1), w1 = _miss_ledger()
+    assert set(r1) == set(WINDOW_MISS_REASONS)      # all there, from 0
+    got = {k: r1[k] - r0.get(k, 0) for k in r1 if r1[k] != r0.get(k, 0)}
+    must, may = MISS_CASES[case]
+    assert must <= set(got) <= must | may, got
+    # Exactly one reason a miss, one observation a window.
+    assert sum(got.values()) == zeros1 - zeros0 > 0
+    assert n1 - n0 == w1 - w0
+    avoidable = sum(v for k, v in got.items() if k in WINDOW_MISS_AVOIDABLE)
+    assert av1 - av0 == avoidable
+    assert (avoidable > 0) == bool(set(got) & {"snapshot_due", "no_pages"})
+    assert {"row_ended", "row_joined", "budget_ends", "speculation",
+            "overlap_off", "no_window_in_flight"} == set(WINDOW_MISS_INHERENT)
+
+
+def test_a_dropped_plan_leaves_its_reason_for_the_window_after_it():
+    """The rows of the window in flight planned without the one that
+    finished meanwhile: the plan is dropped (the rows wait for the
+    resolve), and the window after it names the reason. What the engine
+    keeps of the newest window is its rows' ids and that reason, not
+    the ticket."""
+    r0 = _miss_ledger()[0]
+    eng = _build_engine(K)
+    log = _watch(eng)
+    reqs = _requests(GREEDY, [10, 23, 15])
+    _drive(eng, reqs)
+    r1 = _miss_ledger()[0]
+    assert r1["row_ended"] - r0.get("row_ended", 0) >= 1
+    assert ("empty",) in log            # the dropped plan's dispatch
+    assert eng._window_rows <= {r.request_id for r in reqs}
+    assert eng._window_miss in (None, "row_ended", "budget_ends")
