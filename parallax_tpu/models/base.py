@@ -10,6 +10,9 @@ executor can jit it once per shape bucket with the KV pytree donated.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from collections.abc import Hashable
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,7 @@ import jax.numpy as jnp
 from parallax_tpu.config import LAYER_LINEAR, LAYER_SLIDING, ModelConfig
 from parallax_tpu.models import jamba
 from parallax_tpu.models import layers as L
+from parallax_tpu.obs.trace import note_block_trace
 from parallax_tpu.ops import new_kv_pages
 from parallax_tpu.ops.rope import rope_frequencies, rope_table
 
@@ -85,6 +89,23 @@ class BatchInputs:
     # table and pending summary pages after the rollover, and the window
     # the primary table belongs to.
     eva_window: dict | None = None
+
+
+class BlockKey(NamedTuple):
+    """What a decoder block reads from Python while it is traced, beyond
+    the shapes and structure of its array arguments (which say the
+    layer's kind: a mixer or attention, dense or expert MLP, the step's
+    static flags). Two layers with one key and like arguments are one
+    kind of block and share one trace (``StageModel._block``)."""
+
+    window: int | None       # the layer's sliding window, None = full
+    use_pallas: bool | None
+    axis_name: str | None
+    # (sp_mesh, sp_in_mesh) while the engine traces its SP step, else
+    # None: the SP trace must not be handed the plain step's jaxpr.
+    sp: tuple | None
+    # A family's own per-layer facts (``StageModel._block_key``).
+    extra: Hashable = None
 
 
 class StageModel:
@@ -164,6 +185,9 @@ class StageModel:
         self.cos_table, self.sin_table = rope_table(
             inv, config.max_position_embeddings, scaling
         )
+        # The jaxpr of each kind of block a program has met
+        # (``_block_fn``).
+        self._block_jaxprs: dict = {}
 
     # -- structure --------------------------------------------------------
 
@@ -363,19 +387,16 @@ class StageModel:
             )
 
         new_kv: list[jax.Array] = []
+        carry = None
         for li in range(self.num_local_layers):
             lp = params["layers"][li]
             if lora_sel is not None and str(li) in lora_sel:
                 from parallax_tpu.ops.lora import merge_layer_lora
 
                 lp = merge_layer_lora(lp, lora_sel[str(li)])
-            gi = self.start_layer + li
-            window = (
-                cfg.sliding_window
-                if cfg.layer_type(gi) == LAYER_SLIDING
-                else None
+            x, kv_l, carry = self._block_fn(
+                self._block_key(li), lp, x, kv_caches[li], inputs, carry
             )
-            x, kv_l = self._decoder_layer(lp, x, kv_caches[li], inputs, window)
             new_kv.append(kv_l)
 
         if not self.is_last:
@@ -410,6 +431,64 @@ class StageModel:
     _sp_active = False
     # Set by tp.tp_stage_fn when the lm_head weight is vocab-sharded.
     _lm_head_sharded = False
+
+    def _block_key(self, li: int) -> BlockKey:
+        """Local layer ``li``'s key. The rule a family keeps: a block
+        reads its arguments and its key, nothing else — an attribute
+        that changes after ``__init__`` and is read while a block is
+        traced belongs here (a family's own go into ``extra``), or two
+        layers that differ share a jaxpr. A family that cannot say what
+        its block reads keys it by ``li``: every layer its own kind,
+        traced one by one."""
+        cfg = self.config
+        sliding = cfg.layer_type(self.start_layer + li) == LAYER_SLIDING
+        return BlockKey(
+            window=cfg.sliding_window if sliding else None,
+            use_pallas=self.use_pallas,
+            axis_name=self.axis_name,
+            sp=(self.sp_mesh, self.sp_in_mesh) if self._sp_active else None,
+        )
+
+    def _block_fn(self, key: BlockKey, *args):
+        """The layer loop's one way to call a block: ``_block(key,
+        *args)`` traced to a jaxpr once a kind (the key and the
+        structure, shapes and dtypes of the arguments) and replayed
+        into the caller's trace for every layer of the kind, so the
+        block's Python runs once a kind and program whatever the depth.
+
+        The replay binds the block's equations one by one on the
+        arguments as they are, so the caller's jaxpr is the one the
+        plain loop gave, down to the order in which a decode window's
+        scan first meets each weight, and XLA is handed the same module
+        (a dense stage's letter for letter). A ``jax.jit`` of the block,
+        inlined or lowered as one function called once a layer, takes
+        every leaf of the layer in at the call, in the pytree's order:
+        that reorders the scan's operands, and cost the 3B's decode
+        step 2.2% (PERF.md, PR 45)."""
+        flat, in_tree = jax.tree.flatten(args)
+        kind = (key, in_tree, tuple(jax.typeof(a) for a in flat))
+        hit = self._block_jaxprs.get(kind)
+        if hit is None:
+            closed, out = jax.make_jaxpr(
+                functools.partial(self._block, key), return_shape=True
+            )(*args)
+            hit = self._block_jaxprs[kind] = (
+                closed, jax.tree.structure(out)
+            )
+        closed, out_tree = hit
+        return jax.tree.unflatten(
+            out_tree, jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
+        )
+
+    def _block(self, key: BlockKey, lp: dict, x: jax.Array, kv,
+               inputs: BatchInputs, carry):
+        """One decoder block: ``(x, kv, carry)`` out. ``carry`` is what
+        a family hands from one layer to the next as a value (None
+        here). The body runs only under a trace, once a kind of block
+        and program."""
+        note_block_trace()
+        x, kv = self._decoder_layer(lp, x, kv, inputs, key.window)
+        return x, kv, carry
 
     def _attention(self, lp: dict, h: jax.Array, kv: jax.Array,
                    inputs: BatchInputs, window: int | None):

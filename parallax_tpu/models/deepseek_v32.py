@@ -59,11 +59,6 @@ class DeepseekV32StageModel(DeepseekStageModel):
                 f"{cfg.dsa.indexer_types[self.start_layer]!r}"
             )
         self._idx_softmax_scale = cfg.dsa.index_head_dim ** -0.5
-        # Per-call threading state (reset at every __call__; holds tracers
-        # during jit tracing, which is safe because tracing re-enters
-        # __call__ from the top).
-        self._prev_topk = None
-        self._local_li = 0
 
     # -- cache -------------------------------------------------------------
 
@@ -87,18 +82,14 @@ class DeepseekV32StageModel(DeepseekStageModel):
 
     # -- forward -----------------------------------------------------------
 
-    def __call__(self, params, kv_caches, inputs: BatchInputs):
-        self._prev_topk = None
-        self._local_li = 0
-        return super().__call__(params, kv_caches, inputs)
-
-    def _decoder_layer(self, lp, x, kv, inputs: BatchInputs, window):
-        self._layer_is_full = (
-            self.config.dsa.indexer_types[self.start_layer + self._local_li]
-            == "full"
+    def _block(self, key, lp, x, kv, inputs: BatchInputs, carry):
+        """``carry`` is the newest full layer's top-k (None before the
+        stage's first): it rides into ``_mla_attention`` as a third
+        member of the layer's cache and comes back the same way."""
+        x, (mla_pages, index_pages, topk), _ = super()._block(
+            key, lp, x, (*kv, carry), inputs, None
         )
-        self._local_li += 1
-        return super()._decoder_layer(lp, x, kv, inputs, window)
+        return x, (mla_pages, index_pages), topk
 
     def _indexer_topk(self, p, x, qr, index_cache, inputs: BatchInputs):
         """Lightning indexer: score the cached context, return top-k
@@ -147,25 +138,25 @@ class DeepseekV32StageModel(DeepseekStageModel):
         return dsa_topk_indices(scores, index_topk=d.index_topk), index_cache
 
     def _mla_attention(self, p, x, cache, inputs: BatchInputs):
-        mla_pages, index_pages = cache
+        # A "full" layer is one that holds index pages (new_kv_caches).
+        mla_pages, index_pages, prev_topk = cache
         q_latent, q_pe, latent, k_pe, w_uv, qr, hq = self._mla_qkv(
             p, x, inputs
         )
         mla_pages = store_mla_cache(mla_pages, latent, k_pe,
                                     inputs.slot_mapping)
 
-        if self._layer_is_full:
+        if index_pages is not None:
             topk, index_pages = self._indexer_topk(
                 p["indexer"], x, qr, index_pages, inputs
             )
-            self._prev_topk = topk
         else:
-            if self._prev_topk is None:
+            if prev_topk is None:
                 raise ValueError(
                     "DSA shared layer requires a previous full layer's "
                     "top-k in the same shard"
                 )
-            topk = self._prev_topk
+            topk = prev_topk
 
         out_latent = mla_ragged_sparse_attention_xla(
             q_latent,
@@ -179,7 +170,7 @@ class DeepseekV32StageModel(DeepseekStageModel):
             kv_lora_rank=self.config.mla.kv_lora_rank,
         )
         out = self._mla_out(p, out_latent, w_uv, hq)
-        return out, (mla_pages, index_pages)
+        return out, (mla_pages, index_pages, topk)
 
     # -- init --------------------------------------------------------------
 

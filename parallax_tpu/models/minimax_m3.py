@@ -63,7 +63,6 @@ class MiniMaxM3StageModel(MoEStageModel):
             float(cfg.extra.get("swiglu_limit", 7.0)),
             float(cfg.extra.get("swiglu_beta", 1.0)),
         )
-        self._local_li = 0
 
     # -- cache -------------------------------------------------------------
 
@@ -89,21 +88,14 @@ class MiniMaxM3StageModel(MoEStageModel):
 
     # -- forward -----------------------------------------------------------
 
-    def __call__(self, params, kv_caches, inputs: BatchInputs):
-        self._local_li = 0
-        return super().__call__(params, kv_caches, inputs)
-
-    def _decoder_layer(self, lp, x, kv, inputs: BatchInputs, window):
-        self._layer_gi = self.start_layer + self._local_li
-        self._local_li += 1
-        return super()._decoder_layer(lp, x, kv, inputs, window)
-
     def _attention(self, lp, h, kv, inputs: BatchInputs, window):
         cfg = self.config
         p = lp["self_attn"]
         t = h.shape[0]
         d = cfg.head_dim
-        sparse = self._layer_sparse(self._layer_gi)
+        # A sparse layer is one whose cache holds index pages beside
+        # its K/V pages (new_kv_caches).
+        sparse = isinstance(kv, tuple)
 
         q = L.linear(h, p["q_proj"]).reshape(t, -1, d)
         k = L.linear(h, p["k_proj"]).reshape(t, -1, d)

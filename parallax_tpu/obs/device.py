@@ -53,7 +53,11 @@ import time
 from parallax_tpu.utils import get_logger
 from parallax_tpu.analysis.sanitizer import make_lock
 from parallax_tpu.obs import names as mnames
-from parallax_tpu.obs.trace import current_visit, jit_trace_seconds
+from parallax_tpu.obs.trace import (
+    block_traces,
+    current_visit,
+    jit_trace_seconds,
+)
 
 logger = get_logger(__name__)
 
@@ -406,10 +410,11 @@ class CompileObservatory:
         with self._lock:
             cause = self._diff_cause(self._prev_key.get(family), key)
             self._prev_key[family] = key
-            # With the visit and the thread's trace seconds so far: the
-            # build's own are what they grow by until its event.
+            # With the visit and the thread's trace seconds and traced
+            # blocks so far: the build's own are what they grow by
+            # until its event.
             self._pending.append((family, cause, now, key, current_visit(),
-                                  jit_trace_seconds()))
+                                  jit_trace_seconds(), block_traces()))
         return cause
 
     def set_live_executables(self, family: str, count: int) -> None:
@@ -440,12 +445,13 @@ class CompileObservatory:
         the lock."""
         # (A build nobody noted still fell in the visit it fell in.)
         family, cause, key, visit = "other", "unknown", {}, current_visit()
-        traced = 0.0
+        traced, blocks = 0.0, 0
         while self._pending:
-            fam, c, t, k, v, trace0 = self._pending.pop()
+            fam, c, t, k, v, trace0, blocks0 = self._pending.pop()
             if now - t <= self.NOTE_TTL_S:
                 family, cause, key, visit = fam, c, k, v
                 traced = max(0.0, jit_trace_seconds() - trace0)
+                blocks = block_traces() - blocks0
                 break
         self._recent.append({
             "program": family,
@@ -454,6 +460,7 @@ class CompileObservatory:
             "fun": fun,
             "compile_ms": round(duration_s * 1e3, 3),
             "trace_ms": round(traced * 1e3, 3),
+            "block_traces": blocks,
             "visit": visit,
             "perf_counter_ns": time.perf_counter_ns(),
             "cache_hit": cache_hit,

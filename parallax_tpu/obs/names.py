@@ -55,6 +55,7 @@ LOOP_OFFCPU_MS_TOTAL = "parallax_loop_offcpu_ms_total"
 HOST_PAUSE_MS_TOTAL = "parallax_host_pause_ms_total"
 HOST_PAUSES_TOTAL = "parallax_host_pauses_total"
 JIT_TRACE_MS_TOTAL = "parallax_jit_trace_ms_total"
+BLOCK_TRACES_TOTAL = "parallax_block_traces_total"
 WINDOW_NOT_AHEAD_TOTAL = "parallax_window_not_ahead_total"
 VISIT_WINDOW_AHEAD_AVOIDABLE_MISS = (
     "parallax_visit_window_ahead_avoidable_miss"
@@ -279,6 +280,11 @@ HELP: dict[str, str] = {
         "Milliseconds JAX spent tracing functions to jaxprs and "
         "lowering them to MLIR (a retrace is no compile and costs "
         "Python time all the same)"
+    ),
+    BLOCK_TRACES_TOTAL: (
+        "Times the Python body of a decoder block ran under a trace "
+        "(models/base.py StageModel._block): once a kind of block and "
+        "program, whatever the stage's depth"
     ),
     WINDOW_NOT_AHEAD_TOTAL: (
         "Decode windows that were not enqueued off the carry of the "

@@ -463,10 +463,12 @@ _WARN_ALWAYS_MS = 100.0
 class _JitSeconds(threading.local):
     """Seconds the JAX monitoring listener (utils/compile_cache.py) saw
     on the calling thread: backend compiles (loads from the persistent
-    cache with them), and traces to jaxprs with lowerings to MLIR."""
+    cache with them), and traces to jaxprs with lowerings to MLIR; and
+    the decoder blocks traced on it (``note_block_trace``)."""
 
     compile = 0.0
     trace = 0.0
+    blocks = 0
 
 
 _jit = _JitSeconds()
@@ -490,6 +492,18 @@ def note_jit_seconds(kind: str, seconds: float) -> None:
 def jit_trace_seconds() -> float:
     """Trace and lowering seconds seen on the calling thread so far."""
     return _jit.trace
+
+
+def note_block_trace() -> None:
+    """The Python body of a decoder block runs (models/base.py
+    ``StageModel._block``): under a trace, never in a step."""
+    _jit.blocks += 1
+    _SLOW_VISITS.count_block_trace()
+
+
+def block_traces() -> int:
+    """Decoder blocks traced on the calling thread so far."""
+    return _jit.blocks
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -543,6 +557,7 @@ class SlowVisits:
         self._c_excess: dict[tuple[str, str], object] = {}
         self._c_offcpu = None
         self._c_trace = None
+        self._c_blocks = None
         self._warned_at = 0.0
         self._unwarned = 0
 
@@ -575,6 +590,10 @@ class SlowVisits:
             mnames.JIT_TRACE_MS_TOTAL,
             mnames.help_text(mnames.JIT_TRACE_MS_TOTAL),
         ).labels()
+        self._c_blocks = registry.counter(
+            mnames.BLOCK_TRACES_TOTAL,
+            mnames.help_text(mnames.BLOCK_TRACES_TOTAL),
+        ).labels()
         self._c_offcpu = registry.counter(
             mnames.LOOP_OFFCPU_MS_TOTAL,
             mnames.help_text(mnames.LOOP_OFFCPU_MS_TOTAL),
@@ -585,6 +604,10 @@ class SlowVisits:
     def count_trace(self, ms: float) -> None:
         if self._c_trace is not None:
             self._c_trace.inc(ms)
+
+    def count_block_trace(self) -> None:
+        if self._c_blocks is not None:
+            self._c_blocks.inc()
 
     def baseline_ms(self, phase: str, kind: str = "") -> float | None:
         b = self._base.get((phase, kind))

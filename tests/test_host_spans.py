@@ -1163,8 +1163,8 @@ def test_the_endpoints_say_what_disturbed_the_loop():
         assert 0 < len(recent) <= 16
         for r in recent:
             assert {"program", "cause", "key", "fun", "compile_ms",
-                    "trace_ms", "visit", "perf_counter_ns",
-                    "cache_hit"} == set(r)
+                    "trace_ms", "block_traces", "visit",
+                    "perf_counter_ns", "cache_hit"} == set(r)
             assert r["fun"]
         (window,) = [r for r in recent if r["program"] == "decode_window"][-1:]
         assert window["key"]["k"] >= 1 and "seq" in window["key"]
