@@ -77,6 +77,13 @@ GATE_TABLE: tuple[Gate, ...] = (
     ),
     Gate(
         feature="host_cache_bytes",
+        marker="host KV tier disabled: a looped stack keeps",
+        doc="docs/memory.md",
+        reason="a page id addresses one place a pass in a layer's array; "
+               "the tier's gather and its page images know one a layer",
+    ),
+    Gate(
+        feature="host_cache_bytes",
         marker="host KV tier disabled: unsupported KV layout",
         doc="docs/memory.md",
         reason="non-paged layouts and sub-page budgets cannot tier",

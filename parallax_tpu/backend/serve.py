@@ -407,6 +407,9 @@ def build_local_frontend(
             "stages": [
                 {
                     "layers": [e.model.start_layer, e.model.end_layer],
+                    # loop_passes, kv_cache_layers, kv_bytes_per_token:
+                    # what a page id addresses (docs/memory.md).
+                    **e.kv_layout(),
                     "running": len(e.scheduler.running),
                     "waiting": len(e.scheduler.wait_queue),
                     "num_pages": e.cfg.num_pages,
@@ -660,7 +663,8 @@ def serve_main(args) -> int:
         ((args.max_model_len + page_size - 1) // page_size + 1)
         * args.max_batch_size * 2
     )
-    # Pages are counted only for the layers that attend; a hybrid's
+    # Pages are counted over the stage's cache layers (the layers that
+    # attend, once a pass of a looped stack); a hybrid's
     # state slots (the engine's 2 x batch active + prefix snapshot slots
     # + the null slot) come off the budget first.
     state_slots = 0
@@ -673,7 +677,7 @@ def serve_main(args) -> int:
     num_pages = min(
         derive_num_pages(
             device_free_memory_bytes(args.kv_utilization),
-            config, config.num_paged_layers(start, end), page_size,
+            config, config.num_cache_layers(start, end), page_size,
             state_bytes=state_slots * config.state_bytes_per_slot(start, end),
         ),
         addressable,

@@ -51,10 +51,7 @@ def _model_catalog() -> list[dict]:
         embed = cfg.embedding_params()
         total = layer_params + embed * (1 if cfg.tie_word_embeddings else 2)
         weight_bytes = total * cfg.param_bytes_per_element
-        kv_mib_per_1k = (
-            cfg.kv_bytes_per_token_per_layer() * cfg.num_hidden_layers
-            * 1024 / 2**20
-        )
+        kv_mib_per_1k = cfg.kv_bytes_per_token() * 1024 / 2**20
         per_chip = 16 * 2**30 * HBM_UTILIZATION * (1 - KV_RESERVE_FRACTION)
         out.append(dict(
             name=name,

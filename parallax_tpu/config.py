@@ -261,6 +261,14 @@ class ModelConfig:
     # The residual stream is carried and added in float32 (EvaByte
     # fp32_skip_add); matmul inputs stay in the weights' dtype.
     fp32_residual: bool = False
+    # A looped stack (Ouro ``total_ut_steps``): the stage's layers are
+    # applied this many times a token with the same weights, pass ``u``
+    # layer ``l`` on cache layer ``u * layers + l``, and the final norm
+    # closes every pass (``StageModel.__call__``).
+    loop_passes: int = 1
+    # Each branch of a block is normed again before its add (Ouro's
+    # ``input_layernorm_2`` / ``post_attention_layernorm_2``).
+    sandwich_norm: bool = False
     dtype: str = "bfloat16"
     # Bytes per parameter after quantization (bf16 => 2.0).
     param_bytes_per_element: float = 2.0
@@ -338,6 +346,21 @@ class ModelConfig:
             for i in range(start_layer, end_layer)
         )
 
+    def num_cache_layers(self, start_layer: int = 0,
+                         end_layer: int | None = None) -> int:
+        """Cache layers of ``[start_layer, end_layer)``: what a page id
+        addresses, and what a cached token's bytes are counted over. A
+        looped stack writes every pass to cache layers of its own, so
+        it has ``loop_passes`` times its paged weight layers."""
+        return self.loop_passes * self.num_paged_layers(start_layer, end_layer)
+
+    def kv_bytes_per_token(self, start_layer: int = 0,
+                           end_layer: int | None = None) -> int:
+        """HBM bytes of KV one cached token holds over the cache layers
+        of ``[start_layer, end_layer)`` (bf16 cache)."""
+        return (self.kv_bytes_per_token_per_layer()
+                * self.num_cache_layers(start_layer, end_layer))
+
     def state_bytes_per_slot(self, start_layer: int = 0,
                              end_layer: int | None = None) -> int:
         """Float32 bytes one state slot occupies over the recurrent
@@ -397,7 +420,8 @@ class ModelConfig:
             ffn += h * e.num_experts  # router
         else:
             ffn = 3 * h * self.intermediate_size
-        return attn + ffn + 2 * h  # + 2 rmsnorm vectors
+        # + 2 rmsnorm vectors (4 with a norm on each branch too)
+        return attn + ffn + (4 if self.sandwich_norm else 2) * h
 
     def is_moe_layer(self, layer_idx: int) -> bool:
         if self.moe is None:
@@ -414,7 +438,8 @@ class ModelConfig:
 
         Mirrors the roofline inputs of ``src/scheduling/model_info.py:107-144``
         (2*params matmul FLOPs + attention score FLOPs; MoE counts only the
-        activated experts).
+        activated experts). A looped stack applies the layer
+        ``loop_passes`` times a token.
         """
         h = self.hidden_size
         attn_proj = 2 * num_tokens * (
@@ -430,7 +455,7 @@ class ModelConfig:
             ffn = 2 * num_tokens * 3 * h * e.moe_intermediate_size * active
         else:
             ffn = 2 * num_tokens * 3 * h * self.intermediate_size
-        return float(attn_proj + attn_score + ffn)
+        return float(self.loop_passes * (attn_proj + attn_score + ffn))
 
     def lm_head_flops(self, num_tokens: int) -> float:
         return float(2 * num_tokens * self.hidden_size * self.vocab_size)
@@ -751,6 +776,21 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
         if mamba.proj_bias:
             raise ValueError("mamba_proj_bias is not supported")
 
+    # Ouro: the stack is applied ``total_ut_steps`` times a token.
+    is_ouro = cfg.get("model_type") == "ouro" or "Ouro" in architecture
+    if is_ouro and architecture == "UnknownForCausalLM":
+        architecture = "OuroForCausalLM"
+    loop_passes = int(_get(cfg, "total_ut_steps", default=1)) if is_ouro else 1
+    if loop_passes < 1:
+        raise ValueError(f"total_ut_steps must be >= 1, got {loop_passes}")
+    if is_ouro and float(_get(cfg, "early_exit_threshold", default=1.0)) < 1.0:
+        raise ValueError(
+            "early_exit_threshold < 1 is not supported: rows of one batch "
+            "would leave after different passes (the exit gate is held "
+            "and never evaluated; every row runs all "
+            f"{loop_passes} passes)"
+        )
+
     eva = None
     if is_evabyte or cfg.get("attention_class") == "eva":
         eva = EvaConfig(
@@ -833,8 +873,12 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
         use_rope=mamba is None,
         eva=eva,
         norm_offset=1.0 if _get(cfg, "norm_add_unit_offset") else 0.0,
-        fp32_residual=(mamba is not None
+        # Ouro: 192 block applications on one bf16 stream are not
+        # ``correct`` against the float32 reference (PERF.md, PR 46).
+        fp32_residual=(mamba is not None or is_ouro
                        or bool(_get(cfg, "fp32_skip_add", default=False))),
+        loop_passes=loop_passes,
+        sandwich_norm=is_ouro,
         dtype=str(_get(cfg, "torch_dtype", "dtype", default="bfloat16")),
         param_bytes_per_element=pbpe,
         partial_rotary_factor=float(_get(cfg, "partial_rotary_factor", default=1.0)),

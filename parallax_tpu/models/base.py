@@ -158,6 +158,16 @@ class StageModel:
             raise ValueError(
                 "EVA attention (per-head summary vectors) runs at tp-size 1"
             )
+        if config.loop_passes > 1 and (start_layer, end_layer) != (
+            0, config.num_hidden_layers
+        ):
+            raise ValueError(
+                f"a looped stack ({config.loop_passes} passes over "
+                f"{config.num_hidden_layers} layers) runs whole on one "
+                f"stage, not layers [{start_layer}, {end_layer}): the "
+                "stream would go round the ring of stages once a pass, "
+                "the final norm on the last stage feeding the first"
+            )
         if tp_size > 1:
             for dim, name in (
                 (config.num_attention_heads, "num_attention_heads"),
@@ -219,13 +229,16 @@ class StageModel:
         num_state_slots: int = 0,
     ) -> list[jax.Array]:
         """One paged cache per local layer (state slots for a Mamba
-        layer)."""
+        layer). A looped stack's layer holds every pass's pages in one
+        array, ``loop_passes x num_pages`` of them (pass ``u`` in the
+        ``u``-th ``num_pages``: ``_looped_passes``), so a page id
+        addresses a page in every pass of every layer."""
         if self.config.mamba is not None:
             return jamba.new_caches(self, num_pages, page_size, dtype,
                               num_state_slots)
         return [
             new_kv_pages(
-                num_pages,
+                self.config.loop_passes * num_pages,
                 page_size,
                 self.config.num_key_value_heads,
                 self.config.head_dim,
@@ -302,6 +315,19 @@ class StageModel:
                     "down_proj": dense(k[6], h, cfg.intermediate_size),
                 },
             }
+            if cfg.sandwich_norm:
+                # The branch norms' weights are the residual branches'
+                # scale: (2 x layers)^-0.5, GPT-2's depth scaling of its
+                # residual projections, put where a sandwich norm puts a
+                # branch's scale. At ones every block of a pass rewrites
+                # the stream, and a stack of random blocks applied 192
+                # times amplifies a rounding ~100x: not even a float32
+                # program over a bf16 cache stays within the benchmark's
+                # logprob limit of its float32 reference (PERF.md, PR 46).
+                branch = (2 * cfg.num_hidden_layers) ** -0.5
+                for name in ("input_layernorm_2",
+                             "post_attention_layernorm_2"):
+                    layer[name] = {"weight": jnp.full((h,), branch, dtype)}
             if cfg.use_qk_norm:
                 layer["self_attn"]["q_norm"] = {"weight": jnp.ones((d,), dtype)}
                 layer["self_attn"]["k_norm"] = {"weight": jnp.ones((d,), dtype)}
@@ -349,6 +375,13 @@ class StageModel:
                         * 0.02
                     ).astype(dtype)
                 }
+            if cfg.sandwich_norm:
+                # Ouro's exit gate (hidden -> 1): held, never evaluated
+                # (``normalize_config`` refuses a threshold under 1).
+                params["early_exit_gate"] = dense(
+                    jax.random.fold_in(keys[-1], 2), 1, cfg.hidden_size,
+                    bias=True,
+                )
         if cfg.mamba is not None:
             params = jamba.init_mixers(self, params, rng, dtype)
         return params
@@ -386,23 +419,33 @@ class StageModel:
                 inputs.lora, axis_name=self.axis_name, tp=self.tp_size
             )
 
-        new_kv: list[jax.Array] = []
-        carry = None
-        for li in range(self.num_local_layers):
-            lp = params["layers"][li]
-            if lora_sel is not None and str(li) in lora_sel:
-                from parallax_tpu.ops.lora import merge_layer_lora
+        def one_pass(x, caches, inputs):
+            """The local layers once: ``(x, caches)`` out."""
+            carry, out = None, []
+            for li in range(self.num_local_layers):
+                lp = params["layers"][li]
+                if lora_sel is not None and str(li) in lora_sel:
+                    from parallax_tpu.ops.lora import merge_layer_lora
 
-                lp = merge_layer_lora(lp, lora_sel[str(li)])
-            x, kv_l, carry = self._block_fn(
-                self._block_key(li), lp, x, kv_caches[li], inputs, carry
-            )
-            new_kv.append(kv_l)
+                    lp = merge_layer_lora(lp, lora_sel[str(li)])
+                x, kv_l, carry = self._block_fn(
+                    self._block_key(li), lp, x, caches[li], inputs, carry
+                )
+                out.append(kv_l)
+            return x, out
+
+        if cfg.loop_passes == 1:
+            x, new_kv = one_pass(x, kv_caches, inputs)
+        else:
+            x, new_kv = self._looped_passes(one_pass, params, x, kv_caches,
+                                            inputs)
 
         if not self.is_last:
             return x, new_kv
 
-        x = self._rms(x, params["norm"]["weight"])
+        if cfg.loop_passes == 1:
+            # (A looped stack's final norm closed its last pass.)
+            x = self._rms(x, params["norm"]["weight"])
         x = x[inputs.logits_indices]
         head = params.get("lm_head") or params["embed_tokens"]
         if cfg.fp32_residual:
@@ -418,6 +461,39 @@ class StageModel:
                 logits, self.axis_name, axis=1, tiled=True
             )
         return logits, new_kv
+
+    def _looped_passes(self, one_pass, params, x, kv_caches, inputs):
+        """A looped stack: the layer walk ``loop_passes`` times under one
+        ``lax.fori_loop``, so a program holds the walk once whatever the
+        passes (the unrolled walk's programs were four times the size:
+        57-67 s of compile each on a v5e's host against ~15,
+        PERF.md, PR 46). A layer's cache array holds every pass's pages,
+        ``passes x num_pages`` of them, pass ``u`` in the ``u``-th
+        ``num_pages``: the pass reaches a block only as the page table
+        and slots it is handed, shifted by ``u * num_pages``, so all
+        passes and layers replay one block jaxpr. The final norm closes
+        every pass: its output is pass ``u``'s result and pass
+        ``u + 1``'s input (the last one's is what the head reads)."""
+        passes = self.config.loop_passes
+        total, page_size = kv_caches[0].shape[:2]
+        num_pages = total // passes
+
+        def body(u, state):
+            x, caches = state
+            slots = inputs.slot_mapping
+            shifted = dataclasses.replace(
+                inputs,
+                page_indices=inputs.page_indices + u * num_pages,
+                # (< 0 is padding: nothing is written.)
+                slot_mapping=jnp.where(
+                    slots >= 0, slots + u * (num_pages * page_size), slots
+                ),
+            )
+            x, caches = one_pass(x, caches, shifted)
+            return self._rms(x, params["norm"]["weight"]), caches
+
+        with jax.named_scope("loop_pass"):
+            return jax.lax.fori_loop(0, passes, body, (x, list(kv_caches)))
 
     # Sequence-parallel mode: set by the engine's SP dispatch wrapper while
     # tracing its long-prefill step function (ring attention over the
@@ -543,9 +619,18 @@ class StageModel:
         if not (precise and "mamba" in lp):
             h = h.astype(act)
         attn_out, kv = self._attention(lp, h, kv, inputs, window)
+        if cfg.sandwich_norm:
+            # Each branch is normed again before its add, in the
+            # stream's dtype.
+            attn_out = self._rms(attn_out.astype(x.dtype),
+                                 lp["input_layernorm_2"]["weight"])
         x = x + attn_out
         h = self._rms(x, lp["post_attention_layernorm"]["weight"])
-        x = x + self._mlp(lp, h if precise else h.astype(act))
+        mlp_out = self._mlp(lp, h if precise else h.astype(act))
+        if cfg.sandwich_norm:
+            mlp_out = self._rms(mlp_out.astype(x.dtype),
+                                lp["post_attention_layernorm_2"]["weight"])
+        x = x + mlp_out
         return x, kv
 
     def _mlp(self, lp: dict, h: jax.Array) -> jax.Array:

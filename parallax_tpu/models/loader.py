@@ -94,6 +94,8 @@ def shard_key_filter(
                     if end_layer == num_layers else None)
     if key.startswith("lm_head."):
         return key if end_layer == num_layers else None
+    if key.startswith("model.early_exit_gate."):   # Ouro's exit gate
+        return (key[len("model."):] if end_layer == num_layers else None)
     return None
 
 

@@ -24,7 +24,9 @@ def register_model(*architectures: str):
 # its unit-offset norm, float32 residual, EVA summaries and multi-head
 # output matrix all come from the normalized config (``ModelConfig.eva``).
 # Jamba likewise (``ModelConfig.mamba``, models/jamba.py): its Mamba layers
-# are a mixer in the block's attention slot.
+# are a mixer in the block's attention slot. Ouro too: its looped stack
+# (``ModelConfig.loop_passes``) is the layer loop run several times, its
+# sandwich norms two more vectors in the block (``sandwich_norm``).
 for _arch in (
     "LlamaForCausalLM",
     "MistralForCausalLM",
@@ -32,6 +34,7 @@ for _arch in (
     "Qwen3ForCausalLM",
     "EvaByteForCausalLM",
     "JambaForCausalLM",
+    "OuroForCausalLM",
 ):
     MODEL_REGISTRY[_arch] = StageModel
 

@@ -73,6 +73,11 @@ STATE_SLOTS_IN_USE = "parallax_state_slots_in_use"
 STATE_SLOTS_TOTAL = "parallax_state_slots_total"
 STATE_SNAPSHOT_MS = "parallax_state_snapshot_ms"
 
+# -- the cache's layout (runtime/engine.py): what a page id addresses -------
+LOOP_PASSES = "parallax_loop_passes"
+KV_CACHE_LAYERS = "parallax_kv_cache_layers"
+KV_BYTES_PER_TOKEN = "parallax_kv_bytes_per_token"
+
 # -- KV memory tier (runtime/engine.py) -------------------------------------
 KV_PAGE_OCCUPANCY = "parallax_kv_page_occupancy"
 KV_PREEMPTIONS_TOTAL = "parallax_kv_preemptions_total"
@@ -334,6 +339,19 @@ HELP: dict[str, str] = {
         "Milliseconds of host work per snapshot or restore of a row's "
         "recurrent state (the enqueue of the on-device slot-to-slot "
         "copy); span parallax.engine.state_snapshot"
+    ),
+    LOOP_PASSES: (
+        "Times the stage applies its layers to a token (a looped "
+        "stack's total_ut_steps; 1 for every other model)"
+    ),
+    KV_CACHE_LAYERS: (
+        "Cache layers a page id addresses on the stage: its layers "
+        "that hold pages, once a pass of a looped stack"
+    ),
+    KV_BYTES_PER_TOKEN: (
+        "Device bytes of KV one cached token holds on the stage over "
+        "all its cache layers, at the cache's dtype: what the page "
+        "pool is divided by"
     ),
     KV_PAGE_OCCUPANCY: "Fraction of KV pages in use (0..1)",
     KV_PREEMPTIONS_TOTAL: "Decode-OOM preemptions to the host KV tier",

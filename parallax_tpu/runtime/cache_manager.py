@@ -27,24 +27,25 @@ logger = get_logger(__name__)
 
 
 def kv_bytes_per_page(
-    config: ModelConfig, num_paged_layers: int, page_size: int, dtype_bytes: int = 2
+    config: ModelConfig, num_cache_layers: int, page_size: int, dtype_bytes: int = 2
 ) -> int:
-    """Device bytes one page occupies across this shard's layers that
-    hold pages (``ModelConfig.num_paged_layers``: all of them but a
-    hybrid's recurrent layers, which carry state slots instead).
+    """Device bytes one page occupies across this shard's cache layers
+    (``ModelConfig.num_cache_layers``: every layer but a hybrid's
+    recurrent ones, which carry state slots instead, and once a pass
+    for a looped stack, whose passes each write pages of their own).
 
     Uses the config's per-token accounting, which covers MLA latent+rope
     and the DSA index-key cache (reference DSA/MSA index-cache budgeting,
     cache_manager.py:354-420).
     """
     per_token = config.kv_bytes_per_token_per_layer() * dtype_bytes // 2
-    return per_token * page_size * num_paged_layers
+    return per_token * page_size * num_cache_layers
 
 
 def derive_num_pages(
     free_bytes: int,
     config: ModelConfig,
-    num_paged_layers: int,
+    num_cache_layers: int,
     page_size: int,
     utilization: float = 0.9,
     dtype_bytes: int = 2,
@@ -55,7 +56,7 @@ def derive_num_pages(
     ``state_bytes``: what a hybrid's recurrent-state slots will hold
     (allocated beside the pool), taken off the budget before pages are
     sized."""
-    per_page = kv_bytes_per_page(config, num_paged_layers, page_size, dtype_bytes)
+    per_page = kv_bytes_per_page(config, num_cache_layers, page_size, dtype_bytes)
     budget = int(free_bytes * utilization) - state_bytes
     return max(8, budget // max(1, per_page))
 

@@ -33,12 +33,14 @@ class RooflinePerformanceModel:
 
     def layer_latency_ms(self, batch_size: int = 1, context_len: int = 1024) -> float:
         flops = self.model.decoder_layer_flops(batch_size, context_len)
-        # Decode streams the layer's params + the batch's KV for this layer.
-        param_bytes = (
+        # Decode streams the layer's params + the batch's KV for this
+        # layer, once a pass of a looped stack.
+        passes = self.model.loop_passes
+        param_bytes = passes * (
             self.model.decoder_layer_params(0)
             * self.model.param_bytes_per_element
         )
-        kv_bytes = (
+        kv_bytes = passes * (
             self.model.kv_bytes_per_token_per_layer() * context_len * batch_size
         )
         compute_s = flops / (self.hardware.total_tflops * 1e12)
@@ -347,20 +349,32 @@ class Node:
     # -- capacity ---------------------------------------------------------
 
     def layer_capacity(self) -> int:
-        """Max decoder layers this node can host (HBM-bound)."""
+        """Max decoder layers this node can host (HBM-bound). A looped
+        stack runs whole on one stage (``StageModel`` refuses a partial
+        range), so a node holds all of its layers or none: no allocator
+        is then ever handed a capacity it could cut a range from."""
         cap = self.perf.max_layers_in_memory()
-        return min(cap, self.model.num_hidden_layers)
+        total = self.model.num_hidden_layers
+        if self.model.loop_passes > 1:
+            return total if cap >= total else 0
+        return min(cap, total)
 
     def max_concurrent_requests(self, avg_context: int = 2048) -> int:
         """KV-budget-derived admission cap (reference node.py:212-246)."""
-        layers = self.num_layers or 1
         kv_budget = (
             self.hardware.total_hbm_bytes * HBM_UTILIZATION
             * KV_RESERVE_FRACTION
         )
-        per_req = (
-            self.model.kv_bytes_per_token_per_layer() * avg_context * layers
+        # Over the cache layers of the node's range (one layer's while
+        # it has none): a hybrid's recurrent layers hold no pages, a
+        # looped stack's hold them once a pass.
+        per_token = (
+            self.model.kv_bytes_per_token(self.start_layer, self.end_layer)
+            if self.num_layers
+            else (self.model.kv_bytes_per_token_per_layer()
+                  * self.model.loop_passes)
         )
+        per_req = max(1, per_token) * avg_context
         return max(1, int(kv_budget // per_req))
 
     # -- latency ----------------------------------------------------------

@@ -16,7 +16,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 for _stem in ("test_loadgen", "test_spec", "test_work", "test_trace_reduce",
-              "test_idle_by_span", "test_jamba_work",
+              "test_idle_by_span", "test_jamba_work", "test_ouro_work",
               "test_disturbance_readers", "test_setup_readers"):
     _mod = importlib.import_module(f"benchmarks.tests.{_stem}")
     for _name, _obj in vars(_mod).items():

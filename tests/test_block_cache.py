@@ -86,6 +86,13 @@ V32 = dict(
     # full, shared, shared, full, shared: top-k handed on as a value.
     index_topk_freq=3, index_skip_topk_offset=0,
 )
+OURO = dict(
+    architectures=["OuroForCausalLM"], model_type="ouro", hidden_size=64,
+    num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+    head_dim=16, intermediate_size=128, vocab_size=211, total_ut_steps=2,
+    early_exit_threshold=1, rope_theta=1000000, rms_norm_eps=1e-6,
+    tie_word_embeddings=False, max_position_embeddings=512,
+)
 ENGINE = dict(page_size=8, num_pages=128, max_model_len=512,
               kv_dtype="float32", max_num_tokens_per_batch=512,
               enable_prefix_cache=False)
@@ -104,6 +111,51 @@ def plain_loop(model):
     ``StageModel.__call__`` did before it called a cached block."""
     twin = copy.copy(model)
     twin._block_fn = twin._block    # no cache: every layer's body runs
+    return twin
+
+
+def parents_call(self, params, kv_caches, inputs):
+    """``StageModel.__call__`` as PR 45 left it, letter for letter (the
+    witness lives in this file): one walk over the layers, no pass, no
+    scope. PR 46 put a loop over a looped stack's passes around the walk;
+    a model of one pass must still be handed to XLA as this."""
+    from parallax_tpu.models import layers as L
+
+    cfg = self.config
+    if self.is_first:
+        x = L.embed_lookup(params["embed_tokens"], inputs.token_ids)
+    else:
+        x = inputs.hidden_states
+    if cfg.fp32_residual:
+        x = x.astype(jnp.float32)
+    assert inputs.lora is None
+    new_kv = []
+    carry = None
+    for li in range(self.num_local_layers):
+        lp = params["layers"][li]
+        x, kv_l, carry = self._block_fn(
+            self._block_key(li), lp, x, kv_caches[li], inputs, carry
+        )
+        new_kv.append(kv_l)
+    if not self.is_last:
+        return x, new_kv
+    x = self._rms(x, params["norm"]["weight"])
+    x = x[inputs.logits_indices]
+    head = params.get("lm_head") or params["embed_tokens"]
+    if cfg.fp32_residual:
+        x = x.astype(params["norm"]["weight"].dtype)
+    logits = L.lm_head_logits(x, head)
+    if cfg.eva is not None and cfg.eva.num_pred_heads > 1:
+        logits = logits[:, : cfg.vocab_size]
+    return logits, new_kv
+
+
+def parents_loop(model):
+    """``model`` with the parent's ``__call__`` (same class name, so the
+    jitted function is named alike)."""
+    twin = copy.copy(model)
+    twin.__class__ = type(type(model).__name__, (type(model),),
+                          {"__call__": parents_call})
     return twin
 
 
@@ -206,6 +258,10 @@ CASES = {
     # full with a top-k handed in, layer 4 shared: four kinds.
     "deepseek-v32-shared-topk": dict(hf=V32, kinds=4),
     "lora-on-some-layers": dict(hf=DENSE, kinds=2, lora=(1, 3)),
+    # Two passes over three layers under one ``fori_loop``: the walk is
+    # in the program once, one kind — the pass reaches a block only as
+    # the page table it is handed.
+    "ouro-toy-2-passes": dict(hf=OURO, kinds=1),
     "tp2-under-shard-map": dict(hf=DENSE, kinds=1, tp=2),
     "sp-step-after-the-plain-step": dict(hf=DENSE, kinds=1, sp=8,
                                          prompt_len=300),
@@ -363,6 +419,27 @@ def test_xla_is_handed_the_module_the_plain_loop_gave():
     (key, *args), = [c for c in calls if isinstance(c[0], tuple)][:1]
     assert (window_fn(eng, build_window, model, key).lower(*args).as_text()
             == window_fn(eng, build_window, twin, key).lower(*args).as_text())
+    # PR 46: the walk over the layers now sits inside a loop over a
+    # looped stack's passes. A model of one pass — every accepted cell's
+    # — is handed to XLA as the parent's single walk was, prefill
+    # program and decode window, dense block and hybrid: no scope, no
+    # branch, no reordered operand.
+    for hf in (dict(DENSE, num_hidden_layers=6), EVABYTE, JAMBA):
+        model, params = build(hf)
+        assert model.config.loop_passes == 1
+        old = parents_loop(model)
+        assert (lowered(model, params).as_text()
+                == lowered(old, params).as_text())
+    model, params = build(dict(DENSE, num_hidden_layers=6))
+    assert (window_fn(eng, build_window, model, key).lower(*args).as_text()
+            == window_fn(eng, build_window, parents_loop(model),
+                         key).lower(*args).as_text())
+    # And a looped stack is not: its walk sits in a loop over the
+    # passes, under a scope of its own.
+    looped, lparams = build(OURO)
+    text = lowered(looped, lparams).as_text(debug_info=True)
+    assert "loop_pass" in text
+    assert "loop_pass" not in lowered(model, params).as_text(debug_info=True)
 
 
 def build_layer_indexed(layers):
@@ -374,6 +451,7 @@ def build_layer_indexed(layers):
 @pytest.mark.parametrize("name,make,kinds", [
     ("dense-12-layers", lambda: build(dict(DENSE, num_hidden_layers=12)), 1),
     ("jamba-toy-8-layers", lambda: build(dict(JAMBA, num_hidden_layers=8)), 2),
+    ("ouro-toy-4-passes-of-3", lambda: build(dict(OURO, total_ut_steps=4)), 1),
     ("layer-indexed-6-layers", lambda: build_layer_indexed(6), 6),
 ], ids=lambda v: v if isinstance(v, str) else None)
 def test_a_program_traces_a_kind_of_block_once(name, make, kinds):

@@ -21,6 +21,7 @@ to the kernels themselves.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +42,12 @@ HEAD_DIM, PAGE, PAGES_PER_SEQ, NUM_PAGES, VOCAB = 128, 64, 129, 1024, 152064
 HEADS = [(28, 4), (7, 1)]          # unsharded; one TP=4 shard
 HEAD_IDS = ["28q4kv", "tp4-7q1kv"]
 # The decode kernel also at Qwen2.5-3B's 16/2 heads (the second cell)
-# and at Jamba2-3B's multi-query 20/1 (the fourth).
-DECODE_HEADS = HEADS + [(16, 2), (20, 1)]
-DECODE_HEAD_IDS = HEAD_IDS + ["16q2kv", "mqa-20q1kv"]
-PREFILL_HEADS = HEADS + [(20, 1)]
-PREFILL_HEAD_IDS = HEAD_IDS + ["mqa-20q1kv"]
+# at Jamba2-3B's multi-query 20/1 (the fourth) and at Ouro-2.6B's
+# multi-head 16/16 (the fifth: 192 launches a step, PR 46).
+DECODE_HEADS = HEADS + [(16, 2), (20, 1), (16, 16)]
+DECODE_HEAD_IDS = HEAD_IDS + ["16q2kv", "mqa-20q1kv", "mha-16q16kv"]
+PREFILL_HEADS = HEADS + [(20, 1), (16, 16)]
+PREFILL_HEAD_IDS = HEAD_IDS + ["mqa-20q1kv", "mha-16q16kv"]
 # A page table long enough for 16k tokens (``--max-model-len 16384``).
 PAGES_16K = 16384 // PAGE
 
@@ -282,6 +284,18 @@ def test_fused_prefill_compiles_for_v5e_at_32_kv_heads(v5e, t):
     )
 
 
+def test_fused_sampler_compiles_for_v5e_at_vocab_49152(v5e):
+    """Ouro-2.6B's vocabulary, at the 8-row bucket its two rows run in."""
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    _compile(
+        functools.partial(fused_sample_topk_pallas, interpret=False),
+        a((8, 49152), jnp.float32), a((8, 49152), jnp.float32),
+        a((8,), jnp.float32), a((8,), jnp.int32),
+    )
+
+
 def test_fused_sampler_compiles_for_v5e_at_vocab_320(v5e):
     def a(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -503,3 +517,56 @@ def test_tpu_available_does_not_hide_a_broken_backend(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", broken)
     with pytest.raises(RuntimeError, match="initialize backend"):
         kernel_select.tpu_available()
+
+
+def test_a_looped_step_compiles_for_v5e_with_few_prefetches(
+        v5e, monkeypatch):
+    """The options a looped stack's step programs are compiled with
+    (``engine.LOOPED_STEP_XLA_OPTIONS``) are the installed compiler's,
+    and do what they are there for: a decode step of two of Ouro-2.6B's
+    layers, four passes, at the published widths brings no weight matrix
+    to VMEM in slices and holds 5 asynchronous copies, where the
+    defaults hold 28 ``slice-start`` and 13 ``copy-start`` (every matrix
+    in four slices, every norm vector a copy). The block is the same
+    whatever the depth."""
+    from parallax_tpu.models.base import BatchInputs
+    from parallax_tpu.models.registry import create_stage_model
+    from parallax_tpu.runtime.engine import LOOPED_STEP_XLA_OPTIONS
+
+    monkeypatch.setattr(kernel_select, "tpu_available", lambda: True)
+    cfg = normalize_config(dict(
+        architectures=["OuroForCausalLM"], model_type="ouro",
+        hidden_size=2048, num_hidden_layers=2, num_attention_heads=16,
+        num_key_value_heads=16, head_dim=128, intermediate_size=5632,
+        vocab_size=49152, total_ut_steps=4, early_exit_threshold=1,
+        rope_theta=1000000, rms_norm_eps=1e-6, tie_word_embeddings=False,
+        max_position_embeddings=65536, layer_types=["full_attention"] * 2,
+    ))
+    model = create_stage_model(cfg, 0, 2, use_pallas=True)
+
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def described(tree):
+        return jax.tree.map(lambda x: a(x.shape, x.dtype), tree)
+
+    params = described(jax.eval_shape(
+        lambda k: model.init_params(k, dtype=jnp.bfloat16),
+        jax.random.key(0)))
+    kv = described(jax.eval_shape(lambda: model.new_kv_caches(93, PAGE)))
+    rows = 8
+    inputs = BatchInputs(
+        token_ids=a((rows,), jnp.int32), hidden_states=None,
+        positions=a((rows,), jnp.int32), kv_lens=a((rows,), jnp.int32),
+        page_indices=a((rows, 65), jnp.int32),
+        cu_q_lens=a((rows + 1,), jnp.int32), num_seqs=a((1,), jnp.int32),
+        slot_mapping=a((rows,), jnp.int32),
+        logits_indices=a((rows,), jnp.int32), decode_only=True,
+        decode_fused=True, prefill_fused=False)
+    lowered = jax.jit(lambda p, k, i: model(p, k, i),
+                      donate_argnums=(1,)).lower(params, kv, inputs)
+    text = lowered.compile(
+        compiler_options=dict(LOOPED_STEP_XLA_OPTIONS)).as_text()
+    assert "gqa_fused_decode_pallas" in text
+    assert "slice-start" not in text
+    assert 1 <= len(re.findall(r" copy-start\(", text)) <= 8
