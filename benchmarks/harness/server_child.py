@@ -146,6 +146,25 @@ def make_params(model, seed: int, mesh=None):
     return jax.jit(init, out_shardings=shardings)(key)
 
 
+def check_choice_margins(rows: list[dict]) -> None:
+    """A row's ``choice_margin`` (``benchmarks/references``), where it
+    has one: a float a generated position, none negative and none NaN
+    (either would read as sure or unsure by accident of a comparison)."""
+    for i, row in enumerate(rows):
+        margin = row.get("choice_margin")
+        if margin is None:
+            continue
+        if len(margin) != len(row["tokens"]):
+            raise ValueError(
+                f"reference row {i}: {len(margin)} choice margins for "
+                f"{len(row['tokens'])} tokens")
+        bad = [m for m in margin if not m >= 0]    # a NaN is not >= 0
+        if bad:
+            raise ValueError(
+                f"reference row {i}: a choice margin is a distance, "
+                f"not {bad[0]}")
+
+
 def run_reference(params, hf_cfg: dict, seed: int, out_path: str,
                   ref: dict, rehearse: bool = False) -> None:
     """The configuration's reference (``spec.reference_of``) over its
@@ -166,6 +185,7 @@ def run_reference(params, hf_cfg: dict, seed: int, out_path: str,
             (shape["prompts"], shape["prompt_tokens"])).tolist()
         rows += module.greedy_continuations(
             params, hf_cfg, prompts, shape["new_tokens"])
+    check_choice_margins(rows)
     with open(out_path + ".tmp", "w") as f:
         json.dump({"module": ref["module"], "rows": rows,
                    "seconds": round(time.monotonic() - t0, 3)}, f)

@@ -112,11 +112,15 @@ def test_a_reference_without_summaries_fails_the_rehearsal(tmp_path):
     cfg["bench"]["reference"]["module"] = "evabyte_nosummary_for_test"
     with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # EvaByte's two entries by name: a later configuration's go at the
+    # end of their lists.
+    (config,) = [c for c in bench["configs"] if c["name"] == CONFIG]
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     bench["configs"].append(dict(
-        bench["configs"][-1], name="evabyte-nosummary-for-test",
+        config, name="evabyte-nosummary-for-test",
         file="benchmarks/configs/evabyte-nosummary-for-test.json"))
     bench["workloads"].append(dict(
-        bench["workloads"][-1], name="evabyte-nosummary-for-test.probe",
+        cell, name="evabyte-nosummary-for-test.probe",
         config="evabyte-nosummary-for-test"))
     for m in bench["per_layer"]:
         if CELL in m.get("workloads", ()):
