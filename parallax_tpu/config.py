@@ -29,6 +29,7 @@ LAYER_LINEAR = "linear_attention"      # conv + recurrent state slots (hybrid)
 class MoEConfig:
     """Mixture-of-experts shape info (used for EP sharding + FLOPs estimates)."""
 
+    # The router's width: every routed expert of the layer, held here or not.
     num_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
@@ -43,13 +44,30 @@ class MoEConfig:
     n_group: int = 0
     topk_group: int = 0
     scoring_func: str = "softmax"   # or "sigmoid" (DeepSeek-V3)
-    # Group-selection method: "noaux_tc" (V3: sum of top-2 biased scores),
-    # "group_limited_greedy" (V2: max score per group), "greedy" (no groups).
+    # Group-selection method: "noaux_tc" (V3: sum of top-2 biased scores,
+    # the one method with an ``e_score_correction_bias``),
+    # "group_limited_greedy" (V2: max score per group), "greedy" or "none"
+    # (plain top-k over every expert, whatever ``n_group`` says: A.X-K1).
     topk_method: str = "greedy"
+    # The chip's share of an expert-parallel deployment (top-level keys of
+    # the same names): how many of the ``num_experts`` this stage holds
+    # (0 = all of them) and the first one's index. The router stays
+    # ``num_experts`` wide; only pairs on held experts are computed.
+    experts_held: int = 0
+    expert_offset: int = 0
     # Explicit per-layer MoE mask, resolved at normalize time from the source
     # convention (DeepSeek first_k_dense_replace/moe_layer_freq vs Qwen
     # decoder_sparse_step/mlp_only_layers use different off-by-one rules).
     layer_mask: tuple[bool, ...] = ()
+
+    @property
+    def num_held(self) -> int:
+        """Routed experts whose weights this stage holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def uses_correction_bias(self) -> bool:
+        return self.topk_method == "noaux_tc"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -320,8 +338,11 @@ class ModelConfig:
         """
         elem = 2  # bf16 cache
         if self.mla is not None:
-            # Compressed latent + rope key, shared across heads.
-            base = elem * (self.mla.kv_lora_rank + self.mla.qk_rope_head_dim)
+            # Compressed latent + rope key, shared across heads, as the
+            # cache's rows hold them: whole 128-value lane tiles
+            # (``ops/mla.mla_row_width``: 576 -> 640).
+            row = self.mla.kv_lora_rank + self.mla.qk_rope_head_dim
+            base = elem * (-(-row // 128) * 128)
             if self.dsa is not None:
                 # DSA adds a paged index-key cache alongside the latent
                 # (counted on every layer even though shared-indexer layers
@@ -415,7 +436,7 @@ class ModelConfig:
             )
         if self.moe is not None and self._is_moe_layer(layer_idx):
             e = self.moe
-            ffn = 3 * h * e.moe_intermediate_size * e.num_experts
+            ffn = 3 * h * e.moe_intermediate_size * e.num_held
             ffn += 3 * h * e.shared_expert_intermediate_size * e.num_shared_experts
             ffn += h * e.num_experts  # router
         else:
@@ -647,7 +668,15 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
                 default="noaux_tc" if (is_glm_dsa or _get(cfg, "n_group"))
                 else "greedy",
             )),
+            experts_held=int(_get(cfg, "experts_held", default=0) or 0),
+            expert_offset=int(_get(cfg, "expert_offset", default=0) or 0),
         )
+        if not (0 <= moe.expert_offset
+                and moe.expert_offset + moe.num_held <= moe.num_experts):
+            raise ValueError(
+                f"experts_held={moe.experts_held} from expert_offset="
+                f"{moe.expert_offset} is no share of {moe.num_experts} experts"
+            )
 
     # MiniMax-M3: experts use intermediate_size; DENSE layers use the larger
     # dense_intermediate_size (reference ModelArgs.dense_intermediate_size).
@@ -887,7 +916,11 @@ def normalize_config(raw: dict, model_name: str = "") -> ModelConfig:
                         "rotary_dim", "rope_interleave",
                         "dense_intermediate_size", "swiglu_alpha",
                         "swiglu_limit", "swiglu_beta", "use_gemma_norm",
-                        "use_routing_bias")},
+                        "use_routing_bias",
+                        # Scales of a seeded draw that a configuration
+                        # states for itself (``init_params`` of the
+                        # DeepSeek family; no checkpoint has the key).
+                        "seeded_init")},
     )
 
 

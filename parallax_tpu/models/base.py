@@ -117,7 +117,6 @@ class StageModel:
     # 0.0 = llama convention (ones-init weights); 1.0 = Gemma/Qwen3-Next
     # zero-init ``x_hat * (1 + w)`` for all layer/final/qk norms.
     norm_offset = 0.0
-
     def _rms(self, x, weight):
         return L.rms_norm(x, weight, self.config.rms_norm_eps,
                           offset=self.norm_offset)
@@ -401,6 +400,15 @@ class StageModel:
         sequence's final token — reference ``logits_to_tokens``,
         model.py:88-124).
         """
+        out, new_kv, _ = self.forward(params, kv_caches, inputs)
+        return out, new_kv
+
+    def forward(self, params: dict, kv_caches, inputs: BatchInputs):
+        """The stage, and what its blocks handed on: ``(out, kv, carry)``
+        with the last block's ``carry`` (``_block``: None unless the
+        family hands something from layer to layer, else a dict — a
+        decode step of a stage told its share of the routed experts
+        leaves what its expert layers counted under ``"held"``)."""
         cfg = self.config
         if self.is_first:
             x = L.embed_lookup(params["embed_tokens"], inputs.token_ids)
@@ -419,8 +427,8 @@ class StageModel:
                 inputs.lora, axis_name=self.axis_name, tp=self.tp_size
             )
 
-        def one_pass(x, caches, inputs):
-            """The local layers once: ``(x, caches)`` out."""
+        def walk(x, caches, inputs):
+            """The local layers once: ``(x, caches, carry)`` out."""
             carry, out = None, []
             for li in range(self.num_local_layers):
                 lp = params["layers"][li]
@@ -432,16 +440,19 @@ class StageModel:
                     self._block_key(li), lp, x, caches[li], inputs, carry
                 )
                 out.append(kv_l)
-            return x, out
+            return x, out, carry
 
+        carry = None
         if cfg.loop_passes == 1:
-            x, new_kv = one_pass(x, kv_caches, inputs)
+            x, new_kv, carry = walk(x, kv_caches, inputs)
         else:
-            x, new_kv = self._looped_passes(one_pass, params, x, kv_caches,
-                                            inputs)
+            # (A looped stack hands no carry out of its passes' loop.)
+            x, new_kv = self._looped_passes(
+                lambda *a: walk(*a)[:2], params, x, kv_caches, inputs
+            )
 
         if not self.is_last:
-            return x, new_kv
+            return x, new_kv, carry
 
         if cfg.loop_passes == 1:
             # (A looped stack's final norm closed its last pass.)
@@ -460,7 +471,7 @@ class StageModel:
             logits = jax.lax.all_gather(
                 logits, self.axis_name, axis=1, tiled=True
             )
-        return logits, new_kv
+        return logits, new_kv, carry
 
     def _looped_passes(self, one_pass, params, x, kv_caches, inputs):
         """A looped stack: the layer walk ``loop_passes`` times under one

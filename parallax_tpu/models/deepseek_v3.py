@@ -19,8 +19,10 @@ import jax.numpy as jnp
 
 from parallax_tpu.models import layers as L
 from parallax_tpu.models.base import BatchInputs, StageModel
+from parallax_tpu.models.moe import moe_ffn
 from parallax_tpu.models.qwen3_moe import MoEStageModel
 from parallax_tpu.models.registry import register_model
+from parallax_tpu.obs.trace import note_block_trace
 from parallax_tpu.ops.mla import (
     mla_append_and_attend,
     mla_rope_permute,
@@ -30,7 +32,12 @@ from parallax_tpu.ops.rope import apply_rope
 
 
 @register_model(
-    "DeepseekV2ForCausalLM", "DeepseekV3ForCausalLM", "KimiK2ForCausalLM"
+    "DeepseekV2ForCausalLM", "DeepseekV3ForCausalLM", "KimiK2ForCausalLM",
+    # A.X-K1 (``model_type`` axk1) is this block: MLA with a low-rank
+    # query, ``first_k_dense_replace``, sigmoid-scored routed experts
+    # beside a shared one; its ``topk_method`` "none" is plain top-k
+    # (``moe.route_topk``).
+    "AXK1ForCausalLM",
 )
 class DeepseekStageModel(MoEStageModel):
     """MLA attention + (mostly) MoE FFN."""
@@ -79,15 +86,39 @@ class DeepseekStageModel(MoEStageModel):
 
     # -- layers ------------------------------------------------------------
 
-    def _decoder_layer(self, lp, x, kv, inputs: BatchInputs, window):
+    def _block(self, key, lp, x, kv, inputs: BatchInputs, carry):
+        """``carry`` is a dict of what the family's blocks hand on. On a
+        decode step ``"held"`` is the stage's running
+        ``moe.held_counts`` (zeros from the first block on, so a dense
+        and an expert layer are the stage's two kinds of block); any
+        other step counts nothing."""
+        note_block_trace()
         cfg = self.config
+        carry = dict(carry or {})
+        if inputs.decode_only and cfg.moe is not None:
+            carry.setdefault("held", jnp.zeros((2,), jnp.int32))
+        # With a float32 residual stream (``fp32_residual``) the norms
+        # read it whole and hand the branches their input in the weights'
+        # dtype; the adds promote back to float32.
+        act = lp["input_layernorm"]["weight"].dtype
         h = L.rms_norm(x, lp["input_layernorm"]["weight"], cfg.rms_norm_eps)
-        attn_out, kv = self._mla_attention(lp["self_attn"], h, kv, inputs)
+        attn_out, kv = self._mla_attention(
+            lp["self_attn"], h.astype(act), kv, inputs
+        )
         x = x + attn_out
         h = L.rms_norm(x, lp["post_attention_layernorm"]["weight"],
-                       cfg.rms_norm_eps)
-        x = x + self._mlp(lp, h)
-        return x, kv
+                       cfg.rms_norm_eps).astype(act)
+        if "held" in carry and "experts" in lp["mlp"]:
+            # Frozen and padding rows of a decode window write no cache
+            # row (slot -1): they are no rows of the step.
+            out, counts = moe_ffn(
+                h, lp["mlp"], cfg.moe, axis_name=self.axis_name,
+                use_megablox=self.use_pallas,
+                count_rows=inputs.slot_mapping >= 0,
+            )
+            carry["held"] = carry["held"] + counts
+            return x + out, kv, carry
+        return x + self._mlp(lp, h), kv, carry
 
     def _mla_qkv(self, p, x, inputs: BatchInputs):
         """Shared MLA projection pipeline: returns the absorbed query parts,
@@ -131,9 +162,13 @@ class DeepseekStageModel(MoEStageModel):
         w_kv_b = L.get_weight(p["kv_b_proj"]).reshape(hq, dn + dv, r)
         w_uk = w_kv_b[:, :dn, :]           # [Hq, dn, R]
         w_uv = w_kv_b[:, dn:, :]           # [Hq, dv, R]
+        # Heads leading on both sides: XLA's CPU backend has no bf16 dot
+        # for "thd,hdr->thr" as written (a rehearsal runs there); to a
+        # TPU the two are one dot.
         q_latent = jnp.einsum(
-            "thd,hdr->thr", q_nope, w_uk, preferred_element_type=jnp.float32
-        ).astype(x.dtype)
+            "htd,hdr->htr", jnp.swapaxes(q_nope, 0, 1), w_uk,
+            preferred_element_type=jnp.float32,
+        ).swapaxes(0, 1).astype(x.dtype)
         return q_latent, q_pe, latent, k_pe, w_uv, qr, hq
 
     def _mla_out(self, p, out_latent, w_uv, hq):
@@ -194,6 +229,21 @@ class DeepseekStageModel(MoEStageModel):
                 * (in_dim**-0.5)
             ).astype(dtype)}
 
+        # Two scales a configuration may state for its own seeded draw
+        # (top-level ``seeded_init``; docs/models.md): the standard
+        # deviation of the head's logits whatever the width, and a gain
+        # on the routed experts' ``down_proj`` beside the fan-in scale.
+        # Without the key the draw is the family's as it always was.
+        draw = cfg.extra.get("seeded_init") or {}
+        if "lm_head" in params and "lm_head_logit_std" in draw:
+            params["lm_head"]["weight"] = (
+                jax.random.normal(
+                    jax.random.fold_in(rng, 8000),
+                    (cfg.vocab_size, cfg.hidden_size), jnp.float32,
+                ) * (cfg.hidden_size
+                     / float(draw["lm_head_logit_std"]) ** 2) ** -0.5
+            ).astype(dtype)
+        down_gain = float(draw.get("routed_down_proj_gain", 1.0))
         hq = cfg.num_attention_heads
         dn, dr, dv, r = (
             m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim,
@@ -220,8 +270,10 @@ class DeepseekStageModel(MoEStageModel):
             params["layers"][li]["self_attn"] = attn
 
             if cfg.moe is not None and cfg.is_moe_layer(gi):
-                e, h_, i = (cfg.moe.num_experts, cfg.hidden_size,
-                            cfg.moe.moe_intermediate_size)
+                # The router over every expert, the stacks of those held.
+                e, held, h_, i = (cfg.moe.num_experts, cfg.moe.num_held,
+                                  cfg.hidden_size,
+                                  cfg.moe.moe_intermediate_size)
                 km = jax.random.split(jax.random.fold_in(rng, 7000 + gi), 8)
                 mlp_params = {
                     "gate": {
@@ -229,23 +281,32 @@ class DeepseekStageModel(MoEStageModel):
                             jax.random.normal(km[0], (e, h_), jnp.float32)
                             * h_**-0.5
                         ).astype(dtype),
-                        "e_score_correction_bias": jnp.zeros((e,), jnp.float32),
                     },
                     "experts": {
                         "gate_proj": (
-                            jax.random.normal(km[1], (e, i, h_), jnp.float32)
-                            * h_**-0.5
+                            jax.random.normal(
+                                km[1], (held, i, h_), jnp.float32
+                            ) * h_**-0.5
                         ).astype(dtype),
                         "up_proj": (
-                            jax.random.normal(km[2], (e, i, h_), jnp.float32)
-                            * h_**-0.5
+                            jax.random.normal(
+                                km[2], (held, i, h_), jnp.float32
+                            ) * h_**-0.5
                         ).astype(dtype),
                         "down_proj": (
-                            jax.random.normal(km[3], (e, h_, i), jnp.float32)
-                            * i**-0.5
+                            jax.random.normal(
+                                km[3], (held, h_, i), jnp.float32
+                            ) * (i / down_gain ** 2) ** -0.5
                         ).astype(dtype),
                     },
                 }
+                if cfg.moe.uses_correction_bias:
+                    # Only ``noaux_tc`` selects by biased scores; a leaf
+                    # here would be drawn by a seeded harness and steer
+                    # the selection of a method that has none.
+                    mlp_params["gate"]["e_score_correction_bias"] = (
+                        jnp.zeros((e,), jnp.float32)
+                    )
                 if cfg.moe.num_shared_experts > 0:
                     si = (cfg.moe.shared_expert_intermediate_size
                           or i) * cfg.moe.num_shared_experts

@@ -83,13 +83,13 @@ class DeepseekV32StageModel(DeepseekStageModel):
     # -- forward -----------------------------------------------------------
 
     def _block(self, key, lp, x, kv, inputs: BatchInputs, carry):
-        """``carry`` is the newest full layer's top-k (None before the
-        stage's first): it rides into ``_mla_attention`` as a third
-        member of the layer's cache and comes back the same way."""
-        x, (mla_pages, index_pages, topk), _ = super()._block(
-            key, lp, x, (*kv, carry), inputs, None
+        """``carry["topk"]`` is the newest full layer's top-k (absent
+        before the stage's first): it rides into ``_mla_attention`` as a
+        third member of the layer's cache and comes back the same way."""
+        x, (mla_pages, index_pages, topk), carry = super()._block(
+            key, lp, x, (*kv, (carry or {}).get("topk")), inputs, carry
         )
-        return x, (mla_pages, index_pages), topk
+        return x, (mla_pages, index_pages), {**carry, "topk": topk}
 
     def _indexer_topk(self, p, x, qr, index_cache, inputs: BatchInputs):
         """Lightning indexer: score the cached context, return top-k

@@ -4,18 +4,38 @@ parallelism over the ``tp`` mesh axis.
 Capability parity: reference MoE models run experts via mlx-lm SwitchGLU
 inside a stage (SURVEY.md section 2.7 marks cross-node EP absent; expert
 sharding over ICI is the TPU-native equivalent it prescribes). Params hold
-experts *stacked*: ``experts.gate_proj/up_proj: [E, I, H]``,
-``experts.down_proj: [E, H, I]`` — the loader stacks per-expert HF weights
-at load time, and EP shards the leading expert dim.
+the experts this stage holds *stacked*: ``experts.gate_proj/up_proj:
+[E_held, I, H]``, ``experts.down_proj: [E_held, H, I]`` — the loader stacks
+per-expert HF weights at load time, and EP shards the leading expert dim.
+
+**The share.** A layer is told which of the ``num_experts`` routed experts
+it holds: ``MoEConfig.experts_held`` of them from ``expert_offset`` (all
+from 0 by default; on a mesh each shard's offset moves on by its
+``axis_index``). The router is ``num_experts`` wide and selects
+``num_experts_per_tok`` whatever is held; of a token's selected experts
+only those held here are computed and weighted in. What the absent ones
+would add is *not computed and not stood in for*: on a mesh the shards'
+parts meet in the ``psum``, on one chip of a larger deployment the
+partial sum is the layer's output (the plain reference is given the same
+share: ``benchmarks/references/axk1.py``). The shared expert is whole on
+every chip.
 
 Two compute paths with identical semantics:
-- ``megablox``: sort token-expert pairs by expert, one ``gmm`` per
-  projection (MXU-dense regardless of routing skew). TPU only.
-- fallback: static loop over (local) experts with masked matmuls — used on
-  CPU and for verification.
+- ``megablox``: the pairs on held experts sorted first, by expert; one
+  ``gmm`` per projection over those groups; every other pair lies past
+  the last group, where ``gmm`` neither reads nor writes. TPU only.
+- fallback: static loop over the held experts with masked matmuls — used
+  on CPU and for verification.
+
+Counts (``count_rows``): the distinct held experts the counted rows hit
+and the token-expert pairs landed on them, for the engine's
+``parallax_moe_experts_read`` / ``parallax_moe_pairs_held``.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +56,8 @@ def route_topk(
     *selection* scores only — gate weights come from the unbiased scores —
     and ``n_group``/``topk_group`` restrict selection to the best expert
     groups (group score = sum of each group's top-2 biased scores).
+    ``topk_method`` "none" (A.X-K1) or "greedy" is the plain top-k over
+    every expert, whatever ``n_group`` / ``topk_group`` say.
     """
     logits = jax.lax.dot_general(
         x, router_weight,
@@ -48,7 +70,8 @@ def route_topk(
         scores = jax.nn.softmax(logits, axis=-1)
 
     selection = scores if bias is None else scores + bias.astype(jnp.float32)
-    if moe.n_group > 1 and moe.topk_group > 0:
+    grouped = moe.topk_method not in ("none", "greedy")
+    if grouped and moe.n_group > 1 and moe.topk_group > 0:
         t, e = selection.shape
         per_group = selection.reshape(t, moe.n_group, e // moe.n_group)
         if moe.topk_method == "group_limited_greedy":
@@ -103,52 +126,83 @@ def _stacked_expert_weights(experts: dict):
     return get("gate_proj"), get("up_proj"), get("down_proj")
 
 
-def _moe_fallback(x, p, weights, ids, num_local, expert_offset,
-                  act_fn=_silu_glu):
-    """Masked per-expert loop; correct for any routing, O(E) matmuls."""
+def _moe_fallback(x, p, weights, local_ids, num_local, act_fn=_silu_glu):
+    """Masked loop over the held experts; correct for any routing, O(E)
+    matmuls."""
     t = x.shape[0]
     out = jnp.zeros((t, x.shape[1]), jnp.float32)
     gate_w, up_w, down_w = _stacked_expert_weights(p["experts"])
     for le in range(num_local):
-        ge = expert_offset + le
-        hit = ids == ge                           # [T, K]
+        hit = local_ids == le                     # [T, K]
         w = jnp.sum(jnp.where(hit, weights, 0.0), axis=-1)  # [T]
         y = _expert_ffn(x, gate_w[le], up_w[le], down_w[le], act_fn)
         out = out + y * w[:, None]
     return out
 
 
-def _moe_megablox(x, p, weights, ids, num_local, expert_offset,
-                  act_fn=_silu_glu):
-    """Grouped-matmul path: sort token-expert pairs, gmm per projection."""
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """``gmm``'s (m, k, n) tile: rows in the largest power of two up to
+    128 that divides the pairs (8 rows x 8 experts a token are 64), the
+    whole contraction up to 8,192 and as many output columns as keep a
+    weight tile near 4 MB. The default (128, 128, 128) moves an expert's
+    matrix in 32 KB tiles: 4.9 ms for 12 experts' 7168 x 2048 on a v5e
+    against 1.07-1.12 ms at these (host clock a call, ~0.5 ms of
+    dispatch in each; inside the A.X-K1 cell's step program a layer's
+    three calls take 1.40 ms, 92% of the held experts' stream; PERF.md,
+    PR 51)."""
+    tk = min(k, 8192)
+    tn = max(128, min(n, (1 << 21) // tk // 128 * 128))
+    return math.gcd(m, 128), tk, tn
+
+
+def _moe_megablox(x, p, weights, local_ids, num_local, act_fn=_silu_glu):
+    """Grouped-matmul path. ``local_ids`` [T, K]: a pair's expert as an
+    index into the held stack, ``num_local`` for a pair on an expert
+    that is not held. Held pairs sort first, by expert, and are the
+    groups; the others lie past the last group, outside every ``gmm``."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     t, h = x.shape
-    k = ids.shape[1]
-    flat_ids = ids.reshape(-1)                    # [T*K]
-    flat_w = weights.reshape(-1)
-    order = jnp.argsort(flat_ids)
-    sorted_ids = flat_ids[order]
-    token_of = order // k
-    xs = x[token_of]                              # [T*K, H] gathered rows
-
-    # Group sizes for the local expert slice. Rows routed to non-local
-    # experts are clipped into boundary groups; they ride the gmm for free
-    # and their contribution is masked out below.
-    local_ids = jnp.clip(sorted_ids - expert_offset, 0, num_local - 1)
-    group_sizes = jnp.bincount(local_ids, length=num_local).astype(jnp.int32)
+    k = local_ids.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        flat_ids = local_ids.reshape(-1)              # [T*K]
+        order = jnp.argsort(flat_ids)
+        held = flat_ids[order] < num_local
+        token_of = order // k
+        xs = x[token_of]                              # [T*K, H] gathered rows
+        group_sizes = jnp.bincount(
+            flat_ids, length=num_local + 1
+        )[:num_local].astype(jnp.int32)
 
     gate_w, up_w, down_w = _stacked_expert_weights(p["experts"])
-    g = gmm(xs, jnp.swapaxes(gate_w, 1, 2), group_sizes)
-    u = gmm(xs, jnp.swapaxes(up_w, 1, 2), group_sizes)
-    hme = act_fn(g, u).astype(x.dtype)
-    y = gmm(hme, jnp.swapaxes(down_w, 1, 2), group_sizes)  # [T*K, H]
+    inter = gate_w.shape[1]
+    with jax.named_scope("moe_experts"):
+        # The stacks are [E, out, in]: gmm contracts the last axis.
+        up = functools.partial(
+            gmm, group_sizes=group_sizes, transpose_rhs=True,
+            tiling=_gmm_tiling(t * k, h, inter),
+        )
+        hme = act_fn(up(xs, gate_w), up(xs, up_w)).astype(x.dtype)
+        y = gmm(hme, down_w, group_sizes, transpose_rhs=True,
+                tiling=_gmm_tiling(t * k, inter, h))  # [T*K, H]
 
-    # Zero out pairs routed to non-local experts, weight, scatter back.
-    local = (sorted_ids >= expert_offset) & (sorted_ids < expert_offset + num_local)
-    contrib = y * jnp.where(local, flat_w[order], 0.0)[:, None]
-    out = jnp.zeros((t, h), jnp.float32)
-    return out.at[token_of].add(contrib)
+    with jax.named_scope("moe_dispatch"):
+        # Rows past the last group were never written: select, not scale.
+        contrib = jnp.where(
+            held[:, None], y * weights.reshape(-1)[order][:, None], 0.0
+        )
+        return jnp.zeros((t, h), jnp.float32).at[token_of].add(contrib)
+
+
+def held_counts(local_ids, num_local: int, count_rows) -> jax.Array:
+    """``i32[2]``: the distinct held experts the rows of ``count_rows``
+    (bool[T]) hit, and their pairs that landed on held experts."""
+    ids = jnp.where(count_rows[:, None], local_ids, num_local)
+    per_expert = jnp.bincount(ids.reshape(-1), length=num_local + 1)
+    per_expert = per_expert[:num_local]
+    return jnp.stack(
+        [jnp.count_nonzero(per_expert), jnp.sum(per_expert)]
+    ).astype(jnp.int32)
 
 
 def moe_ffn(
@@ -158,23 +212,31 @@ def moe_ffn(
     axis_name: str | None = None,
     use_megablox: bool | None = None,
     act_fn=_silu_glu,
-) -> jax.Array:
-    """Full MoE block: route, expert-compute (+ optional shared experts),
-    psum over the expert-parallel axis."""
+    count_rows: jax.Array | None = None,
+):
+    """Full MoE block: route over every expert, compute the held experts'
+    part (+ optional shared experts), psum over the expert-parallel
+    axis. With ``count_rows`` (bool[T]: the rows that are live decode
+    rows) returns ``(out, held_counts)``, summed over the axis too."""
     if use_megablox is None:
         use_megablox = jax.default_backend() == "tpu"
 
-    bias = p["gate"].get("e_score_correction_bias")
-    weights, ids = route_topk(x, p["gate"]["weight"], moe, bias=bias)
-    gp = p["experts"]["gate_proj"]
-    num_local = (gp["qweight"] if isinstance(gp, dict) else gp).shape[0]
-    if axis_name is not None:
-        expert_offset = jax.lax.axis_index(axis_name) * num_local
-    else:
-        expert_offset = 0
+    with jax.named_scope("moe_dispatch"):
+        bias = p["gate"].get("e_score_correction_bias")
+        weights, ids = route_topk(x, p["gate"]["weight"], moe, bias=bias)
+        gp = p["experts"]["gate_proj"]
+        num_local = (gp["qweight"] if isinstance(gp, dict) else gp).shape[0]
+        # One rule: the configured share, moved on by the shard's index.
+        expert_offset = moe.expert_offset
+        if axis_name is not None:
+            expert_offset += jax.lax.axis_index(axis_name) * num_local
+        local_ids = ids - expert_offset
+        local_ids = jnp.where(
+            (local_ids >= 0) & (local_ids < num_local), local_ids, num_local
+        )
 
     impl = _moe_megablox if use_megablox else _moe_fallback
-    out = impl(x, p, weights, ids, num_local, expert_offset, act_fn)
+    out = impl(x, p, weights, local_ids, num_local, act_fn)
 
     if "shared_expert" in p:
         # Shared expert uses the standard column/row TP sharding, so its
@@ -197,4 +259,10 @@ def moe_ffn(
 
     if axis_name is not None:
         out = jax.lax.psum(out, axis_name)
-    return out.astype(x.dtype)
+    out = out.astype(x.dtype)
+    if count_rows is None:
+        return out
+    counts = held_counts(local_ids, num_local, count_rows)
+    if axis_name is not None:
+        counts = jax.lax.psum(counts, axis_name)
+    return out, counts

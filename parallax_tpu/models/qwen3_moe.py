@@ -75,7 +75,11 @@ class MoEStageModel(StageModel):
         """Stack per-expert HF weights: ``experts.{i}.gate_proj.weight`` ->
         ``experts.gate_proj [E, I, H]`` (loader hook). Quantized experts
         (``qweight``/``scales``/``biases`` from ops/quant.py) stack into a
-        quantized dict with a leading expert axis."""
+        quantized dict with a leading expert axis. A stage told its
+        share (``MoEConfig.experts_held`` from ``expert_offset``) stacks
+        those experts of a whole layer's checkpoint and keeps the first
+        ``vocab_size`` rows of the embedding and the head."""
+        moe = self.config.moe
         for layer in tree.get("layers", []):
             mlp = layer.get("mlp")
             if not isinstance(mlp, dict):
@@ -83,20 +87,29 @@ class MoEStageModel(StageModel):
             experts = mlp.get("experts")
             if not isinstance(experts, dict) or "gate_proj" in experts:
                 continue
-            n = len(experts)
+            ids = range(len(experts))
+            if len(experts) == moe.num_experts > moe.num_held:
+                ids = range(moe.expert_offset,
+                            moe.expert_offset + moe.num_held)
             stacked = {}
             for proj in ("gate_proj", "up_proj", "down_proj"):
-                first = experts["0"][proj]
+                first = experts[str(ids[0])][proj]
                 if "qweight" in first:
                     stacked[proj] = {
                         k: jnp.stack(
-                            [experts[str(i)][proj][k] for i in range(n)]
+                            [experts[str(i)][proj][k] for i in ids]
                         )
                         for k in first
                     }
                 else:
                     stacked[proj] = jnp.stack(
-                        [experts[str(i)][proj]["weight"] for i in range(n)]
+                        [experts[str(i)][proj]["weight"] for i in ids]
                     )
             mlp["experts"] = stacked
+        if moe.experts_held:
+            rows = self.config.vocab_size
+            for name in ("embed_tokens", "lm_head"):
+                w = tree.get(name, {}).get("weight")
+                if w is not None and w.shape[0] > rows:
+                    tree[name]["weight"] = w[:rows]
         return tree

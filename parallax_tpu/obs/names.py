@@ -68,6 +68,10 @@ EVA_CHUNKS_SUMMARIZED = "parallax_eva_chunks_summarized"
 EVA_WINDOW_ROLLOVERS = "parallax_eva_window_rollovers"
 EVA_PAGES_RELEASED = "parallax_eva_pages_released"
 
+# -- expert layers told their share (models/moe.py, runtime/engine.py) ------
+MOE_EXPERTS_READ = "parallax_moe_experts_read"
+MOE_PAIRS_HELD = "parallax_moe_pairs_held"
+
 # -- recurrent-state slots of hybrid models (runtime/engine.py) -------------
 STATE_SLOTS_IN_USE = "parallax_state_slots_in_use"
 STATE_SLOTS_TOTAL = "parallax_state_slots_total"
@@ -318,6 +322,16 @@ HELP: dict[str, str] = {
     EVA_CHUNKS_SUMMARIZED: (
         "EVA chunks whose summary entry a dispatched step wrote "
         "(prefill and decode), counted once (not per layer)"
+    ),
+    MOE_EXPERTS_READ: (
+        "Distinct held routed experts that the live rows of a decode "
+        "step hit, summed over the stage's expert layers and the steps "
+        "of resolved decode windows (prefill steps do not count)"
+    ),
+    MOE_PAIRS_HELD: (
+        "Token-expert pairs of live decode rows that landed on experts "
+        "this stage holds, summed over its expert layers and the steps "
+        "of resolved decode windows"
     ),
     EVA_WINDOW_ROLLOVERS: (
         "EVA windows completed and rolled over: pending summary pages "

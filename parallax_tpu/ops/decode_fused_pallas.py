@@ -56,9 +56,11 @@ Two grid disciplines live here:
 - **Legacy page-grid helpers** — :func:`decode_page_grid_spec` and the
   :func:`online_softmax_update` / :func:`online_softmax_finish` pair
   are the shared scaffold for the split decode kernels
-  (``ops/attention_pallas.py``, ``ops/mla_pallas.py``,
-  ``ops/dsa_pallas.py``, ``ops/msa_pallas.py``), which previously
-  each carried a private copy of the same grid/accumulator logic.
+  (``ops/attention_pallas.py``, ``ops/dsa_pallas.py``,
+  ``ops/msa_pallas.py``), which previously each carried a private copy
+  of the same grid/accumulator logic. The split latent kernel
+  (``ops/mla_pallas.py``) left the page grid for the streamed core
+  without an append (PR 51).
 
 Everything supports ``interpret=True`` (Pallas interpreter), which is
 how the CPU CI proves parity against the XLA reference paths
@@ -214,7 +216,7 @@ def _kv_head_strided(rows_ref, h: int):
 
 
 def paged_decode_stream(
-    cache: jax.Array,          # [P, page, C, W]
+    cache: jax.Array,          # [P, page, C, W] (or [P, page, W])
     kv_lens: jax.Array,        # i32[S] context length INCLUDING new token
     page_indices: jax.Array,   # i32[S, pages_per_seq]
     slot_mapping: jax.Array,   # i32[S] flat append slot; < 0 skips append
@@ -225,7 +227,7 @@ def paged_decode_stream(
     init,                      # fn(accs, qs, outs) -> None
     fold,                      # fn(accs, qs, outs, rows_ref, base, kv_len)
     finalize,                  # fn(accs, qs, outs, kv_len) -> None
-    append: jax.Array | None = None,   # [S, C, W] rows (cache dtype)
+    append: jax.Array | None = None,   # [S, C, W] ([S, W]) rows, cache dtype
     first_page=None,           # fn(kv_len) -> first page index (window clip)
     interpret: bool = False,
 ):
@@ -267,10 +269,14 @@ def paged_decode_stream(
     donate it), else ``outs...``; single-element outputs are unwrapped.
     """
     s, pages_per_seq = page_indices.shape
-    _, page_size, c, w = cache.shape
+    # A page's rows: ``[C, W]``, or ``[W]`` for a cache without a head
+    # axis (the latent cache: a dense ``[page, W]`` tile).
+    page_size, tail = cache.shape[1], tuple(cache.shape[2:])
     n_ops = len(operands)
     with_append = append is not None
-    bp = decode_pages_per_block(page_size, c, w, cache.dtype)
+    bp = decode_pages_per_block(
+        page_size, tail[0] if len(tail) == 2 else 1, tail[-1], cache.dtype
+    )
 
     def kernel(pages_ref, lens_ref, slots_ref, *refs):
         qs = refs[:n_ops]
@@ -428,7 +434,8 @@ def paged_decode_stream(
         inputs.append(arr)
     if with_append:
         in_specs.append(pl.BlockSpec(
-            (1, c, w), lambda i, pages, lens, slots: (i, 0, 0)
+            (1, *tail),
+            lambda i, pages, lens, slots: (i,) + (0,) * len(tail),
         ))
         inputs.append(append)
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
@@ -458,7 +465,7 @@ def paged_decode_stream(
         aliases = {3 + n_ops + 1: len(out_shapes)}
 
     scratch = [pltpu.VMEM(shape, dtype) for shape, dtype in acc_shapes]
-    scratch.append(pltpu.VMEM((2, bp * page_size, c, w), cache.dtype))
+    scratch.append(pltpu.VMEM((2, bp * page_size, *tail), cache.dtype))
     scratch.append(pltpu.SemaphoreType.DMA((2,)))
     scratch.append(pltpu.SMEM((2,), jnp.int32))
     if with_append:
@@ -615,7 +622,7 @@ def mla_fused_decode_pallas(
     q_pe: jax.Array,          # [S, Hq, Dr]
     latent_new: jax.Array,    # [S, R] this step's compressed latent
     k_pe_new: jax.Array,      # [S, Dr] this step's rope key
-    cache: jax.Array,         # [P, page, 1, R+Dr] (donate for in-place)
+    cache: jax.Array,         # [P, page, W] (donate for in-place)
     kv_lens: jax.Array,       # i32[S]
     page_indices: jax.Array,  # i32[S, pages_per_seq]
     slot_mapping: jax.Array,  # i32[S]
@@ -626,11 +633,13 @@ def mla_fused_decode_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """One fused program: latent-cache append + MLA flash decode.
     Returns ``(out [S, Hq, R], cache)``."""
+    from parallax_tpu.ops.mla import mla_cache_rows
+
     s, hq, r = q_latent.shape
-    _, page_size, _, width = cache.shape
-    append = jnp.concatenate(
-        [latent_new, k_pe_new], axis=-1
-    ).astype(cache.dtype)[:, None, :]                 # [S, 1, W]
+    rope_dim = q_pe.shape[-1]
+    append = mla_cache_rows(
+        latent_new, k_pe_new, cache.shape[-1], cache.dtype
+    )                                                 # [S, W]
 
     def init(accs, qs, outs):
         m_ref, l_ref, o_ref = accs
@@ -640,9 +649,9 @@ def mla_fused_decode_pallas(
 
     def fold(accs, qs, outs, rows_ref, base, n):
         m_ref, l_ref, o_ref = accs
-        block_rows = rows_ref[...][:, 0, :]           # [N, W]
+        block_rows = rows_ref[...]                    # [N, W]
         latent = block_rows[:, :kv_lora_rank]
-        rope = block_rows[:, kv_lora_rank:]
+        rope = block_rows[:, kv_lora_rank:kv_lora_rank + rope_dim]
         ql = qs[0][0]                                 # [Hq, R]
         qp = qs[1][0]                                 # [Hq, Dr]
         scores = (

@@ -216,7 +216,7 @@ def dsa_topk_indices(
 def mla_ragged_sparse_attention_xla(
     q_latent: jax.Array,     # [T, Hq, R]
     q_pe: jax.Array,         # [T, Hq, Dr]
-    cache: jax.Array,        # [P, page, 1, R + Dr] MLA latent cache
+    cache: jax.Array,        # [P, page, W >= R + Dr] MLA latent cache
     kv_lens: jax.Array,      # i32[S]
     page_indices: jax.Array, # i32[S, pages_per_seq]
     cu_q_lens: jax.Array,    # i32[S+1]
@@ -237,7 +237,8 @@ def mla_ragged_sparse_attention_xla(
     (O(T * chunk) transients); small K a single pass.
     """
     t, hq, r = q_latent.shape
-    p, page_size, _, width = cache.shape
+    p, page_size, width = cache.shape
+    dr = q_pe.shape[-1]
     s, pages_per_seq = page_indices.shape
     k = topk_indices.shape[1]
 
@@ -265,7 +266,7 @@ def mla_ragged_sparse_attention_xla(
     def score_block(rows_blk, valid_blk):
         """[T, Kc, R+Dr] gathered block -> masked f32 scores [T, Hq, Kc]."""
         latent = rows_blk[..., :kv_lora_rank]
-        rope = rows_blk[..., kv_lora_rank:]
+        rope = rows_blk[..., kv_lora_rank:kv_lora_rank + dr]
         sc = (
             jnp.einsum("thr,tkr->thk", q_latent, latent,
                        preferred_element_type=jnp.float32)

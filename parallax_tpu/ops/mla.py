@@ -10,8 +10,17 @@ the shared rope key (qk_rope_head_dim) — the "absorbed" decode form: W_UK
 folds into the query, W_UV applies after attention, so HBM per token is
 ~R+Dr instead of 2*H*D.
 
-Cache layout per MLA layer:  [num_pages, page_size, 1, R + Dr]
-(the singleton axis keeps the page-gather code shared with regular KV).
+Cache layout per MLA layer:  [num_pages, page_size, W], a token's row
+``[latent (R) | rope key (Dr) | unused]`` with ``W = mla_row_width(R,
+Dr)``, the row rounded up to whole 128-value lane tiles (576 -> 640).
+Why not ``[.., 1, R + Dr]``, measured on a v5e (PERF.md, PR 51): XLA
+stores a bf16 array whose last axis is no multiple of 128 with its
+*first* axis minor (pages in the lanes), and a singleton second-last
+axis is below Mosaic's tile, so every Pallas call over that array was
+handed a re-laid copy of the whole cache (1.34 GB a layer call at 8,192
+pages). A dense ``[page, W]`` tile is one layout to XLA, to Mosaic and
+to a page's DMA; the 64 spare values a row are what the chip's lanes
+cost, and ``ModelConfig.kv_bytes_per_token_per_layer`` counts them.
 """
 
 from __future__ import annotations
@@ -21,9 +30,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from parallax_tpu.ops.ragged import page_chunks, ragged_token_positions
+from parallax_tpu.ops.ragged import (
+    KV_CHUNK_ROWS,
+    page_chunks,
+    ragged_token_positions,
+)
 
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+_LANES = 128
+# What one chunk's per-token gather of cache rows may take.
+_GATHER_BYTES = 1 << 28
+
+
+def mla_row_width(kv_lora_rank: int, rope_dim: int) -> int:
+    """Values one cached token's row holds: latent and rope key, rounded
+    up to whole lane tiles."""
+    return -(-(kv_lora_rank + rope_dim) // _LANES) * _LANES
 
 
 
@@ -31,7 +53,9 @@ def new_mla_pages(
     num_pages: int, page_size: int, kv_lora_rank: int, rope_dim: int,
     dtype=jnp.bfloat16,
 ) -> jax.Array:
-    return jnp.zeros((num_pages, page_size, 1, kv_lora_rank + rope_dim), dtype)
+    return jnp.zeros(
+        (num_pages, page_size, mla_row_width(kv_lora_rank, rope_dim)), dtype
+    )
 
 
 def store_mla_cache(
@@ -42,12 +66,18 @@ def store_mla_cache(
 ) -> jax.Array:
     """Scatter latent+rope rows (reference reshape_and_cache DSA variant,
     ops.py:370-413)."""
-    p, page, _, width = cache.shape
-    row = jnp.concatenate([latent, k_pe], axis=-1).astype(cache.dtype)
+    p, page, width = cache.shape
+    row = mla_cache_rows(latent, k_pe, width, cache.dtype)
     flat = cache.reshape(p * page, width)
     slots = jnp.where(slot_mapping < 0, p * page, slot_mapping)
     flat = flat.at[slots].set(row, mode="drop")
-    return flat.reshape(p, page, 1, width)
+    return flat.reshape(p, page, width)
+
+
+def mla_cache_rows(latent, k_pe, width: int, dtype) -> jax.Array:
+    """``[T, W]`` cache rows: latent, rope key, zeros up to ``width``."""
+    row = jnp.concatenate([latent, k_pe], axis=-1).astype(dtype)
+    return jnp.pad(row, ((0, 0), (0, width - row.shape[-1])))
 
 
 def mla_append_and_attend(
@@ -141,7 +171,7 @@ def mla_ragged_attention(
 def mla_ragged_attention_xla(
     q_latent: jax.Array,     # [T, Hq, R]   (q_nope absorbed through W_UK)
     q_pe: jax.Array,         # [T, Hq, Dr]
-    cache: jax.Array,        # [P, page, 1, R + Dr]
+    cache: jax.Array,        # [P, page, W >= R + Dr]
     kv_lens: jax.Array,      # i32[S]
     page_indices: jax.Array, # i32[S, pages_per_seq]
     cu_q_lens: jax.Array,    # i32[S+1]
@@ -154,13 +184,14 @@ def mla_ragged_attention_xla(
 
     The caller up-projects with W_UV. Jittable XLA path; the Pallas flash
     kernel (``ops/mla_pallas.py``) covers decode on TPU. Long contexts run
-    a ``lax.scan`` over KV page-chunks with online-softmax accumulation so
+    a loop over KV page-chunks with online-softmax accumulation so
     the transient footprint is O(T * chunk), never O(T * context) — the
     HBM-safety requirement of the reference MLA kernel contract
     (``kernels/mla/mla.cpp``).
     """
     t, hq, r = q_latent.shape
-    p, page_size, _, width = cache.shape
+    p, page_size, width = cache.shape
+    dr = q_pe.shape[-1]
     s, pages_per_seq = page_indices.shape
     kv_cap = pages_per_seq * page_size
 
@@ -168,8 +199,17 @@ def mla_ragged_attention_xla(
     kv_len_tok = kv_lens[seq_of_tok]
 
     # Chunk over whole pages; fall back to a single pass for short caps.
+    # A chunk's rows are gathered once a *token* (``rows_tok`` below):
+    # at serve's 2,048-token batches 512 rows of 640 values would be
+    # 1.3 GB a layer, beside a pool sized to the memory left, so the
+    # chunk shrinks with the batch (whole pages, at least one).
+    chunk_rows = max(
+        page_size,
+        min(KV_CHUNK_ROWS, _GATHER_BYTES // (t * width * cache.dtype.itemsize))
+        // page_size * page_size,
+    )
     padded_pages, chunk_pages, lc, num_chunks = page_chunks(
-        page_indices, page_size
+        page_indices, page_size, chunk_rows
     )
 
     def body(carry, g):
@@ -177,10 +217,10 @@ def mla_ragged_attention_xla(
         pages_g = jax.lax.dynamic_slice_in_dim(
             padded_pages, g * chunk_pages, chunk_pages, axis=1
         )
-        rows = cache[pages_g.reshape(-1), :, 0, :].reshape(s, lc, width)
+        rows = cache[pages_g.reshape(-1)].reshape(s, lc, width)
         rows_tok = rows[seq_of_tok]                  # [T, Lc, width]
         latent = rows_tok[..., :kv_lora_rank]
-        rope = rows_tok[..., kv_lora_rank:]
+        rope = rows_tok[..., kv_lora_rank:kv_lora_rank + dr]
         scores = (
             jnp.einsum("thr,tlr->thl", q_latent, latent,
                        preferred_element_type=jnp.float32)
@@ -208,8 +248,16 @@ def mla_ragged_attention_xla(
         jnp.zeros((t, hq), jnp.float32),
         jnp.zeros((t, hq, r), jnp.float32),
     )
-    (m, l, o), _ = jax.lax.scan(
-        body, init, jnp.arange(num_chunks, dtype=jnp.int32)
+    # Only the chunks some row's context reaches: a chunk past every
+    # ``kv_len`` is masked whole and leaves the accumulators as they
+    # are, and a page table is as long as ``max_model_len`` whatever
+    # the batch (64 chunks of a page for prompts of 256-512: 1.43 s a
+    # 2,048-token step on a v5e against 8 of them; PERF.md, PR 51).
+    live_chunks = jnp.minimum(
+        num_chunks, (jnp.max(kv_lens) + lc - 1) // lc
+    )
+    m, l, o = jax.lax.fori_loop(
+        0, live_chunks, lambda g, carry: body(carry, g)[0], init
     )
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.astype(q_latent.dtype)

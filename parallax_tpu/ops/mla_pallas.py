@@ -1,6 +1,6 @@
 """Pallas TPU kernel: flash-style MLA decode over the compressed latent
 cache — the SPLIT-dispatch kernel (attention only; the latent append
-runs as a separate XLA scatter).
+runs as a separate XLA scatter before it).
 
 Capability parity: reference MLA decode kernel
 (``src/parallax_extensions/kernels/mla/mla.cpp:1-138``, facade
@@ -9,19 +9,26 @@ latent`` per sequence, one query token each. The XLA gather path in
 ``ops/mla.py`` stays as the oracle (tests compare bit-for-bit semantics)
 and the prefill path.
 
-Kernel shape: grid ``(num_seqs, pages_per_seq)`` on the shared
-page-grid scaffold (``ops/decode_fused_pallas.decode_page_grid_spec``);
-each step streams one latent page from HBM into VMEM via the
-scalar-prefetched page table and folds it into the shared
-online-softmax accumulator (``online_softmax_update``). The two matmuls
-per page ([Hq, R] x [R, page] and [Hq, page] x [page, R]) land on the
-MXU; per-page masking handles ragged context lengths, so padding
-sequences (kv_len 0) produce zeros.
+Kernel shape: the streamed core of the fused family
+(``ops/decode_fused_pallas.paged_decode_stream``) without an append —
+grid ``(num_seqs,)``; a row's *valid* pages, and no others, move
+HBM->VMEM in double-buffered blocks of ``decode_pages_per_block`` pages
+(8 of the latent cache's 80 KB pages) and fold into one online-softmax
+accumulator (``online_softmax_update``). The two matmuls per block
+([Hq, R] x [R, N] and [Hq, N] x [N, R]) land on the MXU; the fold masks
+what lies past a row's context, so padding sequences (kv_len 0) produce
+zeros. Until PR 51 this was a ``(num_seqs, pages_per_seq)`` page grid,
+one grid step a page slot of every row, valid or not: at 64 heads, 128
+rows and 64 slots a row a v5e took 3.49 ms a call at ~2k of context
+against 1.25 ms streamed (1.84 / 0.74 at 0.4k, 5.18 / 1.81 at 3.7k;
+host clock around one call, ~0.5 ms of dispatch in each; inside the
+A.X-K1 cell's step program the streamed kernel takes 0.44 ms a layer at
+~1.6k; PERF.md, PR 51).
 
 The fused successor (``decode_fused_pallas.mla_fused_decode_pallas``)
-streams only the valid pages and appends the new latent row in the same
-program; this kernel remains the split fallback and the microbench
-baseline (docs/kernels.md).
+appends the new latent row in the same program; Mosaic refuses its
+one-row DMA (``kernel_select.fused_lowering_gap``), so on a TPU this
+kernel serves latent decode.
 """
 
 from __future__ import annotations
@@ -30,81 +37,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from parallax_tpu.ops.decode_fused_pallas import (
-    decode_page_grid_spec,
     online_softmax_finish,
     online_softmax_update,
+    paged_decode_stream,
 )
 
 _NEG = -1e30
-
-
-def _mla_decode_kernel(
-    # scalar prefetch
-    pages_ref,    # i32[S, pages_per_seq]
-    lens_ref,     # i32[S]
-    # blocks
-    q_lat_ref,    # [1, Hq, R]
-    q_pe_ref,     # [1, Hq, Dr]
-    cache_ref,    # [1, page, 1, R+Dr]
-    out_ref,      # [1, Hq, R]
-    # scratch
-    m_ref,        # f32[Hq, 1]
-    l_ref,        # f32[Hq, 1]
-    o_ref,        # f32[Hq, R]
-    *,
-    sm_scale: float,
-    kv_lora_rank: int,
-):
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    page_size = cache_ref.shape[1]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-    kv_len = lens_ref[s]
-    base = j * page_size
-
-    @pl.when(base < kv_len)
-    def _accumulate():
-        rows = cache_ref[0, :, 0, :]                 # [page, R+Dr]
-        latent = rows[:, :kv_lora_rank]
-        rope = rows[:, kv_lora_rank:]
-        ql = q_lat_ref[0]                            # [Hq, R]
-        qp = q_pe_ref[0]                             # [Hq, Dr]
-        scores = (
-            jax.lax.dot_general(
-                ql, latent, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            + jax.lax.dot_general(
-                qp, rope, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        ) * sm_scale                                 # [Hq, page]
-        pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1
-        )
-        valid = pos < kv_len                         # decode: q at kv_len-1
-
-        def weighted(p):
-            return jax.lax.dot_general(
-                p.astype(latent.dtype), latent, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-
-        online_softmax_update(m_ref, l_ref, o_ref, scores, valid, weighted)
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        online_softmax_finish(l_ref, o_ref, out_ref)
 
 
 @functools.partial(
@@ -114,7 +54,7 @@ def _mla_decode_kernel(
 def mla_decode_attention_pallas(
     q_latent: jax.Array,     # [S, Hq, R] — ONE query token per sequence
     q_pe: jax.Array,         # [S, Hq, Dr]
-    cache: jax.Array,        # [P, page, 1, R+Dr]
+    cache: jax.Array,        # [P, page, W >= R + Dr]
     kv_lens: jax.Array,      # i32[S]
     page_indices: jax.Array, # i32[S, pages_per_seq]
     *,
@@ -124,36 +64,56 @@ def mla_decode_attention_pallas(
 ) -> jax.Array:
     """Flash MLA decode: [S, Hq, R] attention output in latent space."""
     s, hq, r = q_latent.shape
-    p, page_size, _, width = cache.shape
-    _, pages_per_seq = page_indices.shape
+    rope_dim = q_pe.shape[-1]
 
-    grid_spec = decode_page_grid_spec(
-        s, pages_per_seq,
-        in_specs=[
-            pl.BlockSpec((1, hq, r), lambda i, j, pages, lens: (i, 0, 0)),
-            pl.BlockSpec(
-                (1, hq, width - r), lambda i, j, pages, lens: (i, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, page_size, 1, width),
-                lambda i, j, pages, lens: (pages[i, j], 0, 0, 0),
-            ),
+    def init(accs, qs, outs):
+        m_ref, l_ref, o_ref = accs
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    def fold(accs, qs, outs, rows_ref, base, n):
+        m_ref, l_ref, o_ref = accs
+        rows = rows_ref[...]                          # [N, W]
+        latent = rows[:, :kv_lora_rank]
+        rope = rows[:, kv_lora_rank:kv_lora_rank + rope_dim]
+        scores = (
+            jax.lax.dot_general(
+                qs[0][0], latent, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            + jax.lax.dot_general(
+                qs[1][0], rope, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        ) * sm_scale                                  # [Hq, N]
+        pos = base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows_ref.shape[0]), 1
+        )
+
+        def weighted(p):
+            return jax.lax.dot_general(
+                p.astype(latent.dtype), latent, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        # decode: the query sits at kv_len - 1 and attends all before it
+        online_softmax_update(m_ref, l_ref, o_ref, scores, pos < n, weighted)
+
+    def finalize(accs, qs, outs, n):
+        _, l_ref, o_ref = accs
+        online_softmax_finish(l_ref, o_ref, outs[0])
+
+    return paged_decode_stream(
+        cache, kv_lens, page_indices,
+        jnp.full((s,), -1, jnp.int32),                # no append here
+        [(q_latent, True), (q_pe, True)],
+        out_shapes=[((hq, r), q_latent.dtype)],
+        acc_shapes=[
+            ((hq, 1), jnp.float32),
+            ((hq, 1), jnp.float32),
+            ((hq, r), jnp.float32),
         ],
-        out_specs=pl.BlockSpec(
-            (1, hq, r), lambda i, j, pages, lens: (i, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((hq, 1), jnp.float32),
-            pltpu.VMEM((hq, 1), jnp.float32),
-            pltpu.VMEM((hq, r), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _mla_decode_kernel, sm_scale=sm_scale, kv_lora_rank=kv_lora_rank
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, hq, r), q_latent.dtype),
+        init=init, fold=fold, finalize=finalize,
         interpret=interpret,
-    )(page_indices, kv_lens, q_latent, q_pe, cache)
+    )

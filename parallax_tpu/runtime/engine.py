@@ -374,6 +374,11 @@ class StepTicket:
     # when the batch carried logprob rows; resolve() threads the values
     # into commit_token alongside the tokens.
     ms_lp: list | None = None
+    # Per-window ``moe.held_counts`` of every scan step ([k, 2]: distinct
+    # held experts hit, pairs landed on them), from a stage whose expert
+    # layers count (``StageModel.forward``'s ``"held"``); read back with
+    # the tokens and added to the two series at resolve.
+    ms_moe: list | None = None
     # Host-sync speculative verify fallback (K=1 / unpaged windows):
     # (spec_plan, proposals) — the logits readback + accept loop runs
     # at resolve, the designated sync point.
@@ -689,6 +694,13 @@ class StageEngine:
         # share the pool and a window's exact pages go back when it is
         # complete (cache_manager.EvaCacheManager, docs/memory.md "EVA").
         self._eva = model.config.eva
+        # The stage's share of its routed experts rides the pack span
+        # and the status payload's ``kernel`` section.
+        moe = model.config.moe
+        self._expert_share = (
+            {"experts_held": moe.num_held, "expert_offset": moe.expert_offset}
+            if moe is not None else {}
+        )
         if self._eva is not None and self.cfg.speculative_tokens > 0:
             logger.warning(
                 "speculative decoding disabled: EVA rows roll their "
@@ -1975,6 +1987,9 @@ class StageEngine:
                 name, mnames.help_text(name), labelnames=st
             ).labels(**lbl)
 
+        # Expert layers told their share: what decode windows read.
+        self._c_moe_read = stage_counter(mnames.MOE_EXPERTS_READ)
+        self._c_moe_pairs = stage_counter(mnames.MOE_PAIRS_HELD)
         self._c_eva_entries = stage_counter(mnames.EVA_ENTRIES_ATTENDED)
         self._c_eva_chunks = stage_counter(mnames.EVA_CHUNKS_SUMMARIZED)
         self._c_eva_rollovers = stage_counter(mnames.EVA_WINDOW_ROLLOVERS)
@@ -2357,6 +2372,8 @@ class StageEngine:
             "impl": self._attn_impl,
             "decode_fused": self._decode_fused,
             "decode_pages_per_block": pages_per_block,
+            # The stage's share of its routed experts, where it has any.
+            **self._expert_share,
             "prefill_impl": self._prefill_impl,
             "prefill_fused": self._prefill_fused,
             # Fused kernels running in the Pallas interpreter (a forced
@@ -2763,7 +2780,7 @@ class StageEngine:
         def fn(params, kv, inputs: BatchInputs, ms: dict):
             def body(carry, step_i):
                 kv, feed, ctx, stopped, produced, fstate = carry
-                logits, kv = model(
+                logits, kv, handed = model.forward(
                     params, kv, step_inputs_at(inputs, feed, ctx, stopped)
                 )
                 # Feature transforms in the host sampler's exact order
@@ -2821,6 +2838,10 @@ class StageEngine:
                 live = ~stopped
                 nxt = jnp.where(live, nxt, feed)
                 ys = {"toks": nxt}
+                if handed and "held" in handed:
+                    # What the step's expert layers counted
+                    # (``moe.held_counts``), out with the tokens.
+                    ys["moe"] = handed["held"]
                 if has_lp:
                     from parallax_tpu.ops.sampling import token_logprobs
 
@@ -2873,8 +2894,8 @@ class StageEngine:
                  ms["stopped"], ms["produced"], fstate0),
                 jnp.arange(k, dtype=jnp.int32),
             )
-            # ys["toks"]: [k, S] (+ optional "lp" [k, S]); the carry
-            # dict is the device-resident state the NEXT window starts
+            # ys["toks"]: [k, S] (+ optional "lp" [k, S], "moe" [k, 2]);
+            # the carry dict is the device-resident state the NEXT window starts
             # from — returning it lets the host chain windows without
             # reading tokens back in between.
             carry = dict(feed=feed, ctx=ctx, stopped=stopped,
@@ -3714,6 +3735,7 @@ class StageEngine:
         # reads it back.
         windows = []
         lps = [] if "lp" in feats else None
+        moes = []
         # The carry the chain starts from. A fresh window builds it on
         # the host and places it as the window program returns it
         # (``_carry_in``), a handed-over one takes the in-flight
@@ -3757,6 +3779,8 @@ class StageEngine:
             windows.append(ys["toks"])
             if lps is not None:
                 lps.append(ys["lp"])
+            if "moe" in ys:
+                moes.append(ys["moe"])
             feed, ctx = carry["feed"], carry["ctx"]
             stopped, produced = carry["stopped"], carry["produced"]
             fextra = {
@@ -3766,7 +3790,7 @@ class StageEngine:
         self._last_fused_steps = m * k
         if self._eva is not None:
             self._count_eva(plan, m * k)
-        for arr in (*windows, *(lps or ()), produced):
+        for arr in (*windows, *(lps or ()), *moes, produced):
             # Start the D2H copies NOW so resolve()'s readback finds the
             # bytes pre-staged instead of blocking the step thread.
             try:
@@ -3793,7 +3817,7 @@ class StageEngine:
         ticket = StepTicket(
             plan=plan, step_idx=step_idx, t0=t0,
             ms_windows=windows, ms_state=(stopped, produced),
-            ms_lp=lps,
+            ms_lp=lps, ms_moe=moes or None,
             dispatch_seq=self._dispatch_seq,
             program="decode_window",
             chained=chain is not None,
@@ -4002,7 +4026,14 @@ class StageEngine:
                     if ticket.ms_lp else None
                 )
                 produced = np.asarray(ticket.ms_state[1])   # i32[S]
+                moe = (
+                    sum(np.asarray(x).sum(axis=0) for x in ticket.ms_moe)
+                    if ticket.ms_moe else None
+                )                                       # i64[2]
             spans.enter_context(self._commit_span(ticket))
+            if moe is not None:
+                self._c_moe_read.inc(int(moe[0]))
+                self._c_moe_pairs.inc(int(moe[1]))
             total = 0
             gp_committed = gp_window = 0
             for i, seg in enumerate(plan.seqs):
@@ -4710,6 +4741,7 @@ class StageEngine:
             "engine.pack", self._h_visit_pack, rows=len(plan.seqs),
             tokens=plan.total_new_tokens,
             passes=self.model.config.loop_passes,
+            **self._expert_share,
         ) as pack:
             ticket = self._dispatch_plan(plan, sp_plan, t0, chain)
             pack.kind = visit_kind(plan, ticket.program)

@@ -23,7 +23,11 @@ from parallax_tpu.ops.decode_fused_pallas import (
 )
 from parallax_tpu.ops.dsa import dsa_indexer_scores_xla, store_index_cache
 from parallax_tpu.ops.kv_cache_ops import reshape_and_cache
-from parallax_tpu.ops.mla import mla_ragged_attention_xla, store_mla_cache
+from parallax_tpu.ops.mla import (
+    mla_ragged_attention_xla,
+    mla_row_width,
+    store_mla_cache,
+)
 from parallax_tpu.ops.sampling import row_gumbel, sample_tokens
 from parallax_tpu.runtime.engine import EngineConfig, StageEngine
 from parallax_tpu.runtime.pipeline import InProcessPipeline
@@ -128,7 +132,7 @@ def test_mla_fused_parity_and_append():
     lat = jnp.asarray(rng.normal(size=(S, r)), jnp.float32)
     kpe = jnp.asarray(rng.normal(size=(S, dr)), jnp.float32)
     cache = jnp.asarray(
-        rng.normal(size=(num_pages, PAGE, 1, r + dr)), jnp.float32
+        rng.normal(size=(num_pages, PAGE, mla_row_width(r, dr))), jnp.float32
     )
     out, cache_f = mla_fused_decode_pallas(
         ql, qp, lat, kpe, cache, lens, pages, slot,
@@ -393,7 +397,7 @@ def test_mla_fused_block_edges():
     qp = jnp.asarray(rng.normal(size=(s, hq, dr)), jnp.float32)
     lat = jnp.asarray(rng.normal(size=(s, r)), jnp.float32)
     kpe = jnp.asarray(rng.normal(size=(s, dr)), jnp.float32)
-    cache = _poisoned(rng, (num_pages, PAGE, 1, r + dr))
+    cache = _poisoned(rng, (num_pages, PAGE, mla_row_width(r, dr)))
     out, cache_f = mla_fused_decode_pallas(
         ql, qp, lat, kpe, cache, lens, pages, slot,
         sm_scale=0.17, kv_lora_rank=r, interpret=True,

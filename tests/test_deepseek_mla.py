@@ -59,7 +59,8 @@ def test_config_detects_mla_and_moe():
     assert CONFIG.moe.num_experts == 8
     assert not CONFIG.is_moe_layer(0)     # first_k_dense_replace=1
     assert CONFIG.is_moe_layer(1)
-    assert CONFIG.kv_bytes_per_token_per_layer() == 2 * (32 + 8)
+    # One row of 32 + 8 values, held in whole 128-value lane tiles.
+    assert CONFIG.kv_bytes_per_token_per_layer() == 2 * 128
 
 
 @pytest.fixture(scope="module")

@@ -72,7 +72,8 @@ def test_config_detects_dsa():
     assert CONFIG.dsa.indexer_types == ("full",) * 3
     assert CONFIG.dsa.indexer_rope_traditional  # DeepSeek default
     # index cache adds to the per-token KV budget
-    assert CONFIG.kv_bytes_per_token_per_layer() == 2 * (32 + 8 + 32)
+    # The latent row in whole lane tiles (128), the index key beside it.
+    assert CONFIG.kv_bytes_per_token_per_layer() == 2 * (128 + 32)
 
 
 def test_glm_dsa_defaults():

@@ -389,6 +389,57 @@ def _refused_fused_gqa_head_dim_64(dev):
     )
 
 
+def test_latent_decode_compiles_for_v5e(v5e):
+    """The streamed latent decode kernel at A.X-K1's shapes (PR 51): 64
+    heads, latent rank 512 + 64 rope in rows of 640, 128 rows, 64 pages a
+    row (``--max-model-len 4096``), and the pool's ~9,600 pages; the
+    cache goes to Mosaic as XLA lays it out, with no re-laid copy."""
+    from parallax_tpu.ops.mla import mla_row_width
+    from parallax_tpu.ops.mla_pallas import mla_decode_attention_pallas
+
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    s, hq, rank, rope, pages = 128, 64, 512, 64, 9600
+    width = mla_row_width(rank, rope)
+    assert width == 640
+    fn = functools.partial(
+        mla_decode_attention_pallas, sm_scale=192 ** -0.5,
+        kv_lora_rank=rank, interpret=False,
+    )
+    compiled = jax.jit(fn).lower(
+        a((s, hq, rank)), a((s, hq, rope)), a((pages, PAGE, width)),
+        a((s,), jnp.int32), a((s, 64), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_expert_gmm_compiles_for_v5e(v5e):
+    """The routed experts' grouped matmuls at A.X-K1's widths and the
+    tiles ``moe._gmm_tiling`` picks: 128 rows x 8 pairs, 12 held experts
+    of 7168 x 2048, the stacks contracted on their last axis."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from parallax_tpu.models.moe import _gmm_tiling
+
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    h, inter, held, pairs = 7168, 2048, 12, 1024
+    assert _gmm_tiling(pairs, h, inter) == (128, 7168, 256)
+    assert _gmm_tiling(pairs, inter, h) == (128, 2048, 1024)
+    # 8 rows of the reference's replay: 64 pairs in one tile of 64.
+    assert _gmm_tiling(64, h, inter)[0] == 64
+    for m in (pairs, 64):
+        for k, n in ((h, inter), (inter, h)):
+            _compile(
+                functools.partial(gmm, transpose_rhs=True,
+                                  tiling=_gmm_tiling(m, k, n)),
+                a((m, k)), a((held, n, k)), a((held,), jnp.int32),
+            )
+
+
 def _refused_fused_mla(dev):
     """DeepSeek-V2-Lite geometry: 16 heads, latent rank 512 + 64 rope."""
     from parallax_tpu.ops.decode_fused_pallas import mla_fused_decode_pallas
@@ -402,7 +453,7 @@ def _refused_fused_mla(dev):
         interpret=False,
     ), (
         a((s, hq, rank)), a((s, hq, rope)), a((s, rank)), a((s, rope)),
-        a((NUM_PAGES, PAGE, 1, rank + rope)), a((s,), jnp.int32),
+        a((NUM_PAGES, PAGE, 640)), a((s,), jnp.int32),
         a((s, PAGES_PER_SEQ), jnp.int32), a((s,), jnp.int32),
     )
 
@@ -446,11 +497,9 @@ REFUSED = {
         _refused_fused_gqa_head_dim_64,
         "The last dim size is not 128 in original base memref",
     ),
-    "fused-mla": (
-        _refused_fused_mla,
-        r"Slice shape along dimension 1 must be aligned to tiling \(2\), "
-        "but is 1",
-    ),
+    # The one-row append: a (1, 640) block of the [S, 640] rows (and
+    # behind it a one-row DMA into a [page, 640] tile of 16 rows).
+    "fused-mla": (_refused_fused_mla, _TILE_8_128),
     "split-dsa-indexer": (
         functools.partial(_refused_indexer, kind="dsa", fused=False),
         _TILE_8_128,
