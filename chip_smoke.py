@@ -524,6 +524,14 @@ def drive_one_chip(srv: Server, sizes: dict, rehearse: bool) -> dict:
     for path in ("prefill", "decode", "multistep"):
         check(dispatch.get(f"{want_impl}/{path}", 0) > 0,
               f"no {path} dispatch on the fused kernels", dispatch=dispatch)
+    # The windows' sampler, chosen apart from the attention family: the
+    # seeded temperature + top_k requests drew through the sort-free
+    # kernel, and no window sorted.
+    windows = kernel["window_sampler_dispatch_total"]
+    check(kernel["window_sampler"] == want_impl
+          and windows.get(want_impl, 0) > 0 and "sort" not in windows,
+          "a decode window's sampler was not the sort-free kernel",
+          kernel=kernel)
     check(not any(k.startswith("xla/") for k in dispatch),
           "an XLA-attention dispatch ran", dispatch=dispatch)
     requests = status["goodput"]["requests"]
@@ -870,24 +878,31 @@ def child_kernels(out_path: str, rehearse: bool) -> None:
 
     # Sampler: greedy, plain temperature, and top-k rows (k = 1, mid,
     # the fused bound), seeded and unseeded — draws must be identical.
-    s = 8
-    logits = jnp.asarray(rng.standard_normal((s, vocab), dtype=np.float32))
-    temp = jnp.asarray([0.0, 0.7, 1.0, 0.8, 1.3, 0.0, 0.9, 1.0], jnp.float32)
-    top_k = jnp.asarray([0, 0, 1, 20, 64, 5, 2, 0], jnp.int32)
-    seeds = jnp.asarray([-1, 7, 8, -1, 9, -1, 10, 11], jnp.int32)
-    steps = jnp.arange(s, dtype=jnp.int32)
-    key = jax.random.key(0)
-    want = sample_tokens(
-        logits, key, temp, top_k, jnp.ones((s,), jnp.float32),
-        jnp.zeros((s,), jnp.float32), seeds=seeds, out_steps=steps,
-    )
-    got = fused_sample_topk_pallas(
-        logits, row_gumbel(key, s, vocab, seeds, steps), temp, top_k,
-        interpret=interpret,
-    )
-    if not bool(jnp.array_equal(got, want)):
-        raise SystemExit(f"sampler: {got.tolist()} != {want.tolist()}")
-    results["sampler"] = {"tokens": got.tolist()}
+    # At the served vocabulary in the smallest bucket, and at the A.X-K1
+    # cell's window (128 rows of 20,480 logits: a latent-attention
+    # stage's windows take this sampler too).
+    row_temp = [0.0, 0.7, 1.0, 0.8, 1.3, 0.0, 0.9, 1.0]
+    row_top_k = [0, 0, 1, 20, 64, 5, 2, 0]
+    row_seeds = [-1, 7, 8, -1, 9, -1, 10, 11]
+    wide = (16, 320) if rehearse else (128, 20480)
+    for tag, (s, v) in (("sampler", (8, vocab)), ("sampler_wide", wide)):
+        logits = jnp.asarray(rng.standard_normal((s, v), dtype=np.float32))
+        temp = jnp.asarray(np.resize(row_temp, s), jnp.float32)
+        top_k = jnp.asarray(np.resize(row_top_k, s), jnp.int32)
+        seeds = jnp.asarray(np.resize(row_seeds, s), jnp.int32)
+        steps = jnp.arange(s, dtype=jnp.int32)
+        key = jax.random.key(0)
+        want = sample_tokens(
+            logits, key, temp, top_k, jnp.ones((s,), jnp.float32),
+            jnp.zeros((s,), jnp.float32), seeds=seeds, out_steps=steps,
+        )
+        got = fused_sample_topk_pallas(
+            logits, row_gumbel(key, s, v, seeds, steps), temp, top_k,
+            interpret=interpret,
+        )
+        if not bool(jnp.array_equal(got, want)):
+            raise SystemExit(f"{tag}: {got.tolist()} != {want.tolist()}")
+        results[tag] = {"rows": s, "vocab": v, "tokens": got.tolist()[:8]}
 
     stats = dev.memory_stats() or {}
     with open(out_path, "w") as f:

@@ -123,13 +123,17 @@ GATE_TABLE: tuple[Gate, ...] = (
     ),
     Gate(
         feature="decode_fused",
-        marker="decode-fused sampling disabled",
+        marker="fused window sampler disabled",
         doc="docs/kernels.md",
         reason="top-p/min-p and top_k beyond FUSED_SAMPLE_TOPK_MAX need "
-               "the sort-based sampler; logits features (penalties, "
-               "logprobs, grammar, logit_bias) now run in-window as "
-               "scan-carry state and no longer downshift; fused "
-               "attention stays active",
+               "the sort-based sampler, for the whole batch of the "
+               "window; logits features (penalties, logprobs, grammar, "
+               "logit_bias) run in-window as scan-carry state and do "
+               "not downshift. The window's sampler is chosen apart "
+               "from the attention family "
+               "(ops/kernel_select.resolve_window_sampler_fused): fused "
+               "attention may or may not be on beside it and is not "
+               "touched",
     ),
     Gate(
         feature="decode_fused",

@@ -35,6 +35,7 @@ STEP_BATCH_TOKENS = "parallax_step_batch_tokens"
 QUEUE_DEPTH = "parallax_queue_depth"
 RUNNING_REQUESTS = "parallax_running_requests"
 ATTN_KERNEL_DISPATCH_TOTAL = "parallax_attn_kernel_dispatch_total"
+WINDOW_SAMPLER_DISPATCH_TOTAL = "parallax_window_sampler_dispatch_total"
 
 # -- the host's phases of a visit (obs/trace.py host_span; engine.py,
 # backend/serve.py). Each is the duration of the span of the same name.
@@ -308,6 +309,11 @@ HELP: dict[str, str] = {
     ),
     ATTN_KERNEL_DISPATCH_TOTAL: (
         "Engine dispatches by attention kernel implementation"
+    ),
+    WINDOW_SAMPLER_DISPATCH_TOTAL: (
+        "Plain K-step decode windows dispatched, by the sampler their "
+        "program holds: pallas-fused (sort-free kernel), sort "
+        "(full-vocabulary sort), argmax (every row greedy)"
     ),
     EVA_ROLLOVER_MS: (
         "Milliseconds of host work per EVA window rollover (release of "

@@ -1,6 +1,6 @@
 """Kernel-selection policy shared by every attention-family op.
 
-One place answers the three questions the ops facades
+One place answers the questions the ops facades
 (``ops/attention.py``, ``ops/mla.py``, ``ops/dsa.py``, ``ops/msa.py``)
 used to answer each for themselves:
 
@@ -13,6 +13,11 @@ used to answer each for themselves:
   means auto (on on TPU, off elsewhere), ``True`` forces the fused
   kernels even off-TPU (they then run in Pallas interpret mode — the CI
   parity/microbench path), ``False`` pins the split dispatch chain.
+- ``resolve_window_sampler_fused(flag, use_pallas)`` — which sampler a
+  K-step decode window compiles: the sort-free Pallas sampler or the
+  sort. Its own decision: the sampler kernel reads logits and sampling
+  parameters and needs nothing of the attention kernels, so a model
+  whose fused attention does not lower still gets it on a TPU.
 
 The impl names returned by :func:`decode_attn_impl` are the canonical
 labels for the ``parallax_attn_kernel_dispatch_total{impl,path}``
@@ -32,6 +37,11 @@ logger = get_logger(__name__)
 IMPL_FUSED = "pallas-fused"
 IMPL_SPLIT = "pallas-split"
 IMPL_XLA = "xla"
+# The decode window's sampler (``window_sampler_impl``): IMPL_FUSED, the
+# full-vocabulary sort of ``ops/sampling.sample_tokens``, or neither (a
+# window whose rows are all greedy takes the argmax).
+IMPL_SORT = "sort"
+IMPL_ARGMAX = "argmax"
 
 _warned_non_tpu_fused = False
 _warned_auto_off = False
@@ -128,6 +138,22 @@ def resolve_decode_fused(decode_fused: bool | None, config=None) -> bool:
     return bool(decode_fused)
 
 
+def resolve_window_sampler_fused(
+    decode_fused: bool | None, use_pallas: bool | None
+) -> bool:
+    """Whether a K-step decode window's sampled rows may take the
+    sort-free Pallas sampler (``fused_sample_topk_pallas``) in place of
+    ``sample_tokens``' sort. The same ``EngineConfig.decode_fused`` flag
+    forces it (True: anywhere, interpret mode off a TPU) or pins the
+    sort (False); None = auto is decided by what the sampler kernel
+    itself needs — a TPU backend with Pallas not pinned off — and not by
+    :func:`fused_lowering_gap`, which speaks of the attention kernels
+    only. Off a TPU auto keeps the XLA sampler."""
+    if decode_fused is None:
+        return tpu_available() and resolve_use_pallas(use_pallas)
+    return bool(decode_fused)
+
+
 def resolve_prefill_fused(prefill_fused: bool | None, config=None) -> bool:
     """Engine-level fused-prefill choice, mirroring
     :func:`resolve_decode_fused`: None = auto-on-TPU; True forces the
@@ -180,6 +206,13 @@ def prefill_attn_impl(
     if resolve_use_pallas(use_pallas):
         return IMPL_SPLIT
     return IMPL_XLA
+
+
+def window_sampler_impl(fused: bool) -> str:
+    """The impl label of the sampler a stage's decode windows compile
+    for rows the fused sampler serves (greedy, plain temperature,
+    ``top_k`` up to ``FUSED_SAMPLE_TOPK_MAX``)."""
+    return IMPL_FUSED if fused else IMPL_SORT
 
 
 def spec_window_impl(use_pallas: bool | None) -> str:

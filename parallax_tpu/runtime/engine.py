@@ -237,12 +237,16 @@ class EngineConfig:
     # is kernel-only. None (default) = auto: on on TPU, off elsewhere
     # (the XLA reference path stays the numerics oracle). True forces
     # the fused kernels anywhere — off-TPU they run in Pallas interpret
-    # mode (the CI parity/microbench configuration). Rows needing
-    # top-p/min-p (and the per-step host-sampling features: penalties,
-    # logprobs, grammar, logit_bias) keep the split sampler; non-TPU
-    # auto keeps XLA — both fallbacks are registered gates
-    # (analysis/gates.py) and visible in /status `kernel` and the
-    # parallax_attn_kernel_dispatch_total{impl,path} counter.
+    # mode (the CI parity/microbench configuration). The window's
+    # sampler is its own decision under the same flag
+    # (kernel_select.resolve_window_sampler_fused): auto takes the
+    # sort-free sampler on any TPU with Pallas not pinned off, also for
+    # a model whose fused attention kernels do not lower. A batch with
+    # a top-p/min-p/large-top-k row keeps the sort; non-TPU auto keeps
+    # XLA — both fallbacks are registered gates (analysis/gates.py)
+    # and visible in /status `kernel` and the
+    # parallax_attn_kernel_dispatch_total{impl,path} and
+    # parallax_window_sampler_dispatch_total{impl} counters.
     decode_fused: bool | None = None
     # Fused prefill kernel (ops/prefill_fused_pallas.py, docs/kernels.md):
     # multi-token ragged batches (prefill, chunked prefill, mixed) run
@@ -932,15 +936,16 @@ class StageEngine:
             or cfg_m.use_attention_sinks
         )
         # Fused decode kernels (EngineConfig.decode_fused, None = auto on
-        # TPU): decode batches compile the fused variant (KV append inside
-        # the Pallas attention kernel + sort-free fused sampling); the
-        # impl label feeds /status and the kernel-dispatch counter.
+        # TPU): decode batches compile the fused attention variant (KV
+        # append inside the Pallas attention kernel); the impl label
+        # feeds /status and the kernel-dispatch counter.
         from parallax_tpu.ops.kernel_select import (
             decode_attn_impl,
             prefill_attn_impl,
             resolve_decode_fused,
             resolve_prefill_fused,
             resolve_use_pallas,
+            resolve_window_sampler_fused,
         )
         from parallax_tpu.ops.kernel_select import (
             IMPL_SPLIT as _IMPL_SPLIT,
@@ -952,6 +957,12 @@ class StageEngine:
         )
         self._attn_impl = decode_attn_impl(
             self._decode_fused, model.use_pallas
+        )
+        # The decode window's sampler, decided apart from the attention
+        # family: the sort-free sampler needs a TPU (or the forced
+        # interpret mode), not a model whose fused attention lowers.
+        self._window_sampler_fused = resolve_window_sampler_fused(
+            self.cfg.decode_fused, model.use_pallas
         )
         # Fused prefill (EngineConfig.prefill_fused, None = auto on TPU):
         # multi-token ragged batches run the in-kernel-append flash
@@ -2081,6 +2092,15 @@ class StageEngine:
         self._kernel_lock = make_lock("engine.kernel_counts")
         with self._kernel_lock:
             self._kernel_counts: dict[tuple[str, str], int] = {}
+            self._sampler_counts: dict[str, int] = {}
+        # Its neighbour: which sampler each plain K-step decode window
+        # compiled in (pallas-fused / sort / argmax), so a scrape tells
+        # how many windows sorted the vocabulary.
+        self._c_window_sampler = reg.counter(
+            mnames.WINDOW_SAMPLER_DISPATCH_TOTAL,
+            mnames.help_text(mnames.WINDOW_SAMPLER_DISPATCH_TOTAL),
+            labelnames=("stage", "impl"),
+        )
         # Speculative decoding observability (docs/decode_loop.md): how
         # many tokens each proposal source staged, how many survived
         # verification, and how long proposing took — the operator's
@@ -2344,18 +2364,40 @@ class StageEngine:
         with self._kernel_lock:
             self._kernel_counts[key] = self._kernel_counts.get(key, 0) + 1
 
+    def _count_window_sampler(self, sampled: bool, fused: bool) -> None:
+        """One plain decode window dispatched with the sampler its
+        program holds: the sort-free kernel, the sort, or (every row
+        greedy) neither."""
+        from parallax_tpu.ops.kernel_select import (
+            IMPL_ARGMAX,
+            window_sampler_impl,
+        )
+
+        impl = window_sampler_impl(fused) if sampled else IMPL_ARGMAX
+        self._c_window_sampler.labels(
+            stage=self._obs_stage, impl=impl
+        ).inc()
+        with self._kernel_lock:
+            self._sampler_counts[impl] = (
+                self._sampler_counts.get(impl, 0) + 1
+            )
+
     def kernel_dispatch_summary(self) -> dict:
         """The ``kernel`` payload for /status, heartbeats and
-        /cluster/status: the active decode impl + per-(impl, path)
-        dispatch counts, so a silent fallback to the split or XLA path
-        is operator-visible."""
+        /cluster/status: the active decode impl, the decode window's
+        sampler + per-(impl, path) dispatch counts, so a silent fallback
+        to the split or XLA path, or to the sort, is operator-visible."""
         from parallax_tpu.ops.decode_fused_pallas import (
             decode_pages_per_block,
         )
-        from parallax_tpu.ops.kernel_select import fused_interpret
+        from parallax_tpu.ops.kernel_select import (
+            fused_interpret,
+            window_sampler_impl,
+        )
 
         with self._kernel_lock:
             counts = dict(self._kernel_counts)
+            sampler_counts = dict(self._sampler_counts)
         # What the fused decode kernel's page stream runs at: pages a
         # block, derived from the page one device holds of the first
         # paged cache (None with the fused kernels off).
@@ -2372,6 +2414,12 @@ class StageEngine:
             "impl": self._attn_impl,
             "decode_fused": self._decode_fused,
             "decode_pages_per_block": pages_per_block,
+            # What a decode window's sampled rows draw through where the
+            # batch allows it (greedy / temperature / bounded top_k):
+            # decided apart from ``decode_fused``.
+            "window_sampler": window_sampler_impl(
+                self._window_sampler_fused
+            ),
             # The stage's share of its routed experts, where it has any.
             **self._expert_share,
             "prefill_impl": self._prefill_impl,
@@ -2379,13 +2427,17 @@ class StageEngine:
             # Fused kernels running in the Pallas interpreter (a forced
             # off-TPU configuration), not compiled by Mosaic.
             "interpret": (
-                (self._decode_fused or self._prefill_fused)
+                (self._decode_fused or self._prefill_fused
+                 or self._window_sampler_fused)
                 and fused_interpret()
             ),
             "dispatch_total": {
                 f"{impl}/{path}": n
                 for (impl, path), n in sorted(counts.items())
             },
+            "window_sampler_dispatch_total": dict(
+                sorted(sampler_counts.items())
+            ),
         }
 
     def _count_spec_proposed(self, source: str, n: int,
@@ -2515,15 +2567,16 @@ class StageEngine:
         }
 
     def _warn_split_sampling(self, reason: str) -> None:
-        """Warn-once gate site: fused decode is active but this batch's
-        rows force the split (sort-based / host-side) sampler. Fused
-        attention still runs; only the sampling fusion is lost."""
+        """Warn-once gate site: the stage's windows take the sort-free
+        sampler but this batch's rows force the sort-based one. The
+        attention kernels, fused or not, are not touched."""
         if self._warned_split_sampling:
             return
         self._warned_split_sampling = True
         logger.warning(
-            "decode-fused sampling disabled: %s rows force the split "
-            "sampler (fused attention kernels stay active)", reason,
+            "fused window sampler disabled: %s rows force the sort-based "
+            "sampler for their batch (attention kernels unchanged)",
+            reason,
         )
 
     def sample_request_spans(self, rate: float | None) -> None:
@@ -2736,9 +2789,9 @@ class StageEngine:
         flip on ulp-level fusion differences). Unseeded rows draw from
         the window key folded with the scan step and row index.
 
-        ``fused_sample=True`` (decode_fused engines, every sampled row
-        greedy or plain temperature/top-k) swaps the sort-based sampler
-        for the sort-free fused Pallas kernel
+        ``fused_sample=True`` (``_window_sampler_fused`` engines, every
+        sampled row greedy or plain temperature/top-k) swaps the
+        sort-based sampler for the sort-free fused Pallas kernel
         (``decode_fused_pallas.fused_sample_topk_pallas``). The gumbel
         noise comes from the SAME ``ops/sampling.row_gumbel`` source the
         XLA sampler consumes, so fused-on and fused-off draws are
@@ -3636,10 +3689,10 @@ class StageEngine:
         # kernel's threshold extraction is O(top_k * vocab) — a huge k
         # would cost more than the sort it replaces). A top-p/min-p or
         # large-top-k row anywhere in the batch drops the whole batch
-        # to the split (sort-based) sampler — fused attention stays
-        # active (registered gate, analysis/gates.py).
+        # to the sort-based sampler — the attention kernels are not
+        # touched (registered gate, analysis/gates.py).
         fused_sample = False
-        if sampled and self._decode_fused:
+        if sampled and self._window_sampler_fused:
             from parallax_tpu.ops.decode_fused_pallas import (
                 FUSED_SAMPLE_TOPK_MAX,
             )
@@ -3673,6 +3726,7 @@ class StageEngine:
             eva=self._eva, eva_tables=True,
         )
         self._count_kernel_dispatch("multistep")
+        self._count_window_sampler(sampled, fused_sample)
         lora = self._lora_field(plan, inputs)
         if lora is not None:
             inputs = dataclasses.replace(inputs, lora=lora)

@@ -190,14 +190,17 @@ def test_fused_prefill_compiles_for_v5e(v5e, hq, hkv, t, s):
     )
 
 
-@pytest.mark.parametrize("s", [8, 64])
-def test_fused_sampler_compiles_for_v5e(v5e, s):
+# The A.X-K1 cell's window: 128 rows a step at a vocabulary of 20,480 (a
+# latent-attention stage, whose sampler is fused though its attention is
+# not: ``kernel_select.resolve_window_sampler_fused``).
+@pytest.mark.parametrize("s,vocab", [(8, VOCAB), (64, VOCAB), (128, 20480)])
+def test_fused_sampler_compiles_for_v5e(v5e, s, vocab):
     def a(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
     _compile(
         functools.partial(fused_sample_topk_pallas, interpret=False),
-        a((s, VOCAB), jnp.float32), a((s, VOCAB), jnp.float32),
+        a((s, vocab), jnp.float32), a((s, vocab), jnp.float32),
         a((s,), jnp.float32), a((s,), jnp.int32),
     )
 
