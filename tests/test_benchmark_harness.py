@@ -39,7 +39,8 @@ RED_SINCE_A_LATER_ENTRY = {
 for _stem in ("test_loadgen", "test_spec", "test_work", "test_trace_reduce",
               "test_idle_by_span", "test_jamba_work", "test_ouro_work",
               "test_axk1_work", "test_choice_ties", "test_axk1_cell",
-              "test_disturbance_readers", "test_setup_readers"):
+              "test_disturbance_readers", "test_setup_readers",
+              "test_moe_combine_reader"):
     _mod = importlib.import_module(f"benchmarks.tests.{_stem}")
     for _name, _obj in vars(_mod).items():
         if _name in RED_SINCE_A_LATER_ENTRY.get(_stem, ()):

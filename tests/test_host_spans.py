@@ -1129,8 +1129,13 @@ def test_the_endpoints_say_what_disturbed_the_loop():
     pause meter's two counters, and ``/cluster/status_json`` and
     ``/debug/device`` the key of a real compile beside JAX's own name
     for the function built."""
+    engine = build_engine()
+    # The registry is the process's, and an earlier test file on this
+    # worker may have left series under this stage label or another:
+    # hold this engine's own series, by what it adds.
+    before = engine._c_not_ahead["no_window_in_flight"].value
     fe, runner = build_local_frontend(
-        [build_engine()], SimpleTokenizer(), model_name="tiny")
+        [engine], SimpleTokenizer(), model_name="tiny")
 
     async def fn(client):
         resp = await client.post("/v1/completions", json={
@@ -1156,8 +1161,9 @@ def test_the_endpoints_say_what_disturbed_the_loop():
     misses = {k: v for k, v in series.items()
               if k.startswith(mnames.WINDOW_NOT_AHEAD_TOTAL + "{")}
     (first,) = [v for k, v in misses.items()
-                if 'reason="no_window_in_flight"' in k]
-    assert first >= 1
+                if 'reason="no_window_in_flight"' in k
+                and f'stage="{engine._obs_stage}"' in k]
+    assert first - before >= 1
     for payload in (status["device"], device):
         recent = payload["compile"]["recent"]
         assert 0 < len(recent) <= 16

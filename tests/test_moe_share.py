@@ -1,8 +1,10 @@
 """The expert layer told its share (``MoEConfig.experts_held`` /
 ``expert_offset``, ``models/moe.py``): the shares of a layer add up to
 the uncut layer, ``topk_method`` "none" is the plain top-k, the two
-counts against a hand count, and the loader keeps a stage's share of a
-whole layer's checkpoint. Fast, no torch: tier-1 runs these
+counts against a hand count, the loader keeps a stage's share of a
+whole layer's checkpoint, and the grouped-matmul path's movement between
+tokens and pairs around a stood-in ``gmm`` (PR 53). Fast, no torch:
+tier-1 runs these
 (``tests/test_moe.py`` is one of conftest's slow modules)."""
 
 import jax
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from parallax_tpu.config import normalize_config
+from parallax_tpu.config import MoEConfig, normalize_config
 from parallax_tpu.models.moe import moe_ffn, route_topk
 from parallax_tpu.models.registry import create_stage_model
 
@@ -261,3 +263,80 @@ def test_a_configuration_states_the_scales_of_its_own_seeded_draw():
     own["layers"][1]["mlp"]["experts"]["down_proj"] = (
         plain["layers"][1]["mlp"]["experts"]["down_proj"])
     jax.tree.map(np.testing.assert_array_equal, own, plain)
+
+
+def plain_gmm(lhs, rhs, group_sizes, transpose_rhs=False, tiling=None):
+    """``megablox.gmm``'s contract as one einsum: row ``r`` times the
+    matrix of the group it lies in; NaN in every row past the last
+    group, which the kernel never writes."""
+    assert transpose_rhs and lhs.shape[0] % tiling[0] == 0
+    ends = jnp.cumsum(group_sizes)
+    group = jnp.searchsorted(ends, jnp.arange(lhs.shape[0]), side="right")
+    out = jnp.einsum("mk,mnk->mn", lhs,
+                     rhs[jnp.minimum(group, rhs.shape[0] - 1)],
+                     preferred_element_type=jnp.float32)
+    return jnp.where((group < rhs.shape[0])[:, None], out, jnp.nan)
+
+
+# (routed experts, held, offset, what the selection is steered to)
+SHARES = {
+    "every-expert-held": (16, 16, 0, None),
+    "12-of-192-from-0": (192, 12, 0, None),
+    "a-share-no-row-selects": (192, 12, 24, "away"),
+    "every-pair-on-held-experts": (192, 12, 0, "onto"),
+}
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+@pytest.mark.parametrize("k", [2, 8])
+@pytest.mark.parametrize("t", [1, 8, 128, 300])
+def test_megablox_moves_tokens_to_pairs_and_back_as_the_fallback_does(
+        monkeypatch, t, k, share):
+    """``_moe_megablox``'s sort, gather, groups and combine around a
+    stood-in ``gmm``: equal to the masked loop in float32, finite though
+    every unwritten row is NaN, at a row count that is no multiple of 8
+    and at a K that is not 8; the counts of ``count_rows`` by hand."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    monkeypatch.setattr(megablox, "gmm", plain_gmm)
+    experts, held, offset, steer = SHARES[share]
+    h, i = 32, 16
+    moe = MoEConfig(num_experts=experts, num_experts_per_tok=k,
+                    moe_intermediate_size=i, scoring_func="sigmoid",
+                    topk_method="noaux_tc", routed_scaling_factor=2.5,
+                    experts_held=held, expert_offset=offset)
+    rng = np.random.default_rng(1000 * t + 10 * k + len(share))
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+
+    mine = (np.arange(experts) >= offset) & (np.arange(experts) < offset + held)
+    bias = {None: np.zeros(experts), "away": np.where(mine, -1e4, 0.0),
+            "onto": np.where(mine, 1e4, 0.0)}[steer]
+    p = {
+        "gate": {"weight": draw(experts, h),
+                 "e_score_correction_bias": jnp.asarray(bias, jnp.float32)},
+        "experts": {"gate_proj": draw(held, i, h) * h ** -0.5,
+                    "up_proj": draw(held, i, h) * h ** -0.5,
+                    "down_proj": draw(held, h, i) * i ** -0.5},
+    }
+    x = draw(t, h)
+    rows = jnp.asarray(rng.random(t) < 0.7)
+    want, want_counts = moe_ffn(x, p, moe, use_megablox=False,
+                                count_rows=rows)
+    got, got_counts = moe_ffn(x, p, moe, use_megablox=True, count_rows=rows)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+    _, ids = route_topk(x, p["gate"]["weight"], moe,
+                        bias=p["gate"]["e_score_correction_bias"])
+    landed = mine[np.asarray(ids)]                         # [T, K]
+    if steer is not None:
+        assert landed.sum() == {"away": 0, "onto": t * k}[steer]
+    elif experts > held and t >= 128:     # a share: some pairs, not all
+        assert 0 < landed.sum() < t * k
+    counted = np.asarray(ids)[np.asarray(rows)[:, None] & landed]
+    by_hand = [len(set(counted.tolist())), counted.size]
+    assert np.asarray(got_counts).tolist() == by_hand
+    assert np.asarray(want_counts).tolist() == by_hand

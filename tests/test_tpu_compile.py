@@ -443,6 +443,36 @@ def test_expert_gmm_compiles_for_v5e(v5e):
             )
 
 
+def test_expert_layer_compiles_for_v5e_with_no_scatter_over_its_rows(v5e):
+    """An expert layer's decode-sized call at the A.X-K1 cell's shape (128
+    rows x 8 pairs, hidden 7168, 12 of 192 experts held): sort, gather,
+    three ``gmm`` calls and the combine of ``[128 x 8, 7168]`` float32
+    rows (``moe._combine``) compile, and nothing in the program is a
+    scatter that writes ``[rows, hidden]``: a scatter-add applies its
+    1,024 updates one after another (PERF.md, PR 53)."""
+    from parallax_tpu.config import MoEConfig
+    from parallax_tpu.models.moe import moe_ffn
+
+    def a(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rows, h, inter, experts, held, k = 128, 7168, 2048, 192, 12, 8
+    moe = MoEConfig(num_experts=experts, num_experts_per_tok=k,
+                    moe_intermediate_size=inter, scoring_func="sigmoid",
+                    topk_method="none", routed_scaling_factor=2.5,
+                    experts_held=held)
+    p = {"gate": {"weight": a((experts, h))},
+         "experts": {"gate_proj": a((held, inter, h)),
+                     "up_proj": a((held, inter, h)),
+                     "down_proj": a((held, h, inter))}}
+    text = _compile(
+        lambda x, p: moe_ffn(x, p, moe, use_megablox=True), a((rows, h)), p)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 3
+    scatters = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(", text)
+    assert scatters, "the pattern no longer finds gmm's own small scatters"
+    assert f"{rows},{h}" not in scatters
+
+
 def _refused_fused_mla(dev):
     """DeepSeek-V2-Lite geometry: 16 heads, latent rank 512 + 64 rope."""
     from parallax_tpu.ops.decode_fused_pallas import mla_fused_decode_pallas
