@@ -3,8 +3,7 @@
 Detects *gate-shaped* log calls — ``logger.warning``/``logger.info``/
 ``warnings.warn`` whose message says a requested feature is being
 turned off or downgraded ("... disabled: ...", "... ignored ...",
-"forces/using the Python cache manager", "run(s) replicated") — and
-checks each against the reviewed table in
+"run(s) replicated") — and checks each against the reviewed table in
 :mod:`parallax_tpu.analysis.gates`:
 
 - a gate site with no matching table ``marker`` is a finding (an
@@ -29,8 +28,7 @@ from parallax_tpu.analysis.checkers import common
 from parallax_tpu.analysis.linter import Checker, Finding, Module
 
 GATE_MESSAGE_RE = re.compile(
-    r"(disabled[:\s]|\bignored\b|forces the Python|"
-    r"using the Python cache manager|runs? replicated)",
+    r"(disabled[:\s]|\bignored\b|runs? replicated)",
 )
 
 LOG_CALLEES = ("warning", "info", "warn")

@@ -2,15 +2,15 @@
 
 ROADMAP item 2 calls out a whole class of production surprises:
 "feature X silently off" — one config knob warn-disables another
-(host tier vs TP sharding, digests vs the native cache manager, SP vs
-unsupported attention) and nothing but a log line records the loss.
+(host tier vs TP sharding, SP vs unsupported attention) and nothing
+but a log line records the loss.
 This table makes every such gate an *explicit, reviewed* fact:
 
 - the config-gate checker scans the package for gate-shaped log
-  messages ("... disabled: ...", "... ignored ...", "forces the
-  Python cache manager", ...) and fails on any site not covered by a
-  ``marker`` below — adding a new gate without registering it here is
-  a lint error;
+  messages ("... disabled: ...", "... ignored ...", "run(s)
+  replicated") and fails on any site not covered by a ``marker``
+  below — adding a new gate without registering it here is a lint
+  error;
 - each entry must name a real ``EngineConfig`` field (or a CLI flag,
   spelled ``flag:--name``) — renaming the field orphans the entry and
   fails the pass;
@@ -87,18 +87,6 @@ GATE_TABLE: tuple[Gate, ...] = (
         marker="host KV tier disabled: unsupported KV layout",
         doc="docs/memory.md",
         reason="non-paged layouts and sub-page budgets cannot tier",
-    ),
-    Gate(
-        feature="host_cache_bytes",
-        marker="host KV tier enabled: using the Python cache manager",
-        doc="docs/memory.md",
-        reason="native manager does not model tier residency",
-    ),
-    Gate(
-        feature="cache_digests",
-        marker="prefix-digest publishing requested: using the Python",
-        doc="docs/scheduling.md",
-        reason="native tree evicts inside C with no per-node delta log",
     ),
     Gate(
         feature="sp_threshold",
@@ -226,15 +214,6 @@ GATE_TABLE: tuple[Gate, ...] = (
         reason="sharding one prompt's chunks needs an sp mesh axis with "
                "more than one chip; ordinary chunked prefill proceeds "
                "on the single chip",
-    ),
-    Gate(
-        feature="prefill_chunk_skip",
-        marker="prefill chunk skipping disabled",
-        doc="docs/kernels.md",
-        reason="A-B safety knob: turning skipping off forces the Python "
-               "cache manager so admission prefix reuse stays off too — "
-               "strictly-recompute-everything semantics for digest "
-               "comparison",
     ),
     Gate(
         feature="qos",

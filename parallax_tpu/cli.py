@@ -96,9 +96,8 @@ def build_parser() -> argparse.ArgumentParser:
                             "radix tree at chunk-planning time so a warm "
                             "prefix that landed after admission skips its "
                             "covered chunks (docs/kernels.md). "
-                            "--no-prefill-chunk-skip forces the Python "
-                            "cache manager with admission reuse off (A-B "
-                            "digest comparison)")
+                            "--no-prefill-chunk-skip turns admission "
+                            "reuse off too (A-B digest comparison)")
     serve.add_argument("--prefill-seq-parallel", action="store_true",
                        help="shard one long prompt's prefill across this "
                             "stage's chips over the mesh seq axis "
@@ -408,8 +407,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--prefill-chunk-skip", action=argparse.BooleanOptionalAction,
         default=True,
         help="prefix-aware chunk skipping at chunk-planning time "
-             "(docs/kernels.md); --no-prefill-chunk-skip forces the "
-             "Python cache manager with admission reuse off",
+             "(docs/kernels.md); --no-prefill-chunk-skip turns "
+             "admission reuse off too",
     )
     join.add_argument(
         "--compilation-cache-dir", default=None,

@@ -432,10 +432,10 @@ class WorkerNode:
         model_switched = self._maybe_switch_model(alloc.get("model_name"))
         # Cache-aware routing: the scheduler's join/reload replies carry
         # want_digests, and the engine must be built with digest tracking
-        # to honor it (the Python cache manager owns the delta log). A
-        # flip without a layer change — strategy switch via scheduler
-        # restart — still forces a rebuild; in-flight requests abort,
-        # exactly like a reallocation.
+        # to honor it (the radix tree keeps its delta log only when
+        # asked). A flip without a layer change — strategy switch via
+        # scheduler restart — still forces a rebuild; in-flight requests
+        # abort, exactly like a reallocation.
         want_digests = bool(alloc.get("want_digests"))
         digests_switched = want_digests != self.engine_config.cache_digests
         if digests_switched:

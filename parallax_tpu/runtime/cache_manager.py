@@ -66,7 +66,6 @@ def make_cache_manager(
     num_pages: int,
     enable_prefix_cache: bool = True,
     max_model_len: int = 32768,
-    use_native: bool | None = None,
     linear_state: bool = False,
     on_slot_free=None,
     host_tier=None,
@@ -74,70 +73,14 @@ def make_cache_manager(
     prefill_chunk_skip: bool = True,
     eva=None,
 ):
-    """CacheManager factory: the C++ manager (ONE ABI crossing per
-    admit/grow/release — ``native.NativeCacheManager``) by default — a
-    library that fails to build is an error — and pure Python with
-    ``PARALLAX_TPU_NO_NATIVE=1``. Native measures ~3-16x faster in the
-    production regime (full prefix cache under eviction pressure, growing
-    with prompt length); the Python manager remains the behavioral oracle
-    (differential fuzz in tests/test_native_cache.py).
-
-    A ``host_tier`` (:class:`runtime.host_cache.HostKVTier`) forces the
-    Python manager: tier residency lives on radix nodes and in the
-    preemption bookkeeping, which the native structures do not model.
-    ``track_digests`` (prefix-cache-aware routing) does too: the digest
-    delta log lives on the Python radix nodes — the native tree evicts
-    inside C with no per-node observability."""
-    import os
-
+    """The stage's page bookkeeper, chosen by the model:
+    :class:`EvaCacheManager` where ``eva`` (``ModelConfig.eva``) is set
+    — two kinds of entry in one pool, and pages that go back before a
+    request ends; the engine has switched prefix reuse and the host
+    tier off — and :class:`CacheManager` for every other model."""
     if eva is not None:
-        # Two kinds of entry in one pool and pages that go back before a
-        # request ends: the Python ``EvaCacheManager`` (the native
-        # structures model one append-only page list per request). The
-        # engine has switched prefix reuse and the host tier off.
         return EvaCacheManager(page_size, num_pages, eva,
                                max_model_len=max_model_len)
-    if use_native is None:
-        use_native = (
-            not os.environ.get("PARALLAX_TPU_NO_NATIVE")
-            and host_tier is None
-            and not track_digests
-        )
-    if track_digests and use_native:
-        logger.info(
-            "prefix-digest publishing requested: using the Python cache "
-            "manager (the native tree does not expose per-node evictions)"
-        )
-        use_native = False
-    if not prefill_chunk_skip and use_native:
-        # The native manager matches/pins inside C on admission; only the
-        # Python manager can keep inserting (digest parity) while
-        # declining to reuse. Registered gate (analysis/gates.py).
-        logger.info(
-            "prefill chunk skipping disabled: using the Python cache "
-            "manager (radix inserts still populate, admission reuse off)"
-        )
-        use_native = False
-    if host_tier is not None and not os.environ.get(
-        "PARALLAX_TPU_NO_NATIVE"
-    ):
-        # Operators should see the tradeoff they opted into: the tier
-        # buys OOM-free degradation at the cost of the native manager's
-        # faster admit/grow/release bookkeeping.
-        logger.info(
-            "host KV tier enabled: using the Python cache manager "
-            "(the native manager does not model tier residency)"
-        )
-    if use_native and host_tier is None:
-        from parallax_tpu import native
-
-        return native.NativeCacheManager(
-            page_size, num_pages,
-            enable_prefix_cache=enable_prefix_cache,
-            max_model_len=max_model_len,
-            linear_state=linear_state,
-            on_slot_free=on_slot_free,
-        )
     return CacheManager(
         page_size, num_pages, enable_prefix_cache=enable_prefix_cache,
         max_model_len=max_model_len, linear_state=linear_state,
@@ -194,8 +137,7 @@ def ns_salt(salts: dict[str, int], lora_id: str | None) -> int | None:
 
     KV contents depend on the LoRA adapter, so tenants must never
     prefix-hit each other's pages. XOR-salting the token stream keeps
-    its length (page alignment intact), fits the native backend's int32
-    tokens, and is identical for both radix implementations.
+    its length (page alignment intact) and the tokens in 31 bits.
     Cross-tenant collisions require an entire page of positionwise-
     colliding tokens between two distinct adapters' namespaces."""
     if lora_id is None:

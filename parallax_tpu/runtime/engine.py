@@ -26,7 +26,8 @@ from parallax_tpu.config import ModelConfig
 from parallax_tpu.models.base import BatchInputs, StageModel
 from parallax_tpu.ops.sampling import sample_tokens
 from parallax_tpu.runtime.batch import BucketSpec, assemble, default_buckets
-from parallax_tpu.runtime.cache_manager import CacheManager
+from parallax_tpu.runtime.cache_manager import make_cache_manager
+from parallax_tpu.runtime.host_cache import stage_host_tier
 from parallax_tpu.runtime.request import (
     IntermediateRequest,
     Request,
@@ -284,8 +285,7 @@ class EngineConfig:
     # requests to the replica already holding their prefix. Off by
     # default (zero per-insert work); workers enable it automatically
     # when the scheduler's join/heartbeat reply asks for digests
-    # (``want_digests``). Forces the Python cache manager — the native
-    # tree evicts inside C with no per-node observability.
+    # (``want_digests``).
     cache_digests: bool = False
     # Multi-tenant QoS spec (parallax_tpu/qos, docs/qos.md): "on" or a
     # key=value spec enables request classes, deadline-aware EDF
@@ -683,17 +683,11 @@ class StageEngine:
         # it would pick pages-only boundaries the downstream linear
         # stages can never resume from (no snapshot there), turning every
         # repeat prompt into a deterministic downstream abort.
-        from parallax_tpu.runtime.cache_manager import make_cache_manager
-
         hybrid_attention_only_head = (
             model.config.is_hybrid
             and model.is_first and not self._needs_state
             and not model.is_last
         )
-        # Host-DRAM KV tier: demotion target for radix eviction and
-        # preemption; transfers read self.kv LIVE (the step loop donates
-        # and replaces the arrays every dispatch).
-        self.host_tier = None
         # EVA models (``ModelConfig.eva``): summaries and exact entries
         # share the pool and a window's exact pages go back when it is
         # complete (cache_manager.EvaCacheManager, docs/memory.md "EVA").
@@ -711,46 +705,15 @@ class StageEngine:
                 "window over inside plain decode windows only",
             )
             self.cfg.speculative_tokens = 0
-        if self.cfg.host_cache_bytes > 0:
-            if self._eva is not None:
-                logger.info(
-                    "host KV tier disabled: EVA rows hold summary and "
-                    "exact pages that the tier's page images do not "
-                    "tell apart",
-                )
-            elif self._needs_state:
-                logger.warning(
-                    "host KV tier disabled: hybrid linear-state KV "
-                    "cannot be paged to host (recurrent state has no "
-                    "page-granularity image)",
-                )
-            elif mesh is not None and model.tp_size > 1:
-                logger.warning(
-                    "host KV tier disabled: TP-sharded KV transfers "
-                    "are not supported yet",
-                )
-            elif model.config.loop_passes > 1:
-                logger.warning(
-                    "host KV tier disabled: a looped stack keeps a "
-                    "page once a pass in every layer's array, and the "
-                    "tier's page images hold one place a layer",
-                )
-            else:
-                from parallax_tpu.runtime.host_cache import (
-                    tier_from_paged_kv,
-                )
-
-                self.host_tier = tier_from_paged_kv(
-                    self.cfg.host_cache_bytes,
-                    lambda: self.kv,
-                    lambda kv: setattr(self, "kv", kv),
-                    self.cfg.num_pages,
-                )
-                if self.host_tier is None:
-                    logger.warning(
-                        "host KV tier disabled: unsupported KV layout "
-                        "or budget below one page",
-                    )
+        # Host-DRAM KV tier: demotion target for radix eviction and
+        # preemption; transfers read self.kv LIVE (the step loop donates
+        # and replaces the arrays every dispatch).
+        self.host_tier = stage_host_tier(
+            self.cfg.host_cache_bytes, model, mesh,
+            lambda: self.kv,
+            lambda kv: setattr(self, "kv", kv),
+            self.cfg.num_pages,
+        )
         if self._eva is not None and self.cfg.enable_prefix_cache:
             logger.info(
                 "prefix cache disabled: an EVA row releases its exact "
@@ -1826,8 +1789,7 @@ class StageEngine:
         sig = self.kv_page_signature()
         if sig is None:
             return None
-        shared_fn = getattr(self.cache, "shared_prefix_tokens", None)
-        prefix = shared_fn(request.request_id) if shared_fn else 0
+        prefix = self.cache.shared_prefix_tokens(request.request_id)
         datas = [tier.pool.load(h) for h in handles]
         layers = [
             np.stack([d[i] for d in datas])
@@ -1852,8 +1814,7 @@ class StageEngine:
         shared prefix; the caller then falls back to re-prefill, which
         is always correct."""
         tier = self.host_tier
-        adopt = getattr(self.cache, "adopt_migrated", None)
-        if tier is None or adopt is None:
+        if tier is None:
             return False
         if image.signature != self.kv_page_signature():
             return False
@@ -1864,7 +1825,9 @@ class StageEngine:
         handles = tier.store_image(image.layers)
         if handles is None:
             return False
-        if not adopt(request, handles, image.prefix_tokens):
+        if not self.cache.adopt_migrated(
+            request, handles, image.prefix_tokens
+        ):
             tier.free(handles)
             return False
         request.num_computed_tokens = computed
@@ -1886,10 +1849,9 @@ class StageEngine:
 
     def cache_digest_payload(self, full: bool = False) -> dict | None:
         """Prefix-digest delta/snapshot for cache-aware routing heartbeats
-        (None when ``cfg.cache_digests`` is off or the manager does not
-        track digests — e.g. the native manager)."""
-        fn = getattr(self.cache, "digest_payload", None)
-        return fn(full=full) if fn is not None else None
+        (None when ``cfg.cache_digests`` is off or the prefix cache
+        is)."""
+        return self.cache.digest_payload(full=full)
 
     # -- observability (obs/: registry series, tracing, flight) -----------
 
@@ -2208,20 +2170,15 @@ class StageEngine:
         sched = self.scheduler
         self._g_queue.set(len(sched.wait_queue))
         self._g_running.set(len(sched.running))
-        num_pages = getattr(self.cache, "num_pages", 0)
-        free = getattr(self.cache, "num_free_pages", 0)
         self._g_occupancy.set(
-            round(1.0 - free / num_pages, 4) if num_pages else 0.0
+            round(1.0 - self.cache.num_free_pages / self.cache.num_pages, 4)
         )
-        stats = getattr(self.cache, "stats", None)
-        if stats is not None:
-            self._c_preempt.set_total(stats.preemptions)
-            self._c_resumes.set_total(stats.resumes)
-            self._c_kv_oom.set_total(stats.kv_oom_aborts)
-            self._c_evicted.set_total(stats.pages_evicted)
-            self._c_chunk_skip.set_total(
-                getattr(stats, "tokens_chunk_skipped", 0)
-            )
+        stats = self.cache.stats
+        self._c_preempt.set_total(stats.preemptions)
+        self._c_resumes.set_total(stats.resumes)
+        self._c_kv_oom.set_total(stats.kv_oom_aborts)
+        self._c_evicted.set_total(stats.pages_evicted)
+        self._c_chunk_skip.set_total(stats.tokens_chunk_skipped)
         if self._needs_state:
             total = (self._slot_alloc.num_slots
                      + self._prefix_slot_alloc.num_slots)

@@ -406,3 +406,52 @@ def tier_from_paged_kv(
     return HostKVTier(
         budget_bytes, page_nbytes, gather_fn, scatter_fn, low_watermark
     )
+
+
+def stage_host_tier(
+    budget_bytes: int,
+    model,
+    mesh,
+    get_kv: Callable[[], list],
+    set_kv: Callable[[list], None],
+    num_pages: int,
+) -> HostKVTier | None:
+    """The host tier of one engine stage (``model``: its ``StageModel``,
+    ``mesh``: its TP mesh or None), or None with the one reason logged:
+    no budget asked for (silent), a cache whose pages
+    :func:`tier_from_paged_kv` cannot image, or a budget below one
+    page. Every refusal is a registered gate (``analysis/gates.py``)."""
+    if budget_bytes <= 0:
+        return None
+    if model.config.eva is not None:
+        logger.info(
+            "host KV tier disabled: EVA rows hold summary and "
+            "exact pages that the tier's page images do not "
+            "tell apart",
+        )
+    elif model.has_linear_layers:
+        logger.warning(
+            "host KV tier disabled: hybrid linear-state KV "
+            "cannot be paged to host (recurrent state has no "
+            "page-granularity image)",
+        )
+    elif mesh is not None and model.tp_size > 1:
+        logger.warning(
+            "host KV tier disabled: TP-sharded KV transfers "
+            "are not supported yet",
+        )
+    elif model.config.loop_passes > 1:
+        logger.warning(
+            "host KV tier disabled: a looped stack keeps a "
+            "page once a pass in every layer's array, and the "
+            "tier's page images hold one place a layer",
+        )
+    else:
+        tier = tier_from_paged_kv(budget_bytes, get_kv, set_kv, num_pages)
+        if tier is None:
+            logger.warning(
+                "host KV tier disabled: unsupported KV layout "
+                "or budget below one page",
+            )
+        return tier
+    return None

@@ -266,8 +266,7 @@ class RadixPageCache:
         demotion follows SGLang HiCache's HBM->host hierarchy.
         """
         # Victim selection keeps the reference's iterative LRU-leaf
-        # discipline EXACTLY (the native impl is differentially fuzzed
-        # against it): pick the LRU unpinned device-leaf, detach it —
+        # discipline: pick the LRU unpinned device-leaf, detach it —
         # exposing its parent as the next candidate — and repeat. Only
         # the KV transfer is batched: one demoter call covers the whole
         # victim set (single staging gather + async D2H).

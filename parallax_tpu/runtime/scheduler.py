@@ -200,9 +200,8 @@ class Scheduler:
             # re-allocating a prompt. FCFS discipline is unchanged —
             # a resume that does not fit blocks admission like any
             # other head-of-queue request.
-            resume = getattr(self.cache, "resume_from_host", None)
             t0 = time.perf_counter()
-            if resume is None or not resume(req):
+            if not self.cache.resume_from_host(req):
                 return False
             del self.wait_queue[rid]
             self.admitted_total += 1
@@ -292,10 +291,8 @@ class Scheduler:
         requests."""
         if not pol.controller.active:
             return
-        preempt = getattr(self.cache, "preempt_to_host", None)
-        if preempt is None or getattr(self.cache, "host_tier", None) is None:
-            # No tier (or a manager without the preempt path, e.g. the
-            # native backend): enforcement can only hold admissions.
+        if self.cache.host_tier is None:
+            # No tier: enforcement can only hold admissions.
             pol.warn_no_tier_once()
             return
         for req in list(self.running.values()):
@@ -308,7 +305,7 @@ class Scheduler:
                 or getattr(req, "state_slot", None) is not None
             ):
                 continue
-            if not preempt(req):
+            if not self.cache.preempt_to_host(req):
                 continue   # host tier full: the request keeps running
             self._park(req)
             pol.count_park(req)
@@ -471,10 +468,8 @@ class Scheduler:
             # covered span is no longer a pure prefix swap. The guard is
             # race-free because on_batch_computed advances
             # num_computed_tokens at dispatch time, not completion.
-            extend = getattr(self.cache, "extend_prefix_match", None)
-            if (extend is not None
-                    and req.num_computed_tokens == req.num_cached_tokens):
-                if extend(req):
+            if req.num_computed_tokens == req.num_cached_tokens:
+                if self.cache.extend_prefix_match(req):
                     # parallax_prefill_tokens_skipped_total is collected
                     # pull-style from CacheStats (same shape as the
                     # preemption counters) — only the flight/trace event
@@ -741,9 +736,7 @@ class Scheduler:
     def _abort_on_oom(self, req: Request) -> None:
         logger.warning("decode OOM: aborting %s", req.request_id)
         req.abort("kv_oom")
-        stats = getattr(self.cache, "stats", None)
-        if stats is not None:
-            stats.kv_oom_aborts += 1
+        self.cache.stats.kv_oom_aborts += 1
         self._obs_event("kv_oom", req)
 
     def _obs_event(self, kind: str, req: Request, dur: float = 0.0) -> None:
@@ -786,8 +779,8 @@ class Scheduler:
         """
         if self.cache.ensure_capacity(req, new_total_tokens):
             return True
-        preempt = getattr(self.cache, "preempt_to_host", None)
-        if preempt is not None:
+        preempt = self.cache.preempt_to_host
+        if self.cache.host_tier is not None:
             skip: set[str] = set(exclude_scheduled or ())
             while True:
                 victim = self._preemption_victim(req, skip)

@@ -103,7 +103,7 @@ class StepTimingAggregator:
 class CacheStats:
     """Prefix-cache and memory-tier counters for one engine stage.
 
-    Owned by the stage's CacheManager (Python or native) and incremented
+    Owned by the stage's CacheManager and incremented
     on the admission/eviction/preemption paths; summarized per heartbeat
     for ``/cluster/status`` and per run for bench JSON via
     :func:`cache_stats_summary`.
@@ -127,23 +127,17 @@ class CacheStats:
 
 
 def cache_stats_summary(cache) -> dict | None:
-    """Heartbeat/status/bench payload for a CacheManager-like object;
-    None when it carries no stats (metrics never break serving)."""
-    stats = getattr(cache, "stats", None)
-    if stats is None:
-        return None
+    """Heartbeat/status/bench payload of a stage's ``CacheManager``;
+    None when it cannot be read (metrics never break serving)."""
     try:
+        stats = cache.stats
         admitted = stats.tokens_admitted
         hit = stats.tokens_hit_device + stats.tokens_hit_host
-        num_pages = getattr(cache, "num_pages", 0)
-        free = getattr(cache, "num_free_pages", 0)
         d = {
             "tokens_admitted": admitted,
             "tokens_hit_device": stats.tokens_hit_device,
             "tokens_hit_host": stats.tokens_hit_host,
-            "tokens_chunk_skipped": getattr(
-                stats, "tokens_chunk_skipped", 0
-            ),
+            "tokens_chunk_skipped": stats.tokens_chunk_skipped,
             "prefix_hit_rate": round(hit / admitted, 4) if admitted else 0.0,
             "host_hit_rate": (
                 round(stats.tokens_hit_host / admitted, 4) if admitted
@@ -153,14 +147,12 @@ def cache_stats_summary(cache) -> dict | None:
             "preemptions": stats.preemptions,
             "resumes": stats.resumes,
             "kv_oom_aborts": stats.kv_oom_aborts,
-            "page_occupancy": (
-                round(1.0 - free / num_pages, 4) if num_pages else 0.0
+            "page_occupancy": round(
+                1.0 - cache.num_free_pages / cache.num_pages, 4
             ),
-            "cached_pages": getattr(
-                getattr(cache, "prefix_cache", None), "num_cached_pages", 0
-            ),
+            "cached_pages": cache.prefix_cache.num_cached_pages,
         }
-        tier = getattr(cache, "host_tier", None)
+        tier = cache.host_tier
         if tier is not None:
             d.update(
                 host_pages=tier.num_host_pages,

@@ -491,7 +491,7 @@ def test_pressure_evicts_and_recomputes_and_the_host_tier_is_refused(caplog):
         def emit(self, record):
             seen.append(record.getMessage())
 
-    logger = logging.getLogger("parallax_tpu.runtime.engine")
+    logger = logging.getLogger("parallax_tpu.runtime.host_cache")
     handler = Grab()
     logger.addHandler(handler)
     try:

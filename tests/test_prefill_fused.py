@@ -3,9 +3,8 @@ interpret-mode parity against the XLA reference (ragged lengths, cached
 prefixes, page/chunk boundaries, sinks, sliding windows, soft caps,
 attend-only mode), engine-level bit-identity of prefill-fused on/off
 streams (greedy + seeded, sync + overlap, K=1 and K>1), prefix-aware
-chunk skipping (mid-prefill radix re-consult, native and Python
-managers), mid-prefill checkpoint park/restore, and the one-knob
-sequence-parallel prefill path."""
+chunk skipping (mid-prefill radix re-consult), mid-prefill checkpoint
+park/restore, and the one-knob sequence-parallel prefill path."""
 
 import dataclasses
 
@@ -263,13 +262,9 @@ def _run_chunk_skip_pair(model, params, *, chunk_skip, temp=0.0,
     return a.output_ids, b.output_ids, eng
 
 
-@pytest.mark.parametrize("manager", ["native", "python"])
 @pytest.mark.parametrize("temp,seed", [(0.0, None), (0.8, 31)],
                          ids=["greedy", "seeded"])
-def test_chunk_skip_recomputes_zero_covered_chunks(gqa_model, monkeypatch,
-                                                   manager, temp, seed):
-    if manager == "python":
-        monkeypatch.setenv("PARALLAX_TPU_NO_NATIVE", "1")
+def test_chunk_skip_recomputes_zero_covered_chunks(gqa_model, temp, seed):
     model, params = gqa_model
     a_on, b_on, eng_on = _run_chunk_skip_pair(
         model, params, chunk_skip=True, temp=temp, seed=seed)
@@ -285,8 +280,7 @@ def test_chunk_skip_recomputes_zero_covered_chunks(gqa_model, monkeypatch,
 
 def test_chunk_skip_radix_digests_identical(gqa_model, monkeypatch):
     """Skip on/off end with the SAME radix content: the published
-    prefix digests match block for block (cache_digests forces the
-    Python manager on both sides)."""
+    prefix digests match block for block."""
     model, params = gqa_model
     *_, eng_on = _run_chunk_skip_pair(
         model, params, chunk_skip=True, cache_digests=True)
